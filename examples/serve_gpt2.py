@@ -6,8 +6,11 @@ the slot batch, per-request sampling params, per-token streaming, and
 ``serve`` telemetry rows (TTFT/TPOT percentiles, queue depth, slot
 utilization) next to the run.
 
-    # random-weight smoke run (any machine, seconds on CPU)
-    python examples/serve_gpt2.py --requests 8 --slots 4
+    # random weights at the GPT-2 124M geometry (the flags' defaults)
+    python examples/serve_gpt2.py --bf16 --requests 8 --slots 4
+
+    # tiny random-weight smoke run (any machine, seconds on CPU)
+    python examples/serve_gpt2.py --small --requests 8 --slots 4
 
     # real GPT-2 124M weights from a local HF checkpoint
     python examples/serve_gpt2.py --init_hf /path/to/gpt2 \
@@ -34,14 +37,14 @@ def parse_args(argv=None):
                    help="LOCAL HF GPT-2 checkpoint dir/file to serve "
                    "(tpudist.interop conversion); default: random params")
     p.add_argument("--vocab_size", default=None, type=int,
-                   help="default: 50257 with --init_hf, else 256")
+                   help="default: 50257, or 256 with --small")
     p.add_argument("--seq_len", default=1024, type=int)
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
     p.add_argument("--small", action="store_true",
-                   help="tiny random geometry (128 wide, 2 deep) for a "
-                   "seconds-scale smoke run; implied without --init_hf")
+                   help="tiny random geometry (128 wide, 2 deep, byte "
+                   "vocab) for a seconds-scale smoke run")
     p.add_argument("--bf16", action="store_true")
     p.add_argument("--prompt", action="append", default=None,
                    help="comma-separated token ids; repeatable (one per "
@@ -94,9 +97,11 @@ def main(argv=None):
     from tpudist.models.gpt2 import GPT2
     from tpudist.serve import ServeEngine
     from tpudist.telemetry import TelemetrySink
+    from tpudist.utils.cache import place_compile_cache
 
-    small = args.small or not args.init_hf
-    vocab = args.vocab_size or (50257 if args.init_hf else 256)
+    place_compile_cache()
+    small = args.small
+    vocab = args.vocab_size or (256 if small else 50257)
     model = GPT2(
         vocab_size=vocab, max_seq_len=args.seq_len,
         hidden_dim=128 if small else args.hidden_dim,
@@ -178,6 +183,7 @@ def main(argv=None):
     for r in rids:
         print(f"request {r}: {len(engine.result(r))} tokens -> "
               f"{engine.result(r)}")
+    results = [engine.result(r) for r in rids]
     snap = engine.stats.snapshot()
     engine.close()
     sink.close()
@@ -198,7 +204,7 @@ def main(argv=None):
         )
         + f"serve telemetry: {sink.path}"
     )
-    return snap
+    return snap, results
 
 
 if __name__ == "__main__":

@@ -85,10 +85,6 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import time
 
     import jax.numpy as jnp

@@ -397,10 +397,6 @@ def _serve_demo(args):
 
 def main(argv=None):
     args = parse_args(argv)
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     if args.serve:
         return _serve_demo(args)
 
@@ -551,9 +547,9 @@ def main(argv=None):
         if args.device_cache:
             from tpudist.data.device_cache import DeviceCachedLoader
 
-            # staged HERE — before create_train_state compiles anything —
-            # so the one-time H2D rides the fast pre-compile link on
-            # remote attaches (docs/PERF.md §3b)
+            # staged HERE, at bring-up, before create_train_state
+            # compiles anything: no jitted work competes with the one-time
+            # H2D of the set
             loader = DeviceCachedLoader(
                 data, per_process_batch, mesh=mesh, sampler=sampler
             )

@@ -64,14 +64,28 @@ def test_extract_legs_salvages_torn_round_file_tail():
                     "vit_b16_train_images_per_sec_per_chip": 781.2}
 
 
-def test_extract_legs_from_committed_round_archives():
-    """The real archived rounds in the repo: every BENCH_r*.json tail must
-    yield at least one salvaged leg, and BENCH_SUMMARY.json all of them —
-    the seed command's actual inputs."""
+def test_extract_legs_from_committed_round_archives(tmp_path):
+    """The seed command's actual inputs: the committed BENCH_SUMMARY.json
+    must yield every leg, and a round archive — the driver's record of
+    one run: the command, its exit code and the LAST ~2000 chars of its
+    stdout — at least one salvaged leg, whether its tail starts mid-line
+    (the torn summary) or holds whole metric lines."""
     summary = REPO / "BENCH_SUMMARY.json"
     legs = bench_gate.extract_legs(summary.read_text())
     assert len(legs) >= 14
-    for rf in sorted(REPO.glob("BENCH_r0*.json")):
+    torn = ('FU / 0.60 (the width-climb bar)", "vs_baseline": 1.1728}, '
+            '"t5_small_tokens_per_sec_per_chip": {"value": 318621.02, '
+            '"unit": "total (enc+dec) tokens/sec/chip", '
+            '"vs_baseline": 0.3118}, "failed_leg_groups": []}\n')
+    whole = (json.dumps({"metric": "resnet50_train_images_per_sec_per_chip",
+                         "value": 2547.03, "unit": "images/sec/chip",
+                         "vs_baseline": 1.132}) + "\n")
+    for n, tail in ((1, torn), (2, whole)):
+        rf = tmp_path / f"BENCH_r0{n}.json"
+        rf.write_text(json.dumps(
+            {"n": n, "cmd": "python bench.py", "rc": 0, "tail": tail}
+        ))
+    for rf in sorted(tmp_path.glob("BENCH_r0*.json")):
         assert bench_gate.extract_legs(rf.read_text()), rf.name
 
 

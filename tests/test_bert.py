@@ -542,7 +542,7 @@ def test_classifier_accepts_attention_mask():
 def test_mlm_random_replacement_never_injects_mask_id():
     """The 10% random-token replacement draws from the vocab EXCLUDING
     [MASK]: a random draw landing on mask_id would create a target-bearing
-    position the model can only see as masked (ADVICE r2)."""
+    position the model can only see as masked."""
     rng = np.random.Generator(np.random.PCG64(24))
     tokens = rng.integers(0, 5, (512, 64)).astype(np.int32)
     # random_rate=1.0: every selected position becomes a random token, so a

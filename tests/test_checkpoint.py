@@ -23,17 +23,6 @@ from tpudist.train import (
     create_train_state, fit, lm_loss, make_train_step, state_shardings_of,
 )
 
-# jax 0.4.x XLA:CPU reproducibly ABORTS (kills the interpreter, not just
-# the test) stepping a donated jit on orbax-RESTORED arrays inside fit();
-# current jax runs these fine. A dead process costs every later test file
-# its run, so the restore-then-step tests are gated, not braved.
-_OLD_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-_OLD_JAX_RESUME = pytest.mark.skipif(
-    _OLD_JAX, reason="aborts jax 0.4.x XLA:CPU (donated step on restored "
-    "arrays); green on current jax"
-)
-
-
 def _tiny_state(mesh):
     model = resnet18(num_classes=10, small_inputs=True)
     tx = optax.adam(1e-3)
@@ -116,7 +105,6 @@ def _run_fit(tmp_path, epochs, ckpt_dir=None, every=0, tag="a"):
     )
 
 
-@_OLD_JAX_RESUME
 def test_fit_resume_matches_uninterrupted(tmp_path):
     """Train 1 epoch + resume for the 2nd ≡ training 2 epochs straight:
     identical per-step losses (deterministic init, sampler, and updates)."""
@@ -173,7 +161,6 @@ def test_loader_iter_from_skips_at_index_level():
         assert nb.call_count == 2
 
 
-@_OLD_JAX_RESUME
 def test_fit_resume_mid_epoch(tmp_path):
     """checkpoint_every mid-epoch: the resumed run skips exactly the
     consumed batches and finishes the epoch (step counts line up)."""

@@ -9,12 +9,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpudist import comm
 from tpudist import mesh as mesh_lib
 from tpudist.parallel import dp
-from tpudist.utils.compat import shard_map
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,4 @@
-"""The bundled real-image dataset behind CONVERGENCE.json
-(tpudist/data/digits.py)."""
+"""The bundled real-image dataset (tpudist/data/digits.py)."""
 
 import numpy as np
 import pytest

@@ -284,8 +284,8 @@ def test_fit_elastic_resumes_8_to_4(tmp_path, monkeypatch,
     trajectory tracking the uninterrupted 8-device reference (same data
     order; tolerance documented in the module docstring — the first
     resumed step, computed from bit-identical params, is pinned tight).
-    Cache-less via no_persistent_compile_cache: this jax 0.4.x XLA:CPU
-    aborts executing persistent-cache-LOADED executables on the donated-
+    Cache-less via no_persistent_compile_cache: XLA:CPU has been observed
+    to abort executing persistent-cache-LOADED executables on the donated-
     step-on-restored-arrays pattern (test_preempt_fit's documented
     wart)."""
     monkeypatch.delenv(GENERATION_ENV, raising=False)

@@ -1,13 +1,12 @@
 """Eval-path contracts (tpudist.train.evaluate/_padded_batches/fit):
 
 - constant-shape eval batches: a ragged val tail must NOT present a new
-  shape to jit (one compile per eval regardless of val-set size — per-shape
-  recompiles cost minutes each on a remote-compile attach);
+  shape to jit (one compile per eval regardless of val-set size);
 - the ``input_transform`` hook: a model trained through an in-graph
   transform (uint8 loader + device_normalize) must eval through the same
-  one (ADVICE r2);
+  one;
 - fit()'s delayed-metric flush: the last completed step's loss lands in the
-  history/TSV even when a later step or the loader raises (ADVICE r2).
+  history/TSV even when a later step or the loader raises.
 """
 
 import logging

@@ -7,7 +7,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import pytest
 from jax.sharding import PartitionSpec as P
 
 from tpudist import mesh as mesh_lib
@@ -34,13 +33,6 @@ def test_fsdp_spec_picks_largest_divisible_dim():
     assert fsdp_spec((256, 64), 1) == P()
 
 
-@pytest.mark.skipif(
-    tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x XLA:CPU GSPMD orders the BN/grad reductions "
-    "differently enough to breach the tolerance (2.4% loss divergence); "
-    "green on current jax, and the FSDP agreement certificate in "
-    "MULTICHIP_r05.json covers the real-hardware contract",
-)
 def test_fsdp_actually_shards_and_matches_dp():
     mesh = mesh_lib.create_mesh(mesh_lib.MeshConfig(data=2, fsdp=4))
     model = resnet18(num_classes=10, small_inputs=True)

@@ -23,7 +23,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SCRUBBED = (
     "JAX_PLATFORMS",
     "XLA_FLAGS",
-    "TPUDIST_FORCE_CPU",
     "_TPUDIST_DRYRUN_INPROC",
     "JAX_PLATFORM_NAME",
 )

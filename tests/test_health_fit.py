@@ -47,7 +47,7 @@ def _rows(path):
 
 def test_fit_health_stream_and_report(tmp_path):
     cfg = TelemetryConfig(aggregate_every=3, divergence_every=3,
-                          heartbeat_every=4)
+                          heartbeat_every=4, peak_flops=197e12)
     state, losses = _fit(_loader(), tmp_path, "HS", cfg)
     assert len(losses) == 12
 

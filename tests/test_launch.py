@@ -50,7 +50,8 @@ def test_env_and_argv_contract(tmp_path):
     try:
         r = _run_launcher(
             tmp_path,
-            ["--nproc_per_node=2", "--nnode=2", "--node_rank=1",
+            ["--nproc_per_node=2", "--emulate-devices=1", "--nnode=2",
+             "--node_rank=1",
              "--master_addr=10.0.0.1", "--master_port=29777"],
             body, ["--batch_size", "16"],
         )
@@ -81,7 +82,9 @@ def test_fail_fast_terminates_world(tmp_path):
         time.sleep(60)  # rank 0 would hang the world; launcher must kill it
     """)
     t0 = time.time()
-    r = _run_launcher(tmp_path, ["--nproc_per_node=2"], body)
+    r = _run_launcher(
+        tmp_path, ["--nproc_per_node=2", "--emulate-devices=1"], body
+    )
     assert r.returncode == 3
     assert time.time() - t0 < 30, "launcher did not fail fast"
 
@@ -91,7 +94,6 @@ def test_emulate_devices_env(tmp_path):
         import os, sys
         assert os.environ["JAX_PLATFORMS"] == "cpu"
         assert "--xla_force_host_platform_device_count=4" in os.environ["XLA_FLAGS"]
-        assert os.environ["TPUDIST_FORCE_CPU"] == "1"
     """)
     r = _run_launcher(
         tmp_path, ["--nproc_per_node=2", "--emulate-devices=4"], body
@@ -115,7 +117,11 @@ def test_max_restarts_recovers_transient_failure(tmp_path):
     """)
     os.environ["OUT_DIR"] = str(tmp_path)
     try:
-        r = _run_launcher(tmp_path, ["--nproc_per_node=2", "--max_restarts=2"], body)
+        r = _run_launcher(
+            tmp_path,
+            ["--nproc_per_node=2", "--emulate-devices=1", "--max_restarts=2"],
+            body,
+        )
     finally:
         del os.environ["OUT_DIR"]
     assert r.returncode == 0, r.stderr
@@ -133,7 +139,7 @@ def test_sigterm_suppresses_restart(tmp_path):
     script.write_text("import time; time.sleep(60)\n")
     p = subprocess.Popen(
         [sys.executable, "-m", "tpudist.launch", "--nproc_per_node=2",
-         "--max_restarts=5", str(script)],
+         "--emulate-devices=1", "--max_restarts=5", str(script)],
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
     )
@@ -161,6 +167,10 @@ def test_max_restarts_exhausted_reports_failure(tmp_path):
         import sys
         sys.exit(9)  # deterministic failure: every generation dies
     """)
-    r = _run_launcher(tmp_path, ["--nproc_per_node=2", "--max_restarts=1"], body)
+    r = _run_launcher(
+        tmp_path,
+        ["--nproc_per_node=2", "--emulate-devices=1", "--max_restarts=1"],
+        body,
+    )
     assert r.returncode == 9
     assert r.stderr.count("restarting") == 1

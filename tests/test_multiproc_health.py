@@ -11,33 +11,15 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
-# the 2-process children execute real cross-process SPMD programs, which
-# jax 0.4.x's XLA:CPU refuses outright ("Multiprocess computations aren't
-# implemented on the CPU backend" — the same container limitation that
-# gates test_multiproc_fit's world on this jax); green on current jax
-_OLD_JAX = tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5)
-
-pytestmark = [
-    pytest.mark.slow,  # subprocess world: cold-compiles its own jax programs
-    pytest.mark.skipif(
-        _OLD_JAX, reason="jax 0.4.x XLA:CPU cannot execute multi-process "
-        "computations (the children die in create_train_state/probe before "
-        "any health code runs); current jax runs the 2-process world"
-    ),
-]
+pytestmark = pytest.mark.slow  # subprocess world: cold-compiles its own jax programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _STRAGGLER_CHILD = textwrap.dedent("""
     import json, os, time
 
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
     import optax
@@ -93,10 +75,6 @@ _STRAGGLER_CHILD = textwrap.dedent("""
 _DIVERGENCE_CHILD = textwrap.dedent("""
     import json, os
 
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -106,7 +84,7 @@ _DIVERGENCE_CHILD = textwrap.dedent("""
     from tpudist import create_mesh, init_from_env
     from tpudist.parallel.dp import make_divergence_probe
     from tpudist.train import TrainState
-    from tpudist.utils.compat import shard_map
+    from jax import shard_map
 
     ctx = init_from_env()
     mesh = create_mesh()

@@ -26,10 +26,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CHILD = textwrap.dedent("""
     import json, os, sys
 
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
     import optax

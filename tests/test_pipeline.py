@@ -16,13 +16,6 @@ import pytest
 from tpudist import mesh as mesh_lib
 from tpudist.parallel.pp import pipeline_apply, stacked_param_shardings
 
-_OLD_JAX_PARTIAL_MANUAL = pytest.mark.skipif(
-    tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x XLA cannot SPMD-partition the partial-manual "
-    "shard_map composition (PartitionId UNIMPLEMENTED) when the auto "
-    "axes are real (>1); green on current jax — the PPxTP agreement "
-    "certificate in MULTICHIP_r05.json covers the hardware contract",
-)
 
 
 
@@ -49,7 +42,6 @@ def _sequential(params, x):
 
 
 @pytest.mark.parametrize("pipe,num_micro", [(2, 4), (4, 8)])
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipeline_forward_matches_sequential(pipe, num_micro):
     mesh = mesh_lib.create_mesh(
         mesh_lib.MeshConfig(data=8 // pipe, pipe=pipe)
@@ -65,7 +57,6 @@ def test_pipeline_forward_matches_sequential(pipe, num_micro):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipeline_grads_match_sequential():
     pipe, num_micro = 4, 4
     mesh = mesh_lib.create_mesh(mesh_lib.MeshConfig(data=2, pipe=pipe))
@@ -99,7 +90,6 @@ def test_pipeline_params_actually_sharded():
     assert shard.data.shape == (2, 8, 16)
 
 
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipelined_gpt2_train_step():
     """Full compiled train step on PipelinedGPT2 over a data×pipe mesh:
     pipe-sharded stacked blocks + Adam moments, loss finite and decreasing."""
@@ -137,7 +127,6 @@ _GPT2_CFG = dict(vocab_size=64, max_seq_len=16, hidden_dim=32, depth=4,
                  num_heads=4)
 
 
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipelined_gpt2_matches_plain_numerically():
     """PipelinedGPT2 computes the IDENTICAL function as same-seed plain
     GPT2: init-by-conversion (stack_gpt2_params) re-layouts the same param
@@ -170,7 +159,6 @@ def test_pipelined_gpt2_matches_plain_numerically():
     )
 
 
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipelined_train_step_agrees_with_dp():
     """Same-seed PP and DP train steps report the same loss — the local
     mirror of the dryrun's PP agreement leg."""
@@ -290,7 +278,6 @@ def test_pipeline_rejects_unknown_schedule():
         )
 
 
-@_OLD_JAX_PARTIAL_MANUAL
 def test_pipelined_gpt2_with_tensor_parallel_stages():
     """PP x TP: the pipe-manual shard_map leaves 'tensor' under GSPMD, so
     Megatron-sharded stage params must still compute the plain model's

@@ -12,8 +12,8 @@ error-feedback residual and the sharded Adam mirrors).
 
 Model choice: the BN-free tiny MLP of test_dp_equivalence, not a
 transformer — determinism is the point, and the resume runs cache-less
-(``no_persistent_compile_cache``): this container's jax 0.4.x XLA:CPU
-misexecutes cache-LOADED executables on exactly the donated-step-on-
+(``no_persistent_compile_cache``): XLA:CPU has been observed to
+misexecute cache-LOADED executables on exactly the donated-step-on-
 restored-arrays pattern the resume path is made of (the same documented
 wart the guard tests opt out for; fresh compiles of the MLP cost
 seconds)."""

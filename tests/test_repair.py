@@ -10,8 +10,8 @@ to a clean reference that simply never saw the skipped window (no
 stochastic consumer → the repair salt legally changes nothing).
 
 The fit drills run cache-less (``no_persistent_compile_cache``): the
-rollback path is donated-step-on-restored-arrays, the exact pattern this
-container's jax 0.4.x XLA:CPU misexecutes from cache-LOADED executables
+rollback path is donated-step-on-restored-arrays, the exact pattern
+XLA:CPU has been observed to misexecute from cache-LOADED executables
 (the documented wart test_preempt_fit opts out for)."""
 
 import json

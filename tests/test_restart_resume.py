@@ -17,10 +17,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CHILD = textwrap.dedent("""
     import json, os, sys
 
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -72,10 +68,6 @@ _CHILD = textwrap.dedent("""
 _RESILIENCE_CHILD = textwrap.dedent("""
     import json, os
 
-    if os.environ.get("TPUDIST_FORCE_CPU"):
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
     import jax
     import numpy as np
     import optax
@@ -240,19 +232,6 @@ def test_deterministic_crash_exhausts_restart_budget(tmp_path):
     assert "restart budget exhausted" in r.stderr
 
 
-# the 2-process children execute real cross-process SPMD programs, which
-# jax 0.4.x's XLA:CPU refuses outright — the same container limitation
-# that gates test_multiproc_fit/test_multiproc_health; green on current jax
-_OLD_JAX = tuple(
-    int(p) for p in __import__("jax").__version__.split(".")[:2]
-) < (0, 5)
-
-
-@pytest.mark.skipif(
-    _OLD_JAX, reason="jax 0.4.x XLA:CPU cannot execute multi-process "
-    "computations (the children die in create_train_state before any "
-    "resilience code runs); current jax runs the 2-process world"
-)
 def test_chaos_sigterm_two_process_world_resumes(tmp_path):
     """The preemption drill on a 2-process emulated world: every rank's
     chaos injector self-SIGTERMs at the same lockstep step boundary, both

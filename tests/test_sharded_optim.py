@@ -242,7 +242,7 @@ def test_remat_policy_memory_ordering():
     which is exactly why the stored-bytes contract is asserted at the
     autodiff layer where the policy actually acts.
     """
-    from tpudist.utils.compat import saved_residuals
+    from jax._src.ad_checkpoint import saved_residuals
 
     params, x = _mlp_params()
     saved = {}

@@ -187,7 +187,7 @@ def test_t5_incremental_decode_matches_full_forward():
 
 def test_t5_decode_overrun_fails_loudly():
     """Past max_decode_len the bias dynamic_slice and the cache update
-    would silently CLAMP (wrong biases, clobbered last slot — ADVICE r5):
+    would silently CLAMP (wrong biases, clobbered last slot):
     the decode path must fail loudly instead. Eager direct callers get a
     ValueError; a jitted decode loop gets NaN logits for the overrunning
     step (deterministic poison, not plausible-looking garbage)."""

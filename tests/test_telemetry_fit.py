@@ -7,6 +7,7 @@ when telemetry is off."""
 import json
 import pathlib
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -43,7 +44,8 @@ def _rows(path):
 
 
 def test_fit_telemetry_stream_has_all_row_kinds(tmp_path):
-    cfg = TelemetryConfig(heartbeat_every=4)
+    # an explicit peak wins over the device table (which has no CPU row)
+    cfg = TelemetryConfig(heartbeat_every=4, peak_flops=197e12)
     state, losses = fit(
         _tiny_lm(), optax.adam(1e-3), _loader(), epochs=3, job_id="TS",
         batch_size=16, loss_fn=lm_loss, input_key="tokens",
@@ -79,6 +81,9 @@ def test_fit_telemetry_stream_has_all_row_kinds(tmp_path):
     for r in mfu:
         assert r["flops_per_step"] == want
         assert r["mfu"] > 0 and r["tokens_per_sec"] > 0
+    (meta,) = [r for r in rows if r["kind"] == "run_meta"]
+    assert meta["device_kind"] == jax.devices()[0].device_kind
+    assert meta["peak_flops_per_chip"] == 197e12
 
     bd = [r for r in rows if r["kind"] == "step_breakdown"]
     assert [r["step"] for r in bd] == [5, 10]
@@ -261,4 +266,8 @@ def test_fit_moe_rows_and_real_moe_mfu(tmp_path):
     )
     for r in mfu:
         assert r["flops_per_step"] == want
-        assert r["mfu"] is not None and r["mfu"] > 0
+        # default peak on the CPU: no published peak to be a share of, so
+        # the field is null — never a v5e utilisation from a CPU run
+        assert r["mfu"] is None and r["tokens_per_sec"] > 0
+    (meta,) = [r for r in rows if r["kind"] == "run_meta"]
+    assert meta["peak_flops_per_chip"] is None

@@ -1,0 +1,158 @@
+"""The Pallas kernels of the main path, compiled for a DESCRIBED TPU v5e.
+
+No chip is attached here, but the TPU's compiler is installed and compiles
+for a described topology: what Mosaic refuses (a slice off the tiling, too
+much VMEM, an unsupported op) fails here at no chip time, where interpret
+mode — all the rest of the suite ever runs — accepts anything. Each case
+lowers a kernel at the widths ``chip_smoke.py`` runs it at, with
+``interpret=False``, for one described chip, and asserts the compiled
+program really holds the kernel (``tpu_custom_call``). A compile is not a
+run: nothing here says the results are right or fast.
+
+This is the ONE file that describes a topology, and it does so inside a
+fixture: only one process may load libtpu, so a describe at import (or in
+``conftest.py``, a ``skipif`` or a ``parametrize``) would make xdist's
+workers collect different tests — and run none.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tpudist.ops import backend
+from tpudist.ops.decode import (
+    _fused_decode_attention, paged_decode_attention,
+)
+from tpudist.ops.flash_attention import flash_attention
+from tpudist.ops.fused_update import fused_leaf_update
+from tpudist.ops.layernorm import fused_layernorm
+from tpudist.ops.vmem_attention import vmem_attention
+
+# a compile for a described chip is written to the persistent cache but can
+# never be read back without the chip (it warns and recompiles): keep it out
+pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for_chip(one_chip, monkeypatch):
+    """``compile_for_chip(fn, (shape, dtype), ...)`` -> compiled HLO text
+    of ``fn`` on one described chip, kernels Mosaic-compiled: the backend
+    helper sees this process's CPU, so the test answers for it."""
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+
+    def run(fn, *shapes):
+        args = [
+            jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes
+        ]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    return run
+
+
+def _fwd_bwd(attn):
+    """Forward + backward of an attention kernel as one program."""
+    def f(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+    return f
+
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((8, 1024, 12, 64), True),    # GPT-2 124M train step, batch 8/chip
+    ((32, 197, 12, 64), False),   # ViT-B/16: 196 patches + cls, padded to 256
+], ids=["gpt2_s1024_causal", "vit_s197"])
+def test_vmem_attention_fwd_bwd(compile_for_chip, shape, causal):
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(vmem_attention, causal=causal)),
+        *[(shape, BF16)] * 3,
+    )
+    assert hlo.count("tpu_custom_call") >= 2  # forward and backward kernels
+
+
+def test_flash_attention_fwd_bwd_s4096(compile_for_chip):
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(flash_attention, causal=True)),
+        *[((2, 4096, 12, 64), BF16)] * 3,
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("residual", [False, True], ids=["plain", "residual"])
+def test_fused_layernorm_fwd_bwd(compile_for_chip, residual):
+    def loss(x, y, scale, bias):
+        out = fused_layernorm(
+            x, scale, bias, residual=y if residual else None, eps=1e-5,
+        )
+        return sum(o.astype(jnp.float32).sum() for o in jax.tree.leaves(out))
+
+    rows = ((8, 1024, 768), BF16)
+    hlo = compile_for_chip(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+        rows, rows, ((768,), F32), ((768,), F32),
+    )
+    assert hlo.count("tpu_custom_call") >= 2  # forward and backward kernels
+
+
+def test_fused_adamw_leaf_embedding_table(compile_for_chip):
+    """The largest leaf of GPT-2 124M (the padded 50304 x 768 table), with
+    the bf16 compute copy written in the same sweep."""
+    def update(g, m, v, p, hyper):
+        return fused_leaf_update(
+            g, m, v, p, hyper[0], hyper[1], hyper[2], b1=0.9, b2=0.999,
+            eps=1e-8, wd=0.1, compute_dtype=BF16,
+        )
+
+    leaf = ((50304, 768), F32)
+    hlo = compile_for_chip(update, leaf, leaf, leaf, leaf, ((3,), F32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_fused_decode_attention_b16_s1024(compile_for_chip):
+    hlo = compile_for_chip(
+        _fused_decode_attention,
+        ((16, 1, 12, 64), BF16), ((16, 12, 1024, 64), BF16),
+        ((16, 12, 1024, 64), BF16), ((), I32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [1, 4], ids=["decode_tick", "verify_chunk4"])
+def test_paged_decode_attention_block16(compile_for_chip, rows):
+    """124M geometry over a block-16 pool: 16 slots x 64 blocks (1024
+    tokens) + the reserved block; ``rows=4`` is the speculative verify
+    chunk."""
+    pool = ((16 * 64 + 1, 12, 16, 64), BF16)
+    hlo = compile_for_chip(
+        functools.partial(paged_decode_attention, impl="paged"),
+        ((16, rows, 12, 64), BF16), pool, pool, ((16, 64), I32), ((16,), I32),
+    )
+    assert "tpu_custom_call" in hlo

@@ -241,7 +241,7 @@ class CompileCache:
                 blob["payload"], blob["in_tree"], blob["out_tree"]
             )
         except Exception as exc:
-            self.last_load_error = f"{type(exc).__name__}: {exc}"[:300]
+            self.last_load_error = f"{type(exc).__name__}: {exc}"
             return None
 
     def begin_load(self, key: str) -> _LoadHandle:
@@ -320,7 +320,7 @@ class CompileCache:
             )
             if handle.error is not None:
                 info["error"] = (
-                    f"{type(handle.error).__name__}: {handle.error}"[:300]
+                    f"{type(handle.error).__name__}: {handle.error}"
                 )
             elif self.last_load_error is not None:
                 info["error"] = self.last_load_error
@@ -342,24 +342,10 @@ class CompileCache:
         except Exception as exc:
             # lowering/compiling outside the jit fast path failed (exotic
             # step configuration): fall through to ordinary tracing
-            info["error"] = f"{type(exc).__name__}: {exc}"[:300]
+            # the full message: a compiler refusal on the chip must be
+            # readable in the compile_cache row, not cut to its preamble
+            info["error"] = f"{type(exc).__name__}: {exc}"
             return None, info
-
-
-def launder_restored(state):
-    """Compat shim for a jax 0.4.x XLA:CPU wart (the same family as
-    tests/conftest.py's persistent-cache notes): an AOT-DESERIALIZED
-    executable donating orbax-restored buffers corrupts the heap
-    (reproduced: segfault/"corrupted double-linked list" on the first
-    step of a warm restart; 8 clean steps after this shim). Routing the
-    restored state through a jitted identity replaces the orbax-created
-    arrays with jit-produced ones, which the executable digests fine.
-    One state copy at bring-up, and ONLY on the wart platform — real
-    TPU/GPU attaches and current jax return the state untouched."""
-    version = tuple(int(p) for p in jax.__version__.split(".")[:2])
-    if version >= (0, 5) or jax.default_backend() != "cpu":
-        return state
-    return jax.jit(lambda s: s)(state)
 
 
 def wrap_step(step, executable, on_fallback: Callable | None = None,

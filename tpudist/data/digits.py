@@ -2,7 +2,7 @@
 
 The reference trains on auto-downloaded CIFAR-100
 (/root/reference/main.py:43-51). In a zero-egress environment that download
-is impossible, so the recorded convergence evidence (CONVERGENCE.json) uses
+is impossible, so real-data convergence runs (`--dataset digits`) use
 the one REAL image dataset shipped inside the image: scikit-learn's
 ``load_digits`` — 1,797 real 8×8 grayscale handwritten digits (a UCI/NIST
 subset), 10 classes. Images are nearest-neighbor upscaled to 32×32 RGB

@@ -21,6 +21,8 @@ import os
 import jax
 import numpy as np
 
+from tpudist.utils.cache import place_compile_cache
+
 logger = logging.getLogger(__name__)
 
 _initialized = False
@@ -64,14 +66,9 @@ def init_from_env(*, allow_single_process: bool = True) -> DistributedContext:
     ``WORLD_SIZE`` ≤ 1 or absent, runs single-process (all local devices).
     """
     global _initialized
-    # opt-in persistent XLA compile cache: first compile of the train step is
-    # tens of seconds on TPU; restarts (and checkpoint resumes) skip it.
-    # JAX's own knobs win if the user already configured them.
-    # (only the dir is set — thresholds like min-compile-time stay whatever
-    # the user configured via JAX's own env vars)
-    cache_dir = os.environ.get("TPUDIST_COMPILE_CACHE")
-    if cache_dir and not jax.config.jax_compilation_cache_dir:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    # persistent XLA compile cache: the first compile of a train step is
+    # tens of seconds on TPU; restarts and checkpoint resumes skip it
+    place_compile_cache()
 
     nproc = int(os.environ.get("WORLD_SIZE", "1"))
     rank = int(os.environ.get("RANK", "0"))

@@ -16,11 +16,11 @@ injects ``--local_rank=i`` into argv — which ``tpudist.distributed
 .init_from_env`` consumes the way ``dist.init_process_group('env://')``
 does.
 
-On TPU pods the natural topology is ONE process per host driving all local
-chips (so ``--nproc_per_node`` defaults to 1 and ``--nnode/--node_rank``
-describe hosts); ``--nproc_per_node>1`` exists for local CPU emulation of a
-multi-process world (each process gets a disjoint slice of fake CPU devices
-via ``--emulate-devices``).
+On TPU the topology is ONE process per host driving all local chips (a
+chip belongs to one process), so ``--nproc_per_node`` defaults to 1 and
+``--nnode/--node_rank`` describe hosts; ``--nproc_per_node>1`` is for local
+CPU emulation of a multi-process world (each process gets a disjoint slice
+of fake CPU devices via ``--emulate-devices``) and is refused without it.
 
 Beyond the reference's fail-fast, the launcher is a SUPERVISOR
 (``tpudist.resilience.supervisor``): exit codes 75 (preempted) / 76
@@ -118,7 +118,7 @@ def build_parser() -> argparse.ArgumentParser:
 def _emulated_devices(args, generation: int) -> int:
     """The fake-CPU device count generation ``generation`` gets: the
     launcher re-probes the device world at every relaunch — on real
-    hardware the relaunched process re-enumerates its own attach, and
+    hardware the relaunched process re-enumerates its own chips, and
     under emulation the per-generation ``--emulate-devices`` list plays
     the part of hardware that shrank (or returned)."""
     values = [int(v) for v in str(args.emulate_devices).split(",") if v != ""]
@@ -133,7 +133,16 @@ def main(argv: list[str] | None = None) -> int:
         BackoffPolicy, RestartBudget, Supervisor,
     )
 
-    args = build_parser().parse_args(argv)
+    parser = build_parser()
+    args = parser.parse_args(argv)
+    if args.nproc_per_node > 1 and not _emulated_devices(args, 0):
+        parser.error(
+            f"--nproc_per_node={args.nproc_per_node} without "
+            "--emulate-devices: a chip belongs to one process, so "
+            "children that each open the local chips fail or hang. One "
+            "process drives all local chips; use --nnode/--node_rank for "
+            "more hosts"
+        )
     # one stable run id for the job's whole life: minted here (or inherited
     # from an outer launcher), exported via the environment every child —
     # all ranks, all restart generations — is spawned with, so telemetry
@@ -228,7 +237,6 @@ def _run_world(args, stop: dict | None = None, generation: int = 0) -> int:
         emulate = _emulated_devices(args, generation)
         if emulate:
             env["JAX_PLATFORMS"] = "cpu"
-            env["TPUDIST_FORCE_CPU"] = "1"
             # the re-probed world, exported so tooling can tell what this
             # generation was granted without parsing XLA flags
             env["TPUDIST_WORLD_DEVICES"] = str(emulate)

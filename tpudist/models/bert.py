@@ -316,7 +316,7 @@ class BertClassifier(nn.Module):
                  attention_mask=None):
         # attention_mask ([b, s], 1 = real token): padded variable-length
         # classification batches must pass it, or pad tokens join every
-        # softmax (HF BERT semantics require the mask — ADVICE r2)
+        # softmax (HF BERT semantics require the mask)
         hidden = Bert(
             vocab_size=self.vocab_size, max_seq_len=self.max_seq_len,
             hidden_dim=self.hidden_dim, depth=self.depth,
@@ -399,7 +399,7 @@ def mlm_transform(
         corrupted[to_mask] = mask_id
         # draw "random token" from the vocab EXCLUDING mask_id: draw over
         # vocab_size-1 ids and shift the ones at/above mask_id up by one, so
-        # [MASK] can never appear as a target-bearing random id (ADVICE r2)
+        # [MASK] can never appear as a target-bearing random id
         draw = rng.integers(0, vocab_size - 1, int(to_random.sum()))
         corrupted[to_random] = draw + (draw >= mask_id)
         out = dict(batch)

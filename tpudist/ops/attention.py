@@ -137,7 +137,7 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
                 "expect gathers/replication); adjust batch/head counts"
             )
         if multi and divisible:
-            from tpudist.utils.compat import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
             # batch over data/fsdp, heads over tensor (Megatron TP keeps

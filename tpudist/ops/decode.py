@@ -38,6 +38,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.ops import backend
+
 NEG_INF = float(np.finfo(np.float32).min)
 
 # Measured crossover (v5e, GPT-2 124M decode, interleaved A/B medians with
@@ -289,7 +291,7 @@ def _fused_decode_attention(q, keys, values, pos):
             out_specs=q_spec,
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=backend.interpret(),
     )(jnp.asarray(pos, jnp.int32).reshape(1), qt, keys, values)
     return out.reshape(b, s_q, h, dh)
 
@@ -466,7 +468,7 @@ def _paged_fused_attention(q, k_pool, v_pool, block_tables, positions):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        interpret=jax.default_backend() != "tpu",
+        interpret=backend.interpret(),
     )(
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(positions, jnp.int32),
@@ -519,9 +521,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, positions, *,
                 if mesh_lib.TENSOR_AXIS in mesh.axis_names else 1
             h, h_kv = q.shape[2], k_pool.shape[1]
             if tp > 1 and h % tp == 0 and h_kv % tp == 0:
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
-
-                from tpudist.utils.compat import shard_map
 
                 fn = shard_map(
                     _paged_fused_attention,

@@ -37,15 +37,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.ops import backend
+
 NEG_INF = float(np.finfo(np.float32).min)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-
-
-def _interpret() -> bool:
-    # CPU (tests, 8-fake-device mesh) has no Mosaic backend; interpret there.
-    return jax.default_backend() != "tpu"
 
 
 def _fwd_kernel(
@@ -170,7 +167,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(q, k, v)
     return o, lse[..., 0]
 
@@ -315,7 +312,7 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
     block_k = min(block_k, s_k)
     nq, nk = s_q // block_q, s_k // block_k
     if interpret is None:
-        interpret = _interpret()
+        interpret = backend.interpret()
 
     do = g
     delta = jnp.sum(
@@ -442,7 +439,7 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd,
 
 def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, pallas_bwd, kv_len,
                    res, g):
-    if pallas_bwd and not _interpret():
+    if pallas_bwd and not backend.interpret():
         return _bwd_pallas(
             res, g, causal=causal, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, kv_len=kv_len,

@@ -28,13 +28,15 @@ overhead, and the two paths share one formula function so they cannot
 drift. The optimizer-facing wrapper (``tpudist.optim.fused_adamw``) owns
 the tree walk, hyperparameters, and optax ``(init, update)`` surface.
 
-GSPMD note: ``pallas_call`` has no partitioning rule. On replicated state
-(pure DP — the regime §4b measures) every chip runs the sweep on its own
-copy, exactly like the optax chain. Under ZeRO-1 ``shard_state`` the
-interpret path decomposes into partitionable ops (the composition tests
-run there); on a real TPU the compiler may all-gather sharded operands
-around the custom call — combine fused LN with ZeRO-1 freely, but measure
-before combining the fused *optimizer* with it on hardware.
+GSPMD note: ``pallas_call`` has no partitioning rule, and the TPU compiler
+refuses a bare Mosaic call in a program that spans several chips. So where
+a mesh is in context — the train step puts its own there around
+``tx.update`` — the call runs inside a ``shard_map`` with every operand
+replicated: on replicated state (pure DP — the regime §4b measures) every
+chip runs the sweep on its own copy, exactly like the optax chain. A
+SHARDED leaf (ZeRO-1 ``shard_state``, tensor/fsdp axes) is all-gathered
+around the call — combine fused LN with those freely, but measure before
+combining the fused *optimizer* with them on hardware.
 """
 
 from __future__ import annotations
@@ -45,16 +47,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from tpudist.ops import backend
 
 # below this many elements the per-launch overhead dwarfs the sweep; the
 # XLA path runs the same formula (tests pin the two paths to agreement)
 MIN_KERNEL_ELEMS = 8 * 128
 
 _LANES = 128
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def adamw_math(g, m, v, p, lr, b1c, b2c, *, b1, b2, eps, wd):
@@ -165,7 +166,7 @@ def fused_leaf_update(g, m, v, p, lr, b1c, b2c, *, b1, b2, eps, wd=0.0,
         out_shape.append(
             jax.ShapeDtypeStruct((rows_pad, _LANES), jnp.dtype(compute_dtype))
         )
-    out = pl.pallas_call(
+    sweep = pl.pallas_call(
         functools.partial(
             _update_kernel, b1=float(b1), b2=float(b2), eps=float(eps),
             wd=float(wd), has_copy=has_copy,
@@ -175,8 +176,15 @@ def fused_leaf_update(g, m, v, p, lr, b1c, b2c, *, b1, b2, eps, wd=0.0,
                   row_spec, row_spec, row_spec, row_spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
-    )(scalars, prep(g), prep(m), prep(v), prep(p))
+        interpret=backend.interpret(),
+    )
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.size > 1:
+        # no GSPMD rule (module docstring): per chip, on replicated operands
+        sweep = jax.shard_map(
+            sweep, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+        )
+    out = sweep(scalars, prep(g), prep(m), prep(v), prep(p))
 
     def unprep(a):
         return jnp.ravel(a)[:n].reshape(shape)

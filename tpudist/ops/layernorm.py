@@ -65,10 +65,7 @@ from flax import linen as nn
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _interpret() -> bool:
-    # CPU (tests, 8-fake-device mesh) has no Mosaic backend; interpret there.
-    return jax.default_backend() != "tpu"
+from tpudist.ops import backend
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,7 +204,7 @@ def _fwd_call(x, y, scale, bias, cfg: _Cfg):
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     return (out[0], out[1]) if has_residual else (out[0], None)
 
@@ -237,7 +234,7 @@ def _bwd_call(r, g, gr, scale, cfg: _Cfg):
             pltpu.VMEM((8, d_pad), jnp.float32),
             pltpu.VMEM((8, d_pad), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(*args)
     # all 8 accumulator rows hold the same total; row 0 is the reduction
     return dr, ds[:1], db[:1]
@@ -352,9 +349,10 @@ def fused_layernorm(
     out_dtype = jnp.dtype(out_dtype or x.dtype)
 
     if mesh is not None:
-        from tpudist import mesh as mesh_lib
-        from tpudist.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
+
+        from tpudist import mesh as mesh_lib
 
         dp = int(np.prod([
             mesh.shape[a] for a in (mesh_lib.DATA_AXIS, mesh_lib.FSDP_AXIS)

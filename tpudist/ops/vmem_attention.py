@@ -52,15 +52,13 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (VMEM scratch if needed)
 
+from tpudist.ops import backend
+
 NEG_INF = float(np.finfo(np.float32).min)
 
 # per-(b,h) VMEM budget: bwd keeps ~4 [S,S] f32/bf16 intermediates live;
 # S=1024 → ~14 MB of ~16 MB works (measured); S=2048 would need 4×.
 MAX_SEQ = 1024
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _masked_scores(q, k, sm_scale, *, causal, kv_len):
@@ -189,12 +187,7 @@ def _struct(shape, dtype, like):
     pipe-manual region, tpudist.parallel.pp) every pallas output must
     declare how it varies over the manual axes or the shard_map's vma
     check rejects the call."""
-    # old jax has neither jax.typeof nor vma-typed avals — there the plain
-    # struct is always right (no vma check exists to reject it)
-    vma = (
-        getattr(jax.typeof(like), "vma", None)
-        if hasattr(jax, "typeof") else None
-    )
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -246,7 +239,7 @@ def _vmem_fwd_raw(q, k, v, *, causal, sm_scale, kv_len):
             _struct(q.shape, q.dtype, q),
             _struct((b, h, s_q, 1), jnp.float32, q),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(q, k, v)
 
 
@@ -283,7 +276,7 @@ def _vmem_vjp_bwd(causal, sm_scale, kv_len, res, g):
             _struct(k.shape, kv_grad_dtype, k),
             _struct(v.shape, kv_grad_dtype, v),
         ],
-        interpret=_interpret(),
+        interpret=backend.interpret(),
     )(q, k, v, o, g, lse)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 

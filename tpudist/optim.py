@@ -469,10 +469,11 @@ def fused_adamw(
     bytes. Float leaves cast; non-float leaves ride along unchanged.
 
     ZeRO-1: apply ``tpudist.optim.shard_state`` AROUND this (the usual
-    order) — the update math runs on the restored layout; on the CPU
-    interpret path the kernel decomposes into partitionable ops and runs
-    on the 1/W shard, on real TPUs measure before combining (pallas_call
-    has no GSPMD rule — see tpudist.ops.fused_update's module docstring).
+    order) — the update math runs on the restored layout; inside a train
+    step the kernel runs per chip on replicated operands, so a sharded leaf
+    is gathered around it: measure before combining on hardware
+    (pallas_call has no GSPMD rule — see tpudist.ops.fused_update's module
+    docstring).
     """
     from tpudist.ops.fused_update import fused_leaf_update
 

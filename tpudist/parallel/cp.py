@@ -27,9 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from tpudist.utils import compat
-from tpudist.utils.compat import shard_map
 
 from tpudist.mesh import DATA_AXIS, FSDP_AXIS, SEQUENCE_AXIS
 
@@ -80,7 +79,7 @@ def ring_attention_local(
     makes ``axis_size`` hops around the ring; hop ``t`` processes the chunk
     originally owned by device ``(idx - t) mod n``.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
     sm_scale = 1.0 / float(np.sqrt(d))
@@ -108,11 +107,7 @@ def ring_attention_local(
     acc0 = jnp.zeros((b, s_local, h, d), jnp.float32)
     # the zero-init carries must carry the same varying-manual-axes type as
     # the per-shard compute results, or scan rejects the carry signature
-    # (old jax has no vma-typed avals, and no check to satisfy)
-    vma = (
-        tuple(getattr(jax.typeof(q), "vma", ()))
-        if hasattr(jax, "typeof") else ()
-    )
+    vma = tuple(jax.typeof(q).vma)
     if vma:
         m0, l0, acc0 = (jax.lax.pcast(x, vma, to="varying") for x in (m0, l0, acc0))
     (k, v, m, l, acc), _ = jax.lax.scan(
@@ -148,7 +143,7 @@ def ulysses_attention_local(
     head group → all_to_all back. ``attn_fn(q, k, v, causal=...)`` defaults
     to the XLA-oracle attention; pass the flash kernel for long S.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(f"num_heads {q.shape[2]} not divisible by seq axis {n}")
     if attn_fn is None:

@@ -64,11 +64,11 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudist import comm
 from tpudist.mesh import DATA_AXIS, FSDP_AXIS
-from tpudist.utils.compat import shard_map
 
 METHODS = ("none", "bucketed", "quantized", "auto")
 
@@ -213,6 +213,20 @@ class GradReducer:
             )
         axis, method, world, seed = DATA_AXIS, self.method, self.world, self.seed
 
+        def local_grad_fn(*args):
+            # flax turns a Partitioned box's names into a sharding
+            # constraint wherever a mesh is in context, and shard_map puts
+            # its all-manual mesh there. Every `param` call re-evaluates
+            # the partitioned initializer for its shape check, so the
+            # model's `tensor` annotations would be applied inside the
+            # manual region (DenseGeneral: to its flattened 2-D kernel),
+            # which jax refuses. The region is per-replica code — no
+            # logical sharding applies
+            with jax.sharding.use_abstract_mesh(
+                jax.sharding.AbstractMesh((), ())
+            ):
+                return grad_fn(*args)
+
         def local(params, stats, rows, step, res):
             # res: [1, n_buckets, bucket_size] block (or a zeros dummy when
             # EF is off — kept in the signature so both variants share one
@@ -223,7 +237,7 @@ class GradReducer:
                 jax.lax.axis_index(axis),
             )
             if grad_accum == 1:
-                (loss, new_stats), g = grad_fn(params, stats, rows, step)
+                (loss, new_stats), g = local_grad_fn(params, stats, rows, step)
                 mean, r = comm.reduce_buckets(
                     layout.flatten(g), r, layout, axis,
                     jax.random.fold_in(key, 0), method=method,
@@ -245,7 +259,7 @@ class GradReducer:
                         jax.random.fold_in(key, i), method=method,
                     )
                     rsum = rsum + reduced
-                    (l, stats), g = grad_fn(
+                    (l, stats), g = local_grad_fn(
                         params, stats, mb, step * grad_accum + i
                     )
                     return (layout.flatten(g), rsum, stats, lsum + l, r), None
@@ -271,7 +285,7 @@ class GradReducer:
                 def micro(carry, xs):
                     gsum, stats, lsum = carry
                     mb, i = xs
-                    (l, stats), g = grad_fn(
+                    (l, stats), g = local_grad_fn(
                         params, stats, mb, step * grad_accum + i
                     )
                     return (gsum + layout.flatten(g), stats, lsum + l), None

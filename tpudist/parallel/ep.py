@@ -432,9 +432,8 @@ class MoEMlp(nn.Module):
         dispatched-token volume — after which the combine is a local
         gather. Row-parallel ``tensor`` partial sums stay a ``psum``,
         matching the metadata the einsum path hands GSPMD."""
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from tpudist.utils.compat import shard_map
 
         E = self.num_experts
         if E % ep_world:

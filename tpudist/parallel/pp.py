@@ -71,8 +71,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from tpudist.utils import compat
-from tpudist.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudist.mesh import DATA_AXIS, FSDP_AXIS, PIPELINE_AXIS
@@ -113,7 +112,7 @@ def _pipeline_local(
     ``x_local`` (valid on every stage — the last stage's results are
     ``psum``-broadcast over the ``pipe`` axis).
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     nm = x_local.shape[0]
     is_first = stage == 0
@@ -152,16 +151,16 @@ def _pipeline_local(
 
 
 def _pcast_varying(tree, axis_name: str):
-    """Promote zero-initialized carries to the varying-manual-axes type on
-    jax versions that track it (no-op elsewhere) — scan rejects a carry
-    whose type changes between the zeros and the per-shard compute."""
-    if hasattr(jax, "typeof") and hasattr(jax.typeof(
-        jax.tree_util.tree_leaves(tree)[0]
-    ), "vma"):
-        return jax.tree_util.tree_map(
-            lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), tree
-        )
-    return tree
+    """Promote carries to the varying-manual-axes type over ``axis_name``
+    — scan rejects a carry whose type changes between the zeros and the
+    per-shard compute. Leaves that already vary (``zeros_like`` of a
+    varying value) are left alone: pcast refuses varying -> varying."""
+    def cast(x):
+        if axis_name in jax.typeof(x).vma:
+            return x
+        return jax.lax.pcast(x, (axis_name,), to="varying")
+
+    return jax.tree_util.tree_map(cast, tree)
 
 
 def _stage_fn(block_fn):
@@ -185,7 +184,7 @@ def _1f1b_fwd_local(
     backward needs (stage internals are recomputed tick-by-tick there).
     Returns ``(outs, banked)``; ``banked`` grows a leading stage dim so
     its out_spec can be ``P(pipe, ...)``."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     nm = x_local.shape[0]
     is_first = stage == 0
@@ -238,7 +237,7 @@ def _1f1b_bwd_local(
     one-forward-one-backward interleave, ``nm + S - 1`` ticks total —
     accumulating the stage's param grads; stage 0 banks the input
     cotangents."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     stage = jax.lax.axis_index(axis_name)
     banked = banked[0]  # drop the stage dim the fwd out_spec added
     nm = g.shape[0]

@@ -74,17 +74,12 @@ class WindowedProfiler:
         return self
 
     def _start(self) -> None:
-        from tpudist.utils import compat
-
         Path(self.log_dir).mkdir(parents=True, exist_ok=True)
-        options = None
+        options = jax.profiler.ProfileOptions()
         if self.with_stack:
-            # None on old jax (no ProfileOptions): the trace still runs,
-            # just without the python-stack tracer levels
-            options = compat.profile_options(
-                python_tracer_level=1, host_tracer_level=2
-            )
-        compat.start_trace(self.log_dir, options)
+            options.python_tracer_level = 1
+            options.host_tracer_level = 2
+        jax.profiler.start_trace(self.log_dir, profiler_options=options)
         self._tracing = True
 
     def annotate(self, step_num: int):

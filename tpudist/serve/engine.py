@@ -28,9 +28,7 @@ The decode loop is **one-step-delayed**, the same pipeline idiom as
 BEFORE step ``k-1``'s tokens are fetched, and each step's sampled tokens
 feed the next step ON DEVICE (a carried ``[S]`` token array, overridden
 per-slot at admission), so the device never idles waiting for a host
-round-trip. On this repo's remote attach a synchronous per-step fetch
-costs ~100 ms RTT — more than ten 124M decode steps; the delayed fetch
-hides it entirely. The price is bounded and paid only on retirement: a
+round-trip. The price is bounded and paid only on retirement: a
 slot whose stop token is discovered one tick late burns at most ONE
 masked zombie row-step (its write lands at its own cursor and the slot
 is released before anything reads it), and the ``(request_id, slot
@@ -719,6 +717,11 @@ class ServeEngine:
         self.compile_cache_info: dict | None = None
         if compile_cache is not None:
             self._setup_compile_cache(compile_cache, seed=seed)
+            if sink is not None:
+                # the per-program outcomes, a compiler refusal's full
+                # message among them: the jit path it falls back to must
+                # not hide why
+                sink.write("compile_cache", **self.compile_cache_info)
         # program anatomy at bring-up (docs/OBSERVABILITY.md §9): one
         # `anatomy` row per serving program — XLA's own FLOPs/bytes for a
         # decode tick and a prefill body chunk. The AOT executables above
@@ -1343,8 +1346,8 @@ class ServeEngine:
         # ALIASES aligned numpy buffers, and under async dispatch the
         # step may read them only after this tick's host-side bookkeeping
         # (advance/admission) has already mutated them in place —
-        # reproduced on jax 0.4.x as per-process-deterministic corrupted
-        # token streams, pinned by test_serve_paged's aliasing regression
+        # reproduced as per-process-deterministic corrupted token
+        # streams, pinned by test_serve_paged's aliasing regression
         # test. The copies are tiny ([S]-scalar lanes and the [S, MB]
         # table) next to the decode step itself.
         args = [
@@ -1629,7 +1632,7 @@ class ServeEngine:
                     info["programs"][name] = "miss"
                 return exe
             except Exception as exc:  # exotic config: jit path serves it
-                info["programs"][name] = f"error:{type(exc).__name__}"
+                info["programs"][name] = f"error:{type(exc).__name__}: {exc}"
                 return None
 
         decode_args = self._decode_example_args()

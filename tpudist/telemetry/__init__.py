@@ -74,8 +74,9 @@ class TelemetryConfig:
     finite losses, armed only after ``spike_min_steps`` observations);
     ``cooldown_steps`` suppresses event storms after a detection.
     ``capture_steps`` sizes the on-demand profiler window an anomaly arms.
-    ``peak_flops`` is PER-CHIP peak (``None`` → v5e bf16,
-    ``flops.DEFAULT_PEAK_FLOPS``). ``heartbeat_every`` is in steps
+    ``peak_flops`` is PER-CHIP peak (``None`` → the running chip's row of
+    ``flops.DEVICE_PEAKS``; on the CPU there is none and the MFU field is
+    null). ``heartbeat_every`` is in steps
     (``None`` → 10× the TSV log cadence; ``0`` → no heartbeat rows, the
     same off-switch contract as ``fit``'s ``memory_log_every``).
     ``jsonl_dir`` overrides where the sink writes (``None`` → fit's
@@ -475,7 +476,16 @@ class Telemetry:
         self.world_size = world_size
         self.log_every = max(int(log_every), 1)
         self.n_chips = max(int(n_chips), 1)
-        self.peak_flops = config.peak_flops or flops.DEFAULT_PEAK_FLOPS
+        import jax as _jax
+
+        device = _jax.devices()[0]
+        self.device_kind = device.device_kind
+        # the CPU has no published peak to be a fraction of: the MFU field
+        # stays null there, never another chip's share
+        self.peak_flops = config.peak_flops or (
+            None if device.platform == "cpu"
+            else flops.device_peaks(device.device_kind)[0]
+        )
         # None → auto (10x the TSV cadence); 0 → off — the same contract
         # as fit()'s memory_log_every, so `or` (which eats the 0) won't do
         self.heartbeat_every = (
@@ -730,6 +740,7 @@ class Telemetry:
                 flops_per_step=self._flops_per_step,
                 tokens_per_step=self._tokens_per_step,
                 peak_flops_per_chip=self.peak_flops,
+                device_kind=self.device_kind,
                 n_chips=self.n_chips,
                 world_size=self.world_size,
                 flops_counter=getattr(self._model, "flops_counter", None),
@@ -803,10 +814,11 @@ class Telemetry:
             if self._flops_per_step is not None and interval_s > 0:
                 # 8 decimals: a tiny CPU-test model's true MFU is ~1e-8
                 # and must not round to a fake 0.0
-                mfu_val = round(flops.mfu(
-                    self._flops_per_step, interval_s,
-                    peak=self.peak_flops, n_chips=self.n_chips,
-                ), 8)
+                mfu_val = None if self.peak_flops is None else round(
+                    flops.mfu(
+                        self._flops_per_step, interval_s,
+                        peak=self.peak_flops, n_chips=self.n_chips,
+                    ), 8)
                 self.sink.write(
                     "mfu", step,
                     mfu=mfu_val,

@@ -32,17 +32,38 @@ from __future__ import annotations
 import math
 from typing import Any, Mapping
 
-# TPU v5e bf16 peak — the single source of truth for the MFU denominator
-# (bench.py's V5E_BF16_PEAK and examples/mfu_probe.py's --peak default both
-# alias this). Override per-chip via TelemetryConfig.peak_flops / --peak.
-DEFAULT_PEAK_FLOPS = 197e12
+# Published per-chip peaks keyed by jax's ``device_kind``:
+# (bf16 FLOP/s, HBM bytes/s, source) — the one table the MFU denominator
+# and any roofline read. A device that is not here is an error, not a
+# default: a utilisation against another chip's peak is a wrong number.
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9, 'Google Cloud documentation, "TPU v5e"'),
+}
+
+
+def device_peaks(device_kind: str | None = None) -> tuple[float, float, str]:
+    """``(bf16 FLOP/s, HBM bytes/s, source)`` of ``device_kind`` (default:
+    the kind of ``jax.devices()[0]``); raises on a kind with no row."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device kind {device_kind!r}: add a row "
+            "(with its source) to tpudist.telemetry.flops.DEVICE_PEAKS, or "
+            "pass the peak explicitly"
+        ) from None
 
 
 def mfu(flops_per_step: float, step_seconds: float, *,
-        peak: float = DEFAULT_PEAK_FLOPS, n_chips: int = 1) -> float:
+        peak: float | None = None, n_chips: int = 1) -> float:
     """Fraction of aggregate peak the step achieved; 0.0 on a degenerate
     (non-positive) step time rather than a ZeroDivisionError — the same
-    coarse-clock guard as ``MetricsLogger.log_step``.
+    coarse-clock guard as ``MetricsLogger.log_step``. ``peak`` is the
+    per-chip FLOP/s (``None`` → :func:`device_peaks` of the running chip).
 
     ``n_chips`` must be the FULL chip count of the mesh the program spans
     (:func:`mesh_chips`), model axes included: the numerator is total
@@ -54,14 +75,16 @@ def mfu(flops_per_step: float, step_seconds: float, *,
     ``tensor·pipe`` on a composed mesh."""
     if step_seconds <= 0.0:
         return 0.0
+    if peak is None:
+        peak = device_peaks()[0]
     return flops_per_step / step_seconds / (peak * max(n_chips, 1))
 
 
 def mesh_chips(mesh) -> int:
     """The MFU denominator's chip count for ``mesh``: every device the
     compiled program spans — data, fsdp, pipe, and tensor axes alike, and
-    ONLY those (a sub-mesh on a shared attach must not divide by chips it
-    never used). ``fit()``'s telemetry, ``ParallelPlan.n_chips``, and the
+    ONLY those (a sub-mesh must not divide by chips it never used).
+    ``fit()``'s telemetry, ``ParallelPlan.n_chips``, and the
     bench legs all route through this one function so a composed-plan MFU
     row can never disagree with a bench record about the denominator."""
     return int(mesh.size)

@@ -1,37 +1,29 @@
-"""Host-keyed persistent-compile-cache location.
+"""Where JAX's persistent compilation cache lives — decided here only.
 
-XLA:CPU serializes AOT-compiled executables with the *compile* machine's
-feature set; loading them on a host with different CPU features only logs a
-warning ("could lead to execution errors such as SIGILL") and then can
-SIGABRT mid-run — observed in this environment when the VM migrated to a
-host with a different AVX feature mix while ``/tmp``'s cache survived.
-Keying the cache directory by the host's CPU flags turns that crash into a
-cold compile on the new host.
+If ``JAX_COMPILATION_CACHE_DIR`` is in the environment, JAX reads it itself
+and nothing is set in code, so the cache can be placed from outside. Else
+the cache goes to one fixed path inside the checkout: the directory is part
+of the cache's key, so a path that moves (a temp name, a pid, a hash of the
+host) never hits.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
 
+import jax
 
-def host_cpu_fingerprint() -> str:
-    """Short stable hash of this host's CPU feature flags."""
-    flags = ""
-    try:
-        with open("/proc/cpuinfo") as f:
-            for line in f:
-                if line.startswith(("flags", "Features")):
-                    flags = " ".join(sorted(line.split(":", 1)[1].split()))
-                    break
-    except OSError:
-        import platform
-
-        flags = platform.machine() + platform.processor()
-    return hashlib.sha1(flags.encode()).hexdigest()[:10]
+_CHECKOUT = os.path.dirname(  # tpudist/utils/cache.py -> three levels up
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 
-def host_keyed_cache_dir(base: str = "/tmp/tpudist_jax_cache") -> str:
-    return os.environ.get(
-        "TPUDIST_JAX_CACHE_DIR", f"{base}_{host_cpu_fingerprint()}"
-    )
+def place_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its one home and return
+    the directory in use. Call before the first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
