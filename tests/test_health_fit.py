@@ -122,14 +122,17 @@ def test_fit_hang_watchdog_writes_crash_forensics(tmp_path):
     # yielding, so a stall at batch k blocks the loop ~2 batches early),
     # making the crash report's last_rows capture non-trivial — the tail
     # is read BEFORE the watchdog row is written, by crash-path design
-    loader = SleepyLoader(_loader(), stall_epoch=1, stall_at=3, stall_s=1.5)
-    cfg = TelemetryConfig(hang_timeout_s=0.4, sentry=False, mfu=False)
+    # the deadline leaves a healthy step room: under six busy workers a
+    # tiny step has taken over 0.4 s of wall clock, tripped the one-shot
+    # watchdog early and left the forensics of the wrong moment
+    loader = SleepyLoader(_loader(), stall_epoch=1, stall_at=3, stall_s=4.0)
+    cfg = TelemetryConfig(hang_timeout_s=1.5, sentry=False, mfu=False)
     state, losses = _fit(loader, tmp_path, "HG", cfg, epochs=2)
     assert len(losses) == 8  # the stall resolved; training finished
 
     crash = json.loads((tmp_path / "HG_crash_0.json").read_text())
     assert crash["job"] == "HG" and crash["rank"] == 0
-    assert crash["trip"]["age_s"] > 0.4
+    assert crash["trip"]["age_s"] > 1.5
     assert crash["trip"]["last_step"] >= 1
     assert any("MainThread" in k for k in crash["thread_stacks"])
     assert all(isinstance(v, list) and v
@@ -143,14 +146,14 @@ def test_fit_hang_watchdog_writes_crash_forensics(tmp_path):
     rows = _rows(tmp_path / "HG_telemetry_0.jsonl")
     wd = [r for r in rows if r["kind"] == "watchdog"]
     assert len(wd) == 1  # one-shot
-    assert wd[0]["age_s"] > 0.4 and wd[0]["timeout_s"] == 0.4
+    assert wd[0]["age_s"] > 1.5 and wd[0]["timeout_s"] == 1.5
 
     report = json.loads((tmp_path / "HG_report.json").read_text())
     # the watchdog wrote a report at trip time; finish() overwrote it with
     # the final status, KEEPING the trip on record
     assert report["status"] == "completed"
     assert report["watchdog"] is not None
-    assert report["watchdog"]["timeout_s"] == 0.4
+    assert report["watchdog"]["timeout_s"] == 1.5
 
 
 def test_fit_crash_path_writes_report(tmp_path):

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudist.profiling import WindowedProfiler
+from tpudist.telemetry.trace import TRAIN_STEP, span
 
 
 def _trace_dirs(root):
@@ -56,7 +57,7 @@ def test_trace_contains_python_stacks_and_step_annotations(tmp_path):
     x = jnp.arange(8.0)
     with p:
         for i in range(6):
-            with p.annotate(i):
+            with span(TRAIN_STEP, step=i, marks_step=True):
                 jax.block_until_ready(jnp.sum(x * x))
             p.step()
     blob = b"".join(

@@ -90,7 +90,9 @@ def test_fit_telemetry_stream_has_all_row_kinds(tmp_path):
     for r in bd:
         assert r["interval_s"] > 0 and r["dispatch_s"] > 0
         assert r["data_wait_s"] >= 0
-        assert r["device_s"] is not None and r["device_s"] > 0
+        # the barrier-timed field is gone: interval_s is the device-bound
+        # step time, and the row holds nothing the loop had to block for
+        assert "device_s" not in r
 
     beats = [r for r in rows if r["kind"] == "heartbeat"]
     assert [r["step"] for r in beats] == [4, 8, 12]
@@ -271,3 +273,217 @@ def test_fit_moe_rows_and_real_moe_mfu(tmp_path):
         assert r["mfu"] is None and r["tokens_per_sec"] > 0
     (meta,) = [r for r in rows if r["kind"] == "run_meta"]
     assert meta["peak_flops_per_chip"] is None
+
+
+# -- the loop's spans: one helper, the profiler's timeline and the stream ---
+
+MAIN_SPANS = ("fit/next_batch", "tpudist_train", "fit/health",
+              "fit/resolve_wait", "fit/log", "fit/memory_stats",
+              "fit/checkpoint")
+
+
+def _traced_fit(tmp_path, **kw):
+    """A 4-step ``fit(profile=False)`` under a profiler session the TEST
+    started — any session will do, as the benchmark's does."""
+    import glob
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=options)
+    try:
+        fit(
+            _tiny_lm(), optax.adam(1e-3), _loader(), epochs=1, job_id="SP",
+            batch_size=16, loss_fn=lm_loss, input_key="tokens",
+            label_key="tokens", log_dir=str(tmp_path), profile=False,
+            memory_log_every=1, checkpoint_dir=str(tmp_path / "ck"),
+            checkpoint_every=1, **kw,
+        )
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "prof/plugins/profile/*/*.xplane.pb"))
+    by_name = {}
+    data = jax.profiler.ProfileData.from_file(path)
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("fit/", "input/", "tpudist_train")):
+                    by_name.setdefault(e.name, []).append(
+                        (i, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats)))
+    return by_name
+
+
+def test_fit_spans_once_a_step_on_the_profilers_timeline(tmp_path):
+    """Every span of docs/OBSERVABILITY.md §8's table shows once a step
+    with its step_num, under a session fit did not start; the main
+    thread's top-level spans do not overlap, the input spans nest under
+    fit/next_batch, and input/produce runs on another thread's line."""
+    spans = _traced_fit(tmp_path, telemetry=TelemetryConfig(trace=True))
+    # the next() that ends the epoch is no step's work: tagged, set aside
+    ended = {name: [s for *_, s in events if "end" in s]
+             for name, events in spans.items()}
+    spans = {name: [e for e in events if "end" not in e[3]]
+             for name, events in spans.items()}
+    assert [s["step_num"] for s in ended.pop("fit/next_batch")] == [5]
+    assert [s["batch"] for s in ended.pop("input/wait")] == [4]
+    assert [s["batch"] for s in ended.pop("input/produce")] == [4]
+    assert not any(ended.values())
+    for name in MAIN_SPANS:
+        steps = sorted(s["step_num"] for _, _, _, s in spans[name])
+        assert steps == [1, 2, 3, 4], name
+    (main,) = {line for name in MAIN_SPANS for line, *_ in spans[name]}
+    top = sorted((a, b, name) for name in MAIN_SPANS
+                 for _, a, b, _ in spans[name])
+    for (_, end, first), (start, _, second) in zip(top, top[1:]):
+        assert end <= start, (first, second)
+    parents = [(a, b) for _, a, b, _ in spans["fit/next_batch"]]
+    for name in ("input/wait", "input/stage"):
+        assert {line for line, *_ in spans[name]} == {main}
+        for _, a, b, _ in spans[name]:
+            assert any(pa <= a and b <= pb for pa, pb in parents), name
+    assert sorted(s["batch"] for *_, s in spans["input/stage"]) == [0, 1, 2, 3]
+    assert {line for line, *_ in spans["input/produce"]} != {main}
+    assert sorted(s["batch"] for *_, s in spans["input/produce"]) == [
+        0, 1, 2, 3]
+
+
+def test_fit_span_rows_ride_the_stream_with_trace_on(tmp_path):
+    """trace=True: the same spans are rows too, one a step, the save under
+    its older name; the per-step `step` row no longer carries device_s."""
+    _traced_fit(tmp_path, telemetry=TelemetryConfig(trace=True))
+    rows = [r for r in _rows(tmp_path / "SP_telemetry_0.jsonl")
+            if r["kind"] == "span"]
+    names = {r["name"] for r in rows}
+    assert "fit/checkpoint" not in names
+    for name in ("tpudist_train", "fit/health", "fit/resolve_wait",
+                 "fit/log", "fit/memory_stats", "checkpoint", "step"):
+        assert [r["step"] for r in rows if r["name"] == name] == [
+            1, 2, 3, 4], name
+    assert all("device_s" not in r for r in rows)
+    assert all(r["cat"] == "train" and r["ph"] == "X" for r in rows
+               if r["name"].startswith(("fit/", "input/")))
+
+
+def test_fit_stream_without_trace_has_no_span_rows_and_no_device_s(tmp_path):
+    """trace off (the default): spans are profiler annotations only, so
+    the stream holds the kinds and fields it held before, less the
+    barrier-timed device_s."""
+    fit(
+        _tiny_lm(), optax.adam(1e-3), _loader(), epochs=2, job_id="NS",
+        batch_size=16, loss_fn=lm_loss, input_key="tokens",
+        label_key="tokens", log_dir=str(tmp_path), profile=False,
+        telemetry=TelemetryConfig(mfu=False),
+    )
+    rows = _rows(tmp_path / "NS_telemetry_0.jsonl")
+    assert [r["kind"] for r in rows] == [
+        "throughput", "health", "step_breakdown", "run_summary",
+        "train_time",
+    ]
+    assert [k for k in rows[2] if k != "run_id"] == [
+        "v", "t", "kind", "rank", "step", "interval_s", "data_wait_s",
+        "dispatch_s"]
+
+
+def _scope_paths(lowered):
+    import re
+
+    return set(re.findall(r'loc\("(jit\(step_fn\)[^"]*)"',
+                          lowered.as_text(debug_info=True)))
+
+
+def test_lowered_step_names_the_new_scopes_and_leaves_attention_bare():
+    """named_scope is metadata: the lowered step's op paths hold
+    optimizer, grad_clip and loss_head, and nothing sits between a block's
+    flax scope and its attention pallas_call (the benchmark finds the
+    kernel by the name XLA derives from that scope)."""
+    import re
+
+    from tpudist import mesh as mesh_lib
+    from tpudist.models.gpt2 import chunked_lm_forward
+    from tpudist.optim import make_optimizer
+    from tpudist.train import create_train_state, make_train_step
+
+    mesh = mesh_lib.create_mesh(devices=jax.devices()[:1])
+    model = GPT2(vocab_size=VOCAB, max_seq_len=32, hidden_dim=32, depth=2,
+                 num_heads=4, dtype=jnp.bfloat16, attn_impl="vmem",
+                 mesh=mesh, dropout=0.0)
+    for fused in (True, False):
+        tx = make_optimizer(
+            1e-3, optimizer="adam", weight_decay=0.1, clip_norm=1.0,
+            fused=fused, compute_dtype=jnp.bfloat16 if fused else None)
+        state = create_train_state(
+            model, 0, jnp.zeros((1, 32), jnp.int32), tx, mesh=mesh)
+        step = make_train_step(
+            model, tx, mesh, loss_fn=lm_loss, input_key="tokens",
+            label_key="tokens", fused="all" if fused else None,
+            forward_loss=chunked_lm_forward(model, chunk=16))
+        paths = _scope_paths(step.jitted.lower(
+            state, step.stage({"tokens": np.zeros((4, 32), np.int32)})))
+        assert any("/optimizer/grad_clip/" in p for p in paths), fused
+        assert any("/jvp(loss_head)/" in p for p in paths)
+        assert any("/transpose(jvp(loss_head))/" in p for p in paths)
+        if fused:
+            assert "jit(step_fn)/optimizer/pallas_call" in paths
+        kernels = {p for p in paths if p.endswith("/pallas_call")
+                   and re.search(r"/h_\d+/", p)}
+        attention = {p for p in kernels if re.search(r"/h_\d+/pallas_call$", p)}
+        assert {p.split("/")[1] + "/" + p.split("/")[2] for p in attention} == {
+            "jvp(GPT2)/h_0", "jvp(GPT2)/h_1",
+            "transpose(jvp(GPT2))/h_0", "transpose(jvp(GPT2))/h_1"}
+        # the others in a block are the fused norms, under scopes of their own
+        assert all(re.search(r"/h_\d+/ln_[12]/pallas_call$", p)
+                   for p in kernels - attention)
+
+
+def test_lowered_mesh_step_keeps_the_blocks_name_on_the_attention_kernel():
+    """On a multi-device mesh the kernel runs inside a shard_map, whose
+    body starts the name stack afresh: the block's name is put back around
+    the kernel there (tests/test_tpu_compile.py shows XLA then names the
+    call ``h_<n>.<k>``); the fused norms' kernels stay bare."""
+    import re
+
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import create_train_state, make_train_step
+
+    mesh = mesh_lib.create_mesh(devices=jax.devices()[:4])
+    model = GPT2(vocab_size=VOCAB, max_seq_len=32, hidden_dim=32, depth=2,
+                 num_heads=4, dtype=jnp.bfloat16, attn_impl="vmem",
+                 mesh=mesh, dropout=0.0)
+    tx = optax.adam(1e-3)
+    state = create_train_state(
+        model, 0, jnp.zeros((1, 32), jnp.int32), tx, mesh=mesh)
+    step = make_train_step(
+        model, tx, mesh, loss_fn=lm_loss, input_key="tokens",
+        label_key="tokens", fused="ln")
+    text = step.jitted.lower(
+        state, step.stage({"tokens": np.zeros((8, 32), np.int32)})
+    ).as_text(debug_info=True)
+    kernels = set(re.findall(r'loc\("([^"]*pallas_call)"', text))
+    assert kernels == {"h_0/pallas_call", "h_1/pallas_call", "pallas_call"}
+
+
+def test_lowered_explicit_reducer_and_policy_casts_are_named():
+    """grad_exchange names the exchange the program writes itself (the
+    explicit reducer); cast names the precision policy's casts."""
+    from tpudist import amp, mesh as mesh_lib
+    from tpudist.train import create_train_state, make_train_step
+
+    mesh = mesh_lib.create_mesh()
+    model = _tiny_lm()
+    tx = optax.adam(1e-3)
+    state = create_train_state(
+        model, 0, jnp.zeros((1, 16), jnp.int32), tx, mesh=mesh)
+    step = make_train_step(
+        model, tx, mesh, loss_fn=lm_loss, input_key="tokens",
+        label_key="tokens", reduce="bucketed")
+    state = step.grad_reducer.attach_residual(state)
+    lowered = step.jitted.lower(
+        state, step.stage({"tokens": np.zeros((16, 16), np.int32)}))
+    # a shard_map body's name stack starts afresh: no jit(step_fn) prefix
+    assert 'loc("grad_exchange/psum"' in lowered.as_text(debug_info=True)
+    cast = jax.jit(amp.BF16_COMPUTE.cast_to_compute).lower(
+        {"w": jnp.ones((2, 2))}).as_text(debug_info=True)
+    assert "/cast/" in cast

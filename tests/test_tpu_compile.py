@@ -156,3 +156,32 @@ def test_paged_decode_attention_block16(compile_for_chip, rows):
         ((16, rows, 12, 64), BF16), pool, pool, ((16, 64), I32), ((16,), I32),
     )
     assert "tpu_custom_call" in hlo
+
+
+def test_attention_kernel_keeps_block_name_on_mesh(topo, monkeypatch):
+    """On a ``data=4`` mesh the kernel runs inside a ``shard_map``, and XLA
+    names a call after its innermost scope: with the block's ``name=`` the
+    calls are ``h_3.<k>`` — what a trace reader looks for
+    (``benchmarks/families`` ``ATTENTION_OPS``) — and not ``shard_map.<k>``."""
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpudist.mesh import DATA_AXIS, create_mesh
+    from tpudist.ops.attention import multi_head_attention
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    mesh = create_mesh(devices=topo.devices)
+    rows = jax.ShapeDtypeStruct(
+        (32, 1024, 16, 64), BF16, sharding=NamedSharding(mesh, P(DATA_AXIS)))
+
+    def block(q, k, v):
+        with jax.named_scope("h_3"):
+            return multi_head_attention(
+                q, k, v, causal=True, impl="vmem", mesh=mesh, name="h_3")
+
+    hlo = jax.jit(_fwd_bwd(block)).lower(rows, rows, rows).compile().as_text()
+    kernels = re.findall(
+        r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    assert len(kernels) >= 2  # forward and backward
+    assert all(re.match(r"^h_3(\.\d+)?$", k) for k in kernels), kernels

@@ -8,6 +8,7 @@ endpoint."""
 
 import json
 import pathlib
+import time
 import urllib.error
 import urllib.request
 
@@ -287,7 +288,7 @@ def test_telemetry_stream_byte_identical_with_trace_off(tmp_path, monkeypatch):
             tel.exporter = MetricsExporter(0)
         for g in range(1, 6):
             tel.on_step(g, {"loss": 1.0 / g}, epoch=0, interval_s=0.5,
-                        data_wait_s=0.01, dispatch_s=0.2, device_s=0.3)
+                        data_wait_s=0.01, dispatch_s=0.2)
         tel.shutdown()
 
     run(tmp_path / "off.jsonl", traced=False)
@@ -367,3 +368,139 @@ def test_engine_metrics_endpoint_serves_live_stats(tmp_path):
     eng.close()
     sink.close()
     assert eng.exporter is None  # closed and detached
+
+
+# -- the one span helper: a profiler annotation always, a row too with a
+# -- Tracer (docs/OBSERVABILITY.md §8) ----------------------------------------
+
+
+def _capture(tmp_path, body):
+    """Run ``body`` under a CPU profiler session; returns the parsed trace
+    and the session's ``profile_start_time`` (unix ns)."""
+    import glob
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "prof/plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    start = next(
+        dict(p.stats)["profile_start_time"] for p in data.planes
+        if p.name == "Task Environment"
+    )
+    return data, start
+
+
+def _host_events(data, name):
+    return [(line, e, dict(e.stats))
+            for p in data.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events if e.name == name]
+
+
+def test_span_helper_traceme_and_row_share_a_timeline(tmp_path):
+    """One call, two sinks: the TraceMe event in the xplane and the span
+    row agree on name, step, duration and — through the documented clock
+    mapping — start, each within a millisecond."""
+    from tpudist.telemetry.trace import span
+
+    sink = TelemetrySink(tmp_path / "s.jsonl")
+    tracer = Tracer(sink)
+
+    def body():
+        with span("fit/probe", step=7, tracer=tracer, shard=3):
+            time.sleep(0.02)
+
+    data, profile_start = _capture(tmp_path, body)
+    sink.close()
+    ((_, event, stats),) = _host_events(data, "fit/probe")
+    assert stats["step_num"] == 7 and stats["shard"] == 3
+    (row,) = _spans(tmp_path / "s.jsonl", "fit/probe")
+    assert row["step"] == 7 and row["shard"] == 3 and row["ph"] == "X"
+    assert abs(row["dur_s"] * 1e9 - event.duration_ns) < 1e6
+    # the mapping: a row starts (t - dur_s) on the wall clock; an xplane
+    # host event starts start_ns after the session's profile_start_time
+    row_start_ns = (row["t"] - row["dur_s"]) * 1e9 - profile_start
+    assert abs(row_start_ns - event.start_ns) < 1e6
+
+
+def test_span_helper_row_name_tags_and_step_marker(tmp_path):
+    """``row=`` keeps a stream's older name; without a tracer no row is
+    written; ``marks_step`` makes the event a StepTraceAnnotation
+    (``_r``), which XProf groups device work by."""
+    from tpudist.telemetry.trace import TRAIN_STEP, span
+
+    sink = TelemetrySink(tmp_path / "s.jsonl", clock=lambda: 50.0)
+    tracer = Tracer(sink, clock=lambda: 77.0, process_index=1)
+
+    def body():
+        with span("fit/checkpoint", step=4, tracer=tracer, row="checkpoint"):
+            pass
+        with span("fit/log", step=4):  # no tracer: annotation only
+            pass
+        with span(TRAIN_STEP, step=5, marks_step=True):
+            pass
+
+    data, _ = _capture(tmp_path, body)
+    sink.close()
+    assert [r["name"] for r in _spans(tmp_path / "s.jsonl")] == ["checkpoint"]
+    (row,) = _spans(tmp_path / "s.jsonl")
+    assert row == {
+        "v": 1, "t": 50.0, "kind": "span", "rank": 0, "step": 4,
+        "name": "checkpoint", "cat": "train", "ph": "X", "t0": 77.0,
+        "dur_s": 0.0, "process_index": 1, "generation": 0,
+    }
+    assert len(_host_events(data, "fit/checkpoint")) == 1
+    assert len(_host_events(data, "fit/log")) == 1
+    ((_, _, stats),) = _host_events(data, TRAIN_STEP)
+    assert stats["_r"] == 1 and stats["step_num"] == 5
+
+
+def test_prefetch_spans_carry_the_batch_ordinal(tmp_path):
+    """``prefetch_to_mesh``'s three spans: one produce, wait and stage per
+    batch, tagged 0..n-1, as rows and as events. The ``next()`` that finds
+    the stream ended (the producer's sixth, the consumer's sixth wait) is
+    no batch's work: no row, and its event is tagged ``end``."""
+    import numpy as np
+
+    from tpudist.data.loader import prefetch_to_mesh
+    from tpudist.mesh import create_mesh
+
+    sink = TelemetrySink(tmp_path / "s.jsonl")
+    batches = [{"x": np.full((8, 2), i, np.float32)} for i in range(5)]
+    staged = []
+    data, _ = _capture(tmp_path, lambda: staged.extend(prefetch_to_mesh(
+        iter(batches), create_mesh(), tracer=Tracer(sink))))
+    sink.close()
+    assert [int(b["x"][0, 0]) for b in staged] == [0, 1, 2, 3, 4]
+    path = tmp_path / "s.jsonl"
+    for name in ("input/produce", "input/wait", "input/stage"):
+        assert [s["batch"] for s in _spans(path, name)] == [0, 1, 2, 3, 4]
+        events = sorted((st["batch"], st.get("end"))
+                        for _, _, st in _host_events(data, name))
+        last = [] if name == "input/stage" else [(5, 1)]
+        assert events == [(n, None) for n in range(5)] + last, name
+
+
+def test_span_that_meets_the_end_of_its_stream_is_tagged_not_counted(tmp_path):
+    """``TimedIterator``'s last ``next()`` raises StopIteration through
+    ``fit/next_batch``: the event says so (``end``), there is no row, and
+    the steps that did get a batch keep one span each."""
+    from tpudist.telemetry import TimedIterator
+
+    sink = TelemetrySink(tmp_path / "s.jsonl")
+    timed = TimedIterator(iter("ab"), step=10, tracer=Tracer(sink))
+    got = []
+    data, _ = _capture(tmp_path, lambda: got.extend(timed))
+    sink.close()
+    assert got == ["a", "b"]
+    assert [r["step"] for r in _spans(tmp_path / "s.jsonl")] == [11, 12]
+    events = sorted((st["step_num"], st.get("end"))
+                    for _, _, st in _host_events(data, "fit/next_batch"))
+    assert events == [(11, None), (12, None), (13, 1)]

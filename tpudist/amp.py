@@ -58,7 +58,8 @@ def _cast_floats(tree, dtype):
             return jnp.asarray(x, dtype)
         return x
 
-    return jax.tree_util.tree_map(cast, tree)
+    with jax.named_scope("cast"):
+        return jax.tree_util.tree_map(cast, tree)
 
 
 def all_finite(tree) -> jax.Array:

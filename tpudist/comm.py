@@ -270,20 +270,24 @@ def reduce_buckets(
     every hop. The residual is added BEFORE quantization — error feedback:
     what one step drops, a later step transmits.
     """
-    if method == "bucketed":
-        mean = jax.lax.psum(buckets, axis_name) / axis_size(axis_name)
-        return mean, residual
-    if method != "quantized":
+    if method not in ("bucketed", "quantized"):
         raise ValueError(f"unknown reduce method {method!r}")
-    x = buckets if residual is None else buckets + residual
-    q0, s0 = quantize_bucket(x, jax.random.fold_in(key, 0))
-    xq = dequantize(q0, s0)
-    new_residual = None if residual is None else x - xq
-    w = axis_size(axis_name)
-    chunks = xq.reshape(w, layout.buckets_per_chunk, layout.bucket_size)
-    total = ring_allreduce_quantized(chunks, axis_name, jax.random.fold_in(key, 1))
-    mean = total.reshape(layout.n_buckets, layout.bucket_size) / w
-    return mean, new_residual
+    # the exchange the program writes itself, named for the device trace
+    with jax.named_scope("grad_exchange"):
+        if method == "bucketed":
+            mean = jax.lax.psum(buckets, axis_name) / axis_size(axis_name)
+            return mean, residual
+        x = buckets if residual is None else buckets + residual
+        q0, s0 = quantize_bucket(x, jax.random.fold_in(key, 0))
+        xq = dequantize(q0, s0)
+        new_residual = None if residual is None else x - xq
+        w = axis_size(axis_name)
+        chunks = xq.reshape(w, layout.buckets_per_chunk, layout.bucket_size)
+        total = ring_allreduce_quantized(
+            chunks, axis_name, jax.random.fold_in(key, 1)
+        )
+        mean = total.reshape(layout.n_buckets, layout.bucket_size) / w
+        return mean, new_residual
 
 
 def measure_h2d_mbps(nbytes: int = 8 * 1024 * 1024) -> float:

@@ -12,6 +12,7 @@ the copy for step k+1 overlaps the compute of step k.
 from __future__ import annotations
 
 import collections
+import itertools
 import threading
 from typing import Callable, Iterator, Mapping
 
@@ -121,7 +122,7 @@ class DataLoader(SampledLoader):
 
 
 def prefetch_to_mesh(iterator, mesh, *, depth: int = 2, stage_fn=None,
-                     stop_check=None, stop_poll_s: float = 0.5):
+                     stop_check=None, stop_poll_s: float = 0.5, tracer=None):
     """Stage host batches onto the device mesh ``depth`` steps ahead.
 
     The replacement for pinned-memory + synchronous ``.cuda()``: device_put
@@ -140,8 +141,15 @@ def prefetch_to_mesh(iterator, mesh, *, depth: int = 2, stage_fn=None,
     at exactly preemption time) must still reach the graceful
     emergency-checkpoint path instead of blocking in a timeout-less wait
     until the scheduler's SIGKILL.
+
+    Three spans (docs/OBSERVABILITY.md §8; rows too with ``tracer``), each
+    tagged with the batch's ordinal in this stream: ``input/produce`` on
+    the producer thread (one ``next()`` of ``iterator``: gather + host
+    transforms), ``input/wait`` (the consumer blocked on the producer) and
+    ``input/stage`` (``stage_fn``) on the consumer's.
     """
     from tpudist.mesh import shard_batch
+    from tpudist.telemetry.trace import span
 
     queue: collections.deque = collections.deque()
     host_q: collections.deque = collections.deque()
@@ -151,7 +159,13 @@ def prefetch_to_mesh(iterator, mesh, *, depth: int = 2, stage_fn=None,
 
     def _producer():
         try:
-            for item in iterator:
+            batches = iter(iterator)
+            for n in itertools.count():
+                with span("input/produce", tracer=tracer, batch=n) as s:
+                    item = next(batches, DONE)
+                    if item is DONE:
+                        s.ends_stream()
+                        return
                 with lock:
                     while len(host_q) >= depth + 1 and not abandoned:
                         lock.wait()
@@ -193,9 +207,13 @@ def prefetch_to_mesh(iterator, mesh, *, depth: int = 2, stage_fn=None,
     try:
         finished = False
         pending_err: BaseException | None = None
+        n = 0  # ordinal of the next batch to stage
         while True:
             while not finished and pending_err is None and len(queue) < depth:
-                item = _next_host()
+                with span("input/wait", tracer=tracer, batch=n) as s:
+                    item = _next_host()
+                    if item is DONE:
+                        s.ends_stream()
                 if item is DONE:
                     finished = True
                 elif isinstance(item, BaseException):
@@ -206,7 +224,9 @@ def prefetch_to_mesh(iterator, mesh, *, depth: int = 2, stage_fn=None,
                     # in THIS thread propagates from _next_host immediately.
                     pending_err = item
                 else:
-                    queue.append(stage_fn(item))
+                    with span("input/stage", tracer=tracer, batch=n):
+                        queue.append(stage_fn(item))
+                    n += 1
             if queue:
                 yield queue.popleft()
             elif pending_err is not None:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
@@ -117,7 +118,7 @@ class EncoderBlock(nn.Module):
             )
             attn = multi_head_attention(
                 q, k, v, causal=False, mask=key_mask, impl=self.attn_impl,
-                mesh=self.mesh,
+                mesh=self.mesh, name=self.name,
             )
         y = nn.DenseGeneral(
             d, axis=(-2, -1), dtype=self.dtype, name="out",
@@ -446,7 +447,8 @@ def mlm_forward(model: Bert, chunk: int | None = None):
             logits = model.apply(
                 {"params": params}, batch["tokens"], train=True
             )
-            loss = masked_ce_sum(logits, batch["targets"], mask) / denom
+            with jax.named_scope("loss_head"):
+                loss = masked_ce_sum(logits, batch["targets"], mask) / denom
             return loss, batch_stats
 
         hidden = model.apply(

@@ -152,8 +152,9 @@ class Block(nn.Module):
         else:
             attn = multi_head_attention(
                 q, k, v, causal=True, impl=self.attn_impl,
-                # multi-chip Pallas runs need the per-shard shard_map wrap
-                mesh=self.mesh,
+                # multi-chip Pallas runs need the per-shard shard_map wrap,
+                # inside which the kernel keeps this block's name
+                mesh=self.mesh, name=self.name,
             )
         # row-parallel: contraction dim sharded; GSPMD all-reduces the output
         y = nn.DenseGeneral(

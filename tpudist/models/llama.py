@@ -199,7 +199,7 @@ class LlamaBlock(nn.Module):
             else:
                 attn = multi_head_attention(
                     q, k, v, causal=True, impl=self.attn_impl,
-                    mesh=self.mesh,
+                    mesh=self.mesh, name=self.name,
                 )
         # row-parallel output projection; GSPMD all-reduces over 'tensor'
         o = nn.DenseGeneral(

@@ -57,6 +57,10 @@ def lm_head_weight(params):
     raise ValueError(f"no LM head weight among params: {list(params)}")
 
 
+# one name for the head + loss in the device trace, in both passes
+# (jvp(loss_head) / transpose(jvp(loss_head))): its fusions are the step's
+# largest and carry no module path otherwise
+@jax.named_scope("loss_head")
 def chunked_head_reduce(
     logits_fn, h, targets, pos_mask, chunk: int, *, hits: bool = False
 ):

@@ -80,7 +80,7 @@ class EncoderBlock(nn.Module):
         )(y)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         attn = multi_head_attention(q, k, v, impl=self.attn_impl,
-                                    mesh=self.mesh)
+                                    mesh=self.mesh, name=self.name)
         y = nn.DenseGeneral(
             d, axis=(-2, -1), dtype=self.dtype, name="out",
             kernel_init=_partitioned(dense_init, TENSOR_AXIS, None, None),

@@ -19,6 +19,8 @@ in-kernel (``kv_len``).
 
 from __future__ import annotations
 
+from contextlib import nullcontext
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -83,7 +85,7 @@ def dot_product_attention(q, k, v, *, causal: bool = False, mask=None,
 
 def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
                          impl: str = "xla", kv_len: int | None = None,
-                         mesh=None):
+                         mesh=None, name: str | None = None):
     """Dispatch over the three attention paths:
 
     - ``xla``: dense einsum attention (oracle; takes arbitrary masks);
@@ -106,6 +108,12 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
     ``mesh=None`` the kernels still partition correctly under pure
     single-chip-per-process DP (one shard per program) and on the CPU
     interpret path (decomposed into partitionable jax ops).
+
+    ``name``: the caller's own scope name (a block's ``self.name``), put
+    around the kernel INSIDE that ``shard_map``. XLA names a kernel's call
+    after its innermost scope — ``h_3.2`` on one chip, ``shard_map.116``
+    under the wrap — and a trace reader finds the attention kernel by the
+    block's name (``benchmarks/families``), on a mesh as off it.
     """
     if mask is not None and kv_len is not None:
         raise ValueError("pass mask or kv_len, not both")
@@ -145,11 +153,16 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
             # per-shard kernel is exact with no collective
             spec = P((mesh_lib.DATA_AXIS, mesh_lib.FSDP_AXIS), None,
                      mesh_lib.TENSOR_AXIS, None)
+
+            def per_shard(q, k, v):
+                with jax.named_scope(name) if name else nullcontext():
+                    return multi_head_attention(
+                        q, k, v, causal=causal, impl=impl, kv_len=kv_len
+                    )
+
             fn = shard_map(
-                lambda q, k, v: multi_head_attention(
-                    q, k, v, causal=causal, impl=impl, kv_len=kv_len
-                ),
-                mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                per_shard, mesh=mesh,
+                in_specs=(spec, spec, spec), out_specs=spec,
                 # pallas_call can't declare varying-manual-axes on its
                 # out_shape (same caveat as parallel/cp.py)
                 check_vma=False,

@@ -59,6 +59,17 @@ def decay_mask(params) -> Any:
     return jax.tree_util.tree_map(lambda p: p.ndim > 1, params)
 
 
+def _scoped(name: str, tx: optax.GradientTransformation):
+    """``tx`` with its update under ``jax.named_scope(name)``: the device
+    trace then says which link of the chain an op belongs to."""
+
+    def update(updates, state, params=None):
+        with jax.named_scope(name):
+            return tx.update(updates, state, params)
+
+    return optax.GradientTransformation(tx.init, update)
+
+
 def make_optimizer(
     lr: float | optax.Schedule = 1e-3,
     *,
@@ -107,7 +118,7 @@ def make_optimizer(
         return tx
     parts = []
     if clip_norm is not None:
-        parts.append(optax.clip_by_global_norm(clip_norm))
+        parts.append(_scoped("grad_clip", optax.clip_by_global_norm(clip_norm)))
     if optimizer == "adam":
         if weight_decay > 0.0:
             parts.append(
@@ -508,14 +519,15 @@ def fused_adamw(
             # optax.clip_by_global_norm's exact arithmetic (divide by the
             # norm, then scale by the max) so the fused chain stays
             # bit-compatible with the unfused one
-            g_norm = optax.global_norm(grads)
-            grads = jax.tree_util.tree_map(
-                lambda t: jnp.where(
-                    g_norm < clip_norm, t,
-                    (t / g_norm.astype(t.dtype)) * clip_norm,
-                ),
-                grads,
-            )
+            with jax.named_scope("grad_clip"):
+                g_norm = optax.global_norm(grads)
+                grads = jax.tree_util.tree_map(
+                    lambda t: jnp.where(
+                        g_norm < clip_norm, t,
+                        (t / g_norm.astype(t.dtype)) * clip_norm,
+                    ),
+                    grads,
+                )
         count_inc = optax.safe_int32_increment(state.count)
         b1c = 1.0 - b1 ** count_inc.astype(jnp.float32)
         b2c = 1.0 - b2 ** count_inc.astype(jnp.float32)

@@ -16,14 +16,13 @@ Usage mirrors the reference: wrap training in the context manager and call
 ``with_stack=True`` at /root/reference/main.py:77) turns on the profiler's
 python tracer, so captured windows carry host-side python call stacks
 alongside the device timeline — the Kineto python-stack capability,
-natively. :meth:`annotate` additionally brackets each traced step in a
-``StepTraceAnnotation`` so XProf's step-time view can attribute device work
-to training steps.
+natively. ``fit`` brackets each step's dispatch in a ``StepTraceAnnotation``
+(:data:`tpudist.telemetry.trace.TRAIN_STEP`, through the one span helper)
+so XProf's step-time view can attribute device work to training steps.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import threading
 from pathlib import Path
@@ -81,16 +80,6 @@ class WindowedProfiler:
             options.host_tracer_level = 2
         jax.profiler.start_trace(self.log_dir, profiler_options=options)
         self._tracing = True
-
-    def annotate(self, step_num: int):
-        """Context manager bracketing one training step: a
-        ``StepTraceAnnotation`` while a window is recording (XProf's
-        step-time attribution), a no-op otherwise."""
-        if self._tracing:
-            return jax.profiler.StepTraceAnnotation(
-                "tpudist_train", step_num=step_num
-            )
-        return contextlib.nullcontext()
 
     def arm(self, active_steps: int) -> bool:
         """Open an on-demand capture window NOW for the next

@@ -47,6 +47,7 @@ from pathlib import Path
 from typing import Any, Mapping
 
 from tpudist.telemetry import flops
+from tpudist.telemetry.trace import span
 
 __all__ = [
     "TelemetryConfig",
@@ -440,17 +441,24 @@ class TimedIterator:
     the run is input-bound (docs/PERF.md §3's diagnosis, now visible
     per-step instead of requiring a bench A/B)."""
 
-    def __init__(self, iterator):
+    def __init__(self, iterator, *, step: int = 0, tracer=None):
         self._it = iter(iterator)
         self.last_wait_s = 0.0
+        self._step = step  # the last step dispatched: a batch feeds the next
+        self._tracer = tracer
 
     def __iter__(self):
         return self
 
     def __next__(self):
+        self._step += 1
         t0 = time.perf_counter()
         try:
-            return next(self._it)
+            # parent of prefetch_to_mesh's input/wait and input/stage; the
+            # StopIteration that ends the epoch tags the event ``end``
+            with span("fit/next_batch", step=self._step,
+                      tracer=self._tracer):
+                return next(self._it)
         finally:
             self.last_wait_s = time.perf_counter() - t0
 
@@ -750,8 +758,7 @@ class Telemetry:
 
     def on_step(self, step: int, metrics: Mapping[str, float], *, epoch: int,
                 interval_s: float, data_wait_s: float | None = None,
-                dispatch_s: float | None = None,
-                device_s: float | None = None) -> dict | None:
+                dispatch_s: float | None = None) -> dict | None:
         """Record one RESOLVED step (host-side scalar values). Returns the
         anomaly event if the sentry fired, else None."""
         loss = float(metrics.get("loss", float("nan")))
@@ -794,10 +801,6 @@ class Telemetry:
                     interval_s=round(interval_s, 6),
                     data_wait_s=round(data_wait_s or 0.0, 6),
                     dispatch_s=round(dispatch_s, 6),
-                    # device_s is measured on cadence steps only (a
-                    # block_until_ready there would stall the pipeline
-                    # every step); null on the rest
-                    device_s=None if device_s is None else round(device_s, 6),
                     **extra,
                 )
             moe = {
@@ -923,7 +926,6 @@ class Telemetry:
                 "step", interval_s, step=step,
                 data_wait_s=round(data_wait_s or 0.0, 6),
                 dispatch_s=None if dispatch_s is None else round(dispatch_s, 6),
-                device_s=None if device_s is None else round(device_s, 6),
             )
             if event is not None:
                 self.tracer.instant(

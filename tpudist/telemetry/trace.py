@@ -31,8 +31,13 @@ request's phase spans telescope exactly — ``queued + prefill + decode +
 preempted == total`` to float addition error.
 
 Both features are strictly opt-in: with ``trace`` off and no
-``metrics_port``, no object here is constructed and every existing stream
-stays byte-identical (the standing telemetry contract).
+``metrics_port``, no tracer or exporter is constructed and every existing
+stream stays byte-identical (the standing telemetry contract).
+
+:class:`span` is the one way ``fit`` and the input pipeline mark an
+interval of host work: a ``jax.profiler.TraceAnnotation`` always (on the
+profiler's clock, next to the device lines, whenever any profiler session
+records) and a ``span`` row as well when the run has a :class:`Tracer`.
 """
 
 from __future__ import annotations
@@ -41,7 +46,13 @@ import threading
 import time
 from typing import Callable, Mapping
 
-__all__ = ["Tracer", "ServeTracer", "MetricsExporter"]
+import jax
+
+__all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "TRAIN_STEP"]
+
+# the step marker XProf's step-time view groups device work by; the name
+# predates the ``fit/...`` spans and the benchmark's gap labels quote it
+TRAIN_STEP = "tpudist_train"
 
 
 class Tracer:
@@ -82,6 +93,66 @@ class Tracer:
             process_index=self.process_index, generation=self.generation,
             **tags,
         )
+
+
+class span:
+    """One interval of host work, one call per site, two sinks.
+
+    Always a ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for
+    ``marks_step``): about a microsecond while no profiler session is on,
+    and an event on the profiler's own clock — the one the device lines of
+    the same ``.xplane.pb`` are aligned to — while one is, whoever started
+    it. ``step`` and ``tags`` become the event's stats (``step_num``).
+
+    With ``tracer`` (the run's :class:`Tracer`, ``TelemetryConfig(trace=
+    True)``) the close additionally writes the ``span`` row, named ``row``
+    where the stream's name differs from the annotation's. The two clocks
+    (docs/OBSERVABILITY.md §8): an xplane host event starts
+    ``line.timestamp_ns + offset_ps / 1000`` ns after the trace's
+    ``profile_start_time`` (unix ns, on the ``Task Environment`` plane); a
+    row's start on that axis is ``(t - dur_s) * 1e9 - profile_start_time``.
+
+    A ``next()`` that finds its stream at an end is no piece of work for a
+    step: :meth:`ends_stream` (called for a ``StopIteration`` that passes
+    through) tags the event ``end=1``, which readers leave out, and writes
+    no row.
+    """
+
+    __slots__ = ("name", "step", "tags", "tracer", "row", "_annotation", "_t0")
+
+    def __init__(self, name: str, *, step: int | None = None,
+                 tracer: Tracer | None = None, row: str | None = None,
+                 marks_step: bool = False, **tags):
+        self.name = name
+        self.step = step
+        self.tags = tags
+        self.tracer = tracer
+        self.row = row or name
+        kind = (jax.profiler.StepTraceAnnotation if marks_step
+                else jax.profiler.TraceAnnotation)
+        stats = tags if step is None else {"step_num": int(step), **tags}
+        self._annotation = kind(name, **stats)
+
+    def __enter__(self):
+        if self.tracer is not None:
+            self._t0 = self.tracer._clock()
+        self._annotation.__enter__()
+        return self
+
+    def ends_stream(self) -> None:
+        self._annotation.set_metadata(end=1)
+        self.tracer = None
+
+    def __exit__(self, *exc):
+        if exc[0] is StopIteration:
+            self.ends_stream()
+        self._annotation.__exit__(*exc)
+        if self.tracer is not None:
+            self.tracer.span(
+                self.row, self.tracer._clock() - self._t0, t0=self._t0,
+                step=self.step, **self.tags,
+            )
+        return False
 
 
 class _Req:

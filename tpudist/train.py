@@ -578,7 +578,8 @@ def make_train_step(
             logits = model.apply(variables, inputs, train=True, **kwargs)
             new_stats = batch_stats
             aux = 0.0
-        loss = loss_fn(logits, batch[label_key]) + aux
+        with jax.named_scope("loss_head"):
+            loss = loss_fn(logits, batch[label_key]) + aux
         if moe_telemetry:
             return loss, (new_stats, _moe_metrics(
                 updates.get("moe_stats", {})
@@ -676,11 +677,15 @@ def make_train_step(
 
         # the step's mesh in context: a kernel without a GSPMD rule (the
         # fused optimizer's sweep) wraps itself in a shard_map over it
-        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-            updates, new_opt = tx.update(
-                grads, state.opt_state, state.params
-            )
-        new_params = optax.apply_updates(state.params, updates)
+        # named for the device trace (metadata only): without a scope the
+        # update's ops sit at jit(step_fn)/<primitive> and its kernels are
+        # named after the step
+        with jax.named_scope("optimizer"):
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                updates, new_opt = tx.update(
+                    grads, state.opt_state, state.params
+                )
+            new_params = optax.apply_updates(state.params, updates)
         if reducer is not None and reducer.error_feedback:
             # a non-finite step (bf16 spike, data glitch) must not bank its
             # garbage into the error-feedback residual: whether the update
@@ -1556,6 +1561,7 @@ def fit(
         ) as p:
             print("Start")
             from tpudist.telemetry import TimedIterator, build_telemetry
+            from tpudist.telemetry.trace import TRAIN_STEP, span
             from tpudist.telemetry.flops import mesh_chips as flops_chips
 
             # sink attached BEFORE the first log_memory: the dual-sink
@@ -1680,6 +1686,9 @@ def fit(
                             error=f"{type(exc).__name__}: {exc}"[:300],
                         )
             breakdown = tel is not None and tel.config.breakdown
+            # the loop's spans (docs/OBSERVABILITY.md §8) are profiler
+            # annotations always and `span` rows too when the run traces
+            tracer = tel.tracer if tel is not None else None
 
             # live HBM snapshot post-bring-up (params+opt state placed,
             # no activations yet): the measured side of the pre-compile
@@ -1721,49 +1730,42 @@ def fit(
             # duration is the inter-step interval (the sustained rate the
             # reference's clock measures, /root/reference/main.py:95-111).
             pending = None  # (step, epoch, idx, start, metrics, breakdown)
-            # device-time probe staging (see the barrier below): the probe
-            # runs 2 steps before each logged row so neither the logged
-            # interval (barrier stall inflates it) nor the one right before
-            # it (the post-barrier bubble deflates it — the resolve-side
-            # backpressure needs one step to re-establish) is perturbed.
-            # Cadences too short to stagger keep the probe on the logged
-            # step itself.
-            probe_offset = (
-                2 if breakdown and tel.log_every >= 3 else 0
-            )
-            device_probe = None
 
             def resolve(now):
                 g, pe, pidx, pstart, dev_metrics, waits = pending
-                # integer metrics (nonfinite_grad_count, update_skipped)
-                # stay ints — float() here would defeat the sink's
-                # Integral-preserving serialization and land 3.0 in rows
-                # documented as integer counts
-                host = {
-                    k: (v.tolist() if jnp.ndim(v) > 0
-                        else int(v) if jnp.issubdtype(v.dtype, jnp.integer)
-                        else float(v))
-                    for k, v in dev_metrics.items()
-                }
+                # the one place a device-bound loop sleeps: the conversions
+                # block until step g's scalars exist
+                with span("fit/resolve_wait", step=g, tracer=tracer):
+                    # integer metrics (nonfinite_grad_count, update_skipped)
+                    # stay ints — float() here would defeat the sink's
+                    # Integral-preserving serialization and land 3.0 in rows
+                    # documented as integer counts
+                    host = {
+                        k: (v.tolist() if jnp.ndim(v) > 0
+                            else int(v)
+                            if jnp.issubdtype(v.dtype, jnp.integer)
+                            else float(v))
+                        for k, v in dev_metrics.items()
+                    }
                 loss_value = host["loss"]
                 losses.append(loss_value)
-                logger.log_step(g, loss_value, now - pstart)
-                logger.print_progress(pe, pidx, loss_value)
-                if tel is not None:
-                    data_wait_s, dispatch_s, device_s = waits
-                    tel.on_step(
-                        g, host, epoch=pe, interval_s=now - pstart,
-                        data_wait_s=data_wait_s, dispatch_s=dispatch_s,
-                        device_s=device_s,
-                    )
-                if repair_ctl is not None:
-                    # skip-streak arithmetic, anchor promotion clock, and
-                    # replay pricing — after tel.on_step, whose sentry/
-                    # divergence publications may already have set a
-                    # trigger this same resolve
-                    repair_ctl.observe_step(
-                        g, host, interval_s=now - pstart
-                    )
+                with span("fit/log", step=g, tracer=tracer):
+                    logger.log_step(g, loss_value, now - pstart)
+                    logger.print_progress(pe, pidx, loss_value)
+                    if tel is not None:
+                        data_wait_s, dispatch_s = waits
+                        tel.on_step(
+                            g, host, epoch=pe, interval_s=now - pstart,
+                            data_wait_s=data_wait_s, dispatch_s=dispatch_s,
+                        )
+                    if repair_ctl is not None:
+                        # skip-streak arithmetic, anchor promotion clock,
+                        # and replay pricing — after tel.on_step, whose
+                        # sentry/divergence publications may already have
+                        # set a trigger this same resolve
+                        repair_ctl.observe_step(
+                            g, host, interval_s=now - pstart
+                        )
 
             # a SIGTERM that lands while the consumer is BLOCKED on a
             # stalled input pipeline must still reach the graceful path:
@@ -1815,15 +1817,16 @@ def fit(
                     staged = prefetch_to_mesh(
                         batches, mesh,
                         depth=prefetch_depth, stage_fn=step.stage,
-                        stop_check=stop_check,
+                        stop_check=stop_check, tracer=tracer,
                     )
-                    if breakdown or gp is not None:
-                        # data-wait attribution: seconds this loop blocked
-                        # on the prefetch queue (≈0 while the pipeline keeps
-                        # up; → step time when the run is input-bound).
-                        # Goodput needs the same number even when the
-                        # breakdown rows are off.
-                        staged = TimedIterator(staged)
+                    # data-wait attribution: seconds this loop blocked on
+                    # the prefetch queue (≈0 while the pipeline keeps up; →
+                    # step time when the run is input-bound), as the
+                    # `fit/next_batch` span and `last_wait_s` (breakdown
+                    # rows, goodput)
+                    staged = TimedIterator(
+                        staged, step=global_step, tracer=tracer
+                    )
                     for idx, batch in enumerate(staged, start=first_idx):
                         # step-boundary resilience hooks, BEFORE the next
                         # dispatch: chaos first (an injected SIGTERM must
@@ -1841,44 +1844,28 @@ def fit(
                             break
                         start = time.time()
                         global_step += 1
-                        if tel is not None:
-                            tel.observe_batch(batch)
                         dispatch_t0 = time.perf_counter()
-                        with p.annotate(global_step):
+                        with span(TRAIN_STEP, step=global_step,
+                                  tracer=tracer, marks_step=True):
                             state, metrics = step(state, batch)
                         dispatch_s = time.perf_counter() - dispatch_t0
                         for v in metrics.values():
                             v.copy_to_host_async()
                         if tel is not None:
-                            # run-health hooks (no-ops unless configured):
-                            # the watchdog beat marks "the loop is alive"
-                            # once per iteration — placed AFTER dispatch so
-                            # bring-up's first compile sits before the
-                            # first beat and can't false-trip the deadline
-                            # — and the divergence probe dispatches on the
-                            # fresh state at its cadence (async; resolved
-                            # one cadence later on the delayed pipeline)
-                            tel.beat(global_step)
-                            tel.observe_state(global_step, state)
-                        device_s = None
-                        if breakdown:
-                            if (global_step + probe_offset) % tel.log_every == 0:
-                                # cadenced device-time attribution: block
-                                # until THIS step's result exists (includes
-                                # any queued predecessor — the pipeline is
-                                # 1 deep). Once per cadence, staggered off
-                                # the logged step (probe_offset above): a
-                                # per-step barrier would serialize the very
-                                # pipeline it measures, and a barrier inside
-                                # a logged step's interval would inflate
-                                # exactly the throughput/MFU rows that
-                                # advertise the sustained rate.
-                                jax.block_until_ready(metrics["loss"])
-                                device_probe = (
-                                    time.perf_counter() - dispatch_t0
-                                )
-                            if global_step % tel.log_every == 0:
-                                device_s = device_probe
+                            # run-health hooks (no-ops unless configured),
+                            # AFTER dispatch: the batch sizes the MFU
+                            # numerator once; the watchdog beat marks "the
+                            # loop is alive" once per iteration — bring-up's
+                            # first compile sits before the first beat and
+                            # can't false-trip the deadline — and the
+                            # divergence probe dispatches on the fresh state
+                            # at its cadence (async; resolved one cadence
+                            # later on the delayed pipeline)
+                            with span("fit/health", step=global_step,
+                                      tracer=tracer):
+                                tel.observe_batch(batch)
+                                tel.beat(global_step)
+                                tel.observe_state(global_step, state)
                         # profiler schedule advances BEFORE resolve: resolve
                         # may arm the anomaly window, and arming after this
                         # iteration's step() means the window's countdown
@@ -1894,7 +1881,6 @@ def fit(
                             (
                                 staged.last_wait_s if breakdown else None,
                                 dispatch_s,
-                                device_s,
                             ),
                         )
                         if (repair_ctl is not None
@@ -1908,20 +1894,22 @@ def fit(
                             repair_request = repair_ctl.take_trigger()
                             break
                         if mem_every and global_step % mem_every == 0:
-                            m = device_memory_stats()
-                            interval_peak = None
-                            if m:
-                                lp = m.get("peak_bytes_in_use")
-                                if lp is not None and (
-                                        mem_peak_seen is None
-                                        or lp > mem_peak_seen):
-                                    interval_peak = lp
-                                    mem_peak_seen = lp
-                                else:
-                                    interval_peak = m.get("bytes_in_use")
-                            logger.log_memory(
-                                m, peak_bytes_in_use=interval_peak
-                            )
+                            with span("fit/memory_stats", step=global_step,
+                                      tracer=tracer):
+                                m = device_memory_stats()
+                                interval_peak = None
+                                if m:
+                                    lp = m.get("peak_bytes_in_use")
+                                    if lp is not None and (
+                                            mem_peak_seen is None
+                                            or lp > mem_peak_seen):
+                                        interval_peak = lp
+                                        mem_peak_seen = lp
+                                    else:
+                                        interval_peak = m.get("bytes_in_use")
+                                logger.log_memory(
+                                    m, peak_bytes_in_use=interval_peak
+                                )
                         if ckpt is not None and (
                             (checkpoint_every
                              and global_step % checkpoint_every == 0)
@@ -1930,22 +1918,20 @@ def fit(
                                 >= checkpoint_every_s)
                         ):
                             t_save = time.perf_counter()
-                            if ckpt.save(state):
-                                if repair_ctl is not None:
-                                    # a new anchor CANDIDATE — promoted
-                                    # only after anchor_clean_steps clean
-                                    # steps (tpudist.resilience.repair)
-                                    repair_ctl.on_save(global_step)
+                            # the save stall; the stream keeps the row's
+                            # name from before the span had a prefix
+                            with span("fit/checkpoint", step=global_step,
+                                      tracer=tracer, row="checkpoint"):
+                                saved = ckpt.save(state)
+                            if saved and repair_ctl is not None:
+                                # a new anchor CANDIDATE — promoted only
+                                # after anchor_clean_steps clean steps
+                                # (tpudist.resilience.repair)
+                                repair_ctl.on_save(global_step)
                             if gp is not None:
                                 gp.add(
                                     "checkpoint_s",
                                     time.perf_counter() - t_save,
-                                )
-                            if tel is not None and tel.tracer is not None:
-                                tel.tracer.span(
-                                    "checkpoint",
-                                    time.perf_counter() - t_save,
-                                    step=global_step,
                                 )
                             last_save_t = time.monotonic()
                         if gp is not None:
@@ -1972,7 +1958,6 @@ def fit(
                 # the in-flight delayed-fetch step belongs to the
                 # discarded trajectory: drop it before anything else
                 pending = None
-                device_probe = None
                 t_rep = time.perf_counter()
                 total_steps = epochs * steps_per_epoch
                 action = repair_ctl.plan(
