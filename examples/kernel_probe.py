@@ -1,11 +1,11 @@
-"""Achieved-HBM-bandwidth probe for the step-fusion kernels — the
+"""Achieved-HBM-bandwidth probe for the step-fusion layer — the
 measurement behind docs/PERF.md §4c.
 
-The fused LN and fused-AdamW kernels (tpudist/ops/layernorm.py,
-tpudist/ops/fused_update.py) attack the bandwidth-bound non-GEMM tail
-§4b measured, so their figure of merit is GB/s against the chip's HBM
-roofline (v5e: 819 GB/s), not FLOP/s. This probe times each kernel in
-isolation with the same differential method as examples/mfu_probe.py
+The fused LN kernel and the one-pass AdamW update (tpudist/ops/layernorm.py;
+tpudist/ops/fused_update.py — plain XLA since PR 26, one loop fusion a
+leaf) are bandwidth-bound, so their figure of merit is GB/s against the
+chip's HBM roofline (v5e: 819 GB/s), not FLOP/s. This probe times each
+in isolation with the same differential method as examples/mfu_probe.py
 (tpudist.telemetry.microbench: adaptive iters, ``(t(4n)−t(n))/3n``,
 anti-hoisting operands, plausibility retries) and reports
 bytes-moved / second.
@@ -23,7 +23,7 @@ Run on the bench chip::
     python examples/kernel_probe.py                 # default shapes
     python examples/kernel_probe.py --rows 32768 --hidden 1024 --bw 819e9
 
-On CPU it still runs (the kernels interpret) — the GB/s are then host
+On CPU it still runs (the LN kernel interprets) — the GB/s are then host
 numbers, useful only as a smoke test.
 """
 
@@ -90,7 +90,7 @@ def probe_ln(rows: int, hidden: int, dtype, *, bw: float, reps: int):
 
 def probe_fused_update(n_elems: int, *, bw: float, reps: int,
                        compute_dtype=jnp.bfloat16):
-    """Fused AdamW sweep GB/s over one ``n_elems`` fp32 leaf."""
+    """One-pass AdamW update GB/s over one ``n_elems`` fp32 leaf."""
     from tpudist.ops.fused_update import fused_leaf_update
 
     rng = np.random.Generator(np.random.PCG64(1))

@@ -79,7 +79,7 @@ def parse_args(argv=None):
                    choices=["none", "auto", "ln", "optimizer", "all"],
                    help="step-fusion layer (docs/PERF.md §4c): 'ln' = the "
                    "Pallas fused residual-add+LayerNorm kernel in every "
-                   "block, 'optimizer' = the one-pass fused-AdamW kernel "
+                   "block, 'optimizer' = the one-pass fused-AdamW update "
                    "(+ bf16 compute-copy forward under --bf16; requires "
                    "--optimizer adam), 'all' both, 'auto' whatever the "
                    "model/optimizer support")
@@ -412,8 +412,8 @@ def main(argv=None):
 
     steps_per_epoch = len(loader)
     total = args.total_steps or args.epochs * steps_per_epoch
-    # --fused optimizer/all/auto builds the one-pass fused-AdamW kernel
-    # (auto only when the optimizer is adam — the kernel implements the
+    # --fused optimizer/all/auto builds the one-pass fused-AdamW update
+    # (auto only when the optimizer is adam — it implements the
     # adam/adamw update); under --bf16 it also keeps the bf16 compute
     # copy the fused step's forward reads
     fuse_opt = args.fused in ("optimizer", "all") or (

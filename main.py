@@ -114,7 +114,7 @@ def parse_args(argv=None):
                         help="step-fusion layer (docs/PERF.md §4c): 'ln' = "
                         "Pallas fused residual-add+LayerNorm in the "
                         "transformer blocks (vit_b16), 'optimizer' = the "
-                        "one-pass fused-AdamW kernel (requires --optimizer "
+                        "one-pass fused-AdamW update (requires --optimizer "
                         "adam; under --bf16 the forward reads its bf16 "
                         "compute copy), "
                         "'all' both, 'auto' whatever model/optimizer "

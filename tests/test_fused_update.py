@@ -1,8 +1,12 @@
 """One-pass fused AdamW (tpudist/ops/fused_update.py, optim.fused_adamw)
-pinned against the optax reference chain — bit-level in interpret mode for
-the shared-formula small-leaf path, ulp-level for the kernel path — plus
-the compute-copy contract, edge leaves (1-element, odd sizes), and the
-skip_nonfinite / decay-mask / clip / schedule compositions."""
+pinned against the optax reference chain — bit for bit per leaf shape, with
+bf16 gradients on f32 masters and the bf16 copy; ulp-level over compounding
+steps of a mixed tree — plus the guard that nothing params-sized is padded,
+reshaped or sliced around the update, the compute-copy contract, edge
+leaves (1-element, odd sizes), and the skip_nonfinite / decay-mask / clip /
+schedule compositions."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,10 +28,10 @@ from tpudist.optim import (
 def _tree(seed=0):
     r = np.random.Generator(np.random.PCG64(seed))
     return {
-        # > MIN_KERNEL_ELEMS → the Pallas sweep; odd size → pad/mask path
+        # sizes off every tile: a last dimension past 128, an odd 1-D leaf
         "w": jnp.asarray(r.standard_normal((40, 130)), jnp.float32),
         "big": jnp.asarray(r.standard_normal(9001), jnp.float32),
-        # < MIN_KERNEL_ELEMS → the shared-formula XLA path
+        # a bias under one (8, 128) tile
         "b": jnp.asarray(r.standard_normal(7), jnp.float32),
         # the 1-element edge leaf
         "one": jnp.asarray(r.standard_normal(1)[0], jnp.float32),
@@ -42,16 +46,21 @@ def _grads(params, seed):
     )
 
 
-def _run(tx, params, n_steps=5):
+def _run(tx, params, n_steps=5, grad_dtype=None, compiler_options=None):
     state = tx.init(params)
 
-    @jax.jit
+    @functools.partial(jax.jit, compiler_options=compiler_options)
     def step(p, s, g):
         u, s2 = tx.update(g, s, p)
         return optax.apply_updates(p, u), s2
 
     for i in range(n_steps):
-        params, state = step(params, state, _grads(params, 100 + i))
+        grads = _grads(params, 100 + i)
+        if grad_dtype is not None:
+            grads = jax.tree_util.tree_map(
+                lambda g: g.astype(grad_dtype), grads
+            )
+        params, state = step(params, state, grads)
     return params, state
 
 
@@ -73,11 +82,10 @@ def test_matches_optax_chain(wd, clip, sched):
 
     fp, fs = _run(ftx, params)
     rp, rs = _run(rtx, params)
-    # the small-leaf path shares the formula FUNCTION with optax-order
-    # arithmetic and the kernel path runs the same math through the pallas
-    # interpreter — either can differ from optax by an ulp of XLA fusion
-    # reassociation across 5 compounding Adam steps, no more (the bars are
-    # absolute, at ~2.0-magnitude params: ~1-4 float32 ulps)
+    # the update is optax-order arithmetic in one expression per leaf; with
+    # four leaves in one program it can differ from optax by an ulp of XLA
+    # fusion reassociation across 5 compounding Adam steps, no more (the
+    # bars are absolute, at ~2.0-magnitude params: ~1-4 float32 ulps)
     for key in ("b", "one"):
         np.testing.assert_allclose(
             np.asarray(fp[key]), np.asarray(rp[key]), atol=5e-7, rtol=0
@@ -86,6 +94,99 @@ def test_matches_optax_chain(wd, clip, sched):
         np.testing.assert_allclose(
             np.asarray(fp[key]), np.asarray(rp[key]), atol=1e-6, rtol=0
         )
+
+
+# the leaf shapes the cells and the other families have: GPT-2/BERT's qkv
+# and out kernels (heads split), a vocabulary no tile divides, a bias, a
+# conv kernel, a leaf under one (8, 128) tile
+LEAF_SHAPES = [(1024, 3, 16, 64), (16, 64, 1024), (1027, 256), (4096,),
+               (7, 7, 3, 64), (5, 13)]
+
+
+@pytest.mark.parametrize(
+    "shape", LEAF_SHAPES, ids=["x".join(map(str, s)) for s in LEAF_SHAPES]
+)
+def test_leaf_shapes_bit_equal_optax_chain(shape):
+    """The cells' regime, leaf by leaf: bf16 gradients (taken with respect
+    to the bf16 copy) on f32 masters and moments, clip, masked decay, the
+    bf16 copy. Bit-equal to the optax chain given the same gradients —
+    upcast to f32 after the clip, as ``adamw_math`` takes them (optax alone
+    would form ``(1−b1)·g`` and ``g²`` in bf16)."""
+    r = np.random.Generator(np.random.PCG64(3))
+    params = {"kernel": jnp.asarray(r.standard_normal(shape), jnp.float32)}
+    ftx = fused_adamw(1e-2, weight_decay=0.1, mask=decay_mask, clip_norm=1.0,
+                      compute_dtype=jnp.bfloat16)
+    rtx = optax.chain(
+        optax.clip_by_global_norm(1.0),
+        optax.stateless(lambda u, _: jax.tree_util.tree_map(
+            lambda t: t.astype(jnp.float32), u)),
+        optax.adamw(1e-2, weight_decay=0.1, mask=decay_mask),
+    )
+
+    fp, fs = _run(ftx, params, n_steps=3, grad_dtype=jnp.bfloat16)
+    rp, rs = _run(rtx, params, n_steps=3, grad_dtype=jnp.bfloat16)
+    adam = rs[2][0]
+    for got, want in [(fp, rp), (fs.mu, adam.mu), (fs.nu, adam.nu)]:
+        assert got["kernel"].dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(got["kernel"]), np.asarray(want["kernel"])
+        )
+    assert fs.compute["kernel"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(fs.compute["kernel"], np.float32),
+        np.asarray(rp["kernel"].astype(jnp.bfloat16), np.float32),
+    )
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_update_moves_no_leaf_through_another_layout():
+    """The mechanism, checked where no chip is needed: the update of a small
+    GPT-2 tree holds no ``pad``, and no op that lays a leaf out anew (a
+    reshape, a slice, a transpose, a concatenation of something leaf-sized).
+    On a TPU each of those is a read and a write of the leaf (PERF.md §6,
+    PR 26: 20 ms of a 190 ms step) around an update that is elementwise."""
+    from flax import linen as nn
+
+    from tpudist.models.gpt2 import GPT2
+
+    model = GPT2(vocab_size=97, max_seq_len=32, hidden_dim=48, depth=2,
+                 num_heads=4)
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.key(0), jnp.zeros((2, 16), jnp.int32),
+                           train=False)
+    )["params"])
+    tx = fused_adamw(1e-2, weight_decay=0.1, mask=decay_mask, clip_norm=1.0,
+                     compute_dtype=jnp.bfloat16)
+    grads = jax.tree_util.tree_map(
+        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16), params
+    )
+
+    def update(g, s, p):
+        u, s2 = tx.update(g, s, p)
+        return optax.apply_updates(p, u), s2
+
+    jaxpr = jax.make_jaxpr(update)(grads, jax.eval_shape(tx.init, params),
+                                   params)
+    smallest = min(p.size for p in jax.tree_util.tree_leaves(params))
+    assert smallest > 1
+    relayouts = {"reshape", "slice", "dynamic_slice", "transpose",
+                 "concatenate", "gather", "squeeze", "copy"}
+    seen = set()
+    for eqn in _eqns(jaxpr.jaxpr):
+        name = eqn.primitive.name
+        seen.add(name)
+        assert name != "pad", eqn
+        if name in relayouts:
+            sizes = [v.aval.size for v in eqn.invars if hasattr(v, "aval")]
+            assert max(sizes, default=0) < smallest, eqn
+    # the walk saw the update: the moments' multiply-adds and the root
+    assert {"mul", "add", "sqrt", "div"} <= seen
 
 
 def test_decay_mask_actually_masks():
@@ -238,9 +339,29 @@ def test_zero1_shard_state_composition_exact():
     plain = fused_adamw(1e-2, weight_decay=0.1, mask=decay_mask,
                         compute_dtype=jnp.bfloat16)
     sharded = shard_state(plain, mesh, min_size=8)
-    pp, _ = _run(plain, params, n_steps=4)
-    sp, ss = _run(sharded, params, n_steps=4)
+    # Both programs are compiled with XLA's fusion pass off, so each runs
+    # the update's arithmetic op by op, as written. Fused, they are the same
+    # HLO arithmetic in two fusion structures — around the padded moments of
+    # ``big`` XLA:CPU recomputes m' and v' inside the fusion that divides
+    # them — and LLVM contracts multiply-adds to FMAs by what shares a loop:
+    # 1 ulp on 65-136 of ``big``'s 9001 masters from step 2 on, moments and
+    # copy bit-equal, and nothing at all with the ISA held under FMA
+    # (PERF.md §6, PR 26). The math is what this test is about.
+    as_written = {"xla_disable_hlo_passes": "fusion"}
+    pp, ps = _run(plain, params, n_steps=4, compiler_options=as_written)
+    sp, ss = _run(sharded, params, n_steps=4, compiler_options=as_written)
     for a, b in zip(jax.tree_util.tree_leaves(pp),
                     jax.tree_util.tree_leaves(sp)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   atol=1e-7, rtol=0)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # the state too, moments and copy, once the stored leaves are back in
+    # the params' shapes (``big`` is the one that is padded and reshaped)
+    assert {k for k, p in params.items()
+            if ss.mu[k].shape != p.shape} == {"big"}
+    for got, want in [(ss.mu, ps.mu), (ss.nu, ps.nu),
+                      (ss.compute, ps.compute)]:
+        for key, p in params.items():
+            back = jnp.ravel(got[key])[:p.size].reshape(p.shape)
+            assert back.dtype == want[key].dtype
+            np.testing.assert_array_equal(
+                np.asarray(back, np.float32), np.asarray(want[key], np.float32)
+            )

@@ -426,7 +426,11 @@ def test_lowered_step_names_the_new_scopes_and_leaves_attention_bare():
         assert any("/jvp(loss_head)/" in p for p in paths)
         assert any("/transpose(jvp(loss_head))/" in p for p in paths)
         if fused:
-            assert "jit(step_fn)/optimizer/pallas_call" in paths
+            # the one-pass update is plain XLA under the scope: its ops
+            # are named, and it brings no kernel of its own
+            assert "jit(step_fn)/optimizer/sqrt" in paths
+            assert not any("/optimizer/" in p and p.endswith("pallas_call")
+                           for p in paths)
         kernels = {p for p in paths if p.endswith("/pallas_call")
                    and re.search(r"/h_\d+/", p)}
         attention = {p for p in kernels if re.search(r"/h_\d+/pallas_call$", p)}

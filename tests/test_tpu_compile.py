@@ -122,18 +122,38 @@ def test_fused_layernorm_fwd_bwd(compile_for_chip, residual):
     assert hlo.count("tpu_custom_call") >= 2  # forward and backward kernels
 
 
-def test_fused_adamw_leaf_embedding_table(compile_for_chip):
-    """The largest leaf of GPT-2 124M (the padded 50304 x 768 table), with
-    the bf16 compute copy written in the same sweep."""
-    def update(g, m, v, p, hyper):
-        return fused_leaf_update(
-            g, m, v, p, hyper[0], hyper[1], hyper[2], b1=0.9, b2=0.999,
-            eps=1e-8, wd=0.1, compute_dtype=BF16,
-        )
+@pytest.mark.parametrize("shape", [
+    (50257, 1024),       # GPT-2 medium's table: rows no tile divides
+    (1024, 3, 16, 64),   # a qkv kernel, heads split: last dimension 64
+    (16, 64, 1024),      # an out kernel
+], ids=["wte_50257x1024", "qkv_1024x3x16x64", "out_16x64x1024"])
+def test_fused_adamw_leaf_is_one_fusion_in_its_own_layout(
+        compile_for_chip, shape):
+    """The update of one leaf as the cells run it (bf16 gradient, f32
+    moments and master, the bf16 copy, ``apply_updates``' add): the TPU
+    compiler makes ONE fusion of it, whose results are the copy, the
+    moments and the new master — the update itself never reaches HBM — and
+    nothing lays the leaf out anew around it."""
+    import re
 
-    leaf = ((50304, 768), F32)
-    hlo = compile_for_chip(update, leaf, leaf, leaf, leaf, ((3,), F32))
-    assert "tpu_custom_call" in hlo
+    def update(g, m, v, p, lr, b1c, b2c):
+        u, m2, v2, copy = fused_leaf_update(
+            g, m, v, p, lr, b1c, b2c, b1=0.9, b2=0.999, eps=1e-8, wd=0.1,
+            compute_dtype=BF16,
+        )
+        return p + u, m2, v2, copy
+
+    leaf = (shape, F32)
+    hlo = compile_for_chip(update, (shape, BF16), leaf, leaf, leaf,
+                           *[((), F32)] * 3)
+    entry = hlo[hlo.index("ENTRY"):]
+    # (result type, op) of every instruction that yields an array
+    ops = [op for typ, op in re.findall(
+        r"^\s+(?:ROOT )?\S+ = (.*?) ([\w\-]+)\(", entry, re.M
+    ) if re.search(r"\[\d", typ)]
+    assert ops.count("fusion") == 1, ops
+    assert not {"copy", "reshape", "transpose", "pad", "slice",
+                "custom-call"} & set(ops), ops
 
 
 def test_fused_decode_attention_b16_s1024(compile_for_chip):
@@ -185,3 +205,89 @@ def test_attention_kernel_keeps_block_name_on_mesh(topo, monkeypatch):
         r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
     assert len(kernels) >= 2  # forward and backward
     assert all(re.match(r"^h_3(\.\d+)?$", k) for k in kernels), kernels
+
+
+def test_zero1_fused_adamw_updates_a_sharded_leaf_in_shards(topo):
+    """``shard_state(fused_adamw)`` on a ``data=4`` mesh in the cells' regime
+    (bf16 gradients, f32 moments and masters, the bf16 copy, the clip,
+    ``apply_updates``' add; state and masters donated, everything
+    replicated but the state). A leaf stored sharded on a dimension the
+    axis divides is ONE fusion whose results (copy, moments, new master)
+    have the SHARD's shape; no moment is gathered or reduced and nothing is
+    reshaped, padded or transposed; after it comes the one all-gather
+    ZeRO-1 always pays, of the new master. What the compiler does add is
+    whole-leaf copies beside that all-gather: of the donated master before
+    the fusion, of the gathered one into the result. A compile for a
+    described mesh says what the program holds, not what it costs: no cell
+    runs ZeRO-1 (PERF.md §7)."""
+    import math
+    import re
+
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpudist.mesh import DATA_AXIS, create_mesh
+    from tpudist.optim import decay_mask, fused_adamw, shard_state
+
+    mesh = create_mesh(devices=topo.devices)
+    world = mesh.shape[DATA_AXIS]
+    shapes = {"wte": (50257, 1024), "qkv": (1024, 3, 16, 64),
+              "out": (16, 64, 1024), "bias": (4096,)}
+    tx = shard_state(
+        fused_adamw(3e-4, weight_decay=0.1, mask=decay_mask, clip_norm=1.0,
+                    compute_dtype=BF16),
+        mesh,
+    )
+    everywhere = NamedSharding(mesh, P())
+    params, grads = (
+        {k: jax.ShapeDtypeStruct(s, dtype, sharding=everywhere)
+         for k, s in shapes.items()}
+        for dtype in (F32, BF16)
+    )
+    stored = tx.state_shardings(params)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(tx.init, params), stored,
+    )
+    # every leaf here keeps its shape and is split over the axis
+    assert all(m.shape == shapes[k] and DATA_AXIS in m.sharding.spec
+               for k, m in state.mu.items())
+
+    def update(g, s, p):
+        u, s2 = tx.update(g, s, p)
+        return optax.apply_updates(p, u), s2
+
+    hlo = jax.jit(
+        update, donate_argnums=(1, 2),
+        out_shardings=(jax.tree.map(lambda _: everywhere, params), stored),
+    ).lower(grads, state, params).compile().as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    # (name, result type, op, operands) of every instruction
+    instrs = re.findall(
+        r"^\s+(?:ROOT )?(\S+) = (.*?) ([\w\-]+)\((.*?)\)[,\n]", entry, re.M)
+
+    def size(typ):  # elements of the largest array in a result type
+        return max((math.prod(map(int, dims.split(",")))
+                    for dims in re.findall(r"\[([\d,]+)\]", typ)), default=0)
+
+    leaf = {math.prod(s) for s in shapes.values()}
+    shard = {n // world for n in leaf}
+    made_by = {name.lstrip("%"): op for name, _, op, _ in instrs}
+
+    updates = [typ for _, typ, op, _ in instrs
+               if op == "fusion" and typ.count("[") == 4]
+    assert sorted(map(size, updates)) == sorted(shard), updates
+    # no fusion yields a whole leaf: the update runs on shards alone
+    assert not [typ for _, typ, op, _ in instrs
+                if op == "fusion" and size(typ) in leaf]
+    gathers = [typ for _, typ, op, _ in instrs if op == "all-gather"]
+    assert sorted(map(size, gathers)) == sorted(leaf), gathers
+    assert all(typ.startswith("f32") for typ in gathers), gathers
+    ops = {op for _, typ, op, _ in instrs if size(typ) in leaf | shard}
+    assert not {"all-reduce", "all-to-all", "collective-permute", "reshape",
+                "transpose", "pad", "dynamic-update-slice"} & ops, ops
+    copied = [made_by[args.split(",")[0].split()[-1].lstrip("%")]
+              for _, typ, op, args in instrs
+              if op == "copy" and size(typ) in leaf]
+    assert set(copied) <= {"parameter", "all-gather"}, copied
+    assert len(copied) <= 2 * len(shapes), copied

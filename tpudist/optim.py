@@ -92,11 +92,12 @@ def make_optimizer(
     ``skip_nonfinite_updates`` wraps the chain in
     :func:`tpudist.amp.skip_nonfinite`.
 
-    ``fused=True`` builds :func:`fused_adamw` instead — the one-pass
-    Pallas update kernel with bit-compatible math (``optimizer="adam"``
-    only; clipping/decay/mask/skip all compose). ``compute_dtype`` (with
-    ``fused``) keeps the in-state compute-precision param copy the fused
-    train step's forward reads (``make_train_step(fused=...)``).
+    ``fused=True`` builds :func:`fused_adamw` instead — the one-formula
+    update, one sweep per leaf, with bit-compatible math
+    (``optimizer="adam"`` only; clipping/decay/mask/skip all compose).
+    ``compute_dtype`` (with ``fused``) keeps the in-state compute-precision
+    param copy the fused train step's forward reads
+    (``make_train_step(fused=...)``).
     """
     if b2 is None:
         b2 = 0.99 if optimizer == "lion" else 0.999
@@ -286,7 +287,8 @@ def shard_state(
 
     The wrapped transformation stores every state leaf per
     :func:`_zero1_layout`; ``update`` restores the natural layout in-graph
-    (a reshape/slice XLA folds away), runs the inner update, and re-stores
+    (nothing to do for a ``shard`` leaf; a gather, slice and reshape of the
+    leaf for a ``pad`` one), runs the inner update, and re-stores
     — so the inner optimizer's math is untouched and the wrapped step is
     numerically the replicated step (``tests/test_sharded_optim.py`` holds
     it to that on an emulated mesh, non-divisible shapes included).
@@ -400,24 +402,24 @@ def shard_state(
 
 
 # --------------------------------------------------------------------------
-# Fused one-pass AdamW (tpudist.ops.fused_update) — the non-GEMM-tail lever
+# Fused one-pass AdamW (tpudist.ops.fused_update)
 # --------------------------------------------------------------------------
 #
-# docs/PERF.md §4b measured the 124M step's residual as the serial
-# elementwise tail BETWEEN the matmuls; the optax Adam chain (moment pass,
-# bias correction, decayed weights, lr scale) plus the per-step fp32→bf16
-# param casts are the optimizer's share of it. fused_adamw runs the whole
-# update as ONE Pallas sweep per leaf — read (g, m, v, p), write (m', v',
-# update, bf16 compute copy) — behind the standard optax (init, update)
-# surface, so everything that composes with an optimizer here (ZeRO-1
-# shard_state, amp.skip_nonfinite, make_train_step's guard_nonfinite,
-# telemetry's norms) composes with it unchanged.
+# The optax Adam chain (moment pass, bias correction, decayed weights, lr
+# scale) plus the per-step fp32→bf16 param casts are params-sized passes of
+# their own. fused_adamw writes the whole update as ONE elementwise
+# expression per leaf — read (g, m, v, p), write (m', v', update, bf16
+# compute copy) in the leaf's own layout, one loop fusion XLA also folds the
+# clip's scale and apply_updates' add into — behind the standard optax
+# (init, update) surface, so everything that composes with an optimizer here
+# (ZeRO-1 shard_state, amp.skip_nonfinite, make_train_step's
+# guard_nonfinite, telemetry's norms) composes with it unchanged.
 
 
 class FusedAdamWState(NamedTuple):
     """State of :func:`fused_adamw`. ``compute`` is the params-shaped
-    compute-dtype copy (written by the kernel in the same sweep as the
-    moments) or the EMPTY tuple when ``compute_dtype`` is off — zero
+    compute-dtype copy (written in the same sweep as the moments) or the
+    EMPTY tuple when ``compute_dtype`` is off — zero
     leaves, so checkpoints/shardings of copy-less states carry nothing
     extra (the ``TrainState.comm_residual`` convention)."""
 
@@ -430,7 +432,7 @@ class FusedAdamWState(NamedTuple):
 @dataclasses.dataclass(frozen=True)
 class FusedAdamW:
     """Duck-typed ``(init, update)`` optimizer running the one-pass fused
-    AdamW kernel (:mod:`tpudist.ops.fused_update`). Built by
+    AdamW update (:mod:`tpudist.ops.fused_update`). Built by
     :func:`fused_adamw`; detected through wrappers (``shard_state``,
     ``amp.skip_nonfinite`` — both expose ``inner``) by
     :func:`find_fused`."""
@@ -452,13 +454,12 @@ def fused_adamw(
     mask: Callable | None = None,
     clip_norm: float | None = None,
     compute_dtype: Any = None,
-    min_kernel_elems: int | None = None,
 ) -> FusedAdamW:
     """One-pass fused AdamW with an optax-compatible surface.
 
     Matches ``optax.adamw(lr, b1, b2, eps, weight_decay, mask=mask)``
-    (and plain ``optax.adam`` at ``weight_decay=0``) BIT-FOR-BIT in
-    interpret mode — same division-form bias correction, same
+    (and plain ``optax.adam`` at ``weight_decay=0``) BIT-FOR-BIT on the
+    CPU — same division-form bias correction, same
     ``√v̂ + eps`` denominator, same decay-then-scale order
     (tests/test_fused_update.py pins it) — while collapsing the chain's
     per-transform tree passes into one HBM sweep per leaf.
@@ -466,25 +467,27 @@ def fused_adamw(
     ``mask``: callable ``params → tree of static bools`` selecting decayed
     leaves (:func:`decay_mask`); ``None`` decays everything (optax's
     convention). ``clip_norm`` prepends ``clip_by_global_norm`` with
-    optax's exact arithmetic (the global norm is one tree reduction XLA
-    fuses with the backward; the scale rides into the kernel's read of
-    ``g``). ``learning_rate`` may be a schedule (called on the
-    pre-increment step count, optax's convention).
+    optax's exact arithmetic (the global norm is one tree reduction; the
+    scale rides into the sweep's read of ``g``). ``learning_rate`` may be
+    a schedule (called on the pre-increment step count, optax's
+    convention).
 
     ``compute_dtype`` (e.g. ``jnp.bfloat16``) adds a params-shaped compute
-    copy to the state, refreshed by the kernel in the same sweep as the
-    moments: ``compute = compute_dtype(p + update)``, bit-identical to
+    copy to the state, refreshed in the same sweep as the moments:
+    ``compute = compute_dtype(p + update)``, bit-identical to
     casting the post-update master. ``make_train_step(fused=...)`` routes
     the next step's forward through it, which deletes the per-step
     fp32→bf16 cast of every parameter AND halves the forward's param-read
     bytes. Float leaves cast; non-float leaves ride along unchanged.
 
     ZeRO-1: apply ``tpudist.optim.shard_state`` AROUND this (the usual
-    order) — the update math runs on the restored layout; inside a train
-    step the kernel runs per chip on replicated operands, so a sharded leaf
-    is gathered around it: measure before combining on hardware
-    (pallas_call has no GSPMD rule — see tpudist.ops.fused_update's module
-    docstring).
+    order) — the update math runs on the restored layout, and being plain
+    elementwise XLA it partitions like any other op. Compiled for a
+    described 2x2 mesh (tests/test_tpu_compile.py; not measured on the
+    chip), a leaf stored sharded on a divisible dimension is one fusion at
+    the shard's shape, followed by the all-gather of the new master that
+    ZeRO-1 always pays; a leaf in the pad-and-reshape layout (no divisible
+    dimension) is gathered, reshaped and re-stored around its update.
     """
     from tpudist.ops.fused_update import fused_leaf_update
 
@@ -554,8 +557,6 @@ def fused_adamw(
                     compute_dtype if compute_dtype is not None
                     and jnp.issubdtype(p.dtype, jnp.floating) else None
                 ),
-                **({} if min_kernel_elems is None
-                   else {"min_kernel_elems": min_kernel_elems}),
             )
             for g, m, v, p, decayed in zip(
                 g_leaves, m_leaves, v_leaves, p_leaves, wd_leaves
@@ -586,7 +587,7 @@ def find_fused(tx) -> FusedAdamW | None:
     """The :class:`FusedAdamW` inside ``tx``, walking the wrappers that
     expose ``inner`` (:class:`ShardedStateOptimizer`,
     ``amp.SkipNonfinite``) — or ``None``. An ``optax.chain`` hides its
-    members, so a chained fused optimizer keeps the kernel update but is
+    members, so a chained fused optimizer keeps the one-pass update but is
     invisible to the compute-copy wiring; build clipping into
     :func:`fused_adamw` (``clip_norm=``) instead of chaining."""
     seen = 0

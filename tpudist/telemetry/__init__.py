@@ -186,9 +186,9 @@ class TelemetrySink:
     ``step_breakdown``, ``mfu``, ``throughput``, ``memory``, ``anomaly``,
     ``heartbeat``, ``train_time``, ``run_meta``, ``comm`` (explicit
     gradient reduction's one-time wire accounting), ``fusion`` (one-time
-    step-fusion config: which Pallas kernels — fused LN, fused optimizer
-    — the compiled step engaged, and the compute-copy dtype), ``warning``
-    (tagged one-shot diagnoses, e.g. ``h2d_link_bound``,
+    step-fusion config: which fusions — the Pallas fused LN, the one-pass
+    optimizer — the compiled step engaged, and the compute-copy dtype),
+    ``warning`` (tagged one-shot diagnoses, e.g. ``h2d_link_bound``,
     ``checkpoint_fallback``), ``reshard`` (one-time elastic-resume record:
     cross-world-size ZeRO-1 relayout, residual flush, cursor remap),
     ``compile_cache`` (one-time AOT executable-cache outcome:

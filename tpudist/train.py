@@ -675,16 +675,12 @@ def make_train_step(
             grads = jax.tree_util.tree_map(lambda g: g / grad_accum, gsum)
             loss = lsum / grad_accum
 
-        # the step's mesh in context: a kernel without a GSPMD rule (the
-        # fused optimizer's sweep) wraps itself in a shard_map over it
         # named for the device trace (metadata only): without a scope the
-        # update's ops sit at jit(step_fn)/<primitive> and its kernels are
-        # named after the step
+        # update's ops sit at jit(step_fn)/<primitive>
         with jax.named_scope("optimizer"):
-            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
-                updates, new_opt = tx.update(
-                    grads, state.opt_state, state.params
-                )
+            updates, new_opt = tx.update(
+                grads, state.opt_state, state.params
+            )
             new_params = optax.apply_updates(state.params, updates)
         if reducer is not None and reducer.error_feedback:
             # a non-finite step (bf16 spike, data glitch) must not bank its
