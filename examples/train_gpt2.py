@@ -88,9 +88,13 @@ def parse_args(argv=None):
                    "[B,S,V] logits never materialize — raises the max batch/"
                    "seq_len per chip (dense models only)")
     # model family + size
-    p.add_argument("--arch", default="gpt2", choices=["gpt2", "llama"],
+    p.add_argument("--arch", default="gpt2",
+                   choices=["gpt2", "llama", "zaya"],
                    help="decoder family: GPT-2 (learned positions, GELU MLP, "
-                   "tied head) or Llama (RoPE, RMSNorm, SwiGLU, GQA)")
+                   "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA) or ZAYA1 "
+                   "(compressed convolutional attention, top-1 experts "
+                   "routed by an MLP router, no token dropped; --experts, "
+                   "--held, --head_dim, --router_width, --ffn_dim)")
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
@@ -98,6 +102,16 @@ def parse_args(argv=None):
                    help="llama GQA K/V heads (0 = MHA)")
     p.add_argument("--ffn_dim", default=0, type=int,
                    help="llama SwiGLU width (0 = 8/3*hidden rounded to 256)")
+    p.add_argument("--head_dim", default=0, type=int,
+                   help="zaya: size of a latent attention head (0 = "
+                   "hidden_dim / num_heads)")
+    p.add_argument("--router_width", default=256, type=int,
+                   help="zaya: width of the MLP router and of its carry")
+    p.add_argument("--held", default="", type=str,
+                   help="zaya: 'first,count' — the contiguous experts this "
+                   "run holds (one shard's share of an expert-parallel "
+                   "layer: the router scores all --experts, tokens of the "
+                   "others contribute nothing here); empty = all")
     p.add_argument("--rope_theta", default=10000.0, type=float)
     p.add_argument("--tie_embeddings", action="store_true",
                    help="llama: tie the LM head to the embedding")
@@ -282,7 +296,8 @@ def main(argv=None):
     n_dev = jax.device_count()
     if args.expert_axis:
         expert_axis = args.expert_axis
-    elif args.experts:
+    elif args.experts and args.arch != "zaya":
+        # (zaya's dropless layer runs one shard's experts, no exchange)
         # largest axis that divides both the expert count (weights shard
         # evenly) and the devices left over from the other model axes
         avail = max(n_dev // (args.tensor * args.pipe * args.cp), 1)
@@ -347,6 +362,30 @@ def main(argv=None):
                 max_seq_len=args.seq_len, hidden_dim=args.hidden_dim,
                 depth=args.depth, num_heads=args.num_heads, dtype=dtype,
                 attn_impl=args.attn, schedule=args.pipe_schedule,
+            )
+        if args.arch == "zaya":
+            from tpudist.models.zaya import Zaya
+            from tpudist.parallel.ep import Routing
+
+            if args.dropout or args.scan_layers or args.generate or args.init_hf:
+                raise SystemExit(
+                    "zaya trains unrolled, without dropout; --generate and "
+                    "--init_hf have no path for it yet"
+                )
+            held = tuple(int(n) for n in args.held.split(",")) if args.held else None
+            return Zaya(
+                vocab_size=args.vocab_size, max_seq_len=args.seq_len,
+                hidden_dim=args.hidden_dim, depth=args.depth,
+                num_heads=args.num_heads,
+                num_kv_heads=args.num_kv_heads or args.num_heads,
+                head_dim=args.head_dim or args.hidden_dim // args.num_heads,
+                ffn_dim=args.ffn_dim or args.hidden_dim,
+                routing=Routing(
+                    args.experts or 16, top_k=args.moe_top_k, held=held,
+                    router="mlp", router_width=args.router_width,
+                ),
+                rope_theta=args.rope_theta, remat_policy=args.remat_policy,
+                dtype=dtype, attn_impl=args.attn, mesh=mesh,
             )
         if args.arch == "llama":
             from tpudist.models.llama import Llama

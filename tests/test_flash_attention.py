@@ -161,3 +161,19 @@ def test_pallas_bwd_kv_len_matches_scan_bwd():
     # padded keys receive zero gradient
     assert np.abs(np.asarray(got[1][:, :, kv_len:])).max() == 0.0
     assert np.abs(np.asarray(got[2][:, :, kv_len:])).max() == 0.0
+
+
+@pytest.mark.parametrize("seq_q,seq_k,head_dim,want", [
+    (4096, 4096, 128, (512, 1024, True)),   # measured: PERF.md §6, PR 28
+    (2048, 2048, 128, (512, 1024, True)),
+    (2304, 2304, 128, (256, 256, True)),    # the largest that divide
+    (4096, 4096, 64, (128, 128, False)),    # the scan backward's shapes
+    (1024, 1024, 128, (128, 128, False)),
+    (128, 4096, 128, (128, 128, False)),
+])
+def test_default_blocks_follow_the_shape(seq_q, seq_k, head_dim, want):
+    from tpudist.ops.flash_attention import default_blocks
+
+    assert default_blocks(seq_q, seq_k, head_dim) == want
+    block_q, block_k, _ = want
+    assert seq_q % block_q == 0 and seq_k % block_k == 0

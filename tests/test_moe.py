@@ -11,9 +11,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from flax import linen as nn
 
 from tpudist import mesh as mesh_lib
-from tpudist.parallel.ep import MoEMlp, expert_capacity, top_k_dispatch
+from tpudist.parallel.ep import (
+    MoEMlp, Routing, dropless_moe, expert_capacity, top_k_dispatch,
+)
 
 
 def test_expert_capacity():
@@ -461,3 +464,180 @@ def test_moe_gpt2_loss_decreases():
             first = float(metrics["loss"])
     last = float(metrics["loss"])
     assert last < first, f"loss did not decrease: {first} -> {last}"
+
+
+# -- the dropless layer over held experts (ep.dropless_moe) -------------------
+
+
+class _Dropless(nn.Module):
+    """``dropless_moe`` under a parent of its own, as a block calls it."""
+
+    routing: Routing
+    ffn_dim: int
+
+    @nn.compact
+    def __call__(self, u, r=None):
+        return dropless_moe(self, u, r, routing=self.routing,
+                            ffn_dim=self.ffn_dim)
+
+
+def _moe_inputs(T=48, d=16, seed=0):
+    return jax.random.normal(jax.random.key(seed), (2, T // 2, d), jnp.float32)
+
+
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_dropless_matches_einsum_oracle_when_nothing_drops(top_k):
+    """Sorted rows + one grouped product ≡ the one-hot einsum layer given a
+    capacity no token exceeds (one group, capacity = every token): outputs
+    and the gradients of the input, the router and every expert weight.
+    Float32; 1e-5 covers the different summation orders."""
+    E, ff = 4, 24
+    x = _moe_inputs()
+    oracle = MoEMlp(num_experts=E, top_k=top_k, capacity_factor=float(E),
+                    ffn_dim=ff, expert_act="swiglu", num_groups=1,
+                    dispatch_impl="einsum")
+    theirs = nn.meta.unbox(oracle.init(jax.random.key(1), x)["params"])
+    ours = {"moe_router": {"kernel": theirs["router"]},
+            "moe_experts": {k: theirs[k]
+                            for k in ("w_gate", "w_up", "w_down")}}
+    layer = _Dropless(Routing(E, top_k=top_k), ff)
+
+    def f_ours(p, x):
+        return layer.apply({"params": p}, x)[0]
+
+    def f_theirs(p, x):
+        return oracle.apply({"params": p}, x)
+
+    np.testing.assert_allclose(f_ours(ours, x), f_theirs(theirs, x),
+                               rtol=1e-5, atol=1e-6)
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+    g_ours = jax.grad(lambda p, x: jnp.sum(f_ours(p, x) * probe),
+                      argnums=(0, 1))(ours, x)
+    g_theirs = jax.grad(lambda p, x: jnp.sum(f_theirs(p, x) * probe),
+                        argnums=(0, 1))(theirs, x)
+    np.testing.assert_allclose(g_ours[1], g_theirs[1], rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(g_ours[0]["moe_router"]["kernel"],
+                               g_theirs[0]["router"], rtol=1e-4, atol=1e-6)
+    for k in ("w_gate", "w_up", "w_down"):
+        np.testing.assert_allclose(g_ours[0]["moe_experts"][k],
+                                   g_theirs[0][k], rtol=1e-4, atol=1e-6)
+
+
+def test_dropless_drops_no_token_when_the_router_collapses():
+    """A router forced onto one expert: every token is computed by it (the
+    capacity layer at factor 1 zeroes all but T/E of them), and the
+    counters say so."""
+    E, d, ff = 4, 16, 24
+    x = jnp.abs(_moe_inputs(d=d)) + 0.1  # positive: column 2 always wins
+    layer = _Dropless(Routing(E, top_k=1), ff)
+    params = layer.init(jax.random.key(1), x)["params"]
+    collapse = jnp.zeros((d, E)).at[:, 2].set(1.0)
+    params["moe_router"]["kernel"] = collapse
+    (y, _), sown = layer.apply({"params": params}, x, mutable=["moe_stats"])
+    w = params["moe_experts"]
+    gate = jax.nn.softmax(x @ collapse)[..., 2:3]
+    dense = (jax.nn.silu(x @ w["w_gate"][2]) * (x @ w["w_up"][2])) \
+        @ w["w_down"][2]
+    np.testing.assert_allclose(y, gate * dense, rtol=1e-5, atol=1e-6)
+    T = x.shape[0] * x.shape[1]
+    stats = sown["moe_stats"]
+    np.testing.assert_array_equal(stats["tokens"][0], [0, 0, T, 0])
+    assert float(stats["held_share"][0]) == 1.0
+    assert float(stats["load_max_over_mean"][0]) == pytest.approx(E)
+
+    capped = MoEMlp(num_experts=E, top_k=1, capacity_factor=1.0, ffn_dim=ff,
+                    expert_act="swiglu", num_groups=1)
+    theirs = {"router": collapse, **w}
+    kept = jnp.any(capped.apply({"params": theirs}, x) != 0, axis=-1)
+    assert int(jnp.sum(kept)) == T // E  # the rest were dropped
+
+
+def test_selection_bias_moves_the_choice_and_nothing_else():
+    """``Routing.selection_bias`` decides WHICH expert a token takes; the
+    gate stays the chosen expert's unbiased probability and the router's
+    gradient is that gate's. A bias of nought is the plain top-1."""
+    E, d, ff = 4, 16, 24
+    x = _moe_inputs(d=d)
+    plain = _Dropless(Routing(E, top_k=1), ff)
+    params = plain.init(jax.random.key(1), x)["params"]
+    onto_1 = lambda logits: jnp.zeros_like(logits).at[..., 1].set(1e3)
+    forced = _Dropless(Routing(E, top_k=1, selection_bias=onto_1), ff)
+    nought = _Dropless(Routing(E, top_k=1, selection_bias=jnp.zeros_like), ff)
+    w = params["moe_experts"]
+
+    def dense(router, x):
+        gate = jax.nn.softmax(x @ router)[..., 1:2]
+        return gate * ((jax.nn.silu(x @ w["w_gate"][1]) * (x @ w["w_up"][1]))
+                       @ w["w_down"][1])
+
+    def ours(layer, router, x):
+        p = dict(params, moe_router={"kernel": router})
+        return layer.apply({"params": p}, x)[0]
+
+    router = params["moe_router"]["kernel"]
+    np.testing.assert_allclose(ours(forced, router, x), dense(router, x),
+                               rtol=1e-5, atol=1e-6)
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+    got = jax.grad(lambda r: jnp.sum(ours(forced, r, x) * probe))(router)
+    want = jax.grad(lambda r: jnp.sum(dense(r, x) * probe))(router)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
+    np.testing.assert_array_equal(ours(nought, router, x),
+                                  ours(plain, router, x))
+
+
+@pytest.mark.parametrize("selection_bias", [None, "sequence_quantile"])
+def test_two_shares_add_up_to_the_uncut_reference_layer(selection_bias):
+    """The share test: experts 0-7 and 8-15 as two shares of the program's
+    layer, each told what it holds and given its own half of the weights,
+    add up to what the plain reference gives for the whole layer of 16 —
+    the router (MLP, carried state, top-1) runs alike on both and is
+    counted once; by the plain top-1, and by the ZAYA1 cell's bias on the
+    selection (the family's function in the program, the reference's own
+    lines there). Float32, 1e-5: summation order only."""
+    from benchmarks.families import zaya as family
+    from benchmarks.reference import zaya as reference
+    E, d, ff, R = 16, 16, 24, 8
+    x = jax.random.normal(jax.random.key(0), (2, 64, d), jnp.float32)
+    r_prev = jax.random.normal(jax.random.key(3), (2, 64, R), jnp.float32)
+    routing = dict(top_k=1, router="mlp", router_width=R,
+                   selection_bias=family.SELECTION_BIAS[selection_bias])
+    whole = _Dropless(Routing(E, **routing), ff)
+    params = whole.init(jax.random.key(1), x, r_prev)["params"]
+    # biases and scales off their initial 0 / 1, as the harness draws them
+    params = jax.tree_util.tree_map(
+        lambda p: p + 0.05 * jax.random.normal(jax.random.key(p.size), p.shape),
+        params)
+
+    flat = {"moe_router/" + "/".join(k.key for k in path): v
+            for path, v in jax.tree_util.tree_flatten_with_path(
+                params["moe_router"])[0]}
+    flat.update({f"moe_experts/{k}": v
+                 for k, v in params["moe_experts"].items()})
+    want, want_r = reference.expert_sublayer(
+        x, r_prev, flat, num_experts=E, first=0, count=E, eps=1e-5,
+        selection_bias=selection_bias)
+
+    total, shares = 0.0, []
+    for first in (0, 8):
+        mine = dict(params, moe_experts={
+            k: v[first:first + 8] for k, v in params["moe_experts"].items()})
+        share = _Dropless(Routing(E, held=(first, 8), **routing), ff)
+        (y, r), sown = share.apply({"params": mine}, x, r_prev,
+                                   mutable=["moe_stats"])
+        np.testing.assert_allclose(r, want_r, rtol=1e-5, atol=1e-6)
+        total = total + y
+        shares.append(float(sown["moe_stats"]["held_share"][0]))
+    np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-6)
+    assert sum(shares) == pytest.approx(1.0)
+    assert 0.0 < shares[0] < 1.0  # both halves hold some of the tokens
+    if selection_bias:  # ... about half each (loosely: 4 tokens an expert)
+        assert abs(shares[0] - 0.5) < 0.2
+
+
+def test_routing_says_what_it_cannot_hold():
+    with pytest.raises(ValueError, match="held"):
+        Routing(16, held=(12, 8))
+    with pytest.raises(ValueError, match="scoring"):
+        Routing(16, scoring="sigmoid")
+    assert Routing(16).held_range == (0, 16)
+    assert Routing(16, held=(8, 8)).held_range == (8, 8)

@@ -291,3 +291,76 @@ def test_zero1_fused_adamw_updates_a_sharded_leaf_in_shards(topo):
               if op == "copy" and size(typ) in leaf]
     assert set(copied) <= {"parameter", "all-gather"}, copied
     assert len(copied) <= 2 * len(shapes), copied
+
+
+def test_zaya_cell_step_compiles_and_routes_without_one_hot_products(
+        topo, monkeypatch):
+    """The ``zaya1_8b_train_s4096`` cell's whole train step — its own
+    configuration, traffic and family file, 494.8M parameters, 16,384
+    tokens — compiled for one described chip: it fits, the attention kernel
+    is there under the name the trace reader looks for (``cca_attn.<k>``),
+    the grouped products are the compiler's ragged-dot kernels, and no
+    matrix product on the token path (a 2048-wide operand) takes a
+    ``[tokens, experts]`` one-hot operand — only the router's own last
+    layer is that shape."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import common, zaya as family
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import TrainState, make_train_step
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    # the family resolves ``attn auto`` for this process's CPU: answer for
+    # the chip, as ``compile_for_chip`` does for the kernels
+    monkeypatch.setattr(common, "resolve_attn", lambda requested, seq: "flash")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/zaya1-8b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks/traffic/train_s4096_b4.json")) as f:
+        traffic = json.load(f)
+    mesh = mesh_lib.create_mesh(devices=topo.devices[:1])
+    built = family.build(config, traffic, mesh)
+    everywhere = NamedSharding(mesh, P())
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        tree)
+    params = placed(built["param_shapes"])
+    state = TrainState(
+        step=jax.ShapeDtypeStruct((), I32, sharding=everywhere),
+        params=params, batch_stats={},
+        opt_state=placed(jax.eval_shape(built["tx"].init, params)))
+    kw = built["fit"]
+    step = make_train_step(
+        built["model"], built["tx"], mesh, loss_fn=kw["loss_fn"],
+        input_key="tokens", label_key="tokens",
+        forward_loss=kw["forward_loss"], fused=kw["fused"])
+    tokens = traffic["per_chip_batch"] * traffic["seq_len"]
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (traffic["per_chip_batch"], traffic["seq_len"]), I32,
+        sharding=everywhere)}
+    compiled = step.jitted.lower(state, batch).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 4e9 < held < 12.5e9, held  # + the harness's 2 GB copy <= 14.5 GB
+    hlo = compiled.as_text()
+    kernels = re.findall(
+        r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
+    attention = [k for k in kernels if re.match(family.ATTENTION_OPS, k)]
+    # per layer: forward, the forward run again under recomputation, dkv, dq
+    assert len(attention) == 4 * config["num_hidden_layers"], kernels
+    assert sum(k.startswith("ragged-dot") for k in kernels) >= \
+        9 * config["num_hidden_layers"]
+    experts = {config["num_experts"], config["num_experts_held"]}
+    wide = config["hidden_size"]
+    for line in hlo.splitlines():
+        if " dot(" not in line and " convolution(" not in line:
+            continue
+        shapes = [tuple(int(n) for n in dims.split(","))
+                  for dims in re.findall(r"\[([\d,]+)\]", line)]
+        one_hot = any(tokens in s and experts & set(s) for s in shapes)
+        token_path = any(tokens in s and wide in s for s in shapes)
+        assert not (one_hot and token_path), line

@@ -34,13 +34,25 @@ from tpudist.ops.attention import multi_head_attention
 from tpudist.parallel.tp import partitioned as _partitioned
 
 
-def apply_rope(x, *, theta: float = 10000.0, positions=None):
+def apply_rope(x, *, theta: float = 10000.0, positions=None,
+               rotary_dim: int | None = None):
     """Rotary position embedding over ``x: [B, S, H, D]`` (rotate-half
     convention). Angles in fp32; output in ``x.dtype``. ``positions`` is
     ``[S]`` (shared across the batch) or ``[B, S]`` (per-row absolute
     positions — slot-pooled decode, where every cache slot sits at its own
-    sequence length)."""
+    sequence length). ``rotary_dim`` rotates the first ``rotary_dim``
+    channels of every head and passes the rest through untouched (a
+    ``partial_rotary_factor`` < 1); the default is the whole head."""
     b, s, h, d = x.shape
+    if rotary_dim is not None and rotary_dim != d:
+        if not 0 < rotary_dim < d or rotary_dim % 2:
+            raise ValueError(
+                f"rotary_dim {rotary_dim} must be even and within the head "
+                f"size {d}"
+            )
+        rotated = apply_rope(x[..., :rotary_dim], theta=theta,
+                             positions=positions)
+        return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if positions is None:

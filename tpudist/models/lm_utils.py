@@ -135,7 +135,7 @@ def chunked_ce_sum(head_w, h, targets, pos_mask, chunk: int):
     )
 
 
-def chunked_lm_forward(model, chunk: int = 256):
+def chunked_lm_forward(model, chunk: int = 256, *, moe_stats: bool = False):
     """Fused next-token loss that never materializes the [B,S,V] logits.
 
     The plain path's fp32 logits are the HBM high-water mark at realistic
@@ -152,6 +152,11 @@ def chunked_lm_forward(model, chunk: int = 256):
     Returns a ``forward_loss`` for :func:`tpudist.train.make_train_step`:
     ``(params, batch_stats, batch) -> (loss, batch_stats)``. Mean CE over
     all positions — identical math to ``lm_loss`` on full logits.
+
+    ``moe_stats=True`` (what ``forward_loss.with_moe_stats()`` builds; the
+    train step asks for it when telemetry wants the router counters)
+    returns ``(loss, (batch_stats, sown))`` with the blocks pass's
+    ``moe_stats`` collection.
     """
     if getattr(model, "dropout", 0.0):
         raise ValueError(
@@ -167,13 +172,17 @@ def chunked_lm_forward(model, chunk: int = 256):
         raise ValueError(f"chunk must be >= 1, got {chunk}")
     wants_aux = bool(getattr(model, "has_aux_loss", False))
 
+    mutable = (["losses"] if wants_aux else []) + (
+        ["moe_stats"] if moe_stats else []
+    )
+
     def forward_loss(params, batch_stats, batch):
         tokens = batch["tokens"]
-        aux = 0.0
-        if wants_aux:
+        aux, updates = 0.0, {}
+        if mutable:
             hidden, updates = model.apply(
                 {"params": params}, tokens, train=True, return_hidden=True,
-                mutable=["losses"],
+                mutable=mutable,
             )
             aux = sum(
                 jax.tree_util.tree_leaves(updates.get("losses", {})), 0.0
@@ -188,11 +197,19 @@ def chunked_lm_forward(model, chunk: int = 256):
         total = chunked_ce_sum(
             lm_head_weight(params), h, targets, jnp.ones((b, s)), chunk
         )
-        return total / (b * s) + aux, batch_stats
+        loss = total / (b * s) + aux
+        if moe_stats:
+            return loss, (batch_stats, updates.get("moe_stats", {}))
+        return loss, batch_stats
 
     # the hook make_train_step(fused="ln") uses to re-close this loss over
     # its fused_ln model clone (the closure above captured `model`; a
     # cloned model would otherwise never reach the forward)
-    forward_loss.rebuild = lambda m: chunked_lm_forward(m, chunk=chunk)
+    forward_loss.rebuild = lambda m: chunked_lm_forward(
+        m, chunk=chunk, moe_stats=moe_stats
+    )
+    forward_loss.with_moe_stats = lambda: chunked_lm_forward(
+        model, chunk=chunk, moe_stats=True
+    )
     forward_loss.model = model
     return forward_loss

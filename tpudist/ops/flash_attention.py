@@ -45,6 +45,24 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
 
+def default_blocks(seq_q: int, seq_k: int, head_dim: int):
+    """``(block_q, block_k, pallas_bwd)`` for a call that names none, from
+    the (128-padded) sequence lengths and the head size. Measured on one
+    v5e chip (PERF.md §6, PR 28) at batch 4, 4096 tokens, 8 heads of 128,
+    causal, bf16: forward + backward 6.9 ms with blocks 512 x 1024 and the
+    Pallas backward against 26.7 ms with 128 x 128 and the scan backward —
+    so heads of 128 from 2048 tokens on take the largest of those blocks
+    that divide the sequence. Every other shape keeps 128 x 128 and the
+    scan backward (at head size 64 and up to 4096 tokens the scan was the
+    faster one)."""
+    if head_dim == 128 and min(seq_q, seq_k) >= 2048:
+        fit = lambda most, seq: next(
+            b for b in (most, most // 2, most // 4, 128) if seq % b == 0
+        )
+        return fit(512, seq_q), fit(1024, seq_k), True
+    return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, False
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref,  # [1,1,bq,d], [1,1,bk,d], [1,1,bk,d]
     o_ref, lse_ref,       # [1,1,bq,d], [1,1,bq,128] (lane-padded, see _flash_fwd)
@@ -453,8 +471,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(
     q, k, v, *, causal: bool = False,
-    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
-    pallas_bwd: bool = False, kv_len: int | None = None,
+    block_q: int | None = None, block_k: int | None = None,
+    pallas_bwd: bool | None = None, kv_len: int | None = None,
 ):
     """Flash attention on [B, S, H, D] inputs (same layout as
     :func:`tpudist.ops.attention.dot_product_attention`).
@@ -464,11 +482,12 @@ def flash_attention(
     right-padded batches), padded query rows are sliced off the output.
 
     ``pallas_bwd`` selects the Pallas FA-2 backward kernels instead of the
-    default blockwise-scan backward. Both are O(S·block) memory; measured on
+    blockwise-scan backward. Both are O(S·block) memory; measured on
     one v5e chip the scan backward is faster at d=64/S≤4096 shapes (XLA
-    fuses it well) while the kernels close the gap by S=8192 — benchmark
-    your shape before flipping this on. TPU-only: on other backends the
-    flag is ignored and the scan backward runs.
+    fuses it well) while the kernels close the gap by S=8192. TPU-only: on
+    other backends the flag is ignored and the scan backward runs.
+    ``block_q``, ``block_k`` and ``pallas_bwd`` left at ``None`` follow the
+    shape (:func:`default_blocks`).
     """
     if q.ndim != 4:
         raise NotImplementedError(f"expected [B,S,H,D], got {q.shape}")
@@ -496,6 +515,11 @@ def flash_attention(
     if d_pad:
         pad = [(0, 0)] * 3 + [(0, d_pad)]
         q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
+    auto = default_blocks(q.shape[1], k.shape[1], d)
+    block_q, block_k, pallas_bwd = (
+        given if given is not None else chosen
+        for given, chosen in zip((block_q, block_k, pallas_bwd), auto)
+    )
     # [B,S,H,D] → [B,H,S,D] for contiguous per-head tiles
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     o = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, pallas_bwd,

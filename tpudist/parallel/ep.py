@@ -48,7 +48,8 @@ count past dense models. TPU-native design:
 
 from __future__ import annotations
 
-from typing import Any
+import dataclasses
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -494,3 +495,268 @@ class MoEMlp(nn.Module):
                 self.mesh, P((DATA_AXIS, FSDP_AXIS), EXPERT_AXIS, None, None)
             ),
         )
+
+
+# -- dropless routing over held experts ---------------------------------------
+#
+# What a present-day expert model asks (ROADMAP Reach, mechanism 1): no
+# ``capacity`` and no dropped token, tokens sorted by expert and ONE grouped
+# product over the experts this shard holds, and a layer that is told which
+# experts those are. The capacity layer above stays as it is for the
+# GPT-2/Llama fields that configure it.
+
+
+@dataclasses.dataclass(frozen=True)
+class Routing:
+    """How an expert layer routes — the one description a model hands
+    :func:`dropless_moe` (no per-model copies of these fields).
+
+    ``held`` is ``(first, count)``: the contiguous experts THIS shard
+    computes; ``None`` holds all. The router always scores all
+    ``num_experts``; a token whose expert is not held contributes nothing
+    here (its part of the result lives on the shard that holds the expert).
+    ``scoring`` is the normalisation of the router's logits (``softmax``
+    over all experts, float32). ``router`` names the router module:
+    ``"linear"`` (one matrix) or ``"mlp"`` (:class:`MlpRouter`, a small MLP
+    of width ``router_width`` whose state is carried from layer to layer).
+    The selection is the top-k of the scores, with no auxiliary loss.
+    ``selection_bias`` is for models that balance their experts' loads by
+    a bias on the SELECTION (ZAYA1, DeepSeek-V3): a function of the
+    router's logits ``[B, S, E]`` that returns what is added to them
+    before the top-k and nowhere else — the gates stay the unbiased
+    probabilities and no gradient passes through it. Those models carry
+    the bias from step to step outside the gradient; this trainer carries
+    no router state yet (ROADMAP Reach 1), so the caller says how the
+    bias is set. ``None``: the plain top-k."""
+
+    num_experts: int
+    top_k: int = 1
+    held: tuple[int, int] | None = None
+    scoring: str = "softmax"
+    router: str = "linear"
+    router_width: int = 256
+    selection_bias: Callable[[jax.Array], jax.Array] | None = None
+
+    def __post_init__(self):
+        first, count = self.held_range
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"held={self.held} lies outside 0..{self.num_experts}"
+            )
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k={self.top_k} of {self.num_experts}")
+        if self.scoring != "softmax":
+            raise ValueError(f"unknown scoring {self.scoring!r}")
+        if self.router not in ("linear", "mlp"):
+            raise ValueError(f"unknown router {self.router!r}")
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held if self.held is not None else (0, self.num_experts)
+
+
+class MlpRouter(nn.Module):
+    """Router as a small MLP with a state carried through the layer stack:
+    ``r = u·W_down + b``; ``r += depth_scale ⊙ r_prev``; logits =
+    ``W_3 gelu(W_2 gelu(W_1 RMSNorm(r)))``, each with its bias. All
+    float32. Returns ``(logits [T, E], r [T, width])``; ``r`` goes on to the
+    next layer's router (``r_prev``; nought before the first)."""
+
+    num_experts: int
+    width: int = 256
+    norm_eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, u, r_prev):
+        # float32 in full: a TPU's default matmul precision rounds float32
+        # operands to bf16, and the selection is an argmax over near ties
+        f32 = dict(dtype=jnp.float32, param_dtype=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+        r = nn.Dense(self.width, name="down", **f32)(u.astype(jnp.float32))
+        depth_scale = self.param(
+            "depth_scale", nn.initializers.ones_init(), (self.width,),
+            jnp.float32,
+        )
+        r = r + depth_scale * r_prev.astype(jnp.float32)
+        h = nn.RMSNorm(epsilon=self.norm_eps, name="norm",
+                       dtype=jnp.float32, param_dtype=jnp.float32)(r)
+        h = nn.gelu(nn.Dense(self.width, name="fc1", **f32)(h))
+        h = nn.gelu(nn.Dense(self.width, name="fc2", **f32)(h))
+        logits = nn.Dense(self.num_experts, name="out", **f32)(h)
+        return logits, r
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation ``perm`` of the rows; its backward is
+    the gather by ``inverse`` instead of the scatter-add a plain ``take``
+    transposes to."""
+    return jnp.take(x, perm, axis=0)
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return jnp.take(x, perm, axis=0), (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return jnp.take(g, inverse, axis=0), None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def select_experts(logits, routing: Routing):
+    """Router logits ``[B, S, E]`` (float32) → ``(idx [B, S, k] int32,
+    gates [B, S, k] float32)``. The gates are the probabilities of the chosen
+    experts: raw for top-1 (normalising a single gate to 1 would cut the
+    router off from the loss, as :func:`top_k_routing` says), normalised
+    to sum 1 for k ≥ 2. ``routing.selection_bias`` moves the choice
+    only."""
+    logits = logits.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    if routing.selection_bias is None:
+        _, idx = jax.lax.top_k(probs, routing.top_k)
+    else:
+        # the softmax keeps the logits' order, so a bias on the logits
+        # reorders the selection and leaves the gates below as they were
+        raw = jax.lax.stop_gradient(logits)
+        _, idx = jax.lax.top_k(raw + routing.selection_bias(raw),
+                               routing.top_k)
+    # the chosen experts' probabilities by a mask over the E columns: a
+    # gather here transposes to a scatter-add over [tokens, E], the mask
+    # stays elementwise work in both passes
+    chosen = jax.nn.one_hot(idx, routing.num_experts, dtype=probs.dtype)
+    gates = jnp.sum(probs[..., None, :] * chosen, axis=-1)
+    if routing.top_k > 1:
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+    return idx.astype(jnp.int32), gates
+
+
+def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
+                 ffn_dim: int, dtype=jnp.float32, mesh=None,
+                 norm_eps: float = 1e-5):
+    """Dropless expert FFN (SiLU-gated) over the experts ``routing.held``.
+
+    Call it inside ``owner``'s compact method: the router
+    (``moe_router``) and the stacked expert weights (``moe_experts``,
+    ``[held, d, ff]``) become ``owner``'s children and the four stages run
+    under ``moe_router`` / ``moe_dispatch`` / ``moe_experts`` /
+    ``moe_combine``, so that a device trace separates them by the first
+    two components of an op's name (``h_N/moe_experts``; the contract in
+    ``tpudist/telemetry/trace.py``).
+
+    ``u``: ``[B, S, d]`` normed input (float32 for the router; the experts
+    compute in ``dtype``). ``r_prev``: the MLP router's carried state
+    ``[B, S, width]`` or ``None``. Returns ``(y [B, S, d], r)``.
+
+    No capacity, no dropped token: the ``T·k`` (token, choice) rows are
+    stably sorted by local expert id, rows whose expert is not held sort
+    past the last group, and one grouped product (``jax.lax.ragged_dot``)
+    a weight runs over the held groups — those rows are neither computed
+    nor stood in for. All shapes are static. The backward is the same
+    grouped product transposed; the un-sort is a gather both ways.
+    Counters (``moe_stats``, sown on ``owner``): ``tokens`` (rows routed
+    to each held expert), ``held_share`` (share of rows whose expert is
+    held), ``load_max_over_mean`` (over the held experts).
+    """
+    if mesh is not None and int(dict(mesh.shape).get(EXPERT_AXIS, 1)) > 1:
+        raise NotImplementedError(
+            "dropless_moe runs one shard's experts without the exchange; "
+            "on an 'expert' mesh axis > 1 give each shard its Routing.held "
+            "under a shard_map (not in this layer yet)"
+        )
+    b, s, d = u.shape
+    T = b * s
+    k = routing.top_k
+    first, count = routing.held_range
+    tokens = u.reshape(T, d)
+    if routing.router == "mlp":
+        if r_prev is None:
+            r_prev = jnp.zeros((b, s, routing.router_width), jnp.float32)
+        logits, r = MlpRouter(
+            routing.num_experts, routing.router_width, norm_eps,
+            name="moe_router",
+        )(tokens, r_prev.reshape(T, -1))
+        r = r.reshape(b, s, -1)
+    else:
+        logits = nn.Dense(
+            routing.num_experts, use_bias=False, dtype=jnp.float32,
+            param_dtype=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+            name="moe_router",
+        )(tokens.astype(jnp.float32))
+        r = r_prev
+    with jax.named_scope("moe_router"):
+        idx, gates = select_experts(
+            logits.reshape(b, s, routing.num_experts), routing
+        )
+
+    with jax.named_scope("moe_dispatch"):
+        local = idx.reshape(T * k) - first
+        held = (local >= 0) & (local < count)
+        # rows of absent experts take the key ``count``: past every group
+        key = jnp.where(held, local, count)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32)
+        )
+        sizes = jnp.sum(
+            key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
+            axis=0, dtype=jnp.int32,
+        )
+        live = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+        tokens = tokens.astype(dtype)
+        # top-1 sorts the tokens themselves (a gather both ways); with k
+        # choices a token has k rows and its gradient is their sum
+        xs = (_permute_rows(tokens, order, inverse) if k == 1
+              else jnp.take(tokens, order // k, axis=0))
+        # the rows past the last group feed nothing: nought in, and (below)
+        # nought out, whatever the grouped product leaves there
+        xs = jnp.where(live, xs, 0)
+
+    out = GroupedExperts(count, ffn_dim, dtype, name="moe_experts")(
+        xs, sizes
+    )
+
+    with jax.named_scope("moe_combine"):
+        out = jnp.where(live, out, 0)
+        w = (gates.reshape(T * k) * held).astype(dtype)
+        y = _permute_rows(out, inverse, order) * w[:, None]
+        if k > 1:
+            y = jnp.sum(y.reshape(T, k, d), axis=1)
+
+    load = sizes.astype(jnp.float32)
+    owner.sow("moe_stats", "tokens", load)
+    owner.sow("moe_stats", "held_share", jnp.sum(load) / (T * k))
+    owner.sow(
+        "moe_stats", "load_max_over_mean",
+        jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
+    )
+    return y.reshape(b, s, d), r
+
+
+class GroupedExperts(nn.Module):
+    """The held experts' SiLU-gated FFNs over rows sorted by expert:
+    ``(silu(x·w_gate[e]) ⊙ x·w_up[e])·w_down[e]`` for the rows of group
+    ``e``, as three grouped products (``jax.lax.ragged_dot``). Rows past
+    ``sum(sizes)`` belong to no group; what the product leaves there is
+    not defined (the TPU lowering leaves values) and the caller masks it."""
+
+    count: int
+    ffn_dim: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, xs, sizes):
+        d = xs.shape[-1]
+        w = lambda name, shape: self.param(
+            name, nn.initializers.lecun_normal(batch_axis=(0,)), shape,
+            jnp.float32,
+        ).astype(self.dtype)
+        wg = w("w_gate", (self.count, d, self.ffn_dim))
+        wu = w("w_up", (self.count, d, self.ffn_dim))
+        wd = w("w_down", (self.count, self.ffn_dim, d))
+        h = nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) \
+            * jax.lax.ragged_dot(xs, wu, sizes)
+        return jax.lax.ragged_dot(h, wd, sizes)

@@ -48,11 +48,36 @@ from typing import Callable, Mapping
 
 import jax
 
-__all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "TRAIN_STEP"]
+__all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "TRAIN_STEP",
+           "BLOCK_SCOPES", "MOE_COUNTERS"]
 
 # the step marker XProf's step-time view groups device work by; the name
 # predates the ``fit/...`` spans and the benchmark's gap labels quote it
 TRAIN_STEP = "tpudist_train"
+
+# Device scopes inside a block of ``tpudist.models.zaya`` — flax module
+# names and ``jax.named_scope``s (metadata only), direct children of the
+# block ``h_<n>`` so that a trace reader that folds an op's name stack to
+# its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
+# them apart — each with the benchmark metric that reads it
+# (docs/OBSERVABILITY.md §8; tests/test_zaya.py holds the model to them):
+BLOCK_SCOPES = {
+    "cca_proj": "cca_mix_ms",    # CCA's down-projection (q, k, v_a, v_b)
+    "cca_mix": "cca_mix_ms",     # q-k mean, convolutions, norms, rotary, value shift
+    "cca_attn": "cca_attn_roofline",  # the attention call; its kernel is ``cca_attn.<k>``
+    "cca_out": "cca_mix_ms",     # CCA's up-projection
+    "moe_router": "moe_ms",      # router MLP, softmax, selection
+    "moe_dispatch": "moe_ms",    # sort by expert, group sizes, gather
+    "moe_experts": "moe_ms",     # the grouped products and the activation (also ``expert_gemm_roofline``)
+    "moe_combine": "moe_ms",     # un-sort, gate
+}
+# Counters the dropless expert layer sows into ``moe_stats`` (a ``moe`` row
+# field ``h_<n>/<counter>`` a logged step), each with its metric:
+MOE_COUNTERS = {
+    "tokens": "expert_gemm_roofline",  # rows routed to each held expert
+    "held_share": "expert_gemm_roofline",  # share of rows whose expert is held (printed beside the expected)
+    "load_max_over_mean": "expert_load_max_over_mean",  # over the held experts
+}
 
 
 class Tracer:

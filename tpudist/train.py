@@ -510,18 +510,26 @@ def make_train_step(
     # parallel/ep.py) declare it via ``has_aux_loss``; duck-typed models
     # without the attribute keep the plain (non-mutable) apply path
     wants_aux = bool(getattr(model, "has_aux_loss", False))
-    # MoE router observability (docs/OBSERVABILITY.md §1): when telemetry
-    # is on and the model sows router stats (tpudist.parallel.ep's
-    # 'moe_stats' collection), the forward also returns them and they ride
-    # the step metrics into the telemetry "moe" rows. Only on the plain
-    # single-pass path: the explicit reducer's grad_fn contract and the
-    # micro-scan's carry both fix the forward's return shape to
-    # (loss, stats), and router stats are a health signal, not gradient
-    # math — the restricted paths simply don't emit the rows.
+    # MoE router observability (docs/OBSERVABILITY.md §1): when the model
+    # sows router stats (tpudist.parallel.ep's 'moe_stats' collection — a
+    # model with an aux loss does under telemetry, one that says so with
+    # ``sows_moe_stats`` always: a few scalars a layer), the forward also
+    # returns them and they ride the step metrics into the telemetry "moe"
+    # rows. On the plain single-pass path, and under a ``forward_loss``
+    # that can return them (``with_moe_stats``: the chunked-CE forward):
+    # the explicit reducer's grad_fn contract and the micro-scan's carry
+    # both fix the forward's return shape to (loss, stats), and router
+    # stats are a health signal, not gradient math — the restricted paths
+    # simply don't emit the rows.
     moe_telemetry = bool(
-        telemetry and wants_aux and reducer is None and grad_accum == 1
-        and forward_loss is None
+        ((telemetry and wants_aux)
+         or getattr(model, "sows_moe_stats", False))
+        and reducer is None and grad_accum == 1
+        and (forward_loss is None
+             or hasattr(forward_loss, "with_moe_stats"))
     )
+    if moe_telemetry and forward_loss is not None:
+        forward_loss = forward_loss.with_moe_stats()
     # models with a dropout field > 0 need a 'dropout' rng each step; the
     # key is derived from the step counter so every step (and every process,
     # identically — the mask must agree across replicas) draws fresh noise.
@@ -599,7 +607,12 @@ def make_train_step(
                 f"model.router_jitter={jitter_rate} but forward_loss has "
                 "no rng stream; use the default forward or router_jitter=0"
             )
-        forward = lambda params, stats, batch, step: forward_loss(params, stats, batch)
+        if moe_telemetry:
+            def forward(params, stats, batch, step):
+                loss, (new_stats, sown) = forward_loss(params, stats, batch)
+                return loss, (new_stats, _moe_metrics(sown))
+        else:
+            forward = lambda params, stats, batch, step: forward_loss(params, stats, batch)
     from tpudist.remat import checkpoint as _remat_checkpoint
 
     forward = _remat_checkpoint(forward, remat)
