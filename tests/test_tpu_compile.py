@@ -89,13 +89,26 @@ BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 @pytest.mark.parametrize("shape,causal", [
     ((8, 1024, 12, 64), True),    # GPT-2 124M train step, batch 8/chip
     ((32, 197, 12, 64), False),   # ViT-B/16: 196 patches + cls, padded to 256
-], ids=["gpt2_s1024_causal", "vit_s197"])
+    ((8, 1024, 16, 64), True),    # the benchmark's GPT-2 medium cells
+    ((16, 512, 16, 64), False),   # the benchmark's BERT-large cell
+], ids=["gpt2_s1024_causal", "vit_s197", "gpt2_medium_cell", "bert_large_cell"])
 def test_vmem_attention_fwd_bwd(compile_for_chip, shape, causal):
     hlo = compile_for_chip(
         _fwd_bwd(functools.partial(vmem_attention, causal=causal)),
         *[(shape, BF16)] * 3,
     )
     assert hlo.count("tpu_custom_call") >= 2  # forward and backward kernels
+
+
+def test_vmem_attention_causal_gqa_fwd_bwd(compile_for_chip):
+    """Llama-125M's 12:4 heads of 64 at S=1024: the blocked backward adds a
+    head's f32 dk/dv into the output block the group revisits (a 64-wide
+    view of that block is a slice Mosaic refuses)."""
+    q, kv = ((8, 1024, 12, 64), BF16), ((8, 1024, 4, 64), BF16)
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(vmem_attention, causal=True)), q, kv, kv,
+    )
+    assert hlo.count("tpu_custom_call") >= 2
 
 
 def test_flash_attention_fwd_bwd_s4096(compile_for_chip):
@@ -178,11 +191,16 @@ def test_paged_decode_attention_block16(compile_for_chip, rows):
     assert "tpu_custom_call" in hlo
 
 
-def test_attention_kernel_keeps_block_name_on_mesh(topo, monkeypatch):
+@pytest.mark.parametrize("blocks", [(3,), (0, 1, 2)],
+                         ids=["one_block", "three_blocks"])
+def test_attention_kernel_keeps_block_name_on_mesh(topo, monkeypatch, blocks):
     """On a ``data=4`` mesh the kernel runs inside a ``shard_map``, and XLA
     names a call after its innermost scope: with the block's ``name=`` the
     calls are ``h_3.<k>`` — what a trace reader looks for
-    (``benchmarks/families`` ``ATTENTION_OPS``) — and not ``shard_map.<k>``."""
+    (``benchmarks/families`` ``ATTENTION_OPS``) — and not ``shard_map.<k>``.
+    Several blocks share one traced ``pallas_call`` a direction
+    (``vmem_attention._traced_once``) and JAX lowers it once, yet each
+    block's two kernels keep that block's name."""
     import re
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -195,16 +213,21 @@ def test_attention_kernel_keeps_block_name_on_mesh(topo, monkeypatch):
     rows = jax.ShapeDtypeStruct(
         (32, 1024, 16, 64), BF16, sharding=NamedSharding(mesh, P(DATA_AXIS)))
 
-    def block(q, k, v):
-        with jax.named_scope("h_3"):
-            return multi_head_attention(
-                q, k, v, causal=True, impl="vmem", mesh=mesh, name="h_3")
+    def stack(x):
+        for n in blocks:
+            with jax.named_scope(f"h_{n}"):
+                x = x + multi_head_attention(
+                    x, x, x, causal=True, impl="vmem", mesh=mesh,
+                    name=f"h_{n}")
+        return x.astype(jnp.float32).sum()
 
-    hlo = jax.jit(_fwd_bwd(block)).lower(rows, rows, rows).compile().as_text()
+    hlo = jax.jit(jax.grad(stack)).lower(rows).compile().as_text()
     kernels = re.findall(
         r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", hlo)
-    assert len(kernels) >= 2  # forward and backward
-    assert all(re.match(r"^h_3(\.\d+)?$", k) for k in kernels), kernels
+    assert len(kernels) == 2 * len(blocks), kernels  # forward and backward
+    for n in blocks:
+        named = [k for k in kernels if re.match(rf"^h_{n}(\.\d+)?$", k)]
+        assert len(named) == 2, kernels
 
 
 def test_zero1_fused_adamw_updates_a_sharded_leaf_in_shards(topo):

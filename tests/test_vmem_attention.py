@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from tpudist.ops.attention import dot_product_attention, multi_head_attention
-from tpudist.ops.vmem_attention import vmem_attention
+from tpudist.ops.vmem_attention import computed_tile_share, vmem_attention
 
 
 def _qkv(b, s, h, d, seed=0, dtype=jnp.float32):
@@ -21,9 +21,16 @@ def _qkv(b, s, h, d, seed=0, dtype=jnp.float32):
     )
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_matches_oracle_aligned(causal, kernel_parity):
-    q, k, v = _qkv(2, 256, 2, 64, seed=1)
+@pytest.mark.parametrize("causal,s,dtype", [
+    (False, 256, jnp.float32),
+    (True, 128, jnp.float32),    # one query block: the whole tile, masked
+    (True, 256, jnp.float32),    # from here on the causal path runs by blocks
+    (True, 512, jnp.float32),
+    (True, 1024, jnp.float32),
+    (True, 1024, jnp.bfloat16),
+])
+def test_matches_oracle_aligned(causal, s, dtype, kernel_parity):
+    q, k, v = _qkv(2, s, 2, 64, seed=1, dtype=dtype)
     out = vmem_attention(q, k, v, causal=causal)
     ref = dot_product_attention(q, k, v, causal=causal)
     kernel_parity(out, ref)
@@ -50,9 +57,17 @@ def test_kv_len_masks_padded_keys():
     )
 
 
-@pytest.mark.parametrize("causal,s", [(True, 256), (False, 197)])
-def test_grads_match_oracle(causal, s):
-    q, k, v = _qkv(1, s, 2, 64, seed=4)
+@pytest.mark.parametrize("causal,s,dtype", [
+    (True, 256, jnp.float32),
+    (False, 197, jnp.float32),
+    (True, 512, jnp.float32),
+    (True, 1024, jnp.float32),
+    (True, 1024, jnp.bfloat16),
+    (True, 200, jnp.float32),   # padded to 256 and 640: the padding falls
+    (True, 600, jnp.float32),   # inside the last block's diagonal tile
+])
+def test_grads_match_oracle(causal, s, dtype, kernel_parity):
+    q, k, v = _qkv(1, s, 2, 64, seed=4, dtype=dtype)
 
     def loss(fn, q, k, v):
         return jnp.sum(fn(q, k, v) ** 2)
@@ -68,10 +83,13 @@ def test_grads_match_oracle(causal, s):
         argnums=(0, 1, 2),
     )(q, k, v)
     for name, a, b in zip("dq dk dv".split(), g_vmem, g_ref):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=5e-5, atol=5e-5,
-            err_msg=name,
-        )
+        if dtype == jnp.bfloat16:
+            kernel_parity(a, b)
+        else:
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=5e-5, atol=5e-5,
+                err_msg=name,
+            )
 
 
 def test_refuses_long_sequences():
@@ -174,13 +192,16 @@ def test_multi_head_attention_kv_len_flash_impl():
     )
 
 
-@pytest.mark.parametrize("h,h_kv", [(4, 2), (6, 2), (4, 1)])
-def test_gqa_matches_repeated_kv(h, h_kv):
+@pytest.mark.parametrize("h,h_kv,s", [
+    (4, 2, 256), (6, 2, 256), (4, 1, 256),
+    (8, 2, 512),  # dk/dv sum over the group AND over four query blocks
+])
+def test_gqa_matches_repeated_kv(h, h_kv, s):
     """Grouped K/V read natively (no repeat in HBM) equals the repeat-then-
     MHA oracle — forward and all grads, including the f32-accumulated
     dk/dv that sum each query group's contributions."""
     rng = np.random.Generator(np.random.PCG64(30 + h * 10 + h_kv))
-    b, s, d = 2, 256, 64
+    b, d = 2, 64
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, s, h_kv, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, s, h_kv, d)), jnp.float32)
@@ -231,7 +252,8 @@ def test_gqa_through_dispatcher_and_fallback():
         )
 
 
-def test_mesh_shard_map_wrap_matches_unwrapped():
+@pytest.mark.parametrize("seq", [128, 512], ids=["one_block", "four_blocks"])
+def test_mesh_shard_map_wrap_matches_unwrapped(seq):
     """multi_head_attention(mesh=...) runs the kernel per-shard inside
     shard_map (the multi-chip Pallas path: pallas_call has no GSPMD rule);
     the wrap must be loss-exact vs the unwrapped single-program path."""
@@ -243,10 +265,10 @@ def test_mesh_shard_map_wrap_matches_unwrapped():
 
     mesh = mesh_lib.create_mesh()
     rng = np.random.Generator(np.random.PCG64(40))
-    tokens = rng.integers(0, 97, (8, 128)).astype(np.int32)
+    tokens = rng.integers(0, 97, (8, seq)).astype(np.int32)
     losses = {}
     for wrapped in (False, True):
-        model = GPT2(vocab_size=97, max_seq_len=128, hidden_dim=32, depth=2,
+        model = GPT2(vocab_size=97, max_seq_len=seq, hidden_dim=32, depth=2,
                      num_heads=4, attn_impl="vmem",
                      mesh=mesh if wrapped else None)
         tx = optax.adam(1e-3)
@@ -260,3 +282,78 @@ def test_mesh_shard_map_wrap_matches_unwrapped():
         _, metrics = step(state, {"tokens": tokens})
         losses[wrapped] = float(metrics["loss"])
     assert abs(losses[True] - losses[False]) < 2e-5, losses
+
+
+def _score_elements(fn, *args):
+    """Elements of q·kᵀ output the traced program computes: every
+    ``dot_general`` whose result is not D wide, kernels' bodies included."""
+    d = args[0].shape[-1]
+
+    def walk(jaxpr):
+        n = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                shape = eqn.outvars[0].aval.shape
+                n += int(np.prod(shape)) if shape[-1] != d else 0
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += walk(sub)
+        return n
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("s,causal,share", [
+    (1024, True, 0.5625),  # the benchmark's GPT-2 cells: eight blocks of 128
+    (512, True, 0.625),
+    (600, True, 0.6),      # padded to 640: five blocks
+    (200, True, 0.75),     # padded to 256: two blocks
+    (128, True, 1.0),      # one block is the whole tile
+    (1024, False, 1.0),
+    (197, False, 1.0),
+])
+def test_computed_tile_share_is_what_the_kernel_traces(s, causal, share):
+    """The counter of the causal mechanism: static per shape, and the
+    forward kernel's own q·kᵀ products cover exactly that share of the
+    padded square."""
+    s_pad = s + (-s % 128)
+    assert computed_tile_share(s_pad, causal) == share
+    q, k, v = _qkv(1, s, 1, 64, seed=12)
+    scores = _score_elements(
+        functools.partial(vmem_attention, causal=causal), q, k, v
+    )
+    assert scores == share * s_pad * s_pad
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["blocked", "whole"])
+def test_layers_share_one_kernel_trace_a_direction(causal):
+    """A model's layers call the kernel with the same shapes: the
+    ``pallas_call`` of the second to the last layer is the first one's —
+    the same kernel jaxpr, so nothing is traced again and JAX's lowering
+    cache lowers it to Mosaic once a step — and each still sits directly
+    under its own layer's scope, where a trace reader looks for it."""
+    x = _qkv(1, 384, 3, 32, seed=13)[0]  # a shape no other test traces
+
+    def stack(x):
+        for layer in range(3):
+            with jax.named_scope(f"h_{layer}"):
+                x = x + vmem_attention(x, x, x, causal=causal)
+        return x.sum()
+
+    kernels = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                kernels.append((id(eqn.params["jaxpr"]),
+                                str(eqn.source_info.name_stack)))
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(stack))(x).jaxpr)
+    assert len(kernels) == 6  # forward and backward of three layers
+    assert len({body for body, _ in kernels}) == 2
+    for layer in range(3):
+        scopes = [scope for _, scope in kernels if f"h_{layer}" in scope]
+        assert len(scopes) == 2, kernels
+        assert not any("jit(" in scope or "_vmem" in scope for scope in scopes)

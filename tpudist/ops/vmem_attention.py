@@ -14,21 +14,38 @@ Why a third attention path exists
   a recompute-heavy backward; on v5e it only wins from S≈2048.
 - At S ≤ 1024 an ENTIRE head's score matrix fits in VMEM (S=1024 → 4 MB
   f32 of ~16 MB), so this kernel runs one (batch, head) pair per grid
-  step: ONE q·kᵀ MXU call, one plain (not online) softmax on the VPU, one
-  p·v MXU call — scores never touch HBM and there is no per-tile loop
-  overhead. Measured fwd+bwd at GPT-2 shapes (B=8, H=12, S=1024, D=64,
-  bf16, interleaved repeats on one v5e): **4.2 ms vs 9.5 ms XLA** vs
-  10.8/13.4 ms for the blockwise flash variants.
+  step with a plain (not online) softmax on the VPU: the whole row is
+  resident, scores never touch HBM and no running statistics are carried.
+- Bidirectional: ONE q·kᵀ MXU call on the whole S × S tile, one softmax,
+  one p·v MXU call.
+- Causal (two or more blocks of ``BLOCK_Q`` = 128 query rows): block j
+  takes the keys ``[: (j+1)·128]`` only, in ONE q·kᵀ product, and masks
+  that whole (128, prefix) score block with one iota compare and one
+  ``where`` (only its last 128 columns can fail the compare; masking just
+  that diagonal tile, in a product of its own, was measured 0.2 ms a step
+  faster for 1.5x the code and dropped, PERF.md §6) — then the same plain
+  softmax over the prefix and p·v. The kernel computes ``(n+1)/(2n)`` of
+  the square (:func:`computed_tile_share`: 0.5625 at S=1024) and never
+  reads the rest. A block's MXU products are
+  traced one block ahead of its VPU work (:func:`_one_ahead`).
 - The backward is a single kernel per (b, h): recompute p from the saved
-  row log-sum-exp, then the four FA-2 matmuls (dv, dp, dq, dk) back to
-  back on MXU with everything resident in VMEM.
+  row log-sum-exp, then the FA-2 matmuls (dv, dp, dq, dk) back to back on
+  MXU with everything resident in VMEM; causal, tile by tile over the same
+  blocks, dk/dv of a block's key prefix accumulating in f32 VMEM scratch.
+- Measured in the GPT-2 medium cell (B=8, H=16, S=1024, D=64, bf16, 24
+  layers, one v5e; PR 29's traced runs, PERF.md §6): the causal kernel
+  takes 7.08 + 14.29 ms a step forward + backward, against 9.63 + 21.69 for
+  the whole masked tile. At head size 64 every product half-fills the MXU
+  (64 of its 128-deep contraction or of its 128 output columns), which is
+  what holds both kernels under a third of the roofline the benchmark counts.
 
 Ragged / padded sequences
 -------------------------
 TPU tiles want 128-aligned lanes, but callers have S=197 (ViT's 196+cls).
 :func:`vmem_attention` pads q/k/v up to the next 128 multiple and masks the
 padded KEYS inside the kernel (``kv_len`` — one iota compare per score
-tile); padded QUERY rows compute garbage that is sliced off on return.
+tile that holds padding); padded QUERY rows compute garbage that is sliced
+off on return. Causal, padded keys lie past every real query's diagonal.
 This is what makes the kernel applicable to ViT, where the S² f32 traffic
 was previously "structural" (docs/PERF.md §6).
 
@@ -50,7 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (VMEM scratch if needed)
+from jax.experimental.pallas import tpu as pltpu
 
 from tpudist.ops import backend
 
@@ -60,11 +77,33 @@ NEG_INF = float(np.finfo(np.float32).min)
 # S=1024 → ~14 MB of ~16 MB works (measured); S=2048 would need 4×.
 MAX_SEQ = 1024
 
+# rows of one causal query block: the padding granule, so it divides every
+# padded length. On the chip 128 beat 256 and 512 at every S from 256 to 1024
+# (PERF.md §6, PR 29): shorter blocks compute less of the square, and what
+# their shorter MXU passes cost is won back by issuing a block's products ahead
+BLOCK_Q = 128
 
-def _masked_scores(q, k, sm_scale, *, causal, kv_len):
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * sm_scale
+
+def computed_tile_share(s_pad: int, causal: bool) -> float:
+    """Share of a head's S x S score square that the kernel computes: 1.0
+    bidirectional; causal, each of the ``n = s_pad / BLOCK_Q`` query blocks
+    takes the keys up to its own last row, ``(n + 1) / (2 n)`` of the
+    square (one block is the whole square under its mask)."""
+    n = s_pad // BLOCK_Q if causal else 1
+    return (n + 1) / (2 * n)
+
+
+def _mm(a, b, ca, cb):
+    """MXU product contracting ``a``'s dim ``ca`` with ``b``'s ``cb``, f32 out."""
+    return jax.lax.dot_general(
+        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _masked_scores(q, k, sm_scale, *, causal, kv_len, row0=0):
+    """Scaled q·kᵀ with the causal and padded-key masks; ``row0`` is the
+    position of ``q``'s first row among the keys (a query block's offset)."""
+    s = _mm(q, k, 1, 1) * sm_scale
     s_q, s_k = s.shape
     need_kv_mask = kv_len is not None and kv_len < s_k
     if causal or need_kv_mask:
@@ -72,7 +111,7 @@ def _masked_scores(q, k, sm_scale, *, causal, kv_len):
         keep = jnp.ones(s.shape, bool)
         if causal:
             qp = jax.lax.broadcasted_iota(jnp.int32, (s_q, s_k), 0)
-            keep = qp >= kp
+            keep = qp + row0 >= kp if row0 else qp >= kp
         if need_kv_mask:
             keep &= kp < kv_len
         s = jnp.where(keep, s, NEG_INF)
@@ -102,31 +141,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        o = _mm(p.astype(v.dtype), v, 1, 0)
         o_ref[0, i] = (o / l).astype(o_ref.dtype)
         lse_ref[0, i] = m + jnp.log(l)
 
     _loop_heads(group, one)
 
 
+def _zero_on_first_visit(dk_ref, dv_ref, group, ratio):
+    """GQA: the ratio consecutive grid steps mapping to one K/V head revisit
+    the SAME dk/dv output block (Pallas keeps a revisited block resident
+    between consecutive steps); zero it on the first visiting step, the
+    kernels accumulate on every one."""
+    @pl.when((pl.program_id(1) * group) % ratio == 0)
+    def _init():
+        dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
+        dv_ref[0, 0] = jnp.zeros_like(dv_ref[0, 0])
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref,
                 *, sm_scale, causal, kv_len, group, kv_shared, ratio):
     if kv_shared:
-        # GQA: the ratio consecutive grid steps mapping to one K/V head
-        # revisit the SAME dk/dv output block (Pallas keeps a revisited
-        # block resident between consecutive steps); zero it on the first
-        # visiting step, accumulate on the rest
-        hg = pl.program_id(1)
-        first_visit = (hg * group) % ratio == 0
-
-        @pl.when(first_visit)
-        def _init():
-            dk_ref[0, 0] = jnp.zeros_like(dk_ref[0, 0])
-            dv_ref[0, 0] = jnp.zeros_like(dv_ref[0, 0])
+        _zero_on_first_visit(dk_ref, dv_ref, group, ratio)
 
     def one(i):
         q = q_ref[0, i]
@@ -139,21 +176,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         p = jnp.exp(s - lse)  # [Sq, Sk] f32; exact probs (no rescale needed)
         pb = p.astype(v.dtype)
         dob = do.astype(v.dtype)
-        dv = jax.lax.dot_general(
-            pb, dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            dob, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dv = _mm(pb, dob, 0, 0)
+        dp = _mm(dob, v, 1, 1)
         delta = jnp.sum(do * o, axis=-1, keepdims=True)  # [Sq, 1]
         ds = (p * (dp - delta) * sm_scale).astype(v.dtype)
-        dq_ref[0, i] = jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        ).astype(dq_ref.dtype)
-        dk = jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        dq_ref[0, i] = _mm(ds, k, 1, 0).astype(dq_ref.dtype)
+        dk = _mm(ds, q, 0, 0)
         if kv_shared:
             # every q-head in the block feeds the one K/V head's grads
             dv_ref[0, 0] += dv.astype(dv_ref.dtype)
@@ -161,6 +189,99 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
         else:
             dv_ref[0, i] = dv.astype(dv_ref.dtype)
             dk_ref[0, i] = dk.astype(dk_ref.dtype)
+
+    _loop_heads(group, one)
+
+
+def _one_ahead(stage, s_pad):
+    """``(rows, stage(rows))`` for every block of ``BLOCK_Q`` query rows,
+    ``stage`` of the NEXT block traced before the current one is handed
+    out. Mosaic schedules a straight-line kernel close to program order, so
+    a block's MXU products issued one block early run under the previous
+    block's VPU passes: compiled for a v5e the S=1024 forward is 3081
+    bundles a head against 3666 in plain order, the backward 6088 against
+    7247 (the whole tile: 4393 and 9617)."""
+    blocks = [slice(lo, lo + BLOCK_Q) for lo in range(0, s_pad, BLOCK_Q)]
+    ahead = stage(blocks[0])
+    for n, rows in enumerate(blocks):
+        now, ahead = ahead, (
+            stage(blocks[n + 1]) if n + 1 < len(blocks) else None
+        )
+        yield rows, now
+
+
+def _fwd_kernel_blocked(q_ref, k_ref, v_ref, o_ref, lse_ref,
+                        *, sm_scale, kv_len, group, kv_shared):
+    """Causal forward by query blocks: a block scores its rows against the
+    keys up to its own last row only (later keys are never read), then a
+    PLAIN softmax over that prefix (the whole row is resident — no online
+    rescaling) and p·v."""
+    def one(i):
+        ik = 0 if kv_shared else i
+
+        def scores(rows):
+            return _masked_scores(
+                q_ref[0, i, rows], k_ref[0, ik, :rows.stop], sm_scale,
+                causal=True, kv_len=kv_len, row0=rows.start,
+            )
+
+        for rows, s in _one_ahead(scores, q_ref.shape[2]):
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            o = _mm(p.astype(v_ref.dtype), v_ref[0, ik, :rows.stop], 1, 0)
+            o_ref[0, i, rows] = (o / l).astype(o_ref.dtype)
+            lse_ref[0, i, rows] = m + jnp.log(l)
+
+    _loop_heads(group, one)
+
+
+def _bwd_kernel_blocked(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+                        dq_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                        *, sm_scale, kv_len, group, kv_shared, ratio):
+    """Causal backward by the forward's query blocks: per block recompute p
+    from the saved lse, then the FA-2 products. dq of a block is complete
+    in its step; dk/dv of the block's key prefix accumulate in the f32 VMEM
+    scratch, written out once a head (GQA: added to the f32 output block
+    the group's heads revisit)."""
+    if kv_shared:
+        _zero_on_first_visit(dk_ref, dv_ref, group, ratio)
+    dt = v_ref.dtype
+
+    def one(i):
+        ik = 0 if kv_shared else i
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+        def scores_and_dp(rows):
+            q = q_ref[0, i, rows]
+            do = do_ref[0, i, rows].astype(jnp.float32)
+            dob = do.astype(dt)
+            delta = jnp.sum(
+                do * o_ref[0, i, rows].astype(jnp.float32),
+                axis=-1, keepdims=True,
+            )
+            s = _masked_scores(
+                q, k_ref[0, ik, :rows.stop], sm_scale,
+                causal=True, kv_len=kv_len, row0=rows.start,
+            )
+            return q, dob, delta, s, _mm(dob, v_ref[0, ik, :rows.stop], 1, 1)
+
+        for rows, (q, dob, delta, s, dp) in _one_ahead(
+                scores_and_dp, q_ref.shape[2]):
+            keys = slice(0, rows.stop)
+            p = jnp.exp(s - lse_ref[0, i, rows])
+            dv_acc[keys] += _mm(p.astype(dt), dob, 0, 0)
+            ds = (p * (dp - delta) * sm_scale).astype(dt)
+            dq_ref[0, i, rows] = _mm(ds, k_ref[0, ik, keys], 1, 0).astype(
+                dq_ref.dtype)
+            dk_acc[keys] += _mm(ds, q, 0, 0)
+        if kv_shared:
+            dk_ref[0, 0] += dk_acc[...]
+            dv_ref[0, 0] += dv_acc[...]
+        else:
+            dk_ref[0, i] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[...].astype(dv_ref.dtype)
 
     _loop_heads(group, one)
 
@@ -223,12 +344,27 @@ def _geometry(q, k):
     return g, 1, False, _spec(g, s_k, d)
 
 
-def _vmem_fwd_raw(q, k, v, *, causal, sm_scale, kv_len):
+# Both ``pallas_call``s sit in a ``jax.jit(inline=True)``: a model's layers
+# call them with the same shapes, so the second to the last layer inline the
+# first one's cached jaxpr instead of tracing the kernel again (unrolled over
+# eight blocks it is some hundreds of equations: a 24-layer step traced 20 s
+# longer without this, PR 29). Inlined, every layer's ``pallas_call`` equation
+# carries the SAME parameters, so JAX also lowers the kernel to Mosaic once a
+# step and not once a layer; the equation itself stays directly under the
+# caller's scope, where a trace reader looks for it (``h_<n>.<k>``).
+_traced_once = functools.partial(
+    jax.jit, static_argnames=("causal", "sm_scale", "kv_len", "interpret"),
+    inline=True)
+
+
+@_traced_once
+def _vmem_fwd_raw(q, k, v, *, causal, sm_scale, kv_len, interpret):
     b, h, s_q, d = q.shape
     g, ratio, kv_shared, kv_spec = _geometry(q, k)
+    body = (_fwd_kernel_blocked if computed_tile_share(s_q, causal) < 1.0
+            else functools.partial(_fwd_kernel, causal=causal))
     kern = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal, kv_len=kv_len,
-        group=g, kv_shared=kv_shared,
+        body, sm_scale=sm_scale, kv_len=kv_len, group=g, kv_shared=kv_shared,
     )
     return pl.pallas_call(
         kern,
@@ -239,28 +375,34 @@ def _vmem_fwd_raw(q, k, v, *, causal, sm_scale, kv_len):
             _struct(q.shape, q.dtype, q),
             _struct((b, h, s_q, 1), jnp.float32, q),
         ],
-        interpret=backend.interpret(),
+        interpret=interpret,
     )(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _vmem(q, k, v, causal, sm_scale, kv_len):
-    o, _ = _vmem_fwd_raw(q, k, v, causal=causal, sm_scale=sm_scale, kv_len=kv_len)
+    o, _ = _vmem_vjp_fwd(q, k, v, causal, sm_scale, kv_len)
     return o
 
 
 def _vmem_vjp_fwd(q, k, v, causal, sm_scale, kv_len):
-    o, lse = _vmem_fwd_raw(q, k, v, causal=causal, sm_scale=sm_scale, kv_len=kv_len)
+    o, lse = _vmem_fwd_raw(
+        q, k, v, causal=causal, sm_scale=sm_scale, kv_len=kv_len,
+        interpret=backend.interpret(),
+    )
     return o, (q, k, v, o, lse)
 
 
-def _vmem_vjp_bwd(causal, sm_scale, kv_len, res, g):
-    q, k, v, o, lse = res
+@_traced_once
+def _vmem_bwd_raw(q, k, v, o, lse, g, *, causal, sm_scale, kv_len, interpret):
     b, h, s_q, d = q.shape
     grp, ratio, kv_shared, kv_spec = _geometry(q, k)
+    blocked = computed_tile_share(s_q, causal) < 1.0
+    body = (_bwd_kernel_blocked if blocked
+            else functools.partial(_bwd_kernel, causal=causal))
     kern = functools.partial(
-        _bwd_kernel, sm_scale=sm_scale, causal=causal, kv_len=kv_len,
-        group=grp, kv_shared=kv_shared, ratio=ratio,
+        body, sm_scale=sm_scale, kv_len=kv_len, group=grp,
+        kv_shared=kv_shared, ratio=ratio,
     )
     # GQA: dk/dv accumulate ratio/grp revisits (plus grp in-block q-heads)
     # into the same output block — accumulate in f32, cast after
@@ -276,9 +418,21 @@ def _vmem_vjp_bwd(causal, sm_scale, kv_len, res, g):
             _struct(k.shape, kv_grad_dtype, k),
             _struct(v.shape, kv_grad_dtype, v),
         ],
-        interpret=backend.interpret(),
+        # the blocked backward sums a head's dk / dv over its blocks in f32
+        scratch_shapes=(
+            [pltpu.VMEM(k.shape[2:], jnp.float32)] * 2 if blocked else []
+        ),
+        interpret=interpret,
     )(q, k, v, o, g, lse)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _vmem_vjp_bwd(causal, sm_scale, kv_len, res, g):
+    q, k, v, o, lse = res
+    return _vmem_bwd_raw(
+        q, k, v, o, lse, g, causal=causal, sm_scale=sm_scale, kv_len=kv_len,
+        interpret=backend.interpret(),
+    )
 
 
 _vmem.defvjp(_vmem_vjp_fwd, _vmem_vjp_bwd)
