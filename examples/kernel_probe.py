@@ -1,5 +1,5 @@
-"""Achieved-HBM-bandwidth probe for the step-fusion layer — the
-measurement behind docs/PERF.md §4c.
+"""Achieved-HBM-bandwidth probe for the step-fusion layer: an operator's
+tool, outside the benchmark (its readings are in no ledger line).
 
 The fused LN kernel and the one-pass AdamW update (tpudist/ops/layernorm.py;
 tpudist/ops/fused_update.py — plain XLA since PR 26, one loop fusion a
@@ -18,7 +18,7 @@ Byte accounting (the numerator) is the kernel's mandatory HBM traffic:
 - fused AdamW: read g/m/v/p (4×4 B), write m'/v'/u (3×4 B) + the bf16
   copy (2 B) → 30 B/element.
 
-Run on the bench chip::
+Run on the chip::
 
     python examples/kernel_probe.py                 # default shapes
     python examples/kernel_probe.py --rows 32768 --hidden 1024 --bw 819e9
@@ -41,7 +41,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tpudist.telemetry import microbench  # noqa: E402
 
-V5E_HBM_BW = 819e9  # bytes/s — the roofline every PERF.md section quotes
+V5E_HBM_BW = 819e9  # bytes/s: the published v5e peak (benchmarks/peaks.json)
 
 
 def _measure(body, operand, nbytes, *, bw, reps):

@@ -2,7 +2,7 @@
 
 The reference trains on an auto-downloaded dataset
 (/root/reference/main.py:43-51); this environment has zero egress, so the
-convergence-evidence runs (CONVERGENCE.json) use real local text instead:
+convergence runs use real local text instead:
 the Python standard library's sources, the installed numpy/jax package
 sources, and this repository's docs. That is real, structured,
 natural-ish data — exactly what a byte-level LM can learn from — and it

@@ -1,9 +1,9 @@
-"""Per-GEMM MXU-utilization probe — the measurement behind docs/PERF.md §4b.
+"""Per-GEMM MXU-utilization probe: an operator's tool, outside the benchmark
+(its readings are in no ledger line).
 
-The GPT-2 124M training step is kernel-efficiency-limited at hidden=768
-(PERF §4): this probe quantifies WHERE by timing each GEMM shape of the
-step in isolation on the chip, plus the same block mix at wider
-hidden sizes (the "would a bigger model hit higher MFU" experiment).
+How near its peak a GPT-2 step's GEMMs run depends on their width: this
+probe times each GEMM shape of the step in isolation on the chip, plus the
+same block mix at wider hidden sizes (the "would a bigger model hit higher MFU" experiment).
 
 Method: each shape runs inside ONE jitted ``lax.scan`` of ``iters``
 matmuls whose left operand is scaled per-iteration (defeats loop-invariant
@@ -13,7 +13,7 @@ DIFFERENTIAL — ``(t(4n) − t(n)) / 3n`` — so per-call fixed costs cancel
 instead of polluting sub-millisecond GEMMs. Per-shape report: achieved
 TFLOP/s and fraction of the chip's bf16 peak.
 
-Run on the bench chip::
+Run on the chip::
 
     python examples/mfu_probe.py            # per-GEMM table + hidden sweep
     python examples/mfu_probe.py --peak 197e12
@@ -34,8 +34,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # single source of truth for the analytic counters, the GEMM-shape table,
 # and the device peak table (tpudist.telemetry.flops): this file keeps only the
-# CLI — the math it times lives with the MFU accounting that fit()'s
-# telemetry and bench.py's legs share, and the differential-timing
+# CLI — the math it times lives with the MFU accounting of fit()'s
+# telemetry, and the differential-timing
 # skeleton (adaptive iters, (t(4n)−t(n))/3n, anti-hoisting operands,
 # plausibility retries) lives in tpudist.telemetry.microbench so this
 # probe and examples/kernel_probe.py measure the same way
@@ -73,7 +73,7 @@ def main() -> None:
                     help="chip bf16 peak FLOP/s (default: the running "
                     "chip's row of tpudist.telemetry.flops.DEVICE_PEAKS)")
     ap.add_argument("--tokens", type=int, default=8192,
-                    help="GEMM rows = microbatch tokens of the bench step "
+                    help="GEMM rows = microbatch tokens of the step "
                     "(8 seqs x 1024)")
     ap.add_argument("--sweep", default="768,1024,1536,2048",
                     help="hidden sizes for the wider-GEMM block-mix sweep")

@@ -19,7 +19,7 @@ utilization) next to the run.
 
 ``--prompt`` takes comma-separated token ids (the repo ships no
 tokenizer); without any, mixed-length random prompts exercise the
-scheduler the way the bench leg does.
+scheduler.
 """
 
 from __future__ import annotations

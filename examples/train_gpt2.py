@@ -74,10 +74,10 @@ def parse_args(argv=None):
                    help="ZeRO-1 cross-replica optimizer-state sharding "
                    "(tpudist.optim.shard_state): Adam mirrors live "
                    "~1/world_size per chip; with --remat_policy this is "
-                   "the ~1B-on-16GB recipe (docs/PERF.md §10)")
+                   "the ~1B-on-16GB recipe (docs/LM_TRAINING.md)")
     p.add_argument("--fused", default="none",
                    choices=["none", "auto", "ln", "optimizer", "all"],
-                   help="step-fusion layer (docs/PERF.md §4c): 'ln' = the "
+                   help="step-fusion layer: 'ln' = the "
                    "Pallas fused residual-add+LayerNorm kernel in every "
                    "block, 'optimizer' = the one-pass fused-AdamW update "
                    "(+ bf16 compute-copy forward under --bf16; requires "
@@ -167,7 +167,7 @@ def parse_args(argv=None):
                    help="expert dispatch impl (tpudist.parallel.ep): "
                    "'einsum' = the one-hot oracle, 'index' = slot-index "
                    "gather/scatter + the explicit expert-axis all-to-all "
-                   "on a real --expert_axis mesh (docs/PERF.md §13)")
+                   "on a real --expert_axis mesh")
     p.add_argument("--router_z_loss", default=0.0, type=float,
                    help="router z-loss weight (fp32 logit-norm regularizer; "
                    "0 = off, byte-identical trajectory)")
@@ -178,9 +178,8 @@ def parse_args(argv=None):
                    choices=["auto", "xla", "vmem", "flash", "ring", "ulysses",
                             "ulysses_flash"],
                    help="auto picks by context length: the whole-sequence "
-                   "VMEM kernel wins up to 1k (measured 126k vs 80k tok/s "
-                   "at 1024 on v5e), the blockwise flash kernel wins beyond "
-                   "(~14x over XLA at 8k), XLA is the dense-mask oracle")
+                   "VMEM kernel up to 1k, the blockwise flash kernel from "
+                   "2k, dense XLA between; XLA is the dense-mask oracle")
     p.add_argument("--init_hf", default=None, type=str,
                    help="warm-start from a LOCAL HF checkpoint dir/file "
                    "(*.safetensors or pytorch_model*.bin) converted via "
@@ -246,7 +245,7 @@ def main(argv=None):
     if args.attn == "auto":
         # multi_head_attention(impl="auto") would route per-call; resolving
         # here keeps the choice visible in the run's config echo. Matches
-        # attention.py's measured crossover (vmem ≤ 1024, dense XLA in the
+        # attention.py's rule (vmem ≤ 1024, dense XLA in the
         # 1025–2047 window, flash from 2048). Off-TPU the Pallas kernels
         # only run in interpret emulation, so CPU runs stay on XLA; inside
         # --pipe the kernels don't compose with the GPipe shard_map

@@ -111,7 +111,7 @@ def parse_args(argv=None):
     parser.add_argument("--grad_accum", default=1, type=int)
     parser.add_argument("--fused", default="none",
                         choices=["none", "auto", "ln", "optimizer", "all"],
-                        help="step-fusion layer (docs/PERF.md §4c): 'ln' = "
+                        help="step-fusion layer: 'ln' = "
                         "Pallas fused residual-add+LayerNorm in the "
                         "transformer blocks (vit_b16), 'optimizer' = the "
                         "one-pass fused-AdamW update (requires --optimizer "
@@ -125,8 +125,8 @@ def parse_args(argv=None):
                         ": none = implicit XLA psum (optimal on ICI); "
                         "bucketed = explicit fp32 bucketed all-reduce; "
                         "quantized = int8-on-the-wire with per-bucket "
-                        "scales + error feedback (the DCN-bound lever, "
-                        "docs/PERF.md §11); auto = quantized on a "
+                        "scales + error feedback (the DCN-bound lever); "
+                        "auto = quantized on a "
                         "multi-slice attach, none otherwise")
     parser.add_argument("--fsdp", default=1, type=int,
                         help="'fsdp' mesh axis size (tpudist.parallel.plan)"
@@ -251,7 +251,7 @@ def parse_args(argv=None):
     parser.add_argument("--serve_moe_dispatch", default="einsum",
                         choices=["einsum", "index"],
                         help="with --serve_experts: expert dispatch impl "
-                        "(docs/PERF.md §13)")
+                        "(tpudist.parallel.ep)")
     parser.add_argument("--spec_k", default=4, type=int,
                         help="with --spec_draft: draft tokens proposed per "
                         "slot per tick (a slot emits up to spec_k+1 "
@@ -458,7 +458,7 @@ def main(argv=None):
 
     if args.dataset == "imagenet" and args.packed:
         # pre-decoded pack (tpudist.data.packed): pixels stream from a uint8
-        # memmap at memcpy speed — the fix for decode-bound hosts (PERF §3c);
+        # memmap at memcpy speed — the fix for decode-bound hosts;
         # normalization runs in-graph either way (uint8 H2D, 4x less traffic)
         from tpudist.data.packed import load_packed
         from tpudist.data.transforms import (

@@ -53,7 +53,6 @@ else:
 # Measured cold on this 1-core host: see README "Testing" for the number
 # recorded at marking time.
 _SMOKE_FILES = {
-    "test_bench_record.py",
     "test_dp_equivalence.py",
     "test_generate.py",
     "test_lm_data.py",

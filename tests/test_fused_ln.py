@@ -1,6 +1,6 @@
 """Fused residual-add+LayerNorm/RMSNorm kernel (tpudist/ops/layernorm.py)
 vs the flax reference composition, interpret mode on CPU — the parity half
-of the step-fusion layer (docs/PERF.md §4c). Covers the three public
+of the step-fusion layer. Covers the three public
 compositions (plain / post-norm / pre-norm), both norm flavors, fp32+bf16,
 edge shapes (non-lane-divisible hidden, non-tile row counts), gradients,
 and the four model families' ``fused_ln`` knob (identical param trees,

@@ -1,4 +1,4 @@
-"""Step-fusion integration (docs/PERF.md §4c): make_train_step(fused=) /
+"""Step-fusion integration: make_train_step(fused=) /
 fit(fused=) — trajectory equivalence of the fully-fused step against the
 unfused reference (the acceptance bar: 24-step GPT-2, composed with ZeRO-1
 shard_opt_state, the quantized reducer, and guard_nonfinite in one test

@@ -1,11 +1,10 @@
 """The model zoo's per-block ``remat_policy`` wiring (GPT-2, Llama) and
-the ~1B-param HBM budget claim the bench leg records.
+the ~1B-param HBM budget of docs/LM_TRAINING.md's recipe.
 
 Per-block remat must be a pure memory/flop trade: identical loss and
 gradients, identical param NAMES (interop/checkpoints depend on the
 ``h_{i}``/``layer_{i}`` layout), in both the unrolled and scanned layouts.
-The budget test is the test-suite half of the bench's
-``gpt2_1b_shard_state_hbm_budget`` leg: exact eval_shape state bytes at
+The budget test: exact eval_shape state bytes at
 the 1536×36 (~1.1B-param) geometry, replicated provably over 16 GB,
 shard_state + remat under it.
 """
@@ -117,10 +116,10 @@ def test_gpt2_remat_policy_trains_through_step():
 
 @pytest.mark.slow
 def test_1b_budget_replicated_over_sharded_under_16gb():
-    """The acceptance claim behind the bench leg, exactly as computed
-    there: GPT-2 1536×36 (~1.1B params) replicated Adam does NOT fit
+    """The claim of docs/LM_TRAINING.md "Fitting ~1B parameters", from
+    shapes alone: GPT-2 1536×36 (~1.1B params) replicated Adam does NOT fit
     16 GB; ZeRO-1 over 8 replicas + per-block save_nothing remat does
-    (measured numbers, docs/PERF.md §10: 29.8 vs 10.6 GB/chip).
+    (an accounting from shapes; nothing of it is measured on the chip).
     eval_shape only — no arrays are materialized (the trace of the
     36-layer model is the slow part, hence the marker)."""
     mesh = mesh_lib.create_mesh(mesh_lib.MeshConfig(data=8))

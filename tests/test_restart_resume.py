@@ -410,8 +410,7 @@ def test_warm_cache_supervised_restart_skips_compile(tmp_path):
     misses (AOT-compiles at bring-up and stores the executable) and the
     relaunched generation 1 hits — its goodput books cache_load_s with
     compile_s == 0 (iteration 1 was an ordinary step, not a mislabeled
-    compile), which is the accounting the bench's cold-vs-warm A/B
-    records."""
+    compile)."""
     r = _launch_resilience_child(
         tmp_path,
         {"CHAOS": "sigterm@6", "COMPILE_CACHE": str(tmp_path / "cc")},

@@ -465,8 +465,7 @@ def test_full_hit_replay_resumes_without_prefill():
 def test_paged_kernel_engine_greedy_matches_static():
     """The whole engine through the paged Pallas KERNEL path (any
     non-"xla" attn_impl dispatches it; interpret mode on CPU): greedy
-    streams still equal the static xla-model oracle bit-for-bit — the
-    configuration the `paged` bench leg runs."""
+    streams still equal the static xla-model oracle bit-for-bit."""
     kmodel = GPT2(vocab_size=64, max_seq_len=64, hidden_dim=32, depth=2,
                   num_heads=4, attn_impl="fused")
     params = _params(_gpt2(), 1)

@@ -1,5 +1,5 @@
 """tools/stepscope.py: bucketed device-op attribution of profiler traces
-(docs/PERF.md §4c) — classification rules, the total-by-construction
+(docs/OBSERVABILITY.md §9) — classification rules, the total-by-construction
 attribution guarantee, boundedness verdicts, diff mode, and the
 acceptance integration: a REAL ``jax.profiler`` capture of a jitted
 program whose device time stepscope attributes >= 95% (here: 100%, the

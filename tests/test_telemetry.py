@@ -1,6 +1,6 @@
 """Telemetry subsystem units (tpudist.telemetry): the analytic FLOPs
-counters (single source of truth shared by bench.py, examples/mfu_probe.py
-and fit()'s MFU rows), the JSONL sink's strict-JSON contract, the
+counters (shared by examples/mfu_probe.py and fit()'s MFU rows), the
+JSONL sink's strict-JSON contract, the
 NaN/divergence sentry's firing rules, and the in-step health metrics /
 non-finite update guard inside the compiled train step."""
 
@@ -204,9 +204,9 @@ def test_t5_and_vit_dispatch():
     assert odd.flops_counter is None
 
 
-def test_probe_and_bench_share_the_counters():
-    """The dedup satellite: mfu_probe re-exports the flops module's GEMM
-    table (its peak resolves through flops.device_peaks at run time)."""
+def test_mfu_probe_reads_the_flops_modules_gemm_table():
+    """mfu_probe re-exports the flops module's GEMM table (its peak
+    resolves through flops.device_peaks at run time)."""
     import importlib.util
     import pathlib
 
@@ -619,9 +619,9 @@ def test_breakdown_rows_unchanged_without_comm(tmp_path):
 def test_link_bound_warning_fires_once_with_hint(tmp_path):
     """The fit() H2D diagnosis: staging the observed batch at the probed
     link rate would eat most of the step — ONE tagged warning row pointing
-    at DeviceCachedLoader, not a silent 0.08x run."""
+    at DeviceCachedLoader, not a silently slow run."""
     tel = _bare_tel(tmp_path)
-    tel.h2d_mbps = 10.0  # a collapsed link (docs/PERF.md §3 measured 7)
+    tel.h2d_mbps = 10.0  # a collapsed link
     tel.observe_batch({"image": np.zeros((256, 224, 224, 3), np.uint8)})
     for s in range(1, 4):
         tel.on_step(s, {"loss": 1.0}, epoch=0, interval_s=0.1,

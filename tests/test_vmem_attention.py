@@ -132,7 +132,7 @@ def test_multi_head_attention_kv_len_plumbed():
 
 
 def test_gpt2_model_vmem_matches_xla():
-    """Model-level: the bench's attn_impl='vmem' GPT-2 computes the same
+    """Model-level: the cells' attn_impl='vmem' GPT-2 computes the same
     function as the XLA oracle (same params, same tokens, same loss)."""
     import optax
 

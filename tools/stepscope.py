@@ -1,13 +1,13 @@
 """stepscope: measured per-op attribution of device time in a profiler
-capture (docs/PERF.md §4c, docs/OBSERVABILITY.md §9).
+capture (docs/OBSERVABILITY.md §9).
 
 ``WindowedProfiler`` (and any ``jax.profiler`` trace) writes a Chrome
 trace-event file — ``<host>.trace.json.gz`` under
 ``{log_dir}/plugins/profile/<timestamp>/`` — next to the xplane protobuf.
 The JSON side is parseable with nothing but the stdlib, and its XLA op
 events (``ph == "X"`` with an ``hlo_op`` arg, or events on a device-named
-process) carry exactly what the roofline arguments in docs/PERF.md reason
-about by hand: which HLO ops the step's time actually went to. This tool
+process) carry what a roofline argument reasons about by hand: which HLO
+ops the step's time actually went to. This tool
 is the measured other half of ``tpudist/telemetry/anatomy.py``'s static
 counts:
 
@@ -309,7 +309,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="bucket device-op time in a jax profiler capture "
         "(GEMM / collective / attention / elementwise) with compute- vs "
-        "HBM-bound verdicts (docs/PERF.md §4c)"
+        "HBM-bound verdicts (docs/OBSERVABILITY.md §9)"
     )
     ap.add_argument("paths", nargs="+",
                     help="trace file / profile dir / log dir "

@@ -31,8 +31,8 @@ top of it:
   mesh; :meth:`BucketLayout.wire_bytes` does the exact accounting.
 
 Also here (it is link plumbing, not data plumbing):
-:func:`measure_h2d_mbps`, the host→device bandwidth probe ``fit()`` and
-``bench.py`` use to tag link-bound runs instead of failing silently slow.
+:func:`measure_h2d_mbps`, the host→device bandwidth probe ``fit()``
+uses to tag link-bound runs instead of failing silently slow.
 """
 
 from __future__ import annotations
@@ -130,7 +130,8 @@ class BucketLayout:
         the quantized ratio is quoted against.
         ``reductions`` scales for schedules that reduce more than once per
         step (the double-buffered grad-accumulation overlap reduces every
-        microbatch — docs/PERF.md §11 carries the trade's honest math).
+        microbatch, so its wire bytes are ``grad_accum`` times the
+        single reduction's).
         """
         w, n = self.world, self.padded_total
         if w == 1:

@@ -8,8 +8,8 @@ becomes the benchmark.
 The TPU-native fix (MLPerf-style) is to stop shipping pixels per step:
 
 1. stage the WHOLE uint8 dataset to HBM **once, at bring-up** (it removes
-   per-step pixel traffic entirely). CIFAR-100 is 150 MB; the bench's
-   synthetic ImageNet set is 385 MB — both noise against 16 GB HBM;
+   per-step pixel traffic entirely). CIFAR-100 is 150 MB — noise against
+   16 GB HBM;
 2. per step, ship only the sampler's **indices** (a few KB) and gather the
    batch in-graph (``jnp.take``), fused by XLA straight into the normalize
    + first-conv read.
@@ -273,13 +273,13 @@ class RotatingDeviceCache:
     transiently pin a third around a shard transition — size
     ``shard_rows`` for at most THREE shard buffers against free HBM.
 
-    This is the streaming complement to :class:`DeviceCachedLoader`
-    (docs/PERF.md §3c): a packed ImageNet-1k at 224² is ~193 GB against
-    16 GB HBM, but a 2–4 GB shard stages in well under the time the chip
-    spends training through the previous one (shard of R rows buys
+    This is the streaming complement to :class:`DeviceCachedLoader`:
+    a packed ImageNet-1k at 224² is ~193 GB against
+    16 GB HBM, but a 2–4 GB shard stages while the chip
+    trains through the previous one (shard of R rows buys
     ``R/rate`` seconds of compute against ``R·row_bytes/bandwidth``
-    seconds of transfer — at 2,570 img/s and 150 KB/row, any link above
-    ~385 MB/s keeps the rotation ahead, the same §3 requirement as direct
+    seconds of transfer: a link faster than ``rate·row_bytes``
+    keeps the rotation ahead, the same requirement as direct
     streaming, but paid OFF the critical path and with in-graph
     gather/augment/normalize like the resident cache).
 

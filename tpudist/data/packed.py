@@ -2,11 +2,11 @@
 
 The reference's input pipeline decodes JPEGs on the host every epoch
 (/root/reference/main.py:54-63 drives torchvision's loader; an ImageFolder
-re-decodes every sample every pass). At BASELINE configs 2/3 scale a TPU
-chip consumes ~2,570 images/sec, but PIL JPEG decode tops out at O(100)
-images/sec per host core — on a small-host TPU attach the streaming path is
-decode-bound no matter how deep the prefetch queue in front of it
-(docs/PERF.md §3c has the measured math). The TPU-native fix is the MLPerf
+re-decodes every sample every pass). PIL JPEG decode tops out at O(100)
+images/sec per host core, far under what a chip consumes at BASELINE
+configs 2/3 — on a small-host TPU attach the streaming path is decode-bound
+no matter how deep the prefetch queue in front of it (the benchmark has no
+vision cell: not measured on the chip). The TPU-native fix is the MLPerf
 one: **decode once, train from pixels**.
 
 :func:`pack_image_folder` runs the one-time pass: scan the class tree
@@ -66,7 +66,7 @@ def pack_image_folder(
 
     Returns a summary dict (``n``, ``seconds``, ``images_per_sec``,
     ``bytes``) — the pack rate IS the host's sustained JPEG decode rate,
-    which docs/PERF.md §3c compares against the chip's consumption rate.
+    to set against the rate at which the chip consumes images.
     Pass the train split's ``classes`` when packing a val split (same
     label-stability contract as ``scan_image_folder``).
     """

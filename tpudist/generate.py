@@ -83,19 +83,17 @@ def sample_logits(logits, rng, *, temperature: float = 1.0,
     (nucleus sampling). Filters compose in the HF order: temperature →
     top_k → top_p."""
     if temperature == 0.0:
-        # top_k(1) indices, not jnp.argmax: same first-occurrence winner,
-        # but measured 2.2 ms/step cheaper at (128, 50257) on v5e (argmax
-        # lowers to a slower full-vocab reduction than the top-k kernel)
+        # top_k(1) indices, not jnp.argmax: same first-occurrence winner;
+        # argmax lowers to a slower full-vocab reduction than the top-k
+        # kernel (no ledger line: not measured in a cell)
         return jax.lax.top_k(logits, 1)[1][:, 0].astype(jnp.int32)
     logits = logits / temperature
 
     if top_k is not None:
         # sample IN THE TOP-K SUBSET: categorical over the k kept values
         # and map the winner back through the top-k indices. The
-        # full-vocab formulation paid a [B, V] gumbel + reduction per
-        # token — measured ~8 ms/step at (128, 50257) on v5e, i.e. more
-        # than the entire 12-layer transformer step (docs/PERF.md §7b);
-        # the subset pays it on [B, k]. Tie semantics: EXACTLY k ids are
+        # full-vocab formulation pays a [B, V] gumbel + reduction per
+        # token; the subset pays it on [B, k]. Tie semantics: EXACTLY k ids are
         # candidates — ids tied with the k-th value beyond the k-th slot
         # are dropped (a `logits < kth` threshold, like HF's warper,
         # keeps every tied id). Tied ids carry equal probability, so this
@@ -127,8 +125,8 @@ def sample_logits(logits, rng, *, temperature: float = 1.0,
 
 # the per-row sampler resolves its filters inside a static top-K candidate
 # subset (one lax.top_k, no [B, V] sort in the serving hot path — the same
-# full-vocab-chain trap the scalar sampler's subset rework removed,
-# docs/PERF.md §7b). Per-row top_k clamps to the cap; a nucleus that would
+# full-vocab-chain trap the scalar sampler's subset rework removed).
+# Per-row top_k clamps to the cap; a nucleus that would
 # extend past the cap truncates there — at serving temperatures the
 # nucleus lives far inside 128 candidates.
 PER_ROW_TOPK_CAP = 128
@@ -524,10 +522,8 @@ def _run(model, params, cache, prompt, true_len, rng, *, max_new_tokens,
     # cached_kv's mask is causal within the chunk (slot t attendable by
     # row i iff t <= pos + i), so a P-token prompt costs one MXU-shaped
     # forward instead of a P-iteration scan of launch-bound single-token
-    # steps. Measured at P=512, batch 8, GPT-2 124M on v5e: 127.5 vs
-    # 676.7 ms = 5.3x (the 127.5 includes the attach's ~100 ms per-call
-    # floor; docs/PERF.md §7b). The first sampled position is the TRUE
-    # last prompt token's logits (a traced index — the pad tail feeds
+    # steps (no served cell: not measured on the chip). The first sampled
+    # position is the TRUE last prompt token's logits (a traced index — the pad tail feeds
     # nothing), and the cursors rewind to true_len so decode continues
     # exactly where the real prompt ended.
     cache, all_logits = decode_chunk(cache, prompt)

@@ -1,6 +1,6 @@
 """HBM accounting: byte budgets BEFORE compile, live device stats after.
 
-The memory-discipline half of the perf story (docs/PERF.md §10): at ~1B
+Memory discipline (docs/LM_TRAINING.md "Fitting ~1B parameters"): at ~1B
 params on a 16 GB chip the question "will it fit?" must be answerable
 before the first (minutes-long) compile, and the answer must be checkable
 against what the device actually allocated. Three layers:
@@ -17,8 +17,8 @@ against what the device actually allocated. Three layers:
    on TPU; ``None`` on backends that don't report, e.g. CPU), logged by
    ``fit()`` through ``MetricsLogger.log_memory``.
 
-:func:`train_state_budget` assembles 1+2 into the report the bench's ~1B
-leg prints: bytes-per-param for params / moments / activations, replicated
+:func:`train_state_budget` assembles 1+2 into one report:
+bytes-per-param for params / moments / activations, replicated
 vs ``shard_state``, against a stated HBM budget.
 """
 
@@ -208,7 +208,7 @@ def train_state_budget(
 
     Returns a dict with per-component bytes (global and per-chip), the
     per-chip total, ``fits`` against ``hbm_budget_bytes``, and
-    ``bytes_per_param`` — the budget-table row docs/PERF.md §10 prints.
+    ``bytes_per_param`` — one row of a budget table.
 
     ``plan`` (:class:`tpudist.parallel.plan.ParallelPlan`) makes the
     whole table PER-CHIP under the composed placement: params and
@@ -219,8 +219,7 @@ def train_state_budget(
     scaled by the plan's axes (batch over ``data×fsdp``, depth over
     ``pipe``, block internals over ``tensor`` — coarse like the base
     estimate, labeled as one). This is the pre-compile answer to "does
-    this geometry fit ONLY under the plan?" — the ``parallel3d`` bench
-    leg prints both sides.
+    this geometry fit ONLY under the plan?".
     """
     import jax.numpy as jnp
 
@@ -319,7 +318,7 @@ def xla_memory_stats(compiled) -> dict[str, int] | None:
 
 def budget_columns(report: Mapping[str, Any] | None = None, *,
                    compiled=None, device=None) -> dict[str, int | None]:
-    """The three-source HBM comparison row (docs/PERF.md §10): the
+    """The three-source HBM comparison row: the
     pre-compile analytic ESTIMATE, the compiler's XLA-STATIC reservation,
     and the LIVE allocator peak — each ``None`` where its source is
     unavailable (no report / no compiled program / a CPU backend), never
@@ -342,8 +341,8 @@ def budget_columns(report: Mapping[str, Any] | None = None, *,
 def format_budget(report: Mapping[str, Any], *,
                   xla_static_bytes: int | None = None,
                   live_peak_bytes: int | None = None) -> str:
-    """One human line per component, GB with the fits verdict — what the
-    bench leg and PERF table print. ``xla_static_bytes`` /
+    """One human line per component, GB with the fits verdict.
+    ``xla_static_bytes`` /
     ``live_peak_bytes`` (from :func:`budget_columns`) append the measured
     columns next to the estimate when a compiled program / a reporting
     backend is at hand; ``None`` (the default, and what fail-soft sources

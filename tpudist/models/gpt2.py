@@ -117,8 +117,8 @@ class Block(nn.Module):
                 )
             else:
                 # one fused Pallas launch per layer per token unless the
-                # caller pinned the dense oracle (attn_impl="xla") — decode
-                # is launch-bound, not bandwidth-bound (docs/PERF.md §7)
+                # caller pinned the dense oracle (attn_impl="xla"): one
+                # launch a layer in place of the ~6-kernel attention chain
                 attn = decode_attention(
                     q, keys, values, mask, pos,
                     impl="xla" if self.attn_impl == "xla" else "fused",
@@ -255,8 +255,8 @@ class GPT2(nn.Module):
     # the decode path (the KV-cache step has no backward).
     remat_policy: str | None = None
     # fused_ln=True runs every LayerNorm (ln_1/ln_2/ln_f) through the
-    # Pallas fused residual-add+LN kernel (tpudist.ops.layernorm) — the
-    # non-GEMM-tail lever of docs/PERF.md §4c. Same param tree as the
+    # Pallas fused residual-add+LN kernel (tpudist.ops.layernorm), which
+    # the benchmark's cells run (PERF.md §4). Same param tree as the
     # flax modules; decode keeps the reference composition. Usually set
     # via make_train_step(fused="ln"|"all"), which clones the model.
     fused_ln: bool = False

@@ -3,13 +3,13 @@
 The reference contains no attention (its workload is a CNN, SURVEY.md §5
 "long-context: ABSENT") — these ops serve the BASELINE ladder's transformer
 configs (ViT-B/16, GPT-2 124M). Three paths, dispatched by
-:func:`multi_head_attention` (``impl="auto"`` picks by measured crossover):
+:func:`multi_head_attention` (``impl="auto"`` picks by sequence length):
 
 - ``dot_product_attention``: plain XLA einsum attention — the correctness
   oracle, and the only path that takes arbitrary masks.
 - ``tpudist.ops.vmem_attention``: whole-sequence-in-VMEM Pallas kernel for
   S ≤ 1024 — one plain softmax per (batch, head) grid step, no tile loop;
-  the fastest path at bench shapes (2.3× over XLA on the GPT-2 step).
+  the path the benchmark's GPT-2 and BERT cells run (PERF.md §4).
 - ``tpudist.ops.flash_attention``: blockwise FA-2 Pallas kernel for long
   sequences (≥ 2048) — online softmax so the S×S scores never exist.
 
@@ -51,8 +51,8 @@ def kernel_attention(q, k, v, *, causal: bool = False):
     """Best fused-kernel attention for the shape — the ``attn_fn`` to hand
     composition sites (e.g. the Ulysses shard_map body, which sees the FULL
     sequence with a local head group after its all-to-all): vmem kernel at
-    S ≤ 1024, blockwise flash at ≥ 2048, dense XLA between (the measured
-    v5e crossovers)."""
+    S ≤ 1024, blockwise flash at ≥ 2048, dense XLA between (crossovers of
+    an earlier setup; no cell sits on either side of them)."""
     return multi_head_attention(q, k, v, causal=causal, impl="auto")
 
 
@@ -89,13 +89,13 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
     """Dispatch over the three attention paths:
 
     - ``xla``: dense einsum attention (oracle; takes arbitrary masks);
-    - ``vmem``: whole-sequence-in-VMEM Pallas kernel — fastest at S ≤ 1024
-      (measured 2.3× over xla at GPT-2 shapes on v5e) and the only kernel
+    - ``vmem``: whole-sequence-in-VMEM Pallas kernel for S ≤ 1024: the
+      scores never reach HBM; the only kernel
       that handles unaligned S (ViT's 197) by padding + in-kernel key mask;
     - ``flash``: blockwise FA-2 Pallas kernel for long sequences (S ≥ 2048,
       where whole-S scores no longer fit VMEM);
-    - ``auto``: vmem when it applies, else xla below the measured flash
-      crossover (~2048 on v5e), else flash.
+    - ``auto``: vmem when it applies, else xla below 2048 tokens, else
+      flash.
 
     ``kv_len``: static true key length for contiguous right-padded K/V —
     the kernels mask padded keys in-kernel; the dense path builds the
@@ -181,9 +181,9 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
                     warnings.warn(
                         f"vmem attention unavailable ({e}); trying flash/XLA"
                     )
-            # measured crossover on v5e: between the vmem ceiling (1024) and
-            # ~2048 the dense XLA path still beats the blockwise flash
-            # kernel; from 2048 the S² HBM traffic dominates and flash wins
+            # between the vmem ceiling (1024) and 2048 the dense XLA path;
+            # from 2048 the S² HBM traffic dominates and flash takes over
+            # (an earlier setup's crossover, not re-measured in a cell)
             impl = "flash" if max(q.shape[1], k.shape[1]) >= 2048 else "xla"
         elif impl == "vmem":
             import warnings

@@ -18,14 +18,14 @@ Two attention paths over the cache (:func:`decode_attention` dispatches):
   slot mask; ~10 small kernels per layer per token.
 - ``fused``: ONE Pallas launch per layer (:func:`_fused_decode_attention`)
   computing scores + slot mask + softmax + value mix for every head. A
-  batch-8 decode step dispatches ~300 µs-scale kernels and is
-  launch-bound, not bandwidth-bound (docs/PERF.md §7); collapsing the
+  small-batch decode step is a long chain of short kernels; collapsing the
   ~6-kernel attention chain into one launch attacks the kernel-count term
-  directly. Grid is (batch,): each step DMAs the row's whole contiguous
+  directly (no served cell: not measured on the chip). Grid is (batch,): each step DMAs the row's whole contiguous
   [H_kv, S, dh] K/V — the mandatory cache read — and loops heads
   in-kernel, so the kernel rides the byte floor with no score/prob
   intermediates in HBM and no per-head grid overhead (the per-(b, h)
-  grid variant measured slower; see the function docstring).
+  grid variant pays the grid's fixed cost per head; see the function
+  docstring).
 """
 
 from __future__ import annotations
@@ -42,13 +42,11 @@ from tpudist.ops import backend
 
 NEG_INF = float(np.finfo(np.float32).min)
 
-# Measured crossover (v5e, GPT-2 124M decode, interleaved A/B medians with
-# the subset sampler): the fused kernel wins at batch 8 (4.81 vs 5.16
-# ms/step) and loses from batch 32 up (7.60 vs 6.89 at 32; 19.95 vs 11.48
-# at 128) — at serving batch XLA's batched attention GEMMs beat the
-# kernel's per-row head loop, while at latency batch the kernel's single
-# launch beats XLA's ~6-kernel chain. The dispatcher falls back to the
-# dense path above this bound.
+# At serving batch XLA's batched attention GEMMs beat the kernel's per-row
+# head loop, while at latency batch the kernel's single launch beats XLA's
+# ~6-kernel chain; the dispatcher falls back to the dense path above this
+# bound (an earlier setup's crossover; no served cell: not measured on the
+# chip).
 FUSED_MAX_BATCH = 16
 
 
@@ -180,8 +178,7 @@ def cached_kv(module, k, v, max_len: int, pre_update=None, positions=None,
             # a gather-scatter (`.at[arange, :, pos, :].set`): XLA updates
             # the select in-place on the donated buffer and fuses it,
             # while the scatter blocks the in-place path and copies every
-            # layer's full [B, H, max_len, dh] buffer — measured 24.6 vs
-            # 8.9 ms per 4-layer step at the serving shapes on CPU. An
+            # layer's full [B, H, max_len, dh] buffer. An
             # entry at pos + i >= max_len has an all-false one-hot: the
             # write self-clamps (nothing lands, nothing is clobbered).
             kt = k.transpose(0, 2, 1, 3)  # [B, H, s, dh]
@@ -255,11 +252,10 @@ def _fused_decode_attention(q, keys, values, pos):
     group straight from the grouped layout.
 
     Grid is (batch,): one step DMAs the row's whole [H_kv, S, dh] K/V
-    (contiguous) and loops heads in-kernel. Measured against the
-    per-(b, h) grid on v5e at GPT-2 124M shapes: 1536 tiny grid steps
-    paid ~10 µs each at batch 128 (27.3 ms/step vs XLA's 18.0); one step
-    per row with 12 in-kernel heads amortizes the grid overhead into
-    DMA-sized work items.
+    (contiguous) and loops heads in-kernel. A per-(b, h) grid is 1536
+    tiny grid steps at batch 128 and GPT-2 124M's 12 heads, each paying the
+    grid's fixed cost; one step per row with the heads looped in-kernel
+    amortizes it into DMA-sized work items.
     """
     b, s_q, h, dh = q.shape
     h_kv, s_len = keys.shape[1], keys.shape[2]
@@ -317,7 +313,7 @@ def decode_attention(q, keys, values, mask, pos, *, impl: str = "fused",
     # explicit applicability predicate, not try/except NotImplementedError:
     # Pallas itself raises NotImplementedError for unsupported op/platform
     # combinations, and swallowing those would silently run the dense path
-    # while the bench/docs claim the fused kernel. The VMEM bound: one
+    # while the documents claim the fused kernel. The VMEM bound: one
     # grid step stages a row's whole [H_kv, S, dh] K and V panels (double-
     # buffered by the pipeline), so large-cache geometries (e.g. h_kv=8,
     # S=8192, dh=128 bf16 = 32 MB K+V) must take the dense path instead
@@ -493,7 +489,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, positions, *,
     contiguous buffer first (B × max_len bytes through HBM), while the
     kernel walks each row's table and reads only blocks up to the cursor,
     which is what converts the paged layout's saved bytes into tok/s
-    (docs/PERF.md §7c measures the A/B). ``impl="xla"`` is the
+    (no served cell: not measured on the chip). ``impl="xla"`` is the
     gather-then-dense oracle the kernel is tested against (and the
     correctness path on models pinned to ``attn_impl="xla"``).
 

@@ -482,9 +482,9 @@ def flash_attention(
     right-padded batches), padded query rows are sliced off the output.
 
     ``pallas_bwd`` selects the Pallas FA-2 backward kernels instead of the
-    blockwise-scan backward. Both are O(S·block) memory; measured on
-    one v5e chip the scan backward is faster at d=64/S≤4096 shapes (XLA
-    fuses it well) while the kernels close the gap by S=8192. TPU-only: on
+    blockwise-scan backward. Both are O(S·block) memory; the one shape
+    measured on the chip is :func:`default_blocks`' (heads of 128 at 4096
+    tokens, where the kernels win). TPU-only: on
     other backends the flag is ignored and the scan backward runs.
     ``block_q``, ``block_k`` and ``pallas_bwd`` left at ``None`` follow the
     shape (:func:`default_blocks`).

@@ -1,9 +1,7 @@
 """Fused residual-add + LayerNorm/RMSNorm as a Pallas TPU kernel.
 
-docs/PERF.md §4b measured that the GPT-2 124M step's GEMMs run at 85–94% of
-peak and the remaining ~100 ms (~40% of the step) is the serial elementwise
-tail between them — layernorms, residual adds, casts. XLA fuses those
-chains, but each ``x + y`` → ``LayerNorm`` pair still costs separate HBM
+Between a transformer step's GEMMs sits a serial elementwise tail —
+layernorms, residual adds, casts. XLA fuses those chains, but each ``x + y`` → ``LayerNorm`` pair still costs separate HBM
 round trips for the add's result and the norm's two reduction passes. This
 kernel collapses one pair into a single sweep:
 

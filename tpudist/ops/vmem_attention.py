@@ -2,16 +2,15 @@
 
 No reference counterpart (the reference's workload is a CNN,
 /root/reference/main.py:40) — this is the framework's hot-op for the
-transformer configs at bench sequence lengths (GPT-2 S=1024, ViT S=197).
+transformer configs at sequence lengths up to 1024 (GPT-2 S=1024, ViT S=197).
 
 Why a third attention path exists
 ---------------------------------
 - XLA einsum attention materializes the [S,S] f32 score tensor in HBM per
-  layer per direction — the dominant byte term of the GPT-2 step
-  (docs/PERF.md §4) and of ViT (§6).
+  layer per direction.
 - The blockwise flash kernel (``tpudist.ops.flash_attention``) eliminates
   that traffic, but pays online-softmax bookkeeping per (128,128) tile and
-  a recompute-heavy backward; on v5e it only wins from S≈2048.
+  a recompute-heavy backward; ``auto`` takes it from S = 2048 on.
 - At S ≤ 1024 an ENTIRE head's score matrix fits in VMEM (S=1024 → 4 MB
   f32 of ~16 MB), so this kernel runs one (batch, head) pair per grid
   step with a plain (not online) softmax on the VPU: the whole row is
@@ -47,7 +46,7 @@ padded KEYS inside the kernel (``kv_len`` — one iota compare per score
 tile that holds padding); padded QUERY rows compute garbage that is sliced
 off on return. Causal, padded keys lie past every real query's diagonal.
 This is what makes the kernel applicable to ViT, where the S² f32 traffic
-was previously "structural" (docs/PERF.md §6).
+would otherwise be structural.
 
 Sizing rule: the kernel refuses S_pad > MAX_SEQ (per-(b,h) VMEM footprint
 is a handful of [S,S] f32 buffers); longer sequences belong to the
@@ -74,7 +73,8 @@ from tpudist.ops import backend
 NEG_INF = float(np.finfo(np.float32).min)
 
 # per-(b,h) VMEM budget: bwd keeps ~4 [S,S] f32/bf16 intermediates live;
-# S=1024 → ~14 MB of ~16 MB works (measured); S=2048 would need 4×.
+# S=1024 → ~14 MB of ~16 MB compiles and runs (the cells' shape); S=2048
+# would need 4×.
 MAX_SEQ = 1024
 
 # rows of one causal query block: the padding granule, so it divides every
@@ -121,8 +121,8 @@ def _masked_scores(q, k, sm_scale, *, causal, kv_len, row0=0):
 def _loop_heads(group: int, body):
     """Run ``body(i)`` for the block's ``group`` heads. group==1 stays
     straight-line; grouped blocks use fori_loop (compiles one head's code,
-    reuses the per-head VMEM scratch across iterations — measured within 2%
-    of a full unroll at ViT shapes, far cheaper to compile)."""
+    reuses the per-head VMEM scratch across iterations; far cheaper to
+    compile than a full unroll)."""
     if group == 1:
         body(0)
     else:
@@ -291,8 +291,8 @@ def _head_group(h: int, s_pad: int) -> int:
     at one (b, h) pair per step — 1536 near-empty grid steps for ViT-B —
     so group as many heads as the VMEM budget allows (the per-head score
     scratch is reused across the in-kernel loop; only the IO blocks scale
-    with the group). Measured at ViT shapes on v5e: 5.0 ms grouped vs
-    5.8 ms ungrouped vs 7.0 ms XLA (fwd+bwd). Long S keeps group=1 — the
+    with the group; no vision cell: not measured on the chip). Long S
+    keeps group=1 — the
     per-step work is already large and the [S,S] scratch leaves no room."""
     if s_pad > 512:
         return 1

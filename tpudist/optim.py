@@ -309,7 +309,7 @@ def shard_state(
     slice for free) and only the params-shaped update all-gather that
     ZeRO-1 always pays remains. Net wire bytes: ~0.5× fp32-AR for the int8
     grad reduction + 1× for the update all-gather, vs 2× for the implicit
-    fp32 rs+ag — docs/PERF.md §11 carries the full budget table.
+    fp32 rs+ag (byte counts from the layouts; not measured on the chip).
 
     Checkpoints hold the stored (sharded/padded) layout; resuming needs the
     same world size, which the geometry guard in ``fit()`` already

@@ -30,8 +30,8 @@ this module is the opt-in explicit path for exactly that regime
   WITHOUT a residual (``"bucketed"``, or ``error_feedback=False``) have
   nothing to flush and nothing the overlap's extra bytes would buy: they
   accumulate locally and reduce once after the scan — the implicit path's
-  schedule, explicit. docs/PERF.md §11 carries the honest byte math of the
-  EF path's trade (int8 pays for the extra reductions; fp32 would not).
+  schedule, explicit. The EF path's trade in bytes: int8 pays for the extra
+  reductions; fp32 would not.
 
 Semantics vs the implicit path: identical gradients for ``"bucketed"`` (up
 to fp32 reduction order) for deterministic forwards; models with
@@ -408,7 +408,7 @@ class GradReducer:
         for _ in range(max(iters, 1) + 1):  # first run includes the compile
             t0 = time.perf_counter()
             mean, res = fn(buckets, res)
-            float(mean[0, 0])  # value-fetch sync (bench.py's probe rule)
+            float(mean[0, 0])  # a value fetch: the sync that ends the timing
             best = min(best, time.perf_counter() - t0)
         return best * self.reductions_per_step(grad_accum)
 
@@ -474,9 +474,8 @@ def make_divergence_probe(state, mesh: Mesh):
     compare), else a jitted ``probe(state) -> {"replica_divergence",
     "replica_checksum", "sharded_checksum", "state_nonfinite"}`` whose
     scalars ride ``copy_to_host_async`` like the step metrics. Cost: one
-    bandwidth-bound read of the state plus scalar collectives — the bench
-    leg ``gpt2_124m_health_overhead_pct`` holds probe+aggregation under
-    1% of step time at its cadence.
+    bandwidth-bound read of the state plus scalar collectives at the
+    probe's cadence (its share of a step: not measured on the chip).
     """
     if int(mesh.shape[DATA_AXIS]) <= 1:
         return None

@@ -20,7 +20,7 @@ the whole run:
   across generations into the run report's ``goodput`` section;
 - :mod:`~tpudist.resilience.chaos` — deterministic crash/hang/SIGTERM/
   checkpoint-corruption injection (``main.py --chaos``, the recovery
-  tests, the bench's ``gpt2_124m_preempt_recovery_s`` leg);
+  tests);
 - :mod:`~tpudist.resilience.repair` — the self-healing loop
   (``fit(repair=...)``): detector verdicts (replica divergence, skip
   streaks, sustained loss spikes) execute an in-process escalation

@@ -3,8 +3,8 @@
 The recovery path deserves the same adversarial testing the detection
 path got (PR 7's simulated hangs and injected stragglers): this harness
 injects the failure shapes the resilience layer exists for, at an exact
-step boundary, identically from unit tests, the 2-process emulated world,
-``main.py --chaos``, and the bench's recovery legs.
+step boundary, identically from unit tests, the 2-process emulated world
+and ``main.py --chaos``.
 
 Spec grammar (``ChaosSpec.parse``; ``parse_chaos`` accepts a
 comma-separated list so one drill can compose, e.g., an SDC with a later

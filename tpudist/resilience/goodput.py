@@ -43,7 +43,7 @@ through the ``{job}_report.json`` each generation leaves behind:
   price of a repair — the repaired run re-earns that progress on clean
   data. A second-order overlap with ``data_wait_s`` (the discarded
   steps' input waits are in both) is accepted: the residual clamps at
-  zero and the repair legs read this component, not the residual;
+  zero and the repair tests read this component, not the residual;
 - ``productive_step_s`` — the residual: total minus everything above.
   Computing productive time as the residual is what makes the components
   sum to the generation's wall time *exactly* (the report's acceptance
@@ -56,8 +56,7 @@ previous report via :meth:`GoodputTracker.load_previous`) and a
 ``cumulative`` block whose ``restart_overhead_s`` prices recovery: the
 inter-generation wall gaps (supervisor backoff + process spawn) plus
 every resumed generation's bring-up/restore/compile plus every emergency
-save. That number is what the bench leg
-``gpt2_124m_preempt_recovery_s`` records.
+save.
 """
 
 from __future__ import annotations
@@ -140,8 +139,7 @@ class GoodputTracker:
         ``tpudist.compile_cache`` compiled it at bring-up on a miss, or
         deserialized it on a hit): iteration 1 is an ordinary step and
         must not be attributed to ``compile_s``. ``warm`` marks a cache
-        hit — the entry's ``warm_start`` field, what the bench's
-        cold-vs-warm A/B keys on."""
+        hit — the entry's ``warm_start`` field."""
         self._precompiled = True
         self._warm = bool(warm)
 

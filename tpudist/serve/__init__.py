@@ -29,7 +29,7 @@ keeps the decode batch full instead:
   tokens per slot per tick, the target verifies the whole window in ONE
   bulk pass, and acceptance-rejection sampling preserves the target
   distribution exactly — greedy output stays token-identical to the
-  non-speculative engine (docs/SERVING.md §6, docs/PERF.md §7d).
+  non-speculative engine (docs/SERVING.md §6).
 - :mod:`tpudist.serve.stats` — TTFT/TPOT percentiles, queue depth, slot
   utilization, block-pool occupancy / prefix hit rate / preemptions,
   speculative acceptance rate, tokens/s as ``serve`` JSONL rows through

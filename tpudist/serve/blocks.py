@@ -3,8 +3,8 @@
 The contiguous slot pool (:mod:`tpudist.serve.slots`) reserves a
 worst-case ``[max_slots, H, max_seq_len, dh]`` cache — every slot pays
 ``max_seq_len`` whether its request is 20 tokens or 400. Under the
-long-tail budgets real chat traffic has (the serve bench's 16+Exp(80)
-distribution), most of the bytes each decode step's attention window
+long-tail budgets real chat traffic has (say 16+Exp(80) tokens), most of
+the bytes each decode step's attention window
 COULD cover are never written, yet they bound how many requests fit a
 chip. This module replaces that layout with the vLLM-style paged one,
 grounded in the Gemma-on-TPU serving comparison (PAPERS.md,
@@ -550,8 +550,8 @@ class PagedSlotPool:
 def draft_equivalent_blocks(model, draft_model, max_slots: int,
                             block_size: int) -> int:
     """How many TARGET-model KV blocks the draft pool's bytes buy — the
-    equal-HBM handicap for the speculative-vs-autoregressive A/B
-    (bench.py's ``spec`` leg): the speculative engine allocates a full
+    equal-HBM handicap for a speculative-vs-autoregressive A/B: the
+    speculative engine allocates a full
     contiguous draft SlotPool on top of its paged target pool, so the
     honest baseline gives the plain engine that many EXTRA target blocks
     instead. Rounds up (the baseline gets the benefit of the doubt)."""

@@ -24,7 +24,7 @@ delivery, and latency-SLO telemetry. One scheduler **tick**
    with ZERO recompiles.
 
 The decode loop is **one-step-delayed**, the same pipeline idiom as
-``fit()``'s metric fetch (docs/PERF.md §3): step ``k`` is dispatched
+``fit()``'s metric fetch: step ``k`` is dispatched
 BEFORE step ``k-1``'s tokens are fetched, and each step's sampled tokens
 feed the next step ON DEVICE (a carried ``[S]`` token array, overridden
 per-slot at admission), so the device never idles waiting for a host
@@ -64,8 +64,8 @@ single-token draft steps against a second, slot-pinned draft KV pool),
 and the target scores the whole window ``[last, d_1..d_K]`` in ONE bulk
 decode pass — the accepted prefix plus one correction/bonus token all
 land in a single target weight sweep, so a slot emits up to ``spec_k+1``
-tokens per tick at roughly one sequential-pass cost (docs/PERF.md §7d:
-fewer passes beats faster passes). Acceptance-rejection sampling
+tokens per tick at roughly one sequential-pass cost (fewer passes, not
+faster passes). Acceptance-rejection sampling
 (:mod:`tpudist.serve.spec`) preserves the target distribution EXACTLY —
 greedy speculative output is token-identical to the non-speculative
 engine, pinned by test. The cursor becomes DEVICE-carried (``[S]``
@@ -86,9 +86,8 @@ in the ``serve`` rows exactly when the scheduler acts on it).
 Why this wins over static batching: a static batch must assemble before
 prefill (queue wait on the LAST arrival) and every row decodes until the
 LONGEST request finishes (retired rows burn full decode steps). The
-engine's decode batch stays full under mixed-length Poisson arrivals —
-the ``serve`` bench leg measures the tokens/s gap and the TTFT collapse;
-the ``paged`` leg measures what the block pool adds at equal HBM.
+engine's decode batch stays full under mixed-length Poisson arrivals.
+The benchmark has no served cell: the gap is not measured on the chip.
 """
 
 from __future__ import annotations
@@ -337,8 +336,7 @@ def _build_decode_step(model, params, base_key, paged: bool):
     arguments (one compiled step per engine instance): with params as jit
     arguments, XLA re-canonicalizes the big weight layouts on EVERY call
     — the vocab-sized embedding table alone is read with two access
-    patterns — measured 41 vs 17 ms/step at a 4-layer serving geometry
-    on CPU. The static ``generate()`` path keeps params traced because
+    patterns. The static ``generate()`` path keeps params traced because
     one call amortizes that over the whole in-graph scan; the engine
     calls once per token and cannot."""
 
@@ -399,9 +397,9 @@ def engine_param_shardings(model, params, mesh):
     forgoes that leaf's byte saving.
 
     ``params`` may be concrete arrays or a ``jax.eval_shape`` tree — only
-    leaf SHAPES are read, so the ``mc_serve`` bench leg budgets a
-    geometry's per-chip bytes (``tpudist.memory.per_device_bytes``)
-    without materializing a weight."""
+    leaf SHAPES are read, so a geometry's per-chip bytes
+    (``tpudist.memory.per_device_bytes``) can be budgeted without
+    materializing a weight."""
     import flax.linen as nn
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P

@@ -5,7 +5,7 @@ The engine's speculative tick (``tpudist.serve.engine``) runs, per live
 slot: K cheap draft-model steps proposing tokens ``d_1..d_K``, then ONE
 bulk target pass scoring the window ``[t_last, d_1..d_K]`` — K+1 rows of
 target logits from a single weight sweep (the decode cost that matters
-is HBM bytes per sequential pass, docs/PERF.md §7d). This module decides
+is HBM bytes per sequential pass). This module decides
 what to EMIT from those two logit sets.
 
 The acceptance identity (Leviathan et al. / Chen et al.): draft token
@@ -199,8 +199,7 @@ def cache_bytes(model, rows: int, *, tensor_world: int = 1) -> int:
     ``tensor_world``: PER-CHIP bytes on a tensor-sharded engine
     (``ServeEngine(mesh=...)``) — the 4-D buffers shard exactly on the
     KV-head dim, so each chip holds ``1/T`` of every buffer (the engine's
-    head-divisibility refusal guarantees the split is even; the
-    ``mc_serve`` bench leg budgets with this)."""
+    head-divisibility refusal guarantees the split is even)."""
     tree = jax.eval_shape(lambda: model.init_cache(rows))
     total = sum(
         int(np.prod(leaf.shape)) * leaf.dtype.itemsize

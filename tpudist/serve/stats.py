@@ -8,7 +8,7 @@ The two latency SLOs a serving deployment is actually held to:
 - **TTFT** (time to first token): submit → the request's first streamed
   token. Under continuous batching this is queue wait + one prefill + one
   sample; under static batching it includes waiting for the whole batch
-  to assemble — the number the bench leg's comparison shows collapsing.
+  to assemble.
 - **TPOT** (time per output token): the mean inter-token gap AFTER the
   first token, ``(t_done - t_first) / (n_tokens - 1)`` — the streaming
   cadence a reader experiences.
@@ -48,8 +48,8 @@ class ServeStats:
     """Host-side SLO bookkeeping, driven by the engine: ``on_submit`` /
     ``on_first_token`` / ``on_done`` per request, ``on_decode_step`` per
     compiled step, ``on_tick`` once per scheduler tick (writes the cadence
-    row). ``sink=None`` keeps full accounting with no stream (the bench
-    and the notebook path read :meth:`snapshot` directly)."""
+    row). ``sink=None`` keeps full accounting with no stream (a caller
+    reads :meth:`snapshot` directly)."""
 
     def __init__(self, *, slots: int, sink=None, every: int = 50,
                  clock=time.perf_counter, paged: bool = False,
@@ -258,7 +258,7 @@ class ServeStats:
         }
 
     def snapshot(self) -> dict:
-        """Lifetime totals (the bench record's fields)."""
+        """Lifetime totals."""
         wall = max(self._clock() - self.t_start, 1e-9)
         return {
             "wall_s": round(wall, 6),

@@ -12,8 +12,8 @@ dies without (docs/OBSERVABILITY.md):
   (``make_train_step(telemetry=True)``): a handful of reductions XLA fuses
   into the existing gradient psum path, fetched through the same
   one-step-delayed async pipeline as the loss — zero extra host syncs.
-  The bench leg ``telemetry_overhead_pct`` holds the cost under 2% of
-  step time.
+  Its share of a step is not measured on the chip (the benchmark's traced
+  runs turn it off).
 - **why did it die?** — :class:`NanSentry`, the flight recorder: the
   in-graph guard (``make_train_step(guard_nonfinite=True)``) skips the
   poisoned update the step it happens (params/opt-state/BN stats keep
@@ -438,8 +438,8 @@ class TimedIterator:
     """Wrap a batch iterator and record the wall seconds the consumer spent
     blocked in ``next()`` — fit()'s data-wait attribution. With the
     prefetch queue healthy this is ~0; when it grows toward the step time
-    the run is input-bound (docs/PERF.md §3's diagnosis, now visible
-    per-step instead of requiring a bench A/B)."""
+    the run is input-bound, visible per step (the benchmark reads it as
+    ``input_wait_ms``)."""
 
     def __init__(self, iterator, *, step: int = 0, tracer=None):
         self._it = iter(iterator)
@@ -835,10 +835,9 @@ class Telemetry:
 
         if (not self._link_warned and self.h2d_mbps and self._batch_bytes
                 and interval_s > 0):
-            # link-bound diagnosis (docs/PERF.md §3): when just STAGING the
+            # link-bound diagnosis: when just STAGING the
             # batch at the probed H2D rate would eat more than half the
-            # observed step interval, the run is link-bound — a regime
-            # measured at 0.08× on the resnet50_e2e leg — and the framework
+            # observed step interval, the run is link-bound, and the framework
             # mitigation is DeviceCachedLoader (stage the set to HBM once;
             # per-step H2D becomes index-only). The first two resolved
             # intervals are skipped (they carry the jit compile, which
@@ -861,15 +860,15 @@ class Telemetry:
                     interval_s=round(interval_s, 6),
                     hint="per-step H2D staging dominates the step; stage "
                          "the dataset to HBM once with DeviceCachedLoader "
-                         "(docs/PERF.md §3b) or pack+cache for streaming "
-                         "sets (§3c)",
+                         "(tpudist/data/device_cache.py) or pack+cache for "
+                         "streaming sets (tpudist/data/packed.py)",
                 )
                 print(
                     f"tpudist: H2D link-bound run (probe "
                     f"{self.h2d_mbps:.0f} MB/s, batch "
                     f"{self._batch_bytes / 1e6:.1f} MB ≈ {staging_s:.3f}s "
                     f"of a {interval_s:.3f}s step) — consider "
-                    "DeviceCachedLoader (docs/PERF.md §3b)",
+                    "DeviceCachedLoader (tpudist/data/device_cache.py)",
                     file=sys.stderr, flush=True,
                 )
 

@@ -1,7 +1,7 @@
 """Program anatomy: what XLA actually compiled, checked against what we claim.
 
 The MFU rows (docs/OBSERVABILITY.md §5) and the memory budget tables
-(docs/PERF.md §10) both rest on hand-maintained analytic models —
+(``tpudist/memory.py``) both rest on hand-maintained analytic models —
 ``tpudist/telemetry/flops.py``'s counters and ``tpudist/memory.py``'s
 activation estimates. Nothing verified them against the compiled program
 until now. This module asks the compiler directly, once, at bring-up:
@@ -19,7 +19,7 @@ until now. This module asks the compiler directly, once, at bring-up:
   numbers are lying — and ``Telemetry.set_anatomy`` turns that into a
   ``warning`` row naming the counter.
 - :class:`StepTimeRegressionDetector` is the in-run half of the regression
-  sentinel (``tools/bench_gate.py`` is the cross-run half): a rolling
+  sentinel (across runs the driver's ledger judges): a rolling
   median of observed step times against the post-compile baseline, firing
   a one-shot ``perf_regression`` row on sustained slowdown — the
   mid-run drift (data pipeline, thermal, host contention) that per-step

@@ -3,14 +3,13 @@
 MFU (model FLOPs utilization) is the headline efficiency metric of
 "Scalable Training of Language Models using JAX pjit and TPUv4"
 (arXiv:2204.06514): analytic model FLOPs per step divided by step time and
-chip peak. This module is the ONE home for the analytic counters that were
-previously duplicated across ``bench.py``'s per-leg hand math and
-``examples/mfu_probe.py``'s GEMM tables — both now import from here, and
-``fit()``'s telemetry MFU rows use the same numbers, so a bench leg, the
-probe, and a live training run can never disagree about the numerator.
+chip peak. This module is the program's home for the analytic counters:
+``fit()``'s telemetry MFU rows and ``examples/mfu_probe.py``'s GEMM tables
+read them here. The benchmark's ``step_mfu_pct`` uses copies kept with it
+(``benchmarks/families/*.py``, ``benchmarks/peaks.json``);
+tests/test_benchmark_contract.py holds the copies to these.
 
-Accounting convention (docs/PERF.md §4, kept bit-identical to the bench
-legs it replaced): weight GEMMs count forward + dgrad + wgrad
+Accounting convention (PERF.md §3): weight GEMMs count forward + dgrad + wgrad
 (``6 · tokens · matmul_params``); attention counts 6 matmuls per layer
 (QKᵀ and AV, forward + two backward passes: ``12 · tokens · seq · hidden``
 with the causal factor folded into the convention, not halved); embedding
@@ -84,9 +83,8 @@ def mesh_chips(mesh) -> int:
     """The MFU denominator's chip count for ``mesh``: every device the
     compiled program spans — data, fsdp, pipe, and tensor axes alike, and
     ONLY those (a sub-mesh must not divide by chips it never used).
-    ``fit()``'s telemetry, ``ParallelPlan.n_chips``, and the
-    bench legs all route through this one function so a composed-plan MFU
-    row can never disagree with a bench record about the denominator."""
+    ``fit()``'s telemetry and ``ParallelPlan.n_chips`` route through this
+    one function, so a composed-plan MFU row has one denominator."""
     return int(mesh.size)
 
 
@@ -333,7 +331,7 @@ def gpt2_step_shapes(tokens: int, hidden: int, vocab: int = 50257,
                      ce_chunk_rows: int = 4096) -> list[tuple[str, int, int, int]]:
     """The GEMM shapes of one GPT-2 block + tied head, forward and the two
     backward passes (dgrad/wgrad) per GEMM, at ``tokens`` rows — the
-    per-GEMM table behind ``examples/mfu_probe.py`` (docs/PERF.md §4b)."""
+    per-GEMM table behind ``examples/mfu_probe.py``."""
     t, d = tokens, hidden
     fwd = [
         ("qkv", t, d, 3 * d),

@@ -1,5 +1,5 @@
 """Differential on-device microbenchmark timing — the measurement skeleton
-behind ``examples/mfu_probe.py`` (docs/PERF.md §4b) and
+behind ``examples/mfu_probe.py`` and
 ``examples/kernel_probe.py``, factored here so every probe measures the
 same way.
 

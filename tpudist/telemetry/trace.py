@@ -49,11 +49,43 @@ from typing import Callable, Mapping
 import jax
 
 __all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "TRAIN_STEP",
-           "BLOCK_SCOPES", "MOE_COUNTERS"]
+           "FIT_SPANS", "STEP_SCOPES", "BLOCK_SCOPES", "MOE_COUNTERS"]
 
 # the step marker XProf's step-time view groups device work by; the name
 # predates the ``fit/...`` spans and the benchmark's gap labels quote it
 TRAIN_STEP = "tpudist_train"
+
+# Host spans (:class:`span`) of ``fit`` and the input pipeline, one a step,
+# each with what the benchmark reads from it. The call sites spell the
+# names themselves (``train.py``, ``telemetry/__init__.py``,
+# ``data/loader.py``): this table is the contract, and
+# tests/test_telemetry_fit.py and tests/test_benchmark_contract.py hold
+# the program and ``benchmarks/`` to it:
+FIT_SPANS = {
+    "fit/next_batch": "host_span_ms",     # the loop's next(); the input spans nest in it
+    "fit/resolve_wait": "idle_by_span",   # blocked on the lagged step's metrics
+    "fit/log": "loop_host_ms",
+    "fit/health": "loop_host_ms",
+    "fit/memory_stats": "loop_host_ms",
+    "fit/checkpoint": "loop_host_ms",
+    "input/produce": "input_produce_ms",  # producer thread: one next() of the loader
+    "input/wait": "host_span_ms",         # main thread, on the producer's queue
+    "input/stage": "input_stage_ms",      # sharding one batch and its device_put
+    TRAIN_STEP: "loop_host_ms",           # the step's dispatch
+}
+# Device scopes of the train step (``jax.named_scope``: metadata only) by
+# which a trace reader splits a step into passes. The modules that enter
+# them (``train.py``, ``optim.py``, ``amp.py``, ``comm.py``,
+# ``models/lm_utils.py``, ``models/bert.py``) sit below this one and spell
+# the names themselves; tests/test_benchmark_contract.py finds each in a
+# lowered step's name stacks:
+STEP_SCOPES = {
+    "optimizer": "opt_ms",     # the update, around ``tx.update`` and ``apply_updates``
+    "grad_clip": "opt_ms",     # the global-norm clip, under ``optimizer``
+    "cast": "opt_ms",          # the mixed-precision wrapper's compute copy
+    "loss_head": "fwd_ms",     # head and loss; its backward counts to ``bwd_ms``
+    "grad_exchange": "bwd_ms",  # the explicit reducer's exchange
+}
 
 # Device scopes inside a block of ``tpudist.models.zaya`` — flax module
 # names and ``jax.named_scope``s (metadata only), direct children of the

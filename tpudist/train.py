@@ -310,8 +310,8 @@ def make_train_step(
     program (tpudist.telemetry): global grad-norm, param-norm (pre-update),
     update-norm, and the non-finite gradient element count ride the metrics
     pytree out — a handful of reductions XLA fuses into the existing
-    backward/psum path, measured <2% of step time by the bench's
-    ``telemetry_overhead_pct`` leg. ``guard_nonfinite=True`` additionally
+    backward/psum path (their share of a step: not measured on the chip).
+    ``guard_nonfinite=True`` additionally
     SKIPS a poisoned update inside the same program: when the loss or any
     gradient is non-finite, params/opt-state/batch-stats keep their
     pre-step values (the step counter still advances, so data position and
@@ -349,7 +349,7 @@ def make_train_step(
     match, the state must arrive plan-sharded, and an explicit ``reduce``
     request on a model-sharded plan raises naming the fix (the explicit
     reducer reduces over ``data`` only; composed plans keep the implicit
-    GSPMD reduction). Carried as ``step.plan`` for telemetry/bench
+    GSPMD reduction). Carried as ``step.plan`` for telemetry's
     attribution.
 
     ``batch_spec``: per-key PartitionSpec overrides for the staged batch —
@@ -372,8 +372,9 @@ def make_train_step(
     checkpointing — the stronger memory lever for deep models — is the
     model zoo's ``remat_policy`` field, same policy names.
 
-    ``fused`` selects the step-fusion layer attacking the measured
-    non-GEMM tail (docs/PERF.md §4c): ``"ln"`` clones the model with
+    ``fused`` selects the step-fusion layer for the elementwise tail
+    between the GEMMs (the cells run ``"all"``, PERF.md §4): ``"ln"``
+    clones the model with
     ``fused_ln=True`` (the Pallas fused residual-add+LayerNorm kernel in
     every block, ``tpudist.ops.layernorm``), ``"optimizer"`` routes the
     forward through the compute-dtype param copy a
@@ -1019,19 +1020,19 @@ def fit(
     :func:`make_train_step`): ``"none"`` (default, implicit XLA psum),
     ``"bucketed"`` / ``"quantized"`` (explicit bucketed all-reduce, fp32 or
     int8-on-the-wire with error feedback — the DCN-bound data-parallel
-    lever, docs/PERF.md §11), ``"auto"`` (quantized on a multi-slice
+    lever; no cell runs it), ``"auto"`` (quantized on a multi-slice
     attach). fit() attaches the error-feedback residual to the train state,
     records the method in the checkpoint geometry meta, and — with
     telemetry on — streams per-step comm bytes plus a one-time measured
     comm-time probe into the JSONL sink (a ``comm`` column on the step-time
     breakdown rows; rows are unchanged when the feature is off).
 
-    ``fused`` selects the step-fusion layer (see :func:`make_train_step`
-    and docs/PERF.md §4c): ``"ln"`` / ``"optimizer"`` / ``"all"`` /
+    ``fused`` selects the step-fusion layer (see :func:`make_train_step`):
+    ``"ln"`` / ``"optimizer"`` / ``"all"`` /
     ``"auto"``; ``None`` (default) keeps the compiled programs
     bit-identical to previous rounds. With telemetry on, the resolved
-    configuration is recorded as a one-time ``fusion`` JSONL row so bench
-    records and run reports stay attributable to the kernels that
+    configuration is recorded as a one-time ``fusion`` JSONL row so run
+    reports stay attributable to the kernels that
     actually ran.
 
     ``shard_opt_state=True`` wraps ``tx`` in ZeRO-1 cross-replica
@@ -1042,11 +1043,11 @@ def fit(
     same compiled step. Combine with ``remat`` (named policy or the
     models' per-block ``remat_policy``) for the full memory-discipline
     recipe — the pair is what moves the trainable-size frontier on a
-    16 GB chip (docs/PERF.md §10).
+    16 GB chip (docs/LM_TRAINING.md "Fitting ~1B parameters").
 
     ``plan`` (:class:`tpudist.parallel.plan.ParallelPlan`) runs the whole
     loop under one composed ``(data, fsdp, pipe, tensor)`` configuration
-    (docs/PERF.md "Choosing a parallelism plan"): the state is born with
+    (docs/MULTIHOST.md "Choosing a parallelism plan"): the state is born with
     the plan's placements (Megatron/pipe metadata kept, replicated leaves
     fsdp-scattered, ZeRO-1 overlaid when ``shard_opt_state=True`` — via
     ``plan.wrap_zero1``, which never double-shards an fsdp leaf), the
@@ -1636,7 +1637,7 @@ def fit(
                 if fused is not None:
                     # one-time fusion config row: which kernels this run's
                     # compiled step actually engaged — the attribution a
-                    # bench record or run report needs next to its numbers
+                    # run report needs next to its numbers
                     tel.set_fusion(step.fused_info)
                 if step.grad_reducer is not None:
                     # one-time comm accounting + a measured standalone
