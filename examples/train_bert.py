@@ -249,7 +249,9 @@ def evaluate_mlm(model, state, source, args, mesh, *, corruption):
     import jax.numpy as jnp
 
     from tpudist.data.lm import TokenWindowLoader
-    from tpudist.models.bert import MlmHead, mlm_head_logits_fn
+    from tpudist.models.bert import (
+        MlmHead, mlm_head_logits_fn, mlm_head_params,
+    )
     from tpudist.models.lm_utils import chunked_head_reduce
     from tpudist.train import _padded_batches
 
@@ -270,8 +272,8 @@ def evaluate_mlm(model, state, source, args, mesh, *, corruption):
         )
         pos = (batch["mlm_mask"] & row_mask[:, None]).astype(jnp.float32)
         ce_sum, hit_sum = chunked_head_reduce(
-            mlm_head_logits_fn(head, params), hidden, batch["targets"],
-            pos, chunk, hits=True,
+            mlm_head_logits_fn(head), mlm_head_params(params), hidden,
+            batch["targets"], pos, chunk, hits=True,
         )
         return ce_sum, hit_sum, jnp.sum(pos)
 

@@ -417,12 +417,14 @@ def mlm_forward(model: Bert, chunk: int | None = None):
     over the corrupted positions only — the MLM objective. Expects batches
     from :func:`mlm_transform` (``tokens``/``targets``/``mlm_mask``).
 
-    ``chunk`` scans the MLM head over sequence chunks with a checkpointed
-    body, bounding live logits to [B, chunk, V] in forward AND backward —
-    the same HBM discipline as ``chunked_lm_forward`` (at bert-base shapes,
-    batch 32 × seq 512 × V=30522 fp32 logits are ~2 GB otherwise). The
-    chunk path rides the shared :func:`~tpudist.models.lm_utils.
-    chunked_head_reduce` skeleton with :func:`mlm_head_logits_fn`.
+    ``chunk`` scans the MLM head over sequence chunks, bounding live
+    logits to [B, chunk, V] — the same HBM discipline as
+    ``chunked_lm_forward`` (at bert-base shapes, batch 32 × seq 512 ×
+    V=30522 fp32 logits are ~2 GB otherwise). The chunk path rides the
+    shared :func:`~tpudist.models.lm_utils.chunked_head_reduce` skeleton
+    with :func:`mlm_head_logits_fn`: under differentiation the sweep takes
+    each chunk's gradient while its logits are there, so none are kept and
+    none are made a second time.
     """
     import optax
 
@@ -455,19 +457,27 @@ def mlm_forward(model: Bert, chunk: int | None = None):
             {"params": params}, batch["tokens"], train=True,
             return_hidden=True,
         )
-        total = chunked_head_reduce(
-            mlm_head_logits_fn(head, params), hidden, batch["targets"],
-            mask, chunk,
+        # the mean's 1 / denom rides in the weights (chunked_head_reduce)
+        loss = chunked_head_reduce(
+            mlm_head_logits_fn(head), mlm_head_params(params), hidden,
+            batch["targets"], mask / denom, chunk,
         )
-        return total / denom, batch_stats
+        return loss, batch_stats
 
     return forward_loss
 
 
-def mlm_head_logits_fn(head: MlmHead, params):
+def mlm_head_params(params) -> dict:
+    """The ``head_params`` of :func:`mlm_head_logits_fn`: the tied table and
+    the head's own leaves, out of a Bert's parameters."""
+    return {
+        "wte": nn.meta.unbox(params["wte"]),
+        "mlm_head": nn.meta.unbox(params["mlm_head"]),
+    }
+
+
+def mlm_head_logits_fn(head: MlmHead):
     """``logits_fn`` for ``chunked_head_reduce``: BERT's transform + tied
     decode, applied per hidden chunk through the :class:`MlmHead` module
-    (no duplicated head math)."""
-    wte = nn.meta.unbox(params["wte"])
-    head_params = {"params": nn.meta.unbox(params["mlm_head"])}
-    return lambda hc: head.apply(head_params, hc, wte)
+    (no duplicated head math) on :func:`mlm_head_params`."""
+    return lambda p, hc: head.apply({"params": p["mlm_head"]}, hc, p["wte"])

@@ -83,7 +83,7 @@ STEP_SCOPES = {
     "optimizer": "opt_ms",     # the update, around ``tx.update`` and ``apply_updates``
     "grad_clip": "opt_ms",     # the global-norm clip, under ``optimizer``
     "cast": "opt_ms",          # the mixed-precision wrapper's compute copy
-    "loss_head": "fwd_ms",     # head and loss; its backward counts to ``bwd_ms``
+    "loss_head": "fwd_ms",     # head and loss; ``transpose(`` ops to ``bwd_ms``
     "grad_exchange": "bwd_ms",  # the explicit reducer's exchange
 }
 

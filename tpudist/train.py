@@ -331,7 +331,10 @@ def make_train_step(
     ``forward_loss``: optional fused ``(params, batch_stats, batch) →
     (loss, new_stats)`` replacing the default logits+loss_fn composition —
     e.g. :func:`tpudist.models.gpt2.chunked_lm_forward`, which keeps the LM
-    head's logits from ever materializing.
+    head's logits from ever materializing (live logits [B, chunk, V]) and
+    takes the head's gradient in the same sweep that makes them: a
+    ``jax.custom_vjp``, so such a loss is for reverse mode only, and under
+    a whole-forward ``remat`` its sweep is what the backward runs again.
 
     ``dropout_seed`` keys the per-step dropout stream for models whose
     ``dropout`` field is > 0 (the key is folded with the step counter, so
@@ -2167,7 +2170,8 @@ def evaluate_lm(
     or disjoint shards — both score correctly, as long as every process
     yields the same number of batches (collectives run in lockstep).
     ``chunk`` scans the LM head over sequence chunks
-    (:func:`tpudist.models.lm_utils.chunked_ce_sum`) so the [B,S,V] fp32
+    (:func:`tpudist.models.lm_utils.chunked_ce_sum`; nothing is
+    differentiated here, so one head GEMM a chunk) so the [B,S,V] fp32
     logits never materialize — pass it whenever training needed
     ``chunked_lm_forward`` for the same reason, or eval will re-create the
     very HBM peak the training path avoided.
