@@ -89,12 +89,16 @@ def parse_args(argv=None):
                    "seq_len per chip (dense models only)")
     # model family + size
     p.add_argument("--arch", default="gpt2",
-                   choices=["gpt2", "llama", "zaya"],
+                   choices=["gpt2", "llama", "zaya", "kanana"],
                    help="decoder family: GPT-2 (learned positions, GELU MLP, "
-                   "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA) or ZAYA1 "
+                   "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA), ZAYA1 "
                    "(compressed convolutional attention, top-1 experts "
                    "routed by an MLP router, no token dropped; --experts, "
-                   "--held, --head_dim, --router_width, --ffn_dim)")
+                   "--held, --head_dim, --router_width, --ffn_dim) or "
+                   "Kanana-2 (latent attention, a leading dense layer, "
+                   "sigmoid top-k experts beside shared ones, untied head; "
+                   "--experts, --moe_top_k, --held, --head_dim, --rope_dim, "
+                   "--kv_rank, --ffn_dim, --dense_ffn_dim, --shared_experts)")
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
@@ -103,12 +107,25 @@ def parse_args(argv=None):
     p.add_argument("--ffn_dim", default=0, type=int,
                    help="llama SwiGLU width (0 = 8/3*hidden rounded to 256)")
     p.add_argument("--head_dim", default=0, type=int,
-                   help="zaya: size of a latent attention head (0 = "
-                   "hidden_dim / num_heads)")
+                   help="zaya: size of a latent attention head; kanana: of "
+                   "a value head and of a key's part without position "
+                   "(0 = hidden_dim / num_heads)")
+    p.add_argument("--rope_dim", default=0, type=int,
+                   help="kanana: rotary channels of a key beside its "
+                   "--head_dim others (0 = head_dim / 2)")
+    p.add_argument("--kv_rank", default=0, type=int,
+                   help="kanana: width of the key/value latent (0 = "
+                   "hidden_dim / 4)")
+    p.add_argument("--dense_ffn_dim", default=0, type=int,
+                   help="kanana: SwiGLU width of the leading dense layer "
+                   "(0 = 3 * hidden_dim)")
+    p.add_argument("--shared_experts", default=2, type=int,
+                   help="kanana: shared experts of width --ffn_dim beside "
+                   "the routed ones")
     p.add_argument("--router_width", default=256, type=int,
                    help="zaya: width of the MLP router and of its carry")
     p.add_argument("--held", default="", type=str,
-                   help="zaya: 'first,count' — the contiguous experts this "
+                   help="zaya, kanana: 'first,count' — the contiguous experts this "
                    "run holds (one shard's share of an expert-parallel "
                    "layer: the router scores all --experts, tokens of the "
                    "others contribute nothing here); empty = all")
@@ -295,8 +312,8 @@ def main(argv=None):
     n_dev = jax.device_count()
     if args.expert_axis:
         expert_axis = args.expert_axis
-    elif args.experts and args.arch != "zaya":
-        # (zaya's dropless layer runs one shard's experts, no exchange)
+    elif args.experts and args.arch not in ("zaya", "kanana"):
+        # (the dropless layer runs one shard's experts, no exchange)
         # largest axis that divides both the expert count (weights shard
         # evenly) and the devices left over from the other model axes
         avail = max(n_dev // (args.tensor * args.pipe * args.cp), 1)
@@ -382,6 +399,33 @@ def main(argv=None):
                 routing=Routing(
                     args.experts or 16, top_k=args.moe_top_k, held=held,
                     router="mlp", router_width=args.router_width,
+                ),
+                rope_theta=args.rope_theta, remat_policy=args.remat_policy,
+                dtype=dtype, attn_impl=args.attn, mesh=mesh,
+            )
+        if args.arch == "kanana":
+            from tpudist.models.kanana import Kanana
+            from tpudist.parallel.ep import Routing
+
+            if args.dropout or args.scan_layers or args.generate or args.init_hf:
+                raise SystemExit(
+                    "kanana trains unrolled, without dropout; --generate "
+                    "and --init_hf have no path for it yet"
+                )
+            held = tuple(int(n) for n in args.held.split(",")) if args.held else None
+            head_dim = args.head_dim or args.hidden_dim // args.num_heads
+            ffn_dim = args.ffn_dim or args.hidden_dim // 2
+            return Kanana(
+                vocab_size=args.vocab_size, max_seq_len=args.seq_len,
+                hidden_dim=args.hidden_dim, depth=args.depth,
+                num_heads=args.num_heads, nope_dim=head_dim,
+                rope_dim=args.rope_dim or head_dim // 2, v_dim=head_dim,
+                kv_rank=args.kv_rank or args.hidden_dim // 4,
+                dense_ffn_dim=args.dense_ffn_dim or 3 * args.hidden_dim,
+                ffn_dim=ffn_dim, shared_dim=args.shared_experts * ffn_dim,
+                routing=Routing(
+                    args.experts or 128, top_k=args.moe_top_k, held=held,
+                    scoring="sigmoid", routed_scale=2.448,
                 ),
                 rope_theta=args.rope_theta, remat_policy=args.remat_policy,
                 dtype=dtype, attn_impl=args.attn, mesh=mesh,

@@ -37,7 +37,7 @@ if str(REPO) not in sys.path:
 from benchmarks import spans  # noqa: E402
 from tpudist.telemetry import flops  # noqa: E402
 from tpudist.telemetry.trace import (  # noqa: E402
-    FIT_SPANS, STEP_SCOPES, TRAIN_STEP,
+    BLOCK_SCOPES, FIT_SPANS, STEP_SCOPES, TRAIN_STEP,
 )
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -64,7 +64,8 @@ def _one_chip_mesh():
 # -- (a) one FLOP accounting: the family's copy against the program's --------
 
 
-@pytest.mark.parametrize("name", ["gpt2-medium", "bert-large"])
+@pytest.mark.parametrize("name", ["gpt2-medium", "bert-large",
+                                  "kanana-2-30b-a3b"])
 def test_family_flops_per_token_is_the_programs_counter(name, monkeypatch):
     """``step_mfu_pct``'s numerator (``benchmarks/families/*.py``
     ``train_flops_per_token``, "copied from telemetry/flops.py") equals
@@ -118,7 +119,7 @@ def _calls(path, func):
     ]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya"])
+@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana"])
 def test_every_argument_the_cell_passes_is_a_parameter_of_fit(family):
     """``benchmarks/cell.py`` calls ``fit(model, tx, loader, <its own
     keywords>, **built["fit"])``: every one of them is a parameter."""
@@ -196,6 +197,35 @@ def test_exchange_and_loss_head_scopes_are_declared():
     # nothing is declared that the reader would fold into the model
     assert set(STEP_SCOPES) == spans.OPT_SCOPES | {
         spans.EXCHANGE_SCOPE, "loss_head"}
+
+
+# the Kanana-2 cell's readers (PR 32) quote block scopes: each is declared
+# for that metric, and none declared for it is left out
+@pytest.mark.parametrize("metric, attribute", [
+    ("mla_proj_ms", "STAGES"), ("moe_shared_ms", "STAGE"),
+    ("moe_topk_ms", "STAGES"),
+])
+def test_block_stage_readers_sum_declared_scopes(metric, attribute):
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
+    quoted = getattr(reader, attribute)
+    quoted = [quoted] if isinstance(quoted, str) else list(quoted)
+    # ``moe_topk_ms`` reads the stages declared for ``moe_ms`` in its cell
+    declared = "moe_ms" if metric == "moe_topk_ms" else metric
+    assert sorted(quoted) == sorted(
+        s for s, m in BLOCK_SCOPES.items() if m == declared)
+
+
+@pytest.mark.parametrize("family, scope", [("zaya", "cca_attn"),
+                                           ("kanana", "mla_attn")])
+def test_attention_kernel_patterns_follow_the_declared_scope(family, scope):
+    """XLA names a Pallas call after its innermost scope: the family's
+    ``ATTENTION_OPS`` finds ``<scope>.<k>`` for the scope the program
+    declares for that family's roofline metric, and no other block scope."""
+    pattern = _family(family).ATTENTION_OPS
+    assert BLOCK_SCOPES[scope].endswith("_attn_roofline")
+    assert re.match(pattern, scope) and re.match(pattern, f"{scope}.17")
+    assert not [s for s in BLOCK_SCOPES if s != scope
+                and re.match(pattern, s)]
 
 
 @functools.cache
@@ -284,6 +314,44 @@ def test_lowered_step_holds_the_declared_scope(scope, scope_paths):
     passes = {spans.pass_of(p) for p in held}
     want = STEP_SCOPES[scope].removesuffix("_ms")
     assert passes == ({"fwd", "bwd"} if scope == "loss_head" else {want})
+
+
+@pytest.fixture(scope="module")
+def kanana_block_paths():
+    """Name stacks of the tiny Kanana-2 configuration's lowered step under
+    its cell's recipe (per-block recomputation, fused norms, chunked CE)."""
+    from tpudist.train import create_train_state, make_train_step
+
+    config, traffic = _tiny("kanana")
+    mesh = _one_chip_mesh()
+    built = _family("kanana").build(config, traffic, mesh)
+    seq, rows = traffic["seq_len"], traffic["per_chip_batch"]
+    state = create_train_state(
+        built["model"], 0, jnp.zeros((1, seq), jnp.int32), built["tx"],
+        mesh=mesh)
+    step_args = inspect.signature(make_train_step).parameters
+    step = make_train_step(
+        built["model"], built["tx"], mesh,
+        **{k: v for k, v in built["fit"].items() if k in step_args})
+    return _paths(step.jitted.lower(
+        state, step.stage({"tokens": np.zeros((rows, seq), np.int32)})))
+
+
+@pytest.mark.parametrize(
+    "scope", sorted(s for s in BLOCK_SCOPES if not s.startswith("cca_")))
+def test_lowered_kanana_step_holds_the_block_scope_in_both_passes(
+        scope, kanana_block_paths):
+    """Each scope of the Kanana-2 block is in the lowered step's name
+    stacks as a direct child of an expert block, in the forward and in the
+    backward pass, where ``layer_metrics/moe_ms.py`` ``stage_of`` finds it
+    whatever recomputation puts before the block."""
+    from benchmarks.layer_metrics import moe_ms
+
+    held = {p for p in kanana_block_paths
+            if moe_ms.stage_of(p, "%fusion = f32[]") == scope
+            and "/h_1/" in p}
+    assert held, f"no op of the lowered step is under h_1/{scope}"
+    assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
 
 
 # -- (e) documents name files that exist --------------------------------------

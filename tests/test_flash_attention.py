@@ -170,6 +170,7 @@ def test_pallas_bwd_kv_len_matches_scan_bwd():
     (4096, 4096, 64, (128, 128, False)),    # the scan backward's shapes
     (1024, 1024, 128, (128, 128, False)),
     (128, 4096, 128, (128, 128, False)),
+    (8192, 8192, 128, (512, 1024, True)),   # MLA's call: values of 128
 ])
 def test_default_blocks_follow_the_shape(seq_q, seq_k, head_dim, want):
     from tpudist.ops.flash_attention import default_blocks
@@ -177,3 +178,83 @@ def test_default_blocks_follow_the_shape(seq_q, seq_k, head_dim, want):
     assert default_blocks(seq_q, seq_k, head_dim) == want
     block_q, block_k, _ = want
     assert seq_q % block_q == 0 and seq_k % block_k == 0
+
+
+# -- keys of one width, values of another (latent attention) ------------------
+
+
+def _mla_qkv(b, s, h, dk, dv, seed=3):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, dk)),
+            jax.random.normal(ks[1], (b, s, h, dk)),
+            jax.random.normal(ks[2], (b, s, h, dv)),
+            jax.random.normal(ks[3], (b, s, h, dv)))
+
+
+@pytest.mark.parametrize("heads,dk,dv", [(8, 24, 16), (2, 192, 128)])
+@pytest.mark.parametrize("causal", [False, True])
+def test_key_and_value_widths_differ_forward_and_scan_backward(
+        heads, dk, dv, causal):
+    """Keys of 24 on values of 16 (8 heads; each padded to the lane tile)
+    and the MLA shape itself, keys of 192 on values of 128 (fed as they
+    are): output ``[B, S, H, dv]`` and all three gradients against the XLA
+    oracle, the scale ``1/sqrt(dk)``."""
+    q, k, v, g = _mla_qkv(2, 256, heads, dk, dv)
+    attn = lambda fn: jax.vjp(
+        lambda q, k, v: fn(q, k, v, causal=causal), q, k, v)
+    out, vjp = attn(lambda *a, **kw: flash_attention(
+        *a, block_q=128, block_k=128, **kw))
+    ref, ref_vjp = attn(dot_product_attention)
+    assert out.shape == (2, 256, heads, dv)
+    np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), vjp(g), ref_vjp(g)):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("heads,dk,dv", [(8, 24, 16), (2, 192, 128)])
+def test_key_and_value_widths_differ_pallas_backward(heads, dk, dv):
+    """The Pallas dq / dkv kernels (interpret mode here) at the two widths
+    — the 24 / 16 case padded to 128 / 128 as the wrapper pads it, 192 /
+    128 as they are — against the XLA oracle's gradients."""
+    from tpudist.ops.flash_attention import (
+        _bwd_pallas, _flash_fwd, _lane_pad,
+    )
+
+    q, k, v, g = _mla_qkv(1, 256, heads, dk, dv, seed=4)
+    _, ref_vjp = jax.vjp(
+        lambda q, k, v: dot_product_attention(q, k, v, causal=True), q, k, v)
+    lay = lambda x: jnp.pad(
+        x.transpose(0, 2, 1, 3), [(0, 0)] * 3 + [(0, _lane_pad(x.shape[3]))])
+    assert lay(q).shape[3] == (192 if dk == 192 else 128)
+    sm = 1.0 / np.sqrt(dk)
+    o, lse = _flash_fwd(lay(q), lay(k), lay(v), causal=True, sm_scale=sm,
+                        block_q=128, block_k=128)
+    got = _bwd_pallas((lay(q), lay(k), lay(v), o, lse), lay(g), causal=True,
+                      sm_scale=sm, block_q=128, block_k=128, interpret=True)
+    for name, a, b, width in zip(("dq", "dk", "dv"), got, ref_vjp(g),
+                                 (dk, dk, dv)):
+        np.testing.assert_allclose(
+            a.transpose(0, 2, 1, 3)[..., :width], b, atol=5e-5, rtol=5e-5,
+            err_msg=name)
+
+
+def test_equal_widths_reach_the_kernels_they_reached_before():
+    """A call with equal widths of 128 (the ZAYA1 cell's) hands the
+    kernels its operands as they come: no pad of a head, every block of
+    every ``pallas_call`` 128 wide, the scale ``1/sqrt(128)`` — and a
+    head of 64 is still padded to the lane tile, a key of 192 is not."""
+    from tpudist.ops.flash_attention import _lane_pad
+
+    assert [_lane_pad(w) for w in (16, 64, 128, 192, 200, 256)] == \
+        [112, 64, 0, 0, 56, 0]
+    q, k, v = _qkv(b=1, s=256, h=2, d=128)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, causal=True).sum(),
+        (0, 1, 2)))(q, k, v)
+    text = str(jaxpr)
+    assert "pallas_call" in text and " pad[" not in text
+    shapes = {tuple(v.aval.shape) for e in jaxpr.jaxpr.eqns
+              for v in e.outvars if hasattr(v.aval, "shape")}
+    assert (1, 2, 256, 128) in shapes and not any(
+        s[-1] in (192, 256) for s in shapes if len(s) == 4)

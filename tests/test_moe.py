@@ -638,6 +638,7 @@ def test_routing_says_what_it_cannot_hold():
     with pytest.raises(ValueError, match="held"):
         Routing(16, held=(12, 8))
     with pytest.raises(ValueError, match="scoring"):
-        Routing(16, scoring="sigmoid")
+        Routing(16, scoring="tanh")
+    assert Routing(16, scoring="sigmoid").routed_scale == 1.0
     assert Routing(16).held_range == (0, 16)
     assert Routing(16, held=(8, 8)).held_range == (8, 8)

@@ -119,6 +119,24 @@ def test_flash_attention_fwd_bwd_s4096(compile_for_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_flash_attention_keys_192_values_128_fwd_bwd_s8192(compile_for_chip):
+    """The Kanana-2 cell's MLA call: 32 heads, keys of 192, values of 128,
+    8192 tokens, the shape's own blocks and the Pallas backward. Mosaic
+    takes the 192-wide blocks as they are: three kernels, and no operand
+    of them padded to 256."""
+    import re
+
+    q, v = ((1, 8192, 32, 192), BF16), ((1, 8192, 32, 128), BF16)
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(flash_attention, causal=True)), q, q, v)
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3, len(kernels)  # forward, dkv, dq
+    widths = {int(w) for line in kernels
+              for w in re.findall(r"bf16\[1,32,8192,(\d+)\]", line)}
+    assert widths == {128, 192}, widths
+
+
 @pytest.mark.parametrize("residual", [False, True], ids=["plain", "residual"])
 def test_fused_layernorm_fwd_bwd(compile_for_chip, residual):
     def loss(x, y, scale, bias):
@@ -375,6 +393,13 @@ def test_zaya_cell_step_compiles_and_routes_without_one_hot_products(
     attention = [k for k in kernels if re.match(family.ATTENTION_OPS, k)]
     # per layer: forward, the forward run again under recomputation, dkv, dq
     assert len(attention) == 4 * config["num_hidden_layers"], kernels
+    # equal widths of 128 reach the kernels as they did before the kernel
+    # took a key width and a value width: every head-wide operand and
+    # result of every attention kernel is [4, 8, 4096, 128], nothing padded
+    for line in hlo.splitlines():
+        if re.search(r"%?cca_attn[\w.]* = ", line) and "tpu_custom_call" in line:
+            heads = re.findall(r"bf16\[(\d+),(\d+),(\d+),(\d+)\]", line)
+            assert heads and set(heads) == {("4", "8", "4096", "128")}, line
     assert sum(k.startswith("ragged-dot") for k in kernels) >= \
         9 * config["num_hidden_layers"]
     experts = {config["num_experts"], config["num_experts_held"]}
@@ -387,3 +412,73 @@ def test_zaya_cell_step_compiles_and_routes_without_one_hot_products(
         one_hot = any(tokens in s and experts & set(s) for s in shapes)
         token_path = any(tokens in s and wide in s for s in shapes)
         assert not (one_hot and token_path), line
+
+
+def test_kanana_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
+    """The ``kanana2_30b_train_s8192`` cell's whole train step — its own
+    configuration, traffic and family file, 576.0M parameters, 8,192
+    tokens — compiled for one described chip: it fits beside the harness's
+    copy, the MLA kernel is there under the name the trace reader looks
+    for (``mla_attn.<k>``) with keys of 192 and values of 128 and nothing
+    padded to 256, and the grouped products are the compiler's ragged-dot
+    kernels over the 49,152 (token, choice) rows of a layer."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import common, kanana as family
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import TrainState, make_train_step
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    monkeypatch.setattr(common, "resolve_attn", lambda requested, seq: "flash")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/kanana-2-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks/traffic/train_s8192_b1.json")) as f:
+        traffic = json.load(f)
+    mesh = mesh_lib.create_mesh(devices=topo.devices[:1])
+    built = family.build(config, traffic, mesh)
+    everywhere = NamedSharding(mesh, P())
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        tree)
+    params = placed(built["param_shapes"])
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == 575_955_456
+    state = TrainState(
+        step=jax.ShapeDtypeStruct((), I32, sharding=everywhere),
+        params=params, batch_stats={},
+        opt_state=placed(jax.eval_shape(built["tx"].init, params)))
+    kw = built["fit"]
+    step = make_train_step(
+        built["model"], built["tx"], mesh, loss_fn=kw["loss_fn"],
+        input_key="tokens", label_key="tokens",
+        forward_loss=kw["forward_loss"], fused=kw["fused"])
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (traffic["per_chip_batch"], traffic["seq_len"]), I32,
+        sharding=everywhere)}
+    compiled = step.jitted.lower(state, batch).compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 8e9 < held < 12.5e9, held  # + the harness's 2.3 GB copy <= 14.8 GB
+    hlo = compiled.as_text()
+    kernels = {
+        name: line for line in hlo.splitlines()
+        for name in re.findall(
+            r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            line)}
+    attention = [k for k in kernels if re.match(family.ATTENTION_OPS, k)]
+    # per layer: forward, the forward run again under recomputation, dkv, dq
+    assert len(attention) == 4 * config["num_hidden_layers"], sorted(kernels)
+    widths = {int(w) for k in attention
+              for w in re.findall(r"bf16\[1,32,8192,(\d+)\]", kernels[k])}
+    assert widths == {128, 192}, widths
+    expert_layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
+    grouped = [k for k in kernels if k.startswith("ragged-dot")]
+    assert len(grouped) >= 9 * expert_layers, len(grouped)
+    rows = traffic["seq_len"] * config["num_experts_per_tok"]
+    # forward, recomputed forward and the backward's row-wide results
+    assert sum(f"[{rows}," in kernels[k] for k in grouped) \
+        >= 6 * expert_layers

@@ -35,9 +35,12 @@ from tpudist.parallel.tp import partitioned as _partitioned
 
 
 def apply_rope(x, *, theta: float = 10000.0, positions=None,
-               rotary_dim: int | None = None):
-    """Rotary position embedding over ``x: [B, S, H, D]`` (rotate-half
-    convention). Angles in fp32; output in ``x.dtype``. ``positions`` is
+               rotary_dim: int | None = None, interleaved: bool = False):
+    """Rotary position embedding over ``x: [B, S, H, D]``: rotate-half
+    convention (channel ``i`` pairs with ``i + D/2``), or with
+    ``interleaved`` adjacent pairs ``(2i, 2i+1)`` turned in place (a
+    DeepSeek-V3-family ``rope_interleave``), pair ``i`` by ``pos ·
+    theta^(-2i/D)`` either way. Angles in fp32; output in ``x.dtype``. ``positions`` is
     ``[S]`` (shared across the batch) or ``[B, S]`` (per-row absolute
     positions — slot-pooled decode, where every cache slot sits at its own
     sequence length). ``rotary_dim`` rotates the first ``rotary_dim``
@@ -51,7 +54,7 @@ def apply_rope(x, *, theta: float = 10000.0, positions=None,
                 f"size {d}"
             )
         rotated = apply_rope(x[..., :rotary_dim], theta=theta,
-                             positions=positions)
+                             positions=positions, interleaved=interleaved)
         return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     half = d // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -64,6 +67,11 @@ def apply_rope(x, *, theta: float = 10000.0, positions=None,
     else:
         cos = jnp.cos(angles)[None, :, None, :]           # [1, S, 1, half]
         sin = jnp.sin(angles)[None, :, None, :]
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(b, s, h, half, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -47,7 +47,9 @@ DEFAULT_BLOCK_K = 128
 
 def default_blocks(seq_q: int, seq_k: int, head_dim: int):
     """``(block_q, block_k, pallas_bwd)`` for a call that names none, from
-    the (128-padded) sequence lengths and the head size. Measured on one
+    the (128-padded) sequence lengths and the head size (the VALUE width
+    where keys are wider: latent attention's keys of 192 on values of 128
+    take the blocks of heads of 128). Measured on one
     v5e chip (PERF.md §6, PR 28) at batch 4, 4096 tokens, 8 heads of 128,
     causal, bf16: forward + backward 6.9 ms with blocks 512 x 1024 and the
     Pallas backward against 26.7 ms with 128 x 128 and the scan backward —
@@ -63,10 +65,18 @@ def default_blocks(seq_q: int, seq_k: int, head_dim: int):
     return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, False
 
 
+def _lane_pad(width: int) -> int:
+    """Zero columns a head of ``width`` gains before the kernels: up to the
+    128-lane tile, and past one tile up to the next half tile (a key of
+    192 stays 192: the block then spans the array's whole last axis, which
+    Mosaic takes, and no pad is written to HBM)."""
+    return -width % (128 if width <= 128 else 64)
+
+
 def _fwd_kernel(
-    q_ref, k_ref, v_ref,  # [1,1,bq,d], [1,1,bk,d], [1,1,bk,d]
-    o_ref, lse_ref,       # [1,1,bq,d], [1,1,bq,128] (lane-padded, see _flash_fwd)
-    m_scr, l_scr, acc_scr,  # VMEM f32: [bq,128], [bq,128], [bq,d]
+    q_ref, k_ref, v_ref,  # [1,1,bq,d], [1,1,bk,d], [1,1,bk,dv]
+    o_ref, lse_ref,       # [1,1,bq,dv], [1,1,bq,128] (lane-padded, see _flash_fwd)
+    m_scr, l_scr, acc_scr,  # VMEM f32: [bq,128], [bq,128], [bq,dv]
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
@@ -93,7 +103,7 @@ def _fwd_kernel(
     def _compute():
         q = q_ref[0, 0]  # [bq, d]
         k = k_ref[0, 0]  # [bk, d]
-        v = v_ref[0, 0]  # [bk, d]
+        v = v_ref[0, 0]  # [bk, dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
@@ -140,9 +150,10 @@ def _fwd_kernel(
 
 
 def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
-    """q,k,v: [B, H, S, D] → (o [B,H,S,D], lse [B,H,S] f32)."""
+    """q,k: [B, H, S, D], v: [B, H, S, Dv] → (o [B,H,S,Dv], lse [B,H,S]
+    f32)."""
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     # TPU tile constraint: last-two dims of every VMEM block must align to
@@ -164,7 +175,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
     # put a size-1 dim in the sublane slot, which Mosaic's (8,128) tiling
     # rejects on real TPUs (interpret mode doesn't enforce it)
     out_shape = [
-        jax.ShapeDtypeStruct(q.shape, q.dtype),
+        jax.ShapeDtypeStruct((b, h, s_q, d_v), q.dtype),
         jax.ShapeDtypeStruct((b, h, s_q, 128), jnp.float32),
     ]
     o, lse = pl.pallas_call(
@@ -173,17 +184,17 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, qi, ki: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d_v), lambda b, h, qi, ki: (b, h, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, d_v), lambda b, h, qi, ki: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 128), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, d_v), jnp.float32),
         ],
         interpret=backend.interpret(),
     )(q, k, v)
@@ -226,10 +237,10 @@ def _recompute_p_ds(
 
 
 def _bwd_dkv_kernel(
-    q_ref, do_ref, lse_ref, delta_ref,  # [1,1,bq,d], [1,1,bq,d], [1,1,bq,1]×2
-    k_ref, v_ref,                        # [1,1,bk,d] ×2
-    dk_ref, dv_ref,                      # [1,1,bk,d] ×2
-    dk_scr, dv_scr,                      # VMEM f32 [bk,d]
+    q_ref, do_ref, lse_ref, delta_ref,  # [1,1,bq,d], [1,1,bq,dv], [1,1,bq,1]×2
+    k_ref, v_ref,                        # [1,1,bk,d], [1,1,bk,dv]
+    dk_ref, dv_ref,                      # [1,1,bk,d], [1,1,bk,dv]
+    dk_scr, dv_scr,                      # VMEM f32 [bk,d], [bk,dv]
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
@@ -277,8 +288,8 @@ def _bwd_dkv_kernel(
 
 
 def _bwd_dq_kernel(
-    k_ref, v_ref,                        # [1,1,bk,d] ×2
-    q_ref, do_ref, lse_ref, delta_ref,   # [1,1,bq,d]×2, [1,1,bq,1]×2
+    k_ref, v_ref,                        # [1,1,bk,d], [1,1,bk,dv]
+    q_ref, do_ref, lse_ref, delta_ref,   # [1,1,bq,d], [1,1,bq,dv], [1,1,bq,1]×2
     dq_ref,                              # [1,1,bq,d]
     dq_scr,                              # VMEM f32 [bq,d]
     *, sm_scale: float, causal: bool, block_q: int, block_k: int,
@@ -325,7 +336,7 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
     recomputing p from the saved log-sum-exp — no S×S tensor in HBM."""
     q, k, v, o, lse = res
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     block_q = min(block_q, s_q)
     block_k = min(block_k, s_k)
     nq, nk = s_q // block_q, s_k // block_k
@@ -342,10 +353,16 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
     # validated compiled on a real v5e chip (grads match the scan backward)
     lse_c = lse[..., None]  # [b,h,sq,1]
 
-    qspec = pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, i, 0))
-    kspec = pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, i, 0))
+    # q, k, dq, dk are d wide; v, o, do, dv are d_v wide
+    at_i = lambda b, h, i, j: (b, h, i, 0)
+    at_j = lambda b, h, i, j: (b, h, j, 0)
+    qspec = pl.BlockSpec((1, 1, block_q, d), at_i)
+    dospec = pl.BlockSpec((1, 1, block_q, d_v), at_i)
+    kspec = pl.BlockSpec((1, 1, block_k, d), at_i)
+    vspec = pl.BlockSpec((1, 1, block_k, d_v), at_i)
     # dkv grid: i = k block, j = q block (q innermost)
-    qspec_j = pl.BlockSpec((1, 1, block_q, d), lambda b, h, i, j: (b, h, j, 0))
+    qspec_j = pl.BlockSpec((1, 1, block_q, d), at_j)
+    dospec_j = pl.BlockSpec((1, 1, block_q, d_v), at_j)
     rspec_j = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, j, 0))
     rspec_i = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, i, j: (b, h, i, 0))
 
@@ -355,27 +372,28 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
         ),
         grid=(b, h, nk, nq),
-        in_specs=[qspec_j, qspec_j, rspec_j, rspec_j, kspec, kspec],
-        out_specs=[kspec, kspec],
+        in_specs=[qspec_j, dospec_j, rspec_j, rspec_j, kspec, vspec],
+        out_specs=[kspec, vspec],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d_v), jnp.float32),
         ],
         interpret=interpret,
     )(q, do, lse_c, delta, k, v)
 
-    kspec_j = pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0))
+    kspec_j = pl.BlockSpec((1, 1, block_k, d), at_j)
+    vspec_j = pl.BlockSpec((1, 1, block_k, d_v), at_j)
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
         ),
         grid=(b, h, nq, nk),
-        in_specs=[kspec_j, kspec_j, qspec, qspec, rspec_i, rspec_i],
+        in_specs=[kspec_j, vspec_j, qspec, dospec, rspec_i, rspec_i],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -397,7 +415,7 @@ def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
     """
     q, k, v, o, lse = res
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k, d_v = k.shape[2], v.shape[3]
     block_k = min(block_k, s_k)
     nk = s_k // block_k
 
@@ -409,7 +427,7 @@ def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
 
     # [nk, b, h, block_k, d] scan layout
     kb = k.astype(jnp.float32).reshape(b, h, nk, block_k, d).transpose(2, 0, 1, 3, 4)
-    vb = v.astype(jnp.float32).reshape(b, h, nk, block_k, d).transpose(2, 0, 1, 3, 4)
+    vb = v.astype(jnp.float32).reshape(b, h, nk, block_k, d_v).transpose(2, 0, 1, 3, 4)
 
     def one_block(dq_acc, inp):
         ki, kblk, vblk = inp
@@ -433,7 +451,7 @@ def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
     dq0 = jnp.zeros((b, h, s_q, d), jnp.float32)
     dq, (dk, dv) = jax.lax.scan(one_block, dq0, (jnp.arange(nk), kb, vb))
     dk = dk.transpose(1, 2, 0, 3, 4).reshape(b, h, s_k, d)
-    dv = dv.transpose(1, 2, 0, 3, 4).reshape(b, h, s_k, d)
+    dv = dv.transpose(1, 2, 0, 3, 4).reshape(b, h, s_k, d_v)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -475,7 +493,11 @@ def flash_attention(
     pallas_bwd: bool | None = None, kv_len: int | None = None,
 ):
     """Flash attention on [B, S, H, D] inputs (same layout as
-    :func:`tpudist.ops.attention.dot_product_attention`).
+    :func:`tpudist.ops.attention.dot_product_attention`). ``q`` and ``k``
+    share one width, ``v`` may have another (latent attention: keys of
+    192, values of 128): the output is ``v``'s width, the scale
+    ``1/sqrt`` of the keys', and a width that is a multiple of 64 past
+    128 reaches the kernels unpadded.
 
     Unaligned S is padded to the 128-tile multiple: padded KEYS are masked
     inside the kernels (``kv_len`` — also passable explicitly for
@@ -509,13 +531,16 @@ def flash_attention(
     # kv_len == padded length means "nothing masked": drop it so the
     # kernels skip the mask compare entirely
     eff_kv = None if kv_len == k.shape[1] else kv_len
-    # Pad head_dim to the 128-lane tile. Zero-padded q/k leave scores
-    # unchanged; padded v columns produce output columns sliced off below.
-    d_pad = -d % 128
-    if d_pad:
-        pad = [(0, 0)] * 3 + [(0, d_pad)]
-        q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
-    auto = default_blocks(q.shape[1], k.shape[1], d)
+    # Pad each head width to the 128-lane tile (_lane_pad: a width past one
+    # tile only to the half tile). Zero-padded q/k leave scores unchanged;
+    # padded v columns produce output columns sliced off below.
+    d_v = v.shape[3]
+    def pad(x):
+        extra = _lane_pad(x.shape[3])
+        return jnp.pad(x, [(0, 0)] * 3 + [(0, extra)]) if extra else x
+
+    q, k, v = pad(q), pad(k), pad(v)
+    auto = default_blocks(q.shape[1], k.shape[1], d_v)
     block_q, block_k, pallas_bwd = (
         given if given is not None else chosen
         for given, chosen in zip((block_q, block_k, pallas_bwd), auto)
@@ -524,4 +549,4 @@ def flash_attention(
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     o = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, pallas_bwd,
                eff_kv)
-    return o.transpose(0, 2, 1, 3)[:, :s_q, :, :d]
+    return o.transpose(0, 2, 1, 3)[:, :s_q, :, :d_v]

@@ -459,6 +459,12 @@ def vmem_attention(q, k, v, *, causal: bool = False, kv_len: int | None = None):
     """
     if q.ndim != 4:
         raise NotImplementedError(f"expected [B,S,H,D], got {q.shape}")
+    if not q.shape[3] == k.shape[3] == v.shape[3]:
+        raise NotImplementedError(
+            f"vmem attention takes one head size for q, k and v, got "
+            f"{q.shape[3]} / {k.shape[3]} / {v.shape[3]} — the flash kernel "
+            "takes a key width and a value width"
+        )
     b, s_q, h, d = q.shape
     s_k = k.shape[1]
     if kv_len is None:

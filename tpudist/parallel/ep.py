@@ -49,6 +49,7 @@ count past dense models. TPU-native design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import jax
@@ -515,16 +516,19 @@ class Routing:
     computes; ``None`` holds all. The router always scores all
     ``num_experts``; a token whose expert is not held contributes nothing
     here (its part of the result lives on the shard that holds the expert).
-    ``scoring`` is the normalisation of the router's logits (``softmax``
-    over all experts, float32). ``router`` names the router module:
+    ``scoring`` turns the router's logits into scores, float32:
+    ``softmax`` over all experts, or ``sigmoid`` of each (DeepSeek-V3's
+    family: the chosen scores are then normalised to sum 1 over the k
+    chosen and scaled by ``routed_scale``). ``router`` names the router module:
     ``"linear"`` (one matrix) or ``"mlp"`` (:class:`MlpRouter`, a small MLP
     of width ``router_width`` whose state is carried from layer to layer).
     The selection is the top-k of the scores, with no auxiliary loss.
     ``selection_bias`` is for models that balance their experts' loads by
-    a bias on the SELECTION (ZAYA1, DeepSeek-V3): a function of the
-    router's logits ``[B, S, E]`` that returns what is added to them
-    before the top-k and nowhere else — the gates stay the unbiased
-    probabilities and no gradient passes through it. Those models carry
+    a bias on the SELECTION (ZAYA1, DeepSeek-V3): a function of what the
+    selection ranks, ``[B, S, E]`` — the router's logits under ``softmax``
+    (which keeps their order), the scores under ``sigmoid`` — that returns
+    what is added to them before the top-k and nowhere else: the gates
+    stay the unbiased scores and no gradient passes through it. Those models carry
     the bias from step to step outside the gradient; this trainer carries
     no router state yet (ROADMAP Reach 1), so the caller says how the
     bias is set. ``None``: the plain top-k."""
@@ -536,6 +540,7 @@ class Routing:
     router: str = "linear"
     router_width: int = 256
     selection_bias: Callable[[jax.Array], jax.Array] | None = None
+    routed_scale: float = 1.0
 
     def __post_init__(self):
         first, count = self.held_range
@@ -546,7 +551,7 @@ class Routing:
             )
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k={self.top_k} of {self.num_experts}")
-        if self.scoring != "softmax":
+        if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown scoring {self.scoring!r}")
         if self.router not in ("linear", "mlp"):
             raise ValueError(f"unknown router {self.router!r}")
@@ -607,36 +612,68 @@ def _permute_rows_bwd(res, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_of_choices(x, order, inverse, k):
+    """``x[order // k]``: the token of each of the ``T·k`` (token, choice)
+    rows in sorted order (row ``t·k + c`` unsorted is token ``t``'s choice
+    ``c``). Its backward un-sorts the rows' gradients by ``inverse`` and
+    sums a token's ``k``: a gather and a sum where a plain ``take``
+    transposes to a scatter-add over ``T·k`` rows."""
+    return jnp.take(x, order // k, axis=0)
+
+
+def _rows_of_choices_fwd(x, order, inverse, k):
+    return jnp.take(x, order // k, axis=0), (inverse,)
+
+
+def _rows_of_choices_bwd(k, res, g):
+    (inverse,) = res
+    rows, d = g.shape
+    by_token = jnp.take(g, inverse, axis=0).reshape(rows // k, k, d)
+    return (jnp.sum(by_token, axis=1, dtype=jnp.float32).astype(g.dtype),
+            None, None)
+
+
+_rows_of_choices.defvjp(_rows_of_choices_fwd, _rows_of_choices_bwd)
+
+
 def select_experts(logits, routing: Routing):
     """Router logits ``[B, S, E]`` (float32) → ``(idx [B, S, k] int32,
-    gates [B, S, k] float32)``. The gates are the probabilities of the chosen
+    gates [B, S, k] float32)``. The gates are the scores of the chosen
     experts: raw for top-1 (normalising a single gate to 1 would cut the
     router off from the loss, as :func:`top_k_routing` says), normalised
-    to sum 1 for k ≥ 2. ``routing.selection_bias`` moves the choice
-    only."""
+    to sum 1 for k ≥ 2, times ``routing.routed_scale``.
+    ``routing.selection_bias`` moves the choice only."""
     logits = logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if routing.selection_bias is None:
-        _, idx = jax.lax.top_k(probs, routing.top_k)
+    if routing.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        ranked, eps = scores, 1e-20
     else:
+        scores = jax.nn.softmax(logits, axis=-1)
         # the softmax keeps the logits' order, so a bias on the logits
         # reorders the selection and leaves the gates below as they were
-        raw = jax.lax.stop_gradient(logits)
+        ranked, eps = logits, 1e-9
+    if routing.selection_bias is None:
+        _, idx = jax.lax.top_k(scores, routing.top_k)
+    else:
+        raw = jax.lax.stop_gradient(ranked)
         _, idx = jax.lax.top_k(raw + routing.selection_bias(raw),
                                routing.top_k)
-    # the chosen experts' probabilities by a mask over the E columns: a
+    # the chosen experts' scores by a mask over the E columns: a
     # gather here transposes to a scatter-add over [tokens, E], the mask
     # stays elementwise work in both passes
-    chosen = jax.nn.one_hot(idx, routing.num_experts, dtype=probs.dtype)
-    gates = jnp.sum(probs[..., None, :] * chosen, axis=-1)
+    chosen = jax.nn.one_hot(idx, routing.num_experts, dtype=scores.dtype)
+    gates = jnp.sum(scores[..., None, :] * chosen, axis=-1)
     if routing.top_k > 1:
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-9)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + eps)
+    if routing.routed_scale != 1.0:
+        gates = routing.routed_scale * gates
     return idx.astype(jnp.int32), gates
 
 
 def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
-                 ffn_dim: int, dtype=jnp.float32, mesh=None,
-                 norm_eps: float = 1e-5):
+                 ffn_dim: int, shared_dim: int = 0, dtype=jnp.float32,
+                 mesh=None, norm_eps: float = 1e-5):
     """Dropless expert FFN (SiLU-gated) over the experts ``routing.held``.
 
     Call it inside ``owner``'s compact method: the router
@@ -650,6 +687,11 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     ``u``: ``[B, S, d]`` normed input (float32 for the router; the experts
     compute in ``dtype``). ``r_prev``: the MLP router's carried state
     ``[B, S, width]`` or ``None``. Returns ``(y [B, S, d], r)``.
+
+    ``shared_dim`` > 0 adds a shared expert (``moe_shared``, one SiLU-gated
+    FFN of that width over EVERY token, ungated) to the result: every
+    shard of an expert-parallel layer computes it alike, so the shards'
+    results add up to the whole layer's with it counted once.
 
     No capacity, no dropped token: the ``T·k`` (token, choice) rows are
     stably sorted by local expert id, rows whose expert is not held sort
@@ -710,7 +752,7 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
         # top-1 sorts the tokens themselves (a gather both ways); with k
         # choices a token has k rows and its gradient is their sum
         xs = (_permute_rows(tokens, order, inverse) if k == 1
-              else jnp.take(tokens, order // k, axis=0))
+              else _rows_of_choices(tokens, order, inverse, k))
         # the rows past the last group feed nothing: nought in, and (below)
         # nought out, whatever the grouped product leaves there
         xs = jnp.where(live, xs, 0)
@@ -725,6 +767,8 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
         y = _permute_rows(out, inverse, order) * w[:, None]
         if k > 1:
             y = jnp.sum(y.reshape(T, k, d), axis=1)
+    if shared_dim:
+        y = y + SharedExpert(shared_dim, dtype, name="moe_shared")(tokens)
 
     load = sizes.astype(jnp.float32)
     owner.sow("moe_stats", "tokens", load)
@@ -734,6 +778,24 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
         jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
     )
     return y.reshape(b, s, d), r
+
+
+class SharedExpert(nn.Module):
+    """The expert every token passes: ``(silu(x·w_gate) ⊙ x·w_up)·w_down``,
+    no bias (``n_shared_experts`` of a DeepSeek-V3-family layer are one FFN
+    of their summed width)."""
+
+    ffn_dim: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        dense = lambda name, width: nn.Dense(
+            width, use_bias=False, dtype=self.dtype, name=name
+        )
+        h = nn.silu(dense("w_gate", self.ffn_dim)(x)) \
+            * dense("w_up", self.ffn_dim)(x)
+        return dense("w_down", x.shape[-1])(h)
 
 
 class GroupedExperts(nn.Module):

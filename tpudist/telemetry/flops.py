@@ -152,6 +152,34 @@ def llama_moe_train_flops(tokens: float, *, hidden: int, depth: int,
             + depth * 12.0 * tokens * seq * hidden)
 
 
+def kanana_train_flops(tokens: float, *, hidden: int, depth: int,
+                       dense_layers: int, vocab: int, seq: int,
+                       num_heads: int, nope_dim: int, rope_dim: int,
+                       v_dim: int, kv_rank: int, dense_ffn_dim: int,
+                       ffn_dim: int, shared_dim: int, num_experts: int,
+                       top_k: int, held_share: float) -> float:
+    """Kanana-2 / DeepSeek-V3 geometry (tpudist.models.kanana): MLA's four
+    projections in every layer (q, the key/value latent down and up, out),
+    a dense SwiGLU in the leading layers; in the others the fp32 router
+    GEMM H·E, the shared expert (3·H·shared) and ``top_k`` routed SwiGLU
+    experts at the share of the experts this shard HOLDS (a choice whose
+    expert lives elsewhere computes nothing here); un-tied head V·H.
+    Attention 6·S·heads·(key width + value width) a layer: keys are
+    ``nope + rope`` wide, values ``v_dim`` (causal half not taken off)."""
+    dk = nope_dim + rope_dim
+    mla_p = (hidden * num_heads * dk + hidden * (kv_rank + rope_dim)
+             + kv_rank * num_heads * (nope_dim + v_dim)
+             + num_heads * v_dim * hidden)
+    expert_layer_p = (hidden * num_experts + 3 * hidden * shared_dim
+                      + top_k * held_share * 3 * hidden * ffn_dim)
+    weight_matmul_params = (depth * mla_p
+                            + dense_layers * 3 * hidden * dense_ffn_dim
+                            + (depth - dense_layers) * expert_layer_p
+                            + vocab * hidden)
+    return (6.0 * tokens * weight_matmul_params
+            + depth * 6.0 * tokens * seq * num_heads * (dk + v_dim))
+
+
 def bert_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
                      seq: int) -> float:
     """BERT MLM: 12·H² encoder blocks + the MLM head's H² transform and
@@ -282,6 +310,20 @@ def train_step_flops(model: Any, batch: Mapping[str, Any], *,
             num_heads=model.num_heads,
             num_kv_heads=model.num_kv_heads or model.num_heads,
         )
+    if family == "kanana":
+        seq = shape[-1]
+        routing = model.routing
+        return kanana_train_flops(
+            _rows(shape, 1) * seq, hidden=model.hidden_dim,
+            depth=model.depth, dense_layers=model.dense_layers,
+            vocab=model.vocab_size, seq=seq, num_heads=model.num_heads,
+            nope_dim=model.nope_dim, rope_dim=model.rope_dim,
+            v_dim=model.v_dim, kv_rank=model.kv_rank,
+            dense_ffn_dim=model.dense_ffn_dim, ffn_dim=model.ffn_dim,
+            shared_dim=model.shared_dim, num_experts=routing.num_experts,
+            top_k=routing.top_k,
+            held_share=routing.held_range[1] / routing.num_experts,
+        )
     if family == "bert":
         seq = shape[-1]
         return bert_train_flops(
@@ -320,7 +362,8 @@ def tokens_per_step(model: Any, batch: Mapping[str, Any], *,
         shape = batch[input_key].shape
     except (KeyError, AttributeError):
         return None
-    if family in ("gpt2", "llama", "bert", "gpt2_moe", "llama_moe"):
+    if family in ("gpt2", "llama", "bert", "gpt2_moe", "llama_moe",
+                  "kanana"):
         return _rows(shape, 1) * shape[-1]
     if family in ("vit", "resnet"):
         return _rows(shape, 3)

@@ -87,12 +87,16 @@ STEP_SCOPES = {
     "grad_exchange": "bwd_ms",  # the explicit reducer's exchange
 }
 
-# Device scopes inside a block of ``tpudist.models.zaya`` — flax module
+# Device scopes inside a block of ``tpudist.models.zaya`` (``cca_*``) and of
+# ``tpudist.models.kanana`` (``mla_*``, ``moe_shared``), the ``moe_*`` of
+# ``parallel/ep.py`` ``dropless_moe`` in both — flax module
 # names and ``jax.named_scope``s (metadata only), direct children of the
 # block ``h_<n>`` so that a trace reader that folds an op's name stack to
 # its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
 # them apart — each with the benchmark metric that reads it
-# (docs/OBSERVABILITY.md §8; tests/test_zaya.py holds the model to them):
+# (docs/OBSERVABILITY.md §8; tests/test_zaya.py and tests/test_kanana.py
+# hold the models to them; ``moe_topk_ms`` reads the four ``moe_ms``
+# stages in the Kanana-2 cell):
 BLOCK_SCOPES = {
     "cca_proj": "cca_mix_ms",    # CCA's down-projection (q, k, v_a, v_b)
     "cca_mix": "cca_mix_ms",     # q-k mean, convolutions, norms, rotary, value shift
@@ -102,6 +106,13 @@ BLOCK_SCOPES = {
     "moe_dispatch": "moe_ms",    # sort by expert, group sizes, gather
     "moe_experts": "moe_ms",     # the grouped products and the activation (also ``expert_gemm_roofline``)
     "moe_combine": "moe_ms",     # un-sort, gate
+    "moe_shared": "moe_shared_ms",  # the shared expert, on every token
+    "mla_q": "mla_proj_ms",      # MLA's query projection
+    "mla_kv_down": "mla_proj_ms",  # down to the key/value latent and the one rotary key
+    "mla_kv_up": "mla_proj_ms",  # the latent up to each head's k_nope and v
+    "mla_rope": "mla_proj_ms",   # rotary on q_rope / k_rope; q and k put together
+    "mla_attn": "mla_attn_roofline",  # the attention call; its kernel is ``mla_attn.<k>``
+    "mla_out": "mla_proj_ms",    # MLA's output projection
 }
 # Counters the dropless expert layer sows into ``moe_stats`` (a ``moe`` row
 # field ``h_<n>/<counter>`` a logged step), each with its metric:
