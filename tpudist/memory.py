@@ -148,7 +148,10 @@ def transformer_activation_bytes(
       qkv (3H) + attn out + proj in + mlp up (ffn_mult·H) + gelu
       (ffn_mult·H) + proj ≈ ``(8 + 2·ffn_mult)·H`` per layer;
     - ``dots_saveable``: dot/MXU outputs only — qkv (3H) + attn out +
-      mlp up (ffn_mult·H) + proj ≈ ``(5 + ffn_mult)·H``;
+      mlp up (ffn_mult·H) + proj ≈ ``(5 + ffn_mult)·H``. The "attn out"
+      holds for the Pallas attention kernels too: their forward rules name
+      ``o`` (and the [B,H,S] float32 ``lse``, not counted here) and the
+      policy keeps the names (``tpudist/remat.py`` ``KERNEL_RESIDUALS``);
     - ``full`` / ``save_nothing`` (per-block checkpoint): the inter-block
       residual stream (1·H per layer) plus ONE block's internals live
       during its recompute.

@@ -34,10 +34,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudist.ops import backend
+from tpudist.remat import KERNEL_RESIDUALS
 
 NEG_INF = float(np.finfo(np.float32).min)
 
@@ -466,10 +468,23 @@ def _flash(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd, kv_len):
 
 def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd,
                    kv_len):
+    """Forward rule: the output, and ``(q, k, v, o, lse)`` for the backward.
+
+    ``o`` and the [B,H,S] float32 ``lse`` (not the lane-padded buffer the
+    kernel writes) carry the names of ``tpudist/remat.py``
+    ``KERNEL_RESIDUALS``, given BEFORE the residuals and the output are
+    built, so that what a ``dots_saveable`` checkpoint keeps, what the
+    backward reads and what flows on to the output projection are one
+    value: a block's backward then finds both kept and does not launch
+    the forward kernel again. ``q``, ``k``, ``v`` carry no name: layout
+    work on kept projection outputs, which the backward kernels need made
+    again anyway. Outside a ``jax.checkpoint`` a name lowers to nothing.
+    """
     o, lse = _flash_fwd(
         q, k, v, causal=causal, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
+    o, lse = map(checkpoint_name, (o, lse), KERNEL_RESIDUALS)
     return o, (q, k, v, o, lse)
 
 

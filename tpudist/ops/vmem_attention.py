@@ -65,10 +65,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudist.ops import backend
+from tpudist.remat import KERNEL_RESIDUALS
 
 NEG_INF = float(np.finfo(np.float32).min)
 
@@ -386,10 +388,17 @@ def _vmem(q, k, v, causal, sm_scale, kv_len):
 
 
 def _vmem_vjp_fwd(q, k, v, causal, sm_scale, kv_len):
+    """Forward rule: the output, and ``(q, k, v, o, lse)`` for the backward.
+    ``o`` and ``lse`` carry the names of ``tpudist/remat.py``
+    ``KERNEL_RESIDUALS``, given before the residuals and the output are
+    built: a ``dots_saveable`` checkpoint keeps both and a block's backward
+    does not run the forward kernel again (as ``flash_attention``'s rule).
+    Outside a ``jax.checkpoint`` a name lowers to nothing."""
     o, lse = _vmem_fwd_raw(
         q, k, v, causal=causal, sm_scale=sm_scale, kv_len=kv_len,
         interpret=backend.interpret(),
     )
+    o, lse = map(checkpoint_name, (o, lse), KERNEL_RESIDUALS)
     return o, (q, k, v, o, lse)
 
 

@@ -26,7 +26,10 @@ Policies, by descending aggressiveness (ascending activation HBM):
                  save-nothing; kept as the legacy ``remat=True`` spelling)
 ``dots_saveable``save MXU/dot outputs, recompute the elementwise tail —
                  usually the best FLOP/HBM trade on TPU, where recomputing
-                 a matmul costs real roofline and recomputing a gelu is free
+                 a matmul costs real roofline and recomputing a gelu is free.
+                 The attention kernels' output and log-sum-exp are MXU
+                 outputs too (``KERNEL_RESIDUALS``): kept, so a block's
+                 backward does not launch the forward kernel again
 ``none``         no checkpointing — store everything (fastest, hungriest)
 ===============  ============================================================
 
@@ -34,6 +37,20 @@ Measured/contracted ordering of live activation bytes:
 ``save_nothing ≤ full ≤ dots_saveable ≤ none``
 (asserted against XLA's compiled memory analysis in
 ``tests/test_sharded_optim.py``).
+
+**Kernel residuals.** A Pallas attention kernel is a ``pallas_call`` inside
+a ``jax.custom_vjp``: it holds no ``dot_general`` a policy could see, and a
+block's backward would launch the forward kernel a second time only to get
+the residuals ``o`` and ``lse`` back. So the forward rules of
+``ops/flash_attention.py`` and ``ops/vmem_attention.py`` pass the two
+through ``jax.ad_checkpoint.checkpoint_name`` under the names in
+``KERNEL_RESIDUALS``, and ``dots_saveable`` keeps those names beside the
+dots: ``B·H·S·Dv`` in the compute dtype + ``B·H·S`` float32 a layer, the
+size of one projection output. ``full`` and ``save_nothing`` keep nothing;
+outside a ``jax.checkpoint`` a name lowers to nothing. The static counter
+that says it engaged is :func:`forward_attention_kernels`: ``depth`` in a
+training step under ``dots_saveable`` or without recomputation, ``2·depth``
+under ``full`` / ``save_nothing`` (``tests/test_remat_attention.py``).
 """
 
 from __future__ import annotations
@@ -42,15 +59,44 @@ from typing import Any, Callable
 
 import jax
 
+# The attention kernels' forward rules name their output and its row
+# log-sum-exp so (``checkpoint_name``); ``dots_saveable`` keeps both.
+KERNEL_RESIDUALS = ("attn_out", "attn_lse")
+
 # name -> jax.checkpoint policy callable (None = jax.checkpoint's default,
 # which saves nothing). "none" is absent on purpose: it means "do not wrap".
 _POLICIES: dict[str, Any] = {
     "full": None,
     "save_nothing": jax.checkpoint_policies.nothing_saveable,
-    "dots_saveable": jax.checkpoint_policies.dots_saveable,
+    "dots_saveable": jax.checkpoint_policies.save_from_both_policies(
+        jax.checkpoint_policies.dots_saveable,
+        jax.checkpoint_policies.save_only_these_names(*KERNEL_RESIDUALS),
+    ),
 }
 
 POLICY_NAMES = ("none", "full", "dots_saveable", "save_nothing")
+
+_ATTENTION_KERNEL_FILES = ("flash_attention.py", "vmem_attention.py")
+
+
+def forward_attention_kernels(jaxpr) -> int:
+    """Static counter: the ``pallas_call``s of a traced program (a
+    ``ClosedJaxpr`` or ``Jaxpr``, e.g. ``jitted.trace(*args).jaxpr``) whose
+    kernel is an attention FORWARD kernel — ``_fwd_kernel*`` of
+    ``ops/flash_attention.py`` or ``ops/vmem_attention.py``, by the kernel
+    function's own name and file. A training step holds ``depth`` of them
+    where the backward gets ``o`` and ``lse`` from what the policy kept,
+    ``2·depth`` where each block's backward launches the forward again."""
+    n = 0
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "pallas_call":
+            kernel = eqn.params["jaxpr"].debug_info
+            n += (kernel.func_name.startswith("_fwd_kernel")
+                  and kernel.func_filename.endswith(_ATTENTION_KERNEL_FILES))
+            continue  # a kernel's body holds no kernel
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += forward_attention_kernels(sub)
+    return n
 
 
 def resolve(policy: str | bool | None | Callable):
