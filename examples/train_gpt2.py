@@ -89,7 +89,7 @@ def parse_args(argv=None):
                    "seq_len per chip (dense models only)")
     # model family + size
     p.add_argument("--arch", default="gpt2",
-                   choices=["gpt2", "llama", "zaya", "kanana"],
+                   choices=["gpt2", "llama", "zaya", "kanana", "sdar"],
                    help="decoder family: GPT-2 (learned positions, GELU MLP, "
                    "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA), ZAYA1 "
                    "(compressed convolutional attention, top-1 experts "
@@ -98,7 +98,13 @@ def parse_args(argv=None):
                    "Kanana-2 (latent attention, a leading dense layer, "
                    "sigmoid top-k experts beside shared ones, untied head; "
                    "--experts, --moe_top_k, --held, --head_dim, --rope_dim, "
-                   "--kv_rank, --ffn_dim, --dense_ffn_dim, --shared_experts)")
+                   "--kv_rank, --ffn_dim, --dense_ffn_dim, --shared_experts) "
+                   "or SDAR (grouped-query attention with q/k norms, softmax "
+                   "top-k experts, untied head, trained by diffusion over "
+                   "blocks: the noised and the clean copy of a sequence "
+                   "through one stack; --block_length, --t_min, --experts, "
+                   "--moe_top_k, --held, --num_kv_heads, --head_dim, "
+                   "--ffn_dim; the mask token is one id past --vocab_size)")
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
@@ -122,10 +128,16 @@ def parse_args(argv=None):
     p.add_argument("--shared_experts", default=2, type=int,
                    help="kanana: shared experts of width --ffn_dim beside "
                    "the routed ones")
+    p.add_argument("--block_length", default=4, type=int,
+                   help="sdar: tokens of a diffusion block (--seq_len is a "
+                   "multiple of it)")
+    p.add_argument("--t_min", default=1e-3, type=float,
+                   help="sdar: least noise level of a block, t ~ U[t_min, 1] "
+                   "(a masked token's loss weighs 1/t)")
     p.add_argument("--router_width", default=256, type=int,
                    help="zaya: width of the MLP router and of its carry")
     p.add_argument("--held", default="", type=str,
-                   help="zaya, kanana: 'first,count' — the contiguous experts this "
+                   help="zaya, kanana, sdar: 'first,count' — the contiguous experts this "
                    "run holds (one shard's share of an expert-parallel "
                    "layer: the router scores all --experts, tokens of the "
                    "others contribute nothing here); empty = all")
@@ -267,11 +279,15 @@ def main(argv=None):
         # only run in interpret emulation, so CPU runs stay on XLA; inside
         # --pipe the kernels don't compose with the GPipe shard_map
         # (build_model's guard), so auto resolves to XLA there too.
+        # (sdar runs both copies of a sequence, 2 x seq_len rows, under a
+        # block mask that the flash kernel takes and the vmem kernel not)
+        sdar = args.arch == "sdar"
+        rows = args.seq_len * (2 if sdar else 1)
         if args.pipe > 1 or jax.default_backend() != "tpu":
             args.attn = "xla"
-        elif args.seq_len <= 1024:
+        elif rows <= 1024 and not sdar:
             args.attn = "vmem"
-        elif args.seq_len < 2048:
+        elif rows < 2048:
             args.attn = "xla"
         else:
             args.attn = "flash"
@@ -312,7 +328,7 @@ def main(argv=None):
     n_dev = jax.device_count()
     if args.expert_axis:
         expert_axis = args.expert_axis
-    elif args.experts and args.arch not in ("zaya", "kanana"):
+    elif args.experts and args.arch not in ("zaya", "kanana", "sdar"):
         # (the dropless layer runs one shard's experts, no exchange)
         # largest axis that divides both the expert count (weights shard
         # evenly) and the devices left over from the other model axes
@@ -430,6 +446,31 @@ def main(argv=None):
                 rope_theta=args.rope_theta, remat_policy=args.remat_policy,
                 dtype=dtype, attn_impl=args.attn, mesh=mesh,
             )
+        if args.arch == "sdar":
+            from tpudist.models.sdar import Sdar
+            from tpudist.parallel.ep import Routing
+
+            if (args.dropout or args.scan_layers or args.generate
+                    or args.init_hf or args.eval or args.pipe > 1):
+                raise SystemExit(
+                    "sdar trains unrolled, without dropout; --generate, "
+                    "--eval and --init_hf have no path for it yet"
+                )
+            held = tuple(int(n) for n in args.held.split(",")) if args.held else None
+            return Sdar(
+                # one row past the corpus's ids: the mask token
+                vocab_size=args.vocab_size + 1, max_seq_len=args.seq_len,
+                hidden_dim=args.hidden_dim, depth=args.depth,
+                num_heads=args.num_heads,
+                num_kv_heads=args.num_kv_heads or args.num_heads,
+                head_dim=args.head_dim or args.hidden_dim // args.num_heads,
+                ffn_dim=args.ffn_dim or args.hidden_dim // 2,
+                routing=Routing(args.experts or 128, top_k=args.moe_top_k,
+                                held=held),
+                block_length=args.block_length,
+                rope_theta=args.rope_theta, remat_policy=args.remat_policy,
+                dtype=dtype, attn_impl=args.attn, mesh=mesh,
+            )
         if args.arch == "llama":
             from tpudist.models.llama import Llama
 
@@ -486,9 +527,18 @@ def main(argv=None):
         mesh_lib.data_parallel_size(mesh) // ctx.process_count, 1
     )
     per_process_batch = args.batch_size * local_replicas * args.grad_accum
+    corruption = None
+    if args.arch == "sdar":
+        from tpudist.models.sdar import block_diffusion_transform
+
+        # per-block noise on the host, as train_bert.py's mlm_transform
+        corruption = block_diffusion_transform(
+            args.vocab_size, args.block_length, t_min=args.t_min,
+            seed=ctx.process_index,
+        )
     loader = TokenWindowLoader(
         token_source(args), per_process_batch, args.seq_len,
-        vocab_size=args.vocab_size,
+        vocab_size=args.vocab_size, transform=corruption,
         num_replicas=ctx.process_count, rank=ctx.process_index,
     )
 
@@ -512,7 +562,14 @@ def main(argv=None):
     )
 
     forward_loss = None
-    if args.chunked_ce:
+    if args.arch == "sdar":
+        from tpudist.models.sdar import block_diffusion_forward
+
+        # the objective is the forward's own: both copies through the stack
+        # once, the head (always chunked) over the noised rows only
+        forward_loss = block_diffusion_forward(
+            model, chunk=args.chunked_ce or 256)
+    elif args.chunked_ce:
         from tpudist.models.gpt2 import chunked_lm_forward
 
         if args.pipe > 1:

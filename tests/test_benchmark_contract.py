@@ -50,7 +50,7 @@ def _family(name):
 
 def _tiny(family):
     config = json.loads((TINY / "configs" / f"{family}-tiny.json").read_text())
-    traffic = "tiny_mlm" if family == "bert" else "tiny_train"
+    traffic = {"bert": "tiny_mlm", "sdar": "tiny_bd"}.get(family, "tiny_train")
     return config, json.loads(
         (TINY / "traffic" / f"{traffic}.json").read_text())
 
@@ -65,7 +65,7 @@ def _one_chip_mesh():
 
 
 @pytest.mark.parametrize("name", ["gpt2-medium", "bert-large",
-                                  "kanana-2-30b-a3b"])
+                                  "kanana-2-30b-a3b", "sdar-30b-a3b"])
 def test_family_flops_per_token_is_the_programs_counter(name, monkeypatch):
     """``step_mfu_pct``'s numerator (``benchmarks/families/*.py``
     ``train_flops_per_token``, "copied from telemetry/flops.py") equals
@@ -119,7 +119,7 @@ def _calls(path, func):
     ]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana"])
+@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana", "sdar"])
 def test_every_argument_the_cell_passes_is_a_parameter_of_fit(family):
     """``benchmarks/cell.py`` calls ``fit(model, tx, loader, <its own
     keywords>, **built["fit"])``: every one of them is a parameter."""
@@ -199,24 +199,28 @@ def test_exchange_and_loss_head_scopes_are_declared():
         spans.EXCHANGE_SCOPE, "loss_head"}
 
 
-# the Kanana-2 cell's readers (PR 32) quote block scopes: each is declared
-# for that metric, and none declared for it is left out
+# the Kanana-2 cell's readers (PR 32) and the SDAR cell's (PR 34) quote
+# block scopes: each is declared for that metric, and none declared for it
+# is left out
 @pytest.mark.parametrize("metric, attribute", [
     ("mla_proj_ms", "STAGES"), ("moe_shared_ms", "STAGE"),
-    ("moe_topk_ms", "STAGES"),
+    ("moe_topk_ms", "STAGES"), ("bd_proj_ms", "STAGES"),
+    ("bd_moe_ms", "STAGES"),
 ])
 def test_block_stage_readers_sum_declared_scopes(metric, attribute):
     reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
     quoted = getattr(reader, attribute)
     quoted = [quoted] if isinstance(quoted, str) else list(quoted)
-    # ``moe_topk_ms`` reads the stages declared for ``moe_ms`` in its cell
-    declared = "moe_ms" if metric == "moe_topk_ms" else metric
+    # ``moe_topk_ms`` and ``bd_moe_ms`` read the stages declared for
+    # ``moe_ms``, each in its cell
+    declared = "moe_ms" if metric in ("moe_topk_ms", "bd_moe_ms") else metric
     assert sorted(quoted) == sorted(
         s for s, m in BLOCK_SCOPES.items() if m == declared)
 
 
 @pytest.mark.parametrize("family, scope", [("zaya", "cca_attn"),
-                                           ("kanana", "mla_attn")])
+                                           ("kanana", "mla_attn"),
+                                           ("sdar", "bd_attn")])
 def test_attention_kernel_patterns_follow_the_declared_scope(family, scope):
     """XLA names a Pallas call after its innermost scope: the family's
     ``ATTENTION_OPS`` finds ``<scope>.<k>`` for the scope the program
@@ -338,7 +342,8 @@ def kanana_block_paths():
 
 
 @pytest.mark.parametrize(
-    "scope", sorted(s for s in BLOCK_SCOPES if not s.startswith("cca_")))
+    "scope", sorted(s for s in BLOCK_SCOPES
+                    if not s.startswith(("cca_", "attn_", "bd_"))))
 def test_lowered_kanana_step_holds_the_block_scope_in_both_passes(
         scope, kanana_block_paths):
     """Each scope of the Kanana-2 block is in the lowered step's name
@@ -352,6 +357,52 @@ def test_lowered_kanana_step_holds_the_block_scope_in_both_passes(
             and "/h_1/" in p}
     assert held, f"no op of the lowered step is under h_1/{scope}"
     assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
+
+
+@pytest.fixture(scope="module")
+def sdar_block_paths():
+    """Name stacks of the tiny SDAR configuration's lowered step under its
+    cell's recipe (per-block recomputation, fused norms, the block-diffusion
+    forward over both copies)."""
+    from tpudist.train import create_train_state, make_train_step
+
+    config, traffic = _tiny("sdar")
+    mesh = _one_chip_mesh()
+    built = _family("sdar").build(config, traffic, mesh)
+    seq, rows = traffic["seq_len"], traffic["per_chip_batch"]
+    state = create_train_state(
+        built["model"], 0, jnp.zeros((1, seq), jnp.int32), built["tx"],
+        mesh=mesh)
+    step_args = inspect.signature(make_train_step).parameters
+    step = make_train_step(
+        built["model"], built["tx"], mesh,
+        **{k: v for k, v in built["fit"].items() if k in step_args})
+    batch = {"tokens": np.zeros((rows, seq), np.int32),
+             "clean": np.zeros((rows, seq), np.int32),
+             "loss_weight": np.ones((rows, seq), np.float32)}
+    return _paths(step.jitted.lower(state, step.stage(batch)))
+
+
+@pytest.mark.parametrize(
+    "scope", sorted(s for s, m in BLOCK_SCOPES.items()
+                    if m.startswith("bd_") or m == "moe_ms"))
+def test_lowered_sdar_step_holds_the_block_scope_in_both_passes(
+        scope, sdar_block_paths):
+    """Each scope of the SDAR block is in the lowered step's name stacks as
+    a direct child of a block, in the forward and in the backward pass,
+    where ``layer_metrics/moe_ms.py`` ``stage_of`` finds it whatever
+    recomputation puts before the block; ``loss_head`` (``bd_head_ms``) is
+    there beside them."""
+    from benchmarks.layer_metrics import bd_head_ms, moe_ms
+
+    held = {p for p in sdar_block_paths
+            if moe_ms.stage_of(p, "%fusion = f32[]") == scope
+            and "/h_1/" in p}
+    assert held, f"no op of the lowered step is under h_1/{scope}"
+    assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
+    assert bd_head_ms.SCOPE in STEP_SCOPES
+    assert any(spans.scope_of(p).startswith(bd_head_ms.SCOPE)
+               for p in sdar_block_paths)
 
 
 # -- (e) documents name files that exist --------------------------------------

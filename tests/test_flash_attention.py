@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from tpudist.ops.attention import dot_product_attention
-from tpudist.ops.flash_attention import flash_attention
+from tpudist.ops.flash_attention import CAUSAL, flash_attention
 
 
 def _qkv(b=2, s=256, h=4, d=64, dtype=jnp.float32, seed=0):
@@ -111,15 +111,15 @@ def test_pallas_bwd_matches_scan_bwd():
             for _ in range(3)
         )
         o, lse = _flash_fwd(
-            q, k, v, causal=causal, sm_scale=sm, block_q=128, block_k=128
+            q, k, v, mask=CAUSAL if causal else None, sm_scale=sm, block_q=128, block_k=128
         )
         g = jnp.asarray(rng.normal(size=o.shape), jnp.float32)
         res = (q, k, v, o, lse)
         got = _bwd_pallas(
-            res, g, causal=causal, sm_scale=sm, block_q=128, block_k=128,
+            res, g, mask=CAUSAL if causal else None, sm_scale=sm, block_q=128, block_k=128,
             interpret=True,
         )
-        want = _bwd_blockwise(res, g, causal=causal, sm_scale=sm, block_k=128)
+        want = _bwd_blockwise(res, g, mask=CAUSAL if causal else None, sm_scale=sm, block_k=128)
         for a, b in zip(got, want):
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-5
@@ -142,17 +142,17 @@ def test_pallas_bwd_kv_len_matches_scan_bwd():
         for _ in range(3)
     )
     o, lse = _flash_fwd(
-        q, k, v, causal=False, sm_scale=sm, block_q=128, block_k=128,
+        q, k, v, mask=None, sm_scale=sm, block_q=128, block_k=128,
         kv_len=kv_len,
     )
     g = jnp.asarray(rng.normal(size=o.shape), jnp.float32)
     res = (q, k, v, o, lse)
     got = _bwd_pallas(
-        res, g, causal=False, sm_scale=sm, block_q=128, block_k=128,
+        res, g, mask=None, sm_scale=sm, block_q=128, block_k=128,
         kv_len=kv_len, interpret=True,
     )
     want = _bwd_blockwise(
-        res, g, causal=False, sm_scale=sm, block_k=128, kv_len=kv_len
+        res, g, mask=None, sm_scale=sm, block_k=128, kv_len=kv_len
     )
     for name, a, b in zip("dq dk dv".split(), got, want):
         np.testing.assert_allclose(
@@ -228,9 +228,9 @@ def test_key_and_value_widths_differ_pallas_backward(heads, dk, dv):
         x.transpose(0, 2, 1, 3), [(0, 0)] * 3 + [(0, _lane_pad(x.shape[3]))])
     assert lay(q).shape[3] == (192 if dk == 192 else 128)
     sm = 1.0 / np.sqrt(dk)
-    o, lse = _flash_fwd(lay(q), lay(k), lay(v), causal=True, sm_scale=sm,
+    o, lse = _flash_fwd(lay(q), lay(k), lay(v), mask=CAUSAL, sm_scale=sm,
                         block_q=128, block_k=128)
-    got = _bwd_pallas((lay(q), lay(k), lay(v), o, lse), lay(g), causal=True,
+    got = _bwd_pallas((lay(q), lay(k), lay(v), o, lse), lay(g), mask=CAUSAL,
                       sm_scale=sm, block_q=128, block_k=128, interpret=True)
     for name, a, b, width in zip(("dq", "dk", "dv"), got, ref_vjp(g),
                                  (dk, dk, dv)):
@@ -258,3 +258,246 @@ def test_equal_widths_reach_the_kernels_they_reached_before():
               for v in e.outvars if hasattr(v.aval, "shape")}
     assert (1, 2, 256, 128) in shapes and not any(
         s[-1] in (192, 256) for s in shapes if len(s) == 4)
+
+
+# -- the structured mask (BlockMask): causal, block-causal, block diffusion ---
+
+
+def _masked_grads(fn, q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("length, block, block_q, block_k", [
+    (128, 4, 128, 128),    # the cell's block length; one tile a copy
+    (256, 4, 128, 128),    # two tiles a copy: the noised diagonal skips
+    (256, 32, 128, 256),   # blocks wider than a tile is tall: 128 x 256
+    (256, 128, 128, 128),  # a diffusion block the size of a tile
+    (256, 256, 256, 128),  # one block: both copies bidirectional
+    (128, 1, 128, 128),    # blocks of one token: the noised rows see
+                           # themselves and the clean past
+])
+def test_block_diffusion_mask_matches_dense_masked_attention(
+        length, block, block_q, block_k):
+    """The masked kernel (interpret mode) over the ``2 L`` rows of a
+    noised and a clean copy against dense attention under the same mask
+    as a boolean array: output, dq, dk, dv — the scan backward."""
+    from tpudist.ops.attention import BlockMask
+
+    mask = BlockMask(block, length)
+    q, k, v = _qkv(b=1, s=2 * length, h=2, d=32, seed=length + block)
+    dense = lambda q, k, v: dot_product_attention(
+        q, k, v, mask=mask.dense(2 * length)[None, None])
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, mask=mask, block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), _masked_grads(flash, q, k, v),
+                          _masked_grads(dense, q, k, v)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("length, block, noised", [
+    (256, 4, True), (256, 64, True), (512, 16, False), (256, 1, False),
+])
+def test_masked_pallas_backward_matches_the_scan_backward(
+        length, block, noised):
+    """Both backward paths under one mask description: the Pallas dq / dkv
+    kernels (interpret mode) give the blockwise scan's dq, dk, dv — two
+    copies, block-causal, and the causal instance."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import (
+        _bwd_blockwise, _bwd_pallas, _flash_fwd,
+    )
+
+    rows = 2 * length if noised else length
+    mask = BlockMask(block, length if noised else 0)
+    rng = np.random.Generator(np.random.PCG64(block))
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
+               for _ in range(3))
+    sm = 1.0 / np.sqrt(128)
+    o, lse = _flash_fwd(q, k, v, mask=mask, sm_scale=sm, block_q=128,
+                        block_k=256)
+    g = jnp.asarray(rng.normal(size=o.shape), jnp.float32)
+    got = _bwd_pallas((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                      block_q=128, block_k=256, interpret=True)
+    want = _bwd_blockwise((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                          block_k=256)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_block_diffusion_mask_with_grouped_heads_through_the_dispatcher():
+    """GQA 8:1 as the SDAR block calls it: ``multi_head_attention`` repeats
+    the one key/value head over its eight query heads before the kernel;
+    ``flash`` under the mask equals the dense path, and dk / dv come back
+    at the one head (the repeat's transpose sums the group)."""
+    from tpudist.ops.attention import BlockMask, multi_head_attention
+
+    mask = BlockMask(4, 128)
+    ks = jax.random.split(jax.random.key(3), 3)
+    q = jax.random.normal(ks[0], (1, 256, 8, 32))
+    k, v = (jax.random.normal(key, (1, 256, 1, 32)) for key in ks[1:])
+    via = lambda impl: lambda q, k, v: multi_head_attention(
+        q, k, v, mask=mask, impl=impl)
+    np.testing.assert_allclose(via("flash")(q, k, v), via("xla")(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    got, want = (_masked_grads(via(impl), q, k, v) for impl in ("flash", "xla"))
+    assert got[1].shape == k.shape and got[2].shape == v.shape
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4)
+    # ``auto`` keeps a structured mask off the vmem kernel: dense below
+    # 2048 rows (the flash kernel from there on)
+    np.testing.assert_allclose(via("auto")(q, k, v), via("xla")(q, k, v),
+                               atol=1e-6)
+    with pytest.raises(ValueError, match="not both"):
+        multi_head_attention(q, k, v, mask=mask, causal=True)
+
+
+def _computed_tiles(mask, rows, block_q, block_k):
+    """(q tile, k tile) pairs whose products the forward kernel RUNS,
+    observed from outside: a NaN in one key tile's values reaches the
+    output rows of exactly the query tiles that computed it (0 x NaN is
+    NaN: a tile that is computed and masked whole still shows)."""
+    q, k, v = _qkv(b=1, s=rows, h=1, d=32, seed=rows)
+    computed = 0
+    for tile in range(rows // block_k):
+        poisoned = v.at[:, tile * block_k:(tile + 1) * block_k].set(jnp.nan)
+        out = flash_attention(q, k, poisoned, mask=mask, block_q=block_q,
+                              block_k=block_k)
+        hit = np.isnan(np.asarray(out)).any(axis=(0, 2, 3))
+        hit = hit.reshape(rows // block_q, block_q)
+        assert (hit.all(axis=1) | ~hit.any(axis=1)).all()  # whole tiles
+        computed += int(hit.any(axis=1).sum())
+    return computed
+
+
+@pytest.mark.parametrize("length, block, noised, block_q, block_k, share", [
+    (512, 4, True, 128, 256, 16 / 32),  # clean 6, noised to clean 6, diagonal 4
+    (1024, 4, True, 256, 512, 16 / 32),  # the cell's blocks at an eighth
+    (256, 4, True, 128, 128, 8 / 16),   # 3 + 3 + 2
+    (256, 128, True, 128, 128, 6 / 16),  # tile-sized blocks: 3 + 1 + 2
+    (512, 64, False, 128, 128, 10 / 16),  # block-causal
+    (512, 1, False, 128, 128, 10 / 16),   # the causal instance
+    (512, 1, False, 128, 256, 6 / 8),
+])
+def test_computed_tile_share_is_what_the_kernel_runs(
+        length, block, noised, block_q, block_k, share):
+    """The static counter of the tile skipping equals the kernel's own
+    products, tile for tile."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import computed_tile_share
+
+    rows = 2 * length if noised else length
+    mask = BlockMask(block, length if noised else 0)
+    assert computed_tile_share(mask, rows, block_q, block_k) == share
+    tiles = (rows // block_q) * (rows // block_k)
+    assert _computed_tiles(mask, rows, block_q, block_k) == share * tiles
+
+
+def test_tile_share_at_the_cells_shape():
+    """8,192 rows (two copies of 4,096) in blocks of 4 at the blocks the
+    shape takes, 512 x 1024: 48 of 128 tiles — clean to clean 20, noised
+    to clean 20, the noised diagonal 8 — against 72 for a causal call over
+    the same rows and a need of ``(L² + L b) / (2 L)²``; no mask, all."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import (
+        CAUSAL, computed_tile_share, default_blocks,
+    )
+
+    block_q, block_k, pallas_bwd = default_blocks(4096, 4096, 128)
+    assert (block_q, block_k, pallas_bwd) == (512, 1024, True)
+    mask = BlockMask(4, 4096)
+    assert computed_tile_share(mask, 8192, block_q, block_k) == 48 / 128
+    assert computed_tile_share(CAUSAL, 8192, block_q, block_k) == 72 / 128
+    assert computed_tile_share(None, 8192, block_q, block_k) == 1.0
+    need = (4096 ** 2 + 4096 * 4) / 8192 ** 2
+    assert 0.25 < need < 0.2503 < 48 / 128
+
+
+def test_causal_instance_is_the_old_path_to_the_digit():
+    """``causal=True`` IS ``BlockMask()``: one object, the kernels traced
+    from it compare ``q_pos >= k_pos`` and skip ``k0 <= q0 + block_q - 1``
+    as before the mask was a description; the general formula at block 1
+    in one copy is the same function, and both give the same bits."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import CAUSAL
+
+    assert CAUSAL == BlockMask() and CAUSAL.causal
+    pos = np.arange(64)
+    np.testing.assert_array_equal(
+        CAUSAL.allowed(pos[:, None], pos[None, :]), np.tril(np.ones((64, 64), bool)))
+    q, k, v = _qkv(s=384, h=2, d=64, seed=21)
+    old = flash_attention(q, k, v, causal=True, block_q=128, block_k=128)
+    new = flash_attention(q, k, v, mask=CAUSAL, block_q=128, block_k=128)
+    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
+    # the traced forward kernel holds the comparison and no shift, divide
+    # or second copy's offset
+    text = str(jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, v))
+    assert ":bool[128,128] = ge " in text
+    assert "shift_right" not in text and ":bool[128,128] = lt" not in text
+
+
+def test_mask_misuse_is_refused():
+    from tpudist.ops.attention import BlockMask
+
+    q, k, v = _qkv(b=1, s=256, h=1, d=32)
+    with pytest.raises(ValueError, match="not both"):
+        flash_attention(q, k, v, causal=True, mask=BlockMask(4))
+    with pytest.raises(NotImplementedError, match="2 x that many rows"):
+        flash_attention(q, k, v, mask=BlockMask(4, 64))  # not 128-aligned
+    with pytest.raises(NotImplementedError, match="2 x that many rows"):
+        flash_attention(q, k, v, mask=BlockMask(4, 256))  # rows != 2 x 256
+    with pytest.raises(NotImplementedError, match="do not divide a copy"):
+        flash_attention(q, k, v, mask=BlockMask(4, 128), block_q=256)
+    with pytest.raises(ValueError, match="divide noised_len"):
+        BlockMask(8, 12)
+
+
+@pytest.mark.parametrize("block, noised_len, rows", [
+    (4, 256, 512), (128, 256, 512), (256, 256, 512), (1, 128, 256),
+    (32, 256, 512), (64, 0, 512), (3, 0, 384),
+])
+def test_tile_tests_are_the_dense_mask_tile_by_tile(block, noised_len, rows):
+    """``tile_live`` = some pair of the tile allowed, ``tile_full`` = every
+    pair, ``tile_allowed`` = the tile's pairs themselves, each against the
+    boolean array of ``allowed`` cut into tiles, for every tile that lies
+    whole in one copy."""
+    from tpudist.ops.attention import BlockMask
+
+    mask = BlockMask(block, noised_len)
+    dense = np.asarray(mask.dense(rows))
+    for block_q, block_k in ((128, 128), (128, 256), (256, 128)):
+        if noised_len % block_q or noised_len % block_k or rows % block_k \
+                or rows % block_q:
+            continue
+        nq, nk = rows // block_q, rows // block_k
+        tiles = dense.reshape(nq, block_q, nk, block_k).transpose(0, 2, 1, 3)
+        qi, ki = np.arange(nq)[:, None], np.arange(nk)[None, :]
+        live = np.asarray(mask.tile_live(qi, ki, block_q, block_k))
+        np.testing.assert_array_equal(live, tiles.any(axis=(2, 3)))
+        np.testing.assert_array_equal(
+            mask.tile_full(qi, ki, block_q, block_k), tiles.all(axis=(2, 3)))
+        for a, b in zip(*np.nonzero(live)):
+            np.testing.assert_array_equal(
+                mask.tile_allowed(jnp.int32(a), jnp.int32(b), block_q,
+                                  block_k), tiles[a, b])
+
+
+def test_block_causal_mask_on_a_ragged_sequence():
+    """One copy, blocks of 8, 200 rows: the wrapper pads to 256 and the
+    kernels mask the padded keys beside the block mask (the single-branch
+    path: ``kv_len`` and a mask other than the causal one together)."""
+    from tpudist.ops.attention import BlockMask
+
+    mask = BlockMask(8)
+    q, k, v = _qkv(b=1, s=200, h=2, d=32, seed=31)
+    dense = lambda q, k, v: dot_product_attention(
+        q, k, v, mask=mask.dense(200)[None, None])
+    flash = lambda q, k, v: flash_attention(q, k, v, mask=mask)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    for a, b in zip(_masked_grads(flash, q, k, v),
+                    _masked_grads(dense, q, k, v)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)

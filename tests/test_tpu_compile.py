@@ -120,6 +120,22 @@ def test_flash_attention_fwd_bwd_s4096(compile_for_chip):
     assert "tpu_custom_call" in hlo
 
 
+def test_flash_attention_block_diffusion_mask_fwd_bwd_2x4096(compile_for_chip):
+    """The SDAR cell's attention call: 32 heads of 128 over 8,192 rows, the
+    noised and the clean copy of 4,096 tokens under ``BlockMask(4, 4096)``
+    — the mask's tile test on the ``program_id``s and its elementwise test
+    on a 512 x 1024 tile (shifts, compares, and / or of boolean vectors:
+    Mosaic has no select between them), forward and both Pallas backward
+    kernels."""
+    from tpudist.ops.attention import BlockMask
+
+    q = ((1, 8192, 32, 128), BF16)
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(flash_attention, mask=BlockMask(4, 4096))),
+        q, q, q)
+    assert hlo.count("tpu_custom_call") >= 3  # forward, dkv, dq
+
+
 def test_flash_attention_keys_192_values_128_fwd_bwd_s8192(compile_for_chip):
     """The Kanana-2 cell's MLA call: 32 heads, keys of 192, values of 128,
     8192 tokens, the shape's own blocks and the Pallas backward. Mosaic
@@ -493,3 +509,78 @@ def test_kanana_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     # (the grouped products' outputs are not among the kept names)
     assert sum(f"[{rows}," in kernels[k] for k in grouped) \
         >= 6 * expert_layers
+
+
+def test_sdar_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
+    """The ``sdar_30b_bd_train_s4096`` cell's whole train step — its own
+    configuration, traffic and family file, 551.0M parameters, 8,192 rows
+    (the noised and the clean copy of 4,096 tokens) — compiled for one
+    described chip: it fits beside the harness's copy, the masked flash
+    kernel is there under the name the trace reader looks for
+    (``bd_attn.<k>``), 5 forward launches and 3 kernels a layer, over 32
+    heads of 128 at 8,192 rows, and the grouped products are the compiler's
+    ragged-dot kernels over the 65,536 (row, choice) pairs of a layer."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import common, sdar as family
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import TrainState, make_train_step
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    monkeypatch.setattr(common, "resolve_attn", lambda requested, seq: "flash")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/sdar-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmarks/traffic/bd_train_s4096_b1.json")) as f:
+        traffic = json.load(f)
+    mesh = mesh_lib.create_mesh(devices=topo.devices[:1])
+    built = family.build(config, traffic, mesh)
+    everywhere = NamedSharding(mesh, P())
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        tree)
+    params = placed(built["param_shapes"])
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == 550_984_960
+    state = TrainState(
+        step=jax.ShapeDtypeStruct((), I32, sharding=everywhere),
+        params=params, batch_stats={},
+        opt_state=placed(jax.eval_shape(built["tx"].init, params)))
+    kw = built["fit"]
+    step = make_train_step(
+        built["model"], built["tx"], mesh, input_key="tokens",
+        label_key="clean", forward_loss=kw["forward_loss"], fused=kw["fused"])
+    shape = (traffic["per_chip_batch"], traffic["seq_len"])
+    batch = {name: jax.ShapeDtypeStruct(shape, dtype, sharding=everywhere)
+             for name, dtype in (("tokens", I32), ("clean", I32),
+                                 ("loss_weight", F32))}
+    traced = step.jitted.trace(state, batch)
+    assert remat.forward_attention_kernels(traced.jaxpr) \
+        == config["num_hidden_layers"] == 5
+    compiled = traced.lower().compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    assert 8e9 < held < 12.5e9, held  # + the harness's 2.2 GB copy <= 14.7 GB
+    hlo = compiled.as_text()
+    kernels = {
+        name: line for line in hlo.splitlines()
+        for name in re.findall(
+            r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            line)}
+    attention = [k for k in kernels if re.match(family.ATTENTION_OPS, k)]
+    # per layer: forward, dkv, dq (the kept ``o`` and ``lse`` spare the
+    # backward a second forward launch)
+    assert len(attention) == 3 * config["num_hidden_layers"], sorted(kernels)
+    rows = 2 * traffic["seq_len"]
+    for k in attention:
+        heads = set(re.findall(r"bf16\[1,(\d+),(\d+),(\d+)\]", kernels[k]))
+        assert heads == {("32", str(rows), "128")}, kernels[k][:300]
+    grouped = [k for k in kernels if k.startswith("ragged-dot")]
+    assert len(grouped) >= 9 * config["num_hidden_layers"], len(grouped)
+    pairs = rows * config["num_experts_per_tok"]
+    assert sum(f"[{pairs}," in kernels[k] for k in grouped) \
+        >= 6 * config["num_hidden_layers"]

@@ -238,9 +238,11 @@ def test_block_scopes_keep_the_trace_contract(setup):
     model = tiny()
     text = jax.jit(jax.grad(program_loss(model))).lower(
         params, tokens).as_text(debug_info=True)
-    # (``mla_*`` and ``moe_shared`` are the Kanana-2 block's: test_kanana.py)
+    # (``mla_*`` and ``moe_shared`` are the Kanana-2 block's, ``attn_*``
+    # and ``bd_attn`` the SDAR block's: test_kanana.py, test_sdar.py)
     mine = [s for s in BLOCK_SCOPES
-            if not s.startswith("mla_") and s != "moe_shared"]
+            if not s.startswith(("mla_", "attn_", "bd_"))
+            and s != "moe_shared"]
     assert len(mine) == 8
     for scope in mine:
         assert f"h_0/{scope}/" in text, scope

@@ -6,7 +6,8 @@ configs (ViT-B/16, GPT-2 124M). Three paths, dispatched by
 :func:`multi_head_attention` (``impl="auto"`` picks by sequence length):
 
 - ``dot_product_attention``: plain XLA einsum attention — the correctness
-  oracle, and the only path that takes arbitrary masks.
+  oracle, and the only path that takes arbitrary masks (a structured
+  :class:`BlockMask` goes to the flash kernel too).
 - ``tpudist.ops.vmem_attention``: whole-sequence-in-VMEM Pallas kernel for
   S ≤ 1024 — one plain softmax per (batch, head) grid step, no tile loop;
   the path the benchmark's GPT-2 and BERT cells run (PERF.md §4).
@@ -19,11 +20,138 @@ in-kernel (``kv_len``).
 
 from __future__ import annotations
 
+import dataclasses
 from contextlib import nullcontext
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockMask:
+    """A structured attention mask, stated by index arithmetic: the one
+    description the dense path and the flash kernels share (a caller
+    passes it as ``mask=``; it is hashable, so it rides as a static
+    argument).
+
+    Rows and keys are the same ``S`` positions. The first ``noised_len``
+    of them are the NOISED copy of a sequence and the rest its clean copy
+    (``noised_len`` 0: there is one copy); ``p(r)`` is a row's position in
+    its copy and ``blk(r) = p(r) // block``. Query ``i`` sees key ``j``
+    iff
+
+    - both noised and ``blk(i) == blk(j)`` (a block sees itself whole),
+    - ``i`` noised, ``j`` clean and ``blk(j) < blk(i)`` (the clean past),
+    - both clean and ``blk(j) <= blk(i)`` (block-causal).
+
+    ``BlockMask()`` is the causal mask (``block`` 1, one copy),
+    ``BlockMask(b)`` block-causal, ``BlockMask(b, L)`` over ``2 L`` rows
+    the block-diffusion training mask (BD3-LM, arXiv:2503.09573). It
+    answers two questions, for traced scalars, index vectors and numpy
+    arrays alike: which pairs are allowed (:meth:`allowed`; inside a
+    kernel :meth:`tile_allowed`), and whether a (query tile, key tile)
+    holds any allowed pair (:meth:`tile_live`) or nothing else
+    (:meth:`tile_full`) — for tiles that do not straddle ``noised_len``."""
+
+    block: int = 1
+    noised_len: int = 0
+
+    def __post_init__(self):
+        if self.block < 1 or self.noised_len < 0 \
+                or self.noised_len % self.block:
+            raise ValueError(
+                f"block {self.block} must be >= 1 and divide noised_len "
+                f"{self.noised_len}")
+
+    @property
+    def causal(self) -> bool:
+        return self.block == 1 and self.noised_len == 0
+
+    def _blk(self, pos):
+        """Block index of the in-copy positions ``pos``."""
+        if self.block == 1:
+            return pos
+        if self.block & (self.block - 1) == 0:  # a shift, not a division
+            return pos >> (self.block.bit_length() - 1)
+        return pos // self.block
+
+    def allowed(self, q_pos, k_pos):
+        """Elementwise: may query position ``q_pos`` see key ``k_pos``?"""
+        if self.causal:
+            return q_pos >= k_pos
+        if not self.noised_len:
+            return self._blk(k_pos) <= self._blk(q_pos)
+        qn, kn = q_pos < self.noised_len, k_pos < self.noised_len
+        bq = self._blk(q_pos - jnp.where(qn, 0, self.noised_len))
+        bk = self._blk(k_pos - jnp.where(kn, 0, self.noised_len))
+        # (and / or only: Mosaic has no select between boolean vectors)
+        return (qn & kn & (bq == bk)) | (qn & ~kn & (bk < bq)) \
+            | (~qn & ~kn & (bk <= bq))
+
+    def _tile(self, qi, ki, block_q: int, block_k: int):
+        """What a tile that lies whole in one copy knows as SCALARS: the
+        in-copy positions of its first row and first key, and which of the
+        three rules holds in it — ``strict`` (1 where a noised row looks at
+        clean keys: ``blk(j) < blk(i)``, else 0: ``<=``), ``same`` (both
+        noised: ``blk(j) >= blk(i)`` besides) and ``never`` (a clean row
+        on noised keys); ``None`` / ``False`` where one copy rules them
+        out."""
+        q0, k0 = qi * block_q, ki * block_k
+        if not self.noised_len:
+            return q0, k0, None, False, False
+        qn, kn = q0 < self.noised_len, k0 < self.noised_len
+        q0 = q0 - jnp.where(qn, 0, self.noised_len)
+        k0 = k0 - jnp.where(kn, 0, self.noised_len)
+        return q0, k0, (qn & ~kn).astype(jnp.int32), qn & kn, ~qn & kn
+
+    def _tile_rule(self, bq_lo, bq_hi, bk_lo, bk_hi, strict, same, never):
+        """``blk(j) <= blk(i) - strict`` and, where ``same``, ``blk(j) >=
+        blk(i)`` too — for one pair (``lo`` = ``hi``), for SOME pair of a
+        tile (its extreme blocks crossed over) or for EVERY pair (its
+        extreme blocks the other way round)."""
+        ok = bk_lo <= (bq_hi if strict is None else bq_hi - strict)
+        if same is not False:
+            ok &= ~same | (bk_hi >= bq_lo)
+        if never is not False:
+            ok &= ~never
+        return ok
+
+    def tile_live(self, qi, ki, block_q: int, block_k: int):
+        """Has the tile of query rows ``qi * block_q ..`` and keys
+        ``ki * block_k ..`` any allowed pair? The answer for tiles that lie
+        whole in one copy (``block_q`` and ``block_k`` divide
+        ``noised_len``)."""
+        if self.causal:
+            return ki * block_k <= qi * block_q + (block_q - 1)
+        q0, k0, *rule = self._tile(qi, ki, block_q, block_k)
+        return self._tile_rule(
+            self._blk(q0), self._blk(q0 + (block_q - 1)),
+            self._blk(k0), self._blk(k0 + (block_k - 1)), *rule)
+
+    def tile_full(self, qi, ki, block_q: int, block_k: int):
+        """Is EVERY pair of that tile allowed (so that it needs no
+        elementwise mask)?"""
+        q0, k0, *rule = self._tile(qi, ki, block_q, block_k)
+        return self._tile_rule(
+            self._blk(q0 + (block_q - 1)), self._blk(q0),
+            self._blk(k0 + (block_k - 1)), self._blk(k0), *rule)
+
+    def tile_allowed(self, qi, ki, block_q: int, block_k: int):
+        """:meth:`allowed` for the ``[block_q, block_k]`` pairs of that
+        tile, from its scalars: two iotas, two shifts and two compares an
+        element, where the general form pays for finding each element's
+        copy."""
+        q0, k0, strict, same, _ = self._tile(qi, ki, block_q, block_k)
+        shape = (block_q, block_k)
+        bq = self._blk(q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+        bk = self._blk(k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        return self._tile_rule(bq, bq, bk, bk, strict, same, False)
+
+    def dense(self, seq_len: int):
+        """The ``[S, S]`` boolean array of :meth:`allowed`."""
+        pos = jnp.arange(seq_len, dtype=jnp.int32)
+        return self.allowed(pos[:, None], pos[None, :])
 
 
 def repeat_kv(q, k, v, *, head_axis: int = 2):
@@ -88,7 +216,8 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
                          mesh=None, name: str | None = None):
     """Dispatch over the three attention paths:
 
-    - ``xla``: dense einsum attention (oracle; takes arbitrary masks);
+    - ``xla``: dense einsum attention (oracle; takes arbitrary masks, and
+      a :class:`BlockMask` as the boolean array it describes);
     - ``vmem``: whole-sequence-in-VMEM Pallas kernel for S ≤ 1024: the
       scores never reach HBM; the only kernel
       that handles unaligned S (ViT's 197) by padding + in-kernel key mask;
@@ -96,6 +225,11 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
       where whole-S scores no longer fit VMEM);
     - ``auto``: vmem when it applies, else xla below 2048 tokens, else
       flash.
+
+    ``mask``: a boolean array broadcastable to ``[B, H, Sq, Sk]`` (dense
+    path only), or a :class:`BlockMask` — structured, so the flash kernel
+    takes it (skipping tiles with no allowed pair) and ``auto`` sends it
+    there from 2048 rows on, to the dense path below.
 
     ``kv_len``: static true key length for contiguous right-padded K/V —
     the kernels mask padded keys in-kernel; the dense path builds the
@@ -117,7 +251,11 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
     """
     if mask is not None and kv_len is not None:
         raise ValueError("pass mask or kv_len, not both")
-    if mesh is not None and impl in ("vmem", "flash", "auto") and mask is None:
+    structured = isinstance(mask, BlockMask)
+    if structured and causal:
+        raise ValueError("pass causal=True or a BlockMask, not both")
+    if mesh is not None and impl in ("vmem", "flash", "auto") and (
+            mask is None or structured):
         from tpudist import mesh as mesh_lib
 
         dp = int(np.prod([
@@ -157,7 +295,8 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
             def per_shard(q, k, v):
                 with jax.named_scope(name) if name else nullcontext():
                     return multi_head_attention(
-                        q, k, v, causal=causal, impl=impl, kv_len=kv_len
+                        q, k, v, causal=causal, mask=mask, impl=impl,
+                        kv_len=kv_len
                     )
 
             fn = shard_map(
@@ -193,6 +332,8 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
                 "contiguous key padding); using XLA attention"
             )
             impl = "xla"
+        elif structured and max(q.shape[1], k.shape[1]) >= 2048:
+            impl = "flash"  # auto + structured mask: the kernel takes it
         else:
             impl = "xla"  # auto + general mask → dense path
     if k.shape[2] != q.shape[2]:
@@ -200,7 +341,7 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
         # natively)
         k, v = repeat_kv(q, k, v)
     if impl == "flash":
-        if mask is not None:
+        if mask is not None and not structured:
             # no silent fallback: the caller picked flash to keep the S×S
             # scores out of HBM, and a general mask forces the dense path
             import warnings
@@ -214,11 +355,15 @@ def multi_head_attention(q, k, v, *, causal: bool = False, mask=None,
             try:
                 from tpudist.ops.flash_attention import flash_attention
 
-                return flash_attention(q, k, v, causal=causal, kv_len=kv_len)
+                return flash_attention(q, k, v, causal=causal, kv_len=kv_len,
+                                       mask=mask)
             except (ImportError, NotImplementedError) as e:
                 import warnings
 
                 warnings.warn(f"flash attention unavailable ({e}); using XLA attention")
+    if structured:
+        # dense path: the boolean array the description stands for
+        mask = mask.dense(q.shape[1])[None, None]
     if kv_len is not None and kv_len < k.shape[1]:
         # dense path: materialize the contiguous-padding key mask
         mask = (jnp.arange(k.shape[1]) < kv_len)[None, None, None, :]

@@ -13,15 +13,25 @@ Design (FlashAttention-2 style, TPU-first):
 - per tile: one MXU matmul ``q·kᵀ`` (f32 accumulation), online-softmax
   rescale on the VPU, one MXU matmul ``p·v`` into the accumulator — the
   S×S score matrix never exists in HBM;
-- causal masking is two-level: whole K blocks strictly above the diagonal are
-  predicated off with ``pl.when`` (no MXU work issued), the diagonal block is
-  masked elementwise with ``broadcasted_iota``; ``kv_len`` masks right-padded
-  keys the same two-level way (ragged caller shapes are padded to the
-  128-tile multiple by the wrapper);
+- masking is two-level, under ONE description of the mask
+  (:class:`tpudist.ops.attention.BlockMask`: causal, block-causal, or the
+  block-diffusion mask over a noised and a clean copy) that the wrapper,
+  the three kernels and the scan backward share: a (Q tile, K tile) with no
+  allowed pair is predicated off with ``pl.when`` (``BlockMask.tile_live``
+  on the ``program_id``s — no MXU work issued; its K/V blocks are still
+  fetched), a tile whose every pair is allowed runs unmasked
+  (``BlockMask.tile_full``), every other tile is masked elementwise from
+  ``broadcasted_iota`` (the causal instance compares positions, the others
+  ``BlockMask.tile_allowed``); ``kv_len`` masks right-padded keys the same
+  two-level way (ragged caller shapes are padded to the 128-tile multiple
+  by the wrapper). :func:`computed_tile_share` is the static counter of
+  what the first level leaves;
 - two backward paths, both O(S·block) memory, recomputing p from the saved
-  log-sum-exp: the default blockwise ``lax.scan`` in plain JAX (XLA fuses it
-  well — fastest at d=64/moderate S on v5e), and opt-in Pallas FA-2 dq/dkv
-  kernels (``pallas_bwd=True``) for very long sequences.
+  log-sum-exp: a blockwise ``lax.scan`` in plain JAX and the Pallas FA-2
+  dq/dkv kernels. :func:`default_blocks` decides by shape (since PR 28):
+  heads of 128 from 2048 tokens on take blocks up to 512 x 1024 and the
+  Pallas kernels, every other shape 128 x 128 and the scan (fastest at
+  d=64/moderate S on v5e); ``pallas_bwd=`` overrides it.
 
 Numerics: scores/softmax in float32 regardless of input dtype (bf16 in, bf16
 out). Matches ``dot_product_attention`` to ~1e-2 in bf16, ~1e-5 in f32.
@@ -39,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudist.ops import backend
+from tpudist.ops.attention import BlockMask
 from tpudist.remat import KERNEL_RESIDUALS
 
 NEG_INF = float(np.finfo(np.float32).min)
@@ -75,11 +86,89 @@ def _lane_pad(width: int) -> int:
     return -width % (128 if width <= 128 else 64)
 
 
+CAUSAL = BlockMask()
+
+
+def computed_tile_share(mask: BlockMask | None, seq_len: int, block_q: int,
+                        block_k: int) -> float:
+    """Share of the ``seq_len²`` score tiles the kernels compute under
+    ``mask`` at these blocks: the tiles :func:`_tile_live` leaves on, the
+    static counter of the tile skipping (1.0 without a mask). At 8,192
+    rows and 512 x 1024: 0.5625 causal, 0.375 for the block-diffusion mask
+    ``BlockMask(4, 4096)``, whose allowed pairs are 0.2502 of the
+    square."""
+    if mask is None:
+        return 1.0
+    qi = np.arange(seq_len // block_q)[:, None]
+    ki = np.arange(seq_len // block_k)[None, :]
+    return float(np.mean(np.asarray(
+        mask.tile_live(qi, ki, block_q, block_k))))
+
+
+def _tile_live(mask, kv_len, qi, ki, block_q, block_k):
+    """Has tile ``(qi, ki)`` anything to compute? A traced scalar from the
+    ``program_id``s (or ``True``): the mask's own test, and a ``kv_len``
+    shorter than the padded K retires whole K blocks too."""
+    live = True
+    if mask is not None:
+        live = mask.tile_live(qi, ki, block_q, block_k)
+    if kv_len is not None:
+        live &= ki * block_k < kv_len
+    return live
+
+
+def _keep(mask, kv_len, q_pos, k_pos):
+    """Which pairs of a tile count, elementwise, or ``None`` where all
+    do."""
+    if mask is None and kv_len is None:
+        return None
+    keep = jnp.ones(jnp.broadcast_shapes(q_pos.shape, k_pos.shape), bool)
+    if mask is not None:
+        keep &= mask.allowed(q_pos, k_pos)
+    if kv_len is not None:
+        keep &= k_pos < kv_len
+    return keep
+
+
+def _tile_keep(mask, kv_len, qi, ki, block_q, block_k):
+    """:func:`_keep` for tile ``(qi, ki)`` inside a kernel. The causal
+    instance compares positions, as it always did; any other mask answers
+    from the tile's own scalars (``BlockMask.tile_allowed``)."""
+    if mask is None and kv_len is None:
+        return None
+    shape = (block_q, block_k)
+    if mask is None or mask.causal:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return _keep(mask, kv_len, q_pos, k_pos)
+    keep = mask.tile_allowed(qi, ki, block_q, block_k)
+    if kv_len is not None:
+        keep &= ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1) < kv_len
+    return keep
+
+
+def _when_live(mask, kv_len, qi, ki, block_q, block_k, compute):
+    """Run ``compute(mask)`` for tile ``(qi, ki)`` if it has anything to
+    compute. Under a mask other than the causal one the body is traced
+    twice: a tile whose EVERY pair is allowed (``BlockMask.tile_full``:
+    half of the live tiles under the block-diffusion mask) takes the
+    branch without the elementwise mask — a tile's score work is VPU-bound
+    and the mask is a good part of it."""
+    live = _tile_live(mask, kv_len, qi, ki, block_q, block_k)
+    if mask is None or mask.causal or kv_len is not None:
+        pl.when(live)(lambda: compute(mask))
+        return
+    full = mask.tile_full(qi, ki, block_q, block_k)
+    pl.when(live & full)(lambda: compute(None))
+    pl.when(live & ~full)(lambda: compute(mask))
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref,  # [1,1,bq,d], [1,1,bk,d], [1,1,bk,dv]
     o_ref, lse_ref,       # [1,1,bq,dv], [1,1,bq,128] (lane-padded, see _flash_fwd)
     m_scr, l_scr, acc_scr,  # VMEM f32: [bq,128], [bq,128], [bq,dv]
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int,
+    *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
     qi = pl.program_id(2)
@@ -92,17 +181,11 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # Causal: K blocks strictly above the diagonal contribute nothing; a
-    # kv_len shorter than the padded K also retires whole blocks. Skip both
-    # entirely (predicated off — no MXU work issued).
-    block_relevant = True
-    if causal:
-        block_relevant = ki * block_k <= qi * block_q + (block_q - 1)
-    if kv_len is not None:
-        block_relevant &= ki * block_k < kv_len
-
-    @pl.when(block_relevant)
-    def _compute():
+    # Tiles with no allowed pair (causal: K blocks strictly above the
+    # diagonal) contribute nothing; a kv_len shorter than the padded K also
+    # retires whole blocks. Skip both entirely (predicated off — no MXU
+    # work issued).
+    def _compute(mask):
         q = q_ref[0, 0]  # [bq, d]
         k = k_ref[0, 0]  # [bk, d]
         v = v_ref[0, 0]  # [bk, dv]
@@ -110,24 +193,16 @@ def _fwd_kernel(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         s = s * sm_scale  # [bq, bk]
-        if causal or kv_len is not None:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0
-            )
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1
-            )
-            keep = jnp.ones((block_q, block_k), bool)
-            if causal:
-                keep &= q_pos >= k_pos
-            if kv_len is not None:
-                keep &= k_pos < kv_len
+        keep = _tile_keep(mask, kv_len, qi, ki, block_q, block_k)
+        if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
 
         m_prev = m_scr[:, :1]                      # [bq, 1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
-        # rows with nothing unmasked yet keep m = NEG_INF; exp underflows to 0
+        # a row with nothing unmasked yet keeps m = NEG_INF and takes p = 1
+        # from this tile; the first tile that holds a key it may see (every
+        # row has one: its own) rescales that history by alpha = 0
         alpha = jnp.exp(m_prev - m_new)            # [bq, 1] rescale of history
         p = jnp.exp(s - m_new)                     # [bq, bk]
         l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
@@ -140,18 +215,20 @@ def _fwd_kernel(
         )
         acc_scr[...] = acc_scr[...] * alpha + pv
 
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
+
     @pl.when(ki == nk - 1)
     def _finalize():
         l = l_scr[:, :1]
-        # guard fully-masked rows (can't happen for causal with bq>=1, but
-        # keeps the kernel total-function)
+        # guard fully-masked rows (no mask here has one, but it keeps the
+        # kernel total-function)
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[...] / safe_l).astype(o_ref.dtype)
         lse = m_scr[:, :1] + jnp.log(jnp.where(l == 0.0, 1.0, l))  # [bq, 1]
         lse_ref[0, 0] = jnp.broadcast_to(lse, lse_ref.shape[2:])
 
 
-def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
+def _flash_fwd(q, k, v, *, mask, sm_scale, block_q, block_k, kv_len=None):
     """q,k: [B, H, S, D], v: [B, H, S, Dv] → (o [B,H,S,Dv], lse [B,H,S]
     f32)."""
     b, h, s_q, d = q.shape
@@ -170,7 +247,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
     grid = (b, h, s_q // block_q, s_k // block_k)
 
     kernel = functools.partial(
-        _fwd_kernel, sm_scale=sm_scale, causal=causal,
+        _fwd_kernel, sm_scale=sm_scale, mask=mask,
         block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     # lse rides a lane-padded [b,h,s_q,128] buffer: a [*, *, bq] block would
@@ -205,7 +282,7 @@ def _flash_fwd(q, k, v, *, causal, sm_scale, block_q, block_k, kv_len=None):
 
 def _recompute_p_ds(
     qi, ki, q, k, v, do, lse, delta,
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int,
+    *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
     """Shared backward recompute: scores → (p, ds) for one (Q, K) tile.
@@ -217,18 +294,8 @@ def _recompute_p_ds(
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * sm_scale  # [bq, bk]
-    if causal or kv_len is not None:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        keep = jnp.ones((block_q, block_k), bool)
-        if causal:
-            keep &= q_pos >= k_pos
-        if kv_len is not None:
-            keep &= k_pos < kv_len
+    keep = _tile_keep(mask, kv_len, qi, ki, block_q, block_k)
+    if keep is not None:
         s = jnp.where(keep, s, NEG_INF)
     p = jnp.exp(s - lse)  # [bq, bk]
     dp = jax.lax.dot_general(
@@ -243,7 +310,7 @@ def _bwd_dkv_kernel(
     k_ref, v_ref,                        # [1,1,bk,d], [1,1,bk,dv]
     dk_ref, dv_ref,                      # [1,1,bk,d], [1,1,bk,dv]
     dk_scr, dv_scr,                      # VMEM f32 [bk,d], [bk,dv]
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int,
+    *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
     """dk/dv: K/V block resident, sweep over Q blocks (grid dim 3)."""
@@ -256,22 +323,16 @@ def _bwd_dkv_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    relevant = True
-    if causal:
-        # K block contributes only to Q rows at or below the diagonal
-        relevant = qi * block_q + (block_q - 1) >= ki * block_k
-    if kv_len is not None:
-        # fully-padded K blocks produce zero dk/dv (init covers them)
-        relevant &= ki * block_k < kv_len
-
-    @pl.when(relevant)
-    def _compute():
+    # a K block gets nothing from a Q tile that sees none of it (causal:
+    # only Q rows at or below the diagonal count); fully-padded K blocks
+    # produce zero dk/dv (init covers them)
+    def _compute(mask):
         q = q_ref[0, 0]
         do = do_ref[0, 0].astype(jnp.float32)
         p, ds = _recompute_p_ds(
             qi, ki, q, k_ref[0, 0], v_ref[0, 0], do,
             lse_ref[0, 0], delta_ref[0, 0],
-            sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+            sm_scale=sm_scale, mask=mask, block_q=block_q, block_k=block_k,
             kv_len=kv_len,
         )
         # dv += pᵀ·do ; dk += dsᵀ·q
@@ -282,6 +343,8 @@ def _bwd_dkv_kernel(
             ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -294,7 +357,7 @@ def _bwd_dq_kernel(
     q_ref, do_ref, lse_ref, delta_ref,   # [1,1,bq,d], [1,1,bq,dv], [1,1,bq,1]×2
     dq_ref,                              # [1,1,bq,d]
     dq_scr,                              # VMEM f32 [bq,d]
-    *, sm_scale: float, causal: bool, block_q: int, block_k: int,
+    *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
     kv_len: int | None = None,
 ):
     """dq: Q block resident, sweep over K blocks (grid dim 3)."""
@@ -306,20 +369,13 @@ def _bwd_dq_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    relevant = True
-    if causal:
-        relevant = ki * block_k <= qi * block_q + (block_q - 1)
-    if kv_len is not None:
-        relevant &= ki * block_k < kv_len
-
-    @pl.when(relevant)
-    def _compute():
+    def _compute(mask):
         k = k_ref[0, 0]
         _, ds = _recompute_p_ds(
             qi, ki, q_ref[0, 0], k, v_ref[0, 0],
             do_ref[0, 0].astype(jnp.float32),
             lse_ref[0, 0], delta_ref[0, 0],
-            sm_scale=sm_scale, causal=causal, block_q=block_q, block_k=block_k,
+            sm_scale=sm_scale, mask=mask, block_q=block_q, block_k=block_k,
             kv_len=kv_len,
         )
         dq_scr[...] += jax.lax.dot_general(
@@ -327,12 +383,14 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
+
     @pl.when(ki == nk - 1)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
+def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
                 interpret=None):
     """Pallas dq/dk/dv (FlashAttention-2 backward): two kernels, each
     recomputing p from the saved log-sum-exp — no S×S tensor in HBM."""
@@ -370,7 +428,7 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
 
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
+            _bwd_dkv_kernel, sm_scale=sm_scale, mask=mask,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
         ),
         grid=(b, h, nk, nq),
@@ -391,7 +449,7 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
     vspec_j = pl.BlockSpec((1, 1, block_k, d_v), at_j)
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
+            _bwd_dq_kernel, sm_scale=sm_scale, mask=mask,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
         ),
         grid=(b, h, nq, nk),
@@ -404,7 +462,7 @@ def _bwd_pallas(res, g, *, causal, sm_scale, block_q, block_k, kv_len=None,
     return dq, dk, dv
 
 
-def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
+def _bwd_blockwise(res, g, *, mask, sm_scale, block_k, kv_len=None):
     """Blockwise backward from saved (q,k,v,o,lse): lax.scan over K blocks.
 
     Standard flash backward identities with the row log-sum-exp:
@@ -434,13 +492,9 @@ def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
     def one_block(dq_acc, inp):
         ki, kblk, vblk = inp
         s = jnp.einsum("bhqd,bhkd->bhqk", qf, kblk) * sm_scale
-        if causal or kv_len is not None:
-            k_pos = ki * block_k + jnp.arange(block_k)[None, :]
-            keep = jnp.ones(s.shape[-2:], bool)
-            if causal:
-                keep &= q_pos >= k_pos
-            if kv_len is not None:
-                keep &= k_pos < kv_len
+        keep = _keep(mask, kv_len, q_pos,
+                     ki * block_k + jnp.arange(block_k)[None, :])
+        if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - lse_e)                     # [b,h,sq,bk]
         dv = jnp.einsum("bhqk,bhqd->bhkd", p, do)
@@ -458,15 +512,15 @@ def _bwd_blockwise(res, g, *, causal, sm_scale, block_k, kv_len=None):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd, kv_len):
+def _flash(q, k, v, mask, sm_scale, block_q, block_k, pallas_bwd, kv_len):
     o, _ = _flash_fwd(
-        q, k, v, causal=causal, sm_scale=sm_scale,
+        q, k, v, mask=mask, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     return o
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd,
+def _flash_vjp_fwd(q, k, v, mask, sm_scale, block_q, block_k, pallas_bwd,
                    kv_len):
     """Forward rule: the output, and ``(q, k, v, o, lse)`` for the backward.
 
@@ -481,21 +535,21 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, pallas_bwd,
     again anyway. Outside a ``jax.checkpoint`` a name lowers to nothing.
     """
     o, lse = _flash_fwd(
-        q, k, v, causal=causal, sm_scale=sm_scale,
+        q, k, v, mask=mask, sm_scale=sm_scale,
         block_q=block_q, block_k=block_k, kv_len=kv_len,
     )
     o, lse = map(checkpoint_name, (o, lse), KERNEL_RESIDUALS)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, pallas_bwd, kv_len,
+def _flash_vjp_bwd(mask, sm_scale, block_q, block_k, pallas_bwd, kv_len,
                    res, g):
     if pallas_bwd and not backend.interpret():
         return _bwd_pallas(
-            res, g, causal=causal, sm_scale=sm_scale,
+            res, g, mask=mask, sm_scale=sm_scale,
             block_q=block_q, block_k=block_k, kv_len=kv_len,
         )
-    return _bwd_blockwise(res, g, causal=causal, sm_scale=sm_scale,
+    return _bwd_blockwise(res, g, mask=mask, sm_scale=sm_scale,
                           block_k=block_k, kv_len=kv_len)
 
 
@@ -503,7 +557,7 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(
-    q, k, v, *, causal: bool = False,
+    q, k, v, *, causal: bool = False, mask: BlockMask | None = None,
     block_q: int | None = None, block_k: int | None = None,
     pallas_bwd: bool | None = None, kv_len: int | None = None,
 ):
@@ -513,6 +567,12 @@ def flash_attention(
     192, values of 128): the output is ``v``'s width, the scale
     ``1/sqrt`` of the keys', and a width that is a multiple of 64 past
     128 reaches the kernels unpadded.
+
+    ``mask`` is a structured mask (:class:`~tpudist.ops.attention.
+    BlockMask`); ``causal=True`` is its instance ``BlockMask()``, and the
+    two lower to the same kernels. A mask over a noised and a clean copy
+    fixes where the second copy starts, so its ``noised_len`` must be a
+    multiple of 128 (nothing is padded) and the blocks divide it.
 
     Unaligned S is padded to the 128-tile multiple: padded KEYS are masked
     inside the kernels (``kv_len`` — also passable explicitly for
@@ -532,8 +592,17 @@ def flash_attention(
     s_k = k.shape[1]
     if kv_len is None:
         kv_len = s_k
-    if causal and s_q != s_k:
-        raise NotImplementedError("causal path assumes s_q == s_k")
+    if causal:
+        if mask is not None and not mask.causal:
+            raise ValueError("pass causal=True or a mask, not both")
+        mask = CAUSAL
+    if mask is not None and s_q != s_k:
+        raise NotImplementedError("a masked call assumes s_q == s_k")
+    if mask is not None and mask.noised_len and (
+            s_q != 2 * mask.noised_len or mask.noised_len % 128):
+        raise NotImplementedError(
+            f"a mask over two copies of {mask.noised_len} rows needs "
+            f"2 x that many rows (got {s_q}) and a multiple of 128")
     sm_scale = 1.0 / float(np.sqrt(d))
     # Pad ragged sequences to the 128-tile multiple; the kernels mask the
     # padded keys via kv_len and padded query rows are sliced off below.
@@ -555,13 +624,19 @@ def flash_attention(
         return jnp.pad(x, [(0, 0)] * 3 + [(0, extra)]) if extra else x
 
     q, k, v = pad(q), pad(k), pad(v)
-    auto = default_blocks(q.shape[1], k.shape[1], d_v)
+    # blocks follow the shape; under a mask over two copies they divide
+    # one copy, so that no tile straddles the two (BlockMask.tile_live)
+    copy = mask.noised_len if mask is not None and mask.noised_len else None
+    auto = default_blocks(copy or q.shape[1], copy or k.shape[1], d_v)
     block_q, block_k, pallas_bwd = (
         given if given is not None else chosen
         for given, chosen in zip((block_q, block_k, pallas_bwd), auto)
     )
     # [B,S,H,D] → [B,H,S,D] for contiguous per-head tiles
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    o = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, pallas_bwd,
+    if copy and (copy % min(block_q, s_q) or copy % min(block_k, s_k)):
+        raise NotImplementedError(
+            f"blocks {block_q} x {block_k} do not divide a copy of {copy}")
+    o = _flash(qt, kt, vt, mask, sm_scale, block_q, block_k, pallas_bwd,
                eff_kv)
     return o.transpose(0, 2, 1, 3)[:, :s_q, :, :d_v]

@@ -180,6 +180,30 @@ def kanana_train_flops(tokens: float, *, hidden: int, depth: int,
             + depth * 6.0 * tokens * seq * num_heads * (dk + v_dim))
 
 
+def sdar_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
+                     seq: int, num_heads: int, num_kv_heads: int,
+                     head_dim: int, ffn_dim: int, num_experts: int,
+                     top_k: int, held_share: float,
+                     block_length: int) -> float:
+    """SDAR geometry trained by diffusion over blocks
+    (tpudist.models.sdar): ``tokens`` are the TRAINED tokens, ``seq`` the
+    clean length ``L`` — the stack runs ``2 L`` rows a sequence (the noised
+    and the clean copy), so every layer's weights count twice a trained
+    token: the fused q/k/v and the output projection, the fp32 router GEMM
+    H·E and ``top_k`` routed SwiGLU experts at the share of the experts
+    this shard HOLDS; the un-tied head V·H runs over the noised rows only,
+    once a token. Attention at the pairs the mask NEEDS, ``L² + L·b`` a
+    head a sequence (a noised block sees itself and the clean past, the
+    clean copy is block-causal): QK^T and PV, three passes."""
+    attn_p = (hidden * (num_heads + 2 * num_kv_heads) * head_dim
+              + num_heads * head_dim * hidden)
+    layer_p = (attn_p + hidden * num_experts
+               + top_k * held_share * 3 * hidden * ffn_dim)
+    return (6.0 * tokens * (2 * depth * layer_p + vocab * hidden)
+            + depth * 12.0 * tokens * (seq + block_length)
+            * num_heads * head_dim)
+
+
 def bert_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
                      seq: int) -> float:
     """BERT MLM: 12·H² encoder blocks + the MLM head's H² transform and
@@ -324,6 +348,18 @@ def train_step_flops(model: Any, batch: Mapping[str, Any], *,
             top_k=routing.top_k,
             held_share=routing.held_range[1] / routing.num_experts,
         )
+    if family == "sdar":
+        seq = shape[-1]
+        routing = model.routing
+        return sdar_train_flops(
+            _rows(shape, 1) * seq, hidden=model.hidden_dim,
+            depth=model.depth, vocab=model.vocab_size, seq=seq,
+            num_heads=model.num_heads, num_kv_heads=model.num_kv_heads,
+            head_dim=model.head_dim, ffn_dim=model.ffn_dim,
+            num_experts=routing.num_experts, top_k=routing.top_k,
+            held_share=routing.held_range[1] / routing.num_experts,
+            block_length=model.block_length,
+        )
     if family == "bert":
         seq = shape[-1]
         return bert_train_flops(
@@ -363,7 +399,7 @@ def tokens_per_step(model: Any, batch: Mapping[str, Any], *,
     except (KeyError, AttributeError):
         return None
     if family in ("gpt2", "llama", "bert", "gpt2_moe", "llama_moe",
-                  "kanana"):
+                  "kanana", "sdar"):
         return _rows(shape, 1) * shape[-1]
     if family in ("vit", "resnet"):
         return _rows(shape, 3)

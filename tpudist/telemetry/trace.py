@@ -87,16 +87,18 @@ STEP_SCOPES = {
     "grad_exchange": "bwd_ms",  # the explicit reducer's exchange
 }
 
-# Device scopes inside a block of ``tpudist.models.zaya`` (``cca_*``) and of
-# ``tpudist.models.kanana`` (``mla_*``, ``moe_shared``), the ``moe_*`` of
-# ``parallel/ep.py`` ``dropless_moe`` in both — flax module
+# Device scopes inside a block of ``tpudist.models.zaya`` (``cca_*``), of
+# ``tpudist.models.kanana`` (``mla_*``, ``moe_shared``) and of
+# ``tpudist.models.sdar`` (``attn_*``, ``bd_attn``), the ``moe_*`` of
+# ``parallel/ep.py`` ``dropless_moe`` in all three — flax module
 # names and ``jax.named_scope``s (metadata only), direct children of the
 # block ``h_<n>`` so that a trace reader that folds an op's name stack to
 # its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
 # them apart — each with the benchmark metric that reads it
-# (docs/OBSERVABILITY.md §8; tests/test_zaya.py and tests/test_kanana.py
-# hold the models to them; ``moe_topk_ms`` reads the four ``moe_ms``
-# stages in the Kanana-2 cell):
+# (docs/OBSERVABILITY.md §8; tests/test_zaya.py, tests/test_kanana.py and
+# tests/test_sdar.py hold the models to them; ``moe_topk_ms`` reads the
+# four ``moe_ms`` stages in the Kanana-2 cell, ``bd_moe_ms`` in the SDAR
+# cell):
 BLOCK_SCOPES = {
     "cca_proj": "cca_mix_ms",    # CCA's down-projection (q, k, v_a, v_b)
     "cca_mix": "cca_mix_ms",     # q-k mean, convolutions, norms, rotary, value shift
@@ -113,6 +115,11 @@ BLOCK_SCOPES = {
     "mla_rope": "mla_proj_ms",   # rotary on q_rope / k_rope; q and k put together
     "mla_attn": "mla_attn_roofline",  # the attention call; its kernel is ``mla_attn.<k>``
     "mla_out": "mla_proj_ms",    # MLA's output projection
+    "attn_qkv": "bd_proj_ms",    # SDAR's fused q/k/v projection
+    "attn_qk_norm": "bd_proj_ms",  # RMSNorm of every head's q and k
+    "attn_rope": "bd_proj_ms",   # rotary on q and k at the rows' positions
+    "bd_attn": "bd_attn_roofline",  # the masked attention call; its kernel is ``bd_attn.<k>``
+    "attn_out": "bd_proj_ms",    # SDAR's output projection
 }
 # Counters the dropless expert layer sows into ``moe_stats`` (a ``moe`` row
 # field ``h_<n>/<counter>`` a logged step), each with its metric:
