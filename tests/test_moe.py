@@ -523,13 +523,16 @@ def test_dropless_matches_einsum_oracle_when_nothing_drops(top_k):
                                    g_theirs[0][k], rtol=1e-4, atol=1e-6)
 
 
-def test_dropless_drops_no_token_when_the_router_collapses():
+@pytest.mark.parametrize("E,held", [(4, None), (128, (0, 16))],
+                         ids=["all_of_4", "16_of_128_in_3_chunks"])
+def test_dropless_drops_no_token_when_the_router_collapses(E, held):
     """A router forced onto one expert: every token is computed by it (the
     capacity layer at factor 1 zeroes all but T/E of them), and the
-    counters say so."""
-    E, d, ff = 4, 16, 24
+    counters say so — also where the sorted rows are cut into chunks sized
+    for an eighth of them and the one expert fills every chunk."""
+    d, ff = 16, 24
     x = jnp.abs(_moe_inputs(d=d)) + 0.1  # positive: column 2 always wins
-    layer = _Dropless(Routing(E, top_k=1), ff)
+    layer = _Dropless(Routing(E, top_k=1, held=held), ff)
     params = layer.init(jax.random.key(1), x)["params"]
     collapse = jnp.zeros((d, E)).at[:, 2].set(1.0)
     params["moe_router"]["kernel"] = collapse
@@ -540,16 +543,168 @@ def test_dropless_drops_no_token_when_the_router_collapses():
         @ w["w_down"][2]
     np.testing.assert_allclose(y, gate * dense, rtol=1e-5, atol=1e-6)
     T = x.shape[0] * x.shape[1]
+    count = layer.routing.held_range[1]
     stats = sown["moe_stats"]
-    np.testing.assert_array_equal(stats["tokens"][0], [0, 0, T, 0])
+    np.testing.assert_array_equal(
+        stats["tokens"][0], [T if e == 2 else 0 for e in range(count)])
     assert float(stats["held_share"][0]) == 1.0
-    assert float(stats["load_max_over_mean"][0]) == pytest.approx(E)
+    assert float(stats["load_max_over_mean"][0]) == pytest.approx(count)
+    assert float(stats["row_share_computed"][0]) == 1.0
+    if held is not None:
+        return
 
     capped = MoEMlp(num_experts=E, top_k=1, capacity_factor=1.0, ffn_dim=ff,
                     expert_act="swiglu", num_groups=1)
     theirs = {"router": collapse, **w}
     kept = jnp.any(capped.apply({"params": theirs}, x) != 0, axis=-1)
     assert int(jnp.sum(kept)) == T // E  # the rest were dropped
+
+
+# (held, num_experts) -> the chunks the sorted rows are cut into at T = 64
+_HELD = {"16_of_128": ((0, 16), 128, 4), "8_of_16": ((8, 8), 16, 1),
+         "all": (None, 16, 1)}
+_ROUTINGS = ("even", "collapsed", "edge", "none")
+
+
+def _routed(x, kernel, routing, how):
+    """``x`` and a router kernel that send the ``T·k`` choices where the
+    case wants them: three leading features say what kind a token is —
+    ``full`` (every choice on a held expert), ``one`` (its first choice on
+    the first held expert, the others on experts not held), ``none``."""
+    first, count = routing.held_range
+    T = x.shape[0] * x.shape[1]
+    held = jnp.zeros(routing.num_experts, bool).at[first:first + count].set(True)
+    kinds = {"collapsed": (T, 0), "edge": (T // 4, 1), "none": (0, 0)}
+    if how == "even":
+        return x, kernel, None
+    full, one = kinds[how]
+    kind = jnp.where(jnp.arange(T) < full, 0,
+                     jnp.where(jnp.arange(T) < full + one, 1, 2))
+    x = x.at[..., :3].set(jax.nn.one_hot(kind, 3).reshape(*x.shape[:2], 3))
+    kernel = kernel.at[0].set(jnp.where(held, 0.0, -30.0))
+    kernel = kernel.at[1].set(jnp.where(held, -30.0, 0.0).at[first].set(30.0))
+    kernel = kernel.at[2].set(jnp.where(held, -30.0, 0.0))
+    if routing.held is None:  # nothing is "not held": every row is live
+        return x, kernel, T * routing.top_k
+    return x, kernel, min(count, routing.top_k) * full + one
+
+
+@pytest.mark.parametrize(
+    "top_k,held,how",
+    [(k, h, how) for k in (1, 6, 8) for h in _HELD for how in _ROUTINGS
+     if not (h == "all" and how == "none")])
+def test_chunked_layer_is_the_one_chunk_layer(top_k, held, how, monkeypatch):
+    """The sorted rows in chunks, the live ones computed, against the same
+    function with the rows in ONE chunk (the layer with no control flow):
+    the output and the gradients of the tokens, the router and every expert
+    weight, whether the live rows fill a fraction of chunk 0 (``even``),
+    every chunk (``collapsed``), chunk 0 and ONE row of the next
+    (``edge``) or nothing (``none``). No row is dropped in any of them, and
+    ``row_share_computed`` says what was paid. Float32; 1e-5 covers the
+    sums taken chunk by chunk."""
+    from tpudist.parallel import ep
+
+    held_range, E, n_chunks = _HELD[held]
+    routing = Routing(E, top_k=top_k, held=held_range)
+    layer = _Dropless(routing, 24)
+    x = _moe_inputs(T=64)
+    rows = 64 * top_k
+    chunk_rows = rows // n_chunks
+    assert ep.row_chunks(rows, routing.held_range[1], E) \
+        == (chunk_rows, n_chunks)
+    params = layer.init(jax.random.key(1), x)["params"]
+    x, kernel, n_live = _routed(x, params["moe_router"]["kernel"], routing,
+                                how)
+    params["moe_router"]["kernel"] = kernel
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+
+    @jax.jit
+    def run(params, x):
+        def loss(params, x):
+            (y, _), sown = layer.apply({"params": params}, x,
+                                       mutable=["moe_stats"])
+            return jnp.sum(y * probe), (y, sown["moe_stats"])
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(
+            params, x)
+
+    (_, (y, stats)), grads = run(params, x)
+    if n_live is not None:
+        assert int(jnp.sum(stats["tokens"][0])) == n_live
+    live = int(jnp.sum(stats["tokens"][0]))
+    ran = max(1, -(-live // chunk_rows))
+    assert float(stats["row_share_computed"][0]) == ran / n_chunks
+    if how == "edge" and n_chunks > 1:
+        assert live == chunk_rows + 1 and ran == 2
+
+    monkeypatch.setattr(ep, "row_chunks", lambda rows, count, E: (rows, 1))
+    run.clear_cache()
+    (_, (y1, stats1)), grads1 = run(params, x)
+    assert float(stats1["row_share_computed"][0]) == 1.0
+    np.testing.assert_array_equal(stats["tokens"][0], stats1["tokens"][0])
+    np.testing.assert_allclose(y, y1, rtol=1e-5, atol=1e-6)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+        grads, grads1)
+    if live:
+        assert float(jnp.max(jnp.abs(y))) > 0
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, the bodies of its loops, branches and
+    custom rules included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _gather_rows(jaxpr, width):
+    """Row counts of every ``[..rows, width]`` gather in a jaxpr, and the
+    names of the control-flow primitives in it."""
+    shapes = [eqn.outvars[0].aval.shape for eqn in _eqns(jaxpr)
+              if eqn.primitive.name == "gather"]
+    rows = [int(np.prod(shape[:-1])) for shape in shapes
+            if len(shape) >= 2 and shape[-1] == width]
+    control = {eqn.primitive.name for eqn in _eqns(jaxpr)
+               if eqn.primitive.name in ("while", "cond", "scan")}
+    return rows, control
+
+
+@pytest.mark.parametrize("rows,top_k,count,E,want", [
+    (49_152, 6, 16, 128, (12_288, 4)),   # kanana2_30b_train_s8192
+    (65_536, 8, 16, 128, (16_384, 4)),   # sdar_30b_bd_train_s4096
+    (16_384, 1, 8, 16, (16_384, 1)),     # zaya1_8b_train_s4096
+])
+def test_row_chunks_is_what_a_traced_layer_gathers(rows, top_k, count, E,
+                                                   want):
+    """The static counter: ``row_chunks`` at the three expert cells' row
+    counts, against the gathers of a traced forward and backward at those
+    rows — the dispatch and the combine's backward gather ``chunk_rows``
+    rows, only the combine and the dispatch's backward still gather all
+    ``T·k`` — and ONE chunk is the layer with no control flow at all."""
+    from tpudist.parallel import ep
+
+    chunk_rows, n_chunks = ep.row_chunks(rows, count, E)
+    assert (chunk_rows, n_chunks) == want
+    d = 8
+    layer = _Dropless(Routing(E, top_k=top_k, held=(0, count)), 8)
+    x = jax.ShapeDtypeStruct((1, rows // top_k, d), jnp.float32)
+    params = jax.eval_shape(layer.init, jax.random.key(0), x)["params"]
+
+    def loss(params, x):
+        return jnp.sum(layer.apply({"params": params}, x)[0])
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+    gathered, control = _gather_rows(traced.jaxpr, d)
+    assert set(gathered) == {chunk_rows, rows}
+    if n_chunks == 1:
+        assert not control
+    else:
+        # chunk 0 straight and the loop's body, forward and backward twice
+        # (the rows, then the rows' gradients); chunk 0 and a further
+        # chunk for each of the two gathers that stay ``T·k`` rows wide
+        assert gathered.count(chunk_rows) == 4 and gathered.count(rows) == 4
+        assert control == {"while"}
 
 
 def test_selection_bias_moves_the_choice_and_nothing_else():

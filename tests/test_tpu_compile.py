@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from tpudist import remat
+from tpudist.parallel import ep
 from tpudist.ops import backend
 from tpudist.ops.decode import (
     _fused_decode_attention, paged_decode_attention,
@@ -72,6 +73,38 @@ def compile_for_chip(one_chip, monkeypatch):
         return jax.jit(fn).lower(*args).compile().as_text()
 
     return run
+
+
+def _expert_control_flow(jaxpr):
+    """Name stacks of the ``cond`` and ``while`` equations of a jaxpr (the
+    bodies of its loops, branches and custom rules included) that sit under
+    a stage of the dropless expert layer."""
+    found = []
+    for eqn in jaxpr.eqns:
+        stack = str(eqn.source_info.name_stack)
+        if eqn.primitive.name in ("cond", "while") and "moe_" in stack:
+            found.append(f"{stack}/{eqn.primitive.name}")
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _expert_control_flow(sub)
+    return found
+
+
+def _expert_stages(lowered):
+    """``{stage: ops}`` of a lowered step's name stacks that hold a
+    ``moe_*`` component, by what the benchmark's reader makes of them
+    (``layer_metrics/moe_ms.py`` ``stage_of``: the component right after
+    the block's)."""
+    import re
+
+    from benchmarks.layer_metrics import moe_ms
+
+    stages = {}
+    for path in set(re.findall(r'loc\("([^"]+)"',
+                               lowered.as_text(debug_info=True))):
+        if re.search(r"/h_\d+/.*moe_", path):
+            stage = moe_ms.stage_of(path, "%fusion = f32[]")
+            stages[stage] = stages.get(stage, 0) + 1
+    return stages
 
 
 def _fwd_bwd(attn):
@@ -405,6 +438,11 @@ def test_zaya_cell_step_compiles_and_routes_without_one_hot_products(
     # ``lse``, so no block's backward launches the forward kernel again
     assert remat.forward_attention_kernels(traced.jaxpr) \
         == config["num_hidden_layers"]
+    # half the experts are held: the sorted rows are ONE chunk, and the
+    # expert layer brings no loop and no branch into the step
+    assert ep.row_chunks(tokens, config["num_experts_held"],
+                         config["num_experts"]) == (tokens, 1)
+    assert not _expert_control_flow(traced.jaxpr)
     compiled = traced.lower().compile()
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
@@ -484,10 +522,26 @@ def test_kanana_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     traced = step.jitted.trace(state, batch)
     assert remat.forward_attention_kernels(traced.jaxpr) \
         == config["num_hidden_layers"]
-    compiled = traced.lower().compile()
+    rows = traffic["seq_len"] * config["num_experts_per_tok"]
+    chunk_rows, n_chunks = ep.row_chunks(
+        rows, config["num_experts_held"], config["n_routed_experts"])
+    assert (chunk_rows, n_chunks) == (12_288, 4)
+    # the loops over the live chunks sit INSIDE the stages' scopes, so the
+    # benchmark's reader still finds every op of the layer under its stage
+    assert _expert_control_flow(traced.jaxpr)
+    lowered = traced.lower()
+    assert set(_expert_stages(lowered)) == {
+        "moe_norm", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine", "moe_shared"}
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    assert 8e9 < held < 12.5e9, held  # + the harness's 2.3 GB copy <= 14.8 GB
+    # + the harness's 2.3 GB copy <= 15.1 GB of the chip's 16.9. The bytes
+    # LIVE at the schedule's peak fell with the chunked expert layer (11.47
+    # -> 11.22 GB); the allocation rose 12.27 -> 12.67 GB, because the
+    # chunks that do not run still exist, zero-filled, in every buffer that
+    # crosses a stage (three quarters of each)
+    assert 8e9 < held < 12.8e9, held
     hlo = compiled.as_text()
     kernels = {
         name: line for line in hlo.splitlines()
@@ -504,11 +558,12 @@ def test_kanana_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     expert_layers = config["num_hidden_layers"] - config["first_k_dense_replace"]
     grouped = [k for k in kernels if k.startswith("ragged-dot")]
     assert len(grouped) >= 9 * expert_layers, len(grouped)
-    rows = traffic["seq_len"] * config["num_experts_per_tok"]
     # forward, recomputed forward and the backward's row-wide results
-    # (the grouped products' outputs are not among the kept names)
-    assert sum(f"[{rows}," in kernels[k] for k in grouped) \
+    # (the grouped products' outputs are not among the kept names), over
+    # ONE chunk of the 49,152 rows each; none runs over all of them
+    assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
         >= 6 * expert_layers
+    assert not any(f"[{rows}," in kernels[k] for k in grouped)
 
 
 def test_sdar_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
@@ -561,7 +616,16 @@ def test_sdar_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     traced = step.jitted.trace(state, batch)
     assert remat.forward_attention_kernels(traced.jaxpr) \
         == config["num_hidden_layers"] == 5
-    compiled = traced.lower().compile()
+    pairs = 2 * traffic["seq_len"] * config["num_experts_per_tok"]
+    chunk_rows, n_chunks = ep.row_chunks(
+        pairs, config["num_experts_held"], config["num_experts"])
+    assert (chunk_rows, n_chunks) == (16_384, 4)
+    assert _expert_control_flow(traced.jaxpr)
+    lowered = traced.lower()
+    assert set(_expert_stages(lowered)) == {
+        "moe_norm", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine"}
+    compiled = lowered.compile()
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     assert 8e9 < held < 12.5e9, held  # + the harness's 2.2 GB copy <= 14.7 GB
@@ -581,6 +645,6 @@ def test_sdar_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
         assert heads == {("32", str(rows), "128")}, kernels[k][:300]
     grouped = [k for k in kernels if k.startswith("ragged-dot")]
     assert len(grouped) >= 9 * config["num_hidden_layers"], len(grouped)
-    pairs = rows * config["num_experts_per_tok"]
-    assert sum(f"[{pairs}," in kernels[k] for k in grouped) \
+    assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
         >= 6 * config["num_hidden_layers"]
+    assert not any(f"[{pairs}," in kernels[k] for k in grouped)

@@ -516,6 +516,10 @@ class Routing:
     computes; ``None`` holds all. The router always scores all
     ``num_experts``; a token whose expert is not held contributes nothing
     here (its part of the result lives on the shard that holds the expert).
+    So about ``count / num_experts`` of a shard's (token, choice) rows are
+    live, on every shard of every such deployment, and the layer sizes its
+    row chunks from that ratio (:func:`row_chunks`): rows of experts not
+    held are sorted last and the chunks they fill do no row-wide work.
     ``scoring`` turns the router's logits into scores, float32:
     ``softmax`` over all experts, or ``sigmoid`` of each (DeepSeek-V3's
     family: the chosen scores are then normalised to sum 1 over the k
@@ -637,6 +641,194 @@ def _rows_of_choices_bwd(k, res, g):
 _rows_of_choices.defvjp(_rows_of_choices_fwd, _rows_of_choices_bwd)
 
 
+# -- the sorted rows in chunks: row-wide work over the live ones only ---------
+#
+# A shard that holds ``count`` of ``num_experts`` experts sees about
+# ``count / num_experts`` of its ``T·k`` sorted rows live (routed to a held
+# expert); the rest sort last and feed nothing. The rows are cut into
+# ``n_chunks`` equal chunks of a static size and every row-wide stage runs
+# chunk by chunk over the chunks that hold a live row: chunk 0 straight,
+# the others in a loop whose trip count the device reads from ``n_live``.
+# No shape depends on the routing and no row is dropped: an uneven router
+# pays for one chunk more. Between the stages the rows travel as a pair
+# ``(chunk 0, the other chunks)``: chunk 0 goes from one stage's kernels to
+# the next's as it is; the rest is written only in a step that needs it.
+
+_ROW_TILE = 16  # rows of a bf16 tile: every chunk starts on a tile's edge
+
+# The rules below sit in a ``jax.jit(inline=True)``: a model's expert layers
+# call them with the same shapes, in the forward, the recomputed forward
+# and the backward of each of the four traces ``fit`` makes of a step, so
+# all but the first inline a cached jaxpr instead of tracing the loops and
+# the ``jax.vjp``s inside them again (untraced-once, a four-layer step
+# traced a second longer, and ``setup_s`` rose by 6 s: PR 35). Inlined, the
+# equations stay directly under the caller's stage scope.
+_traced_once = functools.partial(jax.jit, inline=True)
+
+
+def row_chunks(rows: int, count: int, num_experts: int) -> tuple[int, int]:
+    """``(chunk_rows, n_chunks)`` for ``rows`` sorted (token, choice) rows
+    on a shard that holds ``count`` of ``num_experts`` experts: chunks of
+    about twice the expected live rows, ``2 · rows · count / num_experts``,
+    that divide ``rows`` into whole row tiles. One chunk — the layer
+    without any control flow — where half the experts or more are held."""
+    for n in range(max(1, num_experts // (2 * count)), 0, -1):
+        if rows % (n * _ROW_TILE) == 0:
+            return rows // n, n
+    return rows, 1
+
+
+def _live_chunks(n_live, chunk_rows: int):
+    """How many chunks run: those whose first row is live, and chunk 0."""
+    return jnp.maximum(1, (n_live + chunk_rows - 1) // chunk_rows)
+
+
+def _split(x, chunk_rows: int):
+    """Sorted rows as the ``(chunk 0, the other chunks)`` pair."""
+    return x[:chunk_rows], x[chunk_rows:]
+
+
+def _live_mask(c, chunk_rows: int, n_live):
+    return c * chunk_rows + jnp.arange(chunk_rows, dtype=jnp.int32) < n_live
+
+
+def _over_live_chunks(fn, n_live, *chunked, first=None):
+    """``fn(c, *chunk) -> (row results, sums)`` over the chunks that hold
+    a live row. ``chunked`` are ``(chunk 0, the other chunks)`` pairs; the
+    row results come back as such pairs (nought in the chunks that did not
+    run), the sums added up over the chunks that ran. ``first``: chunk 0's
+    results, where the caller has them already."""
+    heads, rests = zip(*chunked)
+    chunk_rows = heads[0].shape[0]
+    rows, sums = fn(0, *heads) if first is None else first
+    rest_rows = tuple(
+        jnp.zeros((rests[0].shape[0],) + r.shape[1:], r.dtype) for r in rows
+    )
+
+    def body(c, carry):
+        rest_rows, sums = carry
+        at = (c - 1) * chunk_rows
+        chunk, more = fn(c, *(
+            jax.lax.dynamic_slice_in_dim(r, at, chunk_rows) for r in rests
+        ))
+        rest_rows = tuple(
+            jax.lax.dynamic_update_slice_in_dim(buf, r, at, 0)
+            for buf, r in zip(rest_rows, chunk)
+        )
+        return rest_rows, tuple(s + m for s, m in zip(sums, more))
+
+    rest_rows, sums = jax.lax.fori_loop(
+        1, _live_chunks(n_live, chunk_rows), body, (rest_rows, sums)
+    )
+    return tuple(zip(rows, rest_rows)), sums
+
+
+@functools.partial(_traced_once, static_argnames="k")
+def _sum_live_rows(x, inverse, n_live, k: int, w=None):
+    """``Σ_c w[t·k + c] · x[inverse[t·k + c]]`` for every token ``t``,
+    float32 ``[T, d]``, a row at or past ``n_live`` counting nought and
+    never read. ``x`` is a ``(chunk 0, the other chunks)`` pair: ONE
+    ``T·k``-row gather from chunk 0, and one more for every further chunk
+    that holds a live row."""
+    head, rest = x
+    chunk_rows = head.shape[0]
+    # choice-major, ``[k, T]``: a token's sum then runs over the LEADING
+    # axis of the gathered ``[k, T, d]`` — no relayout of a ``T·k``-row
+    # buffer whose ``k`` is no multiple of the 8-row tile
+    by_choice = lambda v: v.reshape(-1, k).T
+
+    def part(c, rows):
+        at = inverse - c * chunk_rows
+        at = jnp.where((at >= 0) & (at < chunk_rows) & (inverse < n_live),
+                       at, chunk_rows)
+        got = jnp.take(rows, by_choice(at), axis=0, mode="fill",
+                       fill_value=0)
+        if w is not None:
+            got = got * by_choice(w)[..., None]
+        return jnp.sum(got, axis=0, dtype=jnp.float32)
+
+    return jax.lax.fori_loop(
+        1, _live_chunks(n_live, chunk_rows),
+        lambda c, total: total + part(c, jax.lax.dynamic_slice_in_dim(
+            rest, (c - 1) * chunk_rows, chunk_rows)),
+        part(0, head),
+    )
+
+
+@functools.partial(_traced_once, static_argnames=("k", "chunk_rows"))
+def _gather_live_rows(x, order, n_live, k: int, chunk_rows: int):
+    def gather(c, at):
+        rows = jnp.take(x, at // k, axis=0)
+        live = _live_mask(c, chunk_rows, n_live)[:, None]
+        return (jnp.where(live, rows, 0),), ()
+
+    (xs,), _ = _over_live_chunks(gather, n_live, _split(order, chunk_rows))
+    return xs
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _live_rows_of_choices(x, order, inverse, n_live, k, chunk_rows):
+    """:func:`_rows_of_choices` over the live chunks: ``x[order // k]`` for
+    the rows before ``n_live``, nought past them, as a ``(chunk 0, the
+    other chunks)`` pair. Its backward is :func:`_sum_live_rows` of the
+    rows' gradients: the un-sort and a token's sum, the live rows only."""
+    return _gather_live_rows(x, order, n_live, k, chunk_rows)
+
+
+def _live_rows_of_choices_fwd(x, order, inverse, n_live, k, chunk_rows):
+    xs = _gather_live_rows(x, order, n_live, k, chunk_rows)
+    return xs, (inverse, n_live)
+
+
+def _live_rows_of_choices_bwd(k, chunk_rows, res, g):
+    inverse, n_live = res
+    return (_sum_live_rows(g, inverse, n_live, k).astype(g[0].dtype),
+            None, None, None)
+
+
+_live_rows_of_choices.defvjp(_live_rows_of_choices_fwd,
+                             _live_rows_of_choices_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _mix_live_rows(out, w, order, inverse, n_live, k):
+    """The combine over the live rows: ``y[t] = Σ_c w[t·k + c] ·
+    out[inverse[t·k + c]]`` (:func:`_sum_live_rows`; ``out`` a ``(chunk 0,
+    the other chunks)`` pair, ``w`` nought where a choice is not held).
+    Its backward runs in SORTED order over the live chunks — the rows'
+    gradient ``w[order[j]] · dy[order[j] // k]`` and the gates'
+    ``<out[j], dy[order[j] // k]>`` — and un-sorts the gates' scalars
+    only."""
+    return _sum_live_rows(out, inverse, n_live, k, w).astype(w.dtype)
+
+
+def _mix_live_rows_fwd(out, w, order, inverse, n_live, k):
+    return (_mix_live_rows(out, w, order, inverse, n_live, k),
+            (out, w, order, inverse, n_live))
+
+
+@functools.partial(_traced_once, static_argnames="k")
+def _mix_live_rows_bwd(k, res, dy):
+    out, w, order, inverse, n_live = res
+    chunk_rows = out[0].shape[0]
+
+    def grads(c, out_c, at):
+        _, vjp = jax.vjp(lambda o, g: o * g[:, None], out_c, jnp.take(w, at))
+        d_out, d_w = vjp(jnp.take(dy, at // k, axis=0))
+        live = _live_mask(c, chunk_rows, n_live)
+        return (jnp.where(live[:, None], d_out, 0),
+                jnp.where(live, d_w, 0)), ()
+
+    (d_out, d_w), _ = _over_live_chunks(
+        grads, n_live, out, _split(order, chunk_rows)
+    )
+    return (d_out, jnp.take(jnp.concatenate(d_w), inverse),
+            None, None, None)
+
+
+_mix_live_rows.defvjp(_mix_live_rows_fwd, _mix_live_rows_bwd)
+
+
 def select_experts(logits, routing: Routing):
     """Router logits ``[B, S, E]`` (float32) → ``(idx [B, S, k] int32,
     gates [B, S, k] float32)``. The gates are the scores of the chosen
@@ -697,11 +889,23 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     stably sorted by local expert id, rows whose expert is not held sort
     past the last group, and one grouped product (``jax.lax.ragged_dot``)
     a weight runs over the held groups — those rows are neither computed
-    nor stood in for. All shapes are static. The backward is the same
-    grouped product transposed; the un-sort is a gather both ways.
+    nor stood in for. All shapes are static, and still only the live rows
+    are worked on: the sorted rows are cut into :func:`row_chunks`'
+    ``n_chunks`` equal chunks, the gathers, products, masks and the
+    backward of each stage run chunk by chunk, chunk 0 always and a
+    further chunk only while its first row is before ``n_live =
+    sum(sizes)`` (a loop INSIDE each stage's scope whose trip count the
+    device reads; nothing is dropped when the router is uneven, the step
+    pays for one chunk more). What stays ``T·k`` rows wide is one gather
+    in the combine and its mirror in the dispatch's backward, from the
+    live rows. With one chunk (half the experts or more held) the layer is
+    the plain one: no loop, no branch. The backward is the same grouped
+    product transposed; the un-sort is a gather both ways.
     Counters (``moe_stats``, sown on ``owner``): ``tokens`` (rows routed
     to each held expert), ``held_share`` (share of rows whose expert is
-    held), ``load_max_over_mean`` (over the held experts).
+    held), ``load_max_over_mean`` (over the held experts),
+    ``row_share_computed`` (rows of the chunks that ran ÷ ``T·k``:
+    ``1 / n_chunks`` on an even step).
     """
     if mesh is not None and int(dict(mesh.shape).get(EXPERT_AXIS, 1)) > 1:
         raise NotImplementedError(
@@ -713,6 +917,7 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     T = b * s
     k = routing.top_k
     first, count = routing.held_range
+    chunk_rows, n_chunks = row_chunks(T * k, count, routing.num_experts)
     tokens = u.reshape(T, d)
     if routing.router == "mlp":
         if r_prev is None:
@@ -747,26 +952,34 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
             key[:, None] == jnp.arange(count, dtype=key.dtype)[None, :],
             axis=0, dtype=jnp.int32,
         )
-        live = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+        n_live = jnp.sum(sizes)
         tokens = tokens.astype(dtype)
-        # top-1 sorts the tokens themselves (a gather both ways); with k
-        # choices a token has k rows and its gradient is their sum
-        xs = (_permute_rows(tokens, order, inverse) if k == 1
-              else _rows_of_choices(tokens, order, inverse, k))
-        # the rows past the last group feed nothing: nought in, and (below)
-        # nought out, whatever the grouped product leaves there
-        xs = jnp.where(live, xs, 0)
+        if n_chunks == 1:
+            live = (jnp.arange(T * k) < n_live)[:, None]
+            # top-1 sorts the tokens themselves (a gather both ways); with
+            # k choices a token has k rows and its gradient is their sum
+            xs = (_permute_rows(tokens, order, inverse) if k == 1
+                  else _rows_of_choices(tokens, order, inverse, k))
+            # the rows past the last group feed nothing: nought in, and
+            # (below) nought out, whatever the grouped product leaves there
+            xs = jnp.where(live, xs, 0)
+        else:
+            xs = _live_rows_of_choices(tokens, order, inverse, n_live, k,
+                                       chunk_rows)
 
     out = GroupedExperts(count, ffn_dim, dtype, name="moe_experts")(
         xs, sizes
     )
 
     with jax.named_scope("moe_combine"):
-        out = jnp.where(live, out, 0)
         w = (gates.reshape(T * k) * held).astype(dtype)
-        y = _permute_rows(out, inverse, order) * w[:, None]
-        if k > 1:
-            y = jnp.sum(y.reshape(T, k, d), axis=1)
+        if n_chunks == 1:
+            out = jnp.where(live, out, 0)
+            y = _permute_rows(out, inverse, order) * w[:, None]
+            if k > 1:
+                y = jnp.sum(y.reshape(T, k, d), axis=1)
+        else:
+            y = _mix_live_rows(out, w, order, inverse, n_live, k)
     if shared_dim:
         y = y + SharedExpert(shared_dim, dtype, name="moe_shared")(tokens)
 
@@ -776,6 +989,13 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     owner.sow(
         "moe_stats", "load_max_over_mean",
         jnp.max(load) / jnp.maximum(jnp.mean(load), 1.0),
+    )
+    # one chunk always runs whole: a constant, so that the plain layer's
+    # step gains no operation for it
+    owner.sow(
+        "moe_stats", "row_share_computed",
+        _live_chunks(n_live, chunk_rows).astype(jnp.float32) / n_chunks
+        if n_chunks > 1 else jnp.float32(1.0),
     )
     return y.reshape(b, s, d), r
 
@@ -803,7 +1023,10 @@ class GroupedExperts(nn.Module):
     ``(silu(x·w_gate[e]) ⊙ x·w_up[e])·w_down[e]`` for the rows of group
     ``e``, as three grouped products (``jax.lax.ragged_dot``). Rows past
     ``sum(sizes)`` belong to no group; what the product leaves there is
-    not defined (the TPU lowering leaves values) and the caller masks it."""
+    not defined (the TPU lowering leaves values) and the caller masks it.
+    ``xs`` is the sorted rows, or the ``(chunk 0, the other chunks)`` pair
+    :func:`dropless_moe` cuts them into: then the products run over the
+    chunks that hold a live row and the result is such a pair too."""
 
     count: int
     ffn_dim: int
@@ -811,7 +1034,8 @@ class GroupedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, xs, sizes):
-        d = xs.shape[-1]
+        chunked = isinstance(xs, tuple)
+        d = (xs[0] if chunked else xs).shape[-1]
         w = lambda name, shape: self.param(
             name, nn.initializers.lecun_normal(batch_axis=(0,)), shape,
             jnp.float32,
@@ -819,6 +1043,73 @@ class GroupedExperts(nn.Module):
         wg = w("w_gate", (self.count, d, self.ffn_dim))
         wu = w("w_up", (self.count, d, self.ffn_dim))
         wd = w("w_down", (self.count, self.ffn_dim, d))
+        if chunked:
+            return _gated_ffn_live(xs, wg, wu, wd, sizes)
         h = nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) \
             * jax.lax.ragged_dot(xs, wu, sizes)
         return jax.lax.ragged_dot(h, wd, sizes)
+
+
+def _chunk_sizes(sizes, c, chunk_rows: int):
+    """The rows of each group that lie in chunk ``c`` of the sorted rows."""
+    ends = jnp.cumsum(sizes)
+    lo = c * chunk_rows
+    return jnp.clip(ends, lo, lo + chunk_rows) \
+        - jnp.clip(ends - sizes, lo, lo + chunk_rows)
+
+
+@jax.custom_vjp
+def _gated_ffn_live(xs, wg, wu, wd, sizes):
+    """:class:`GroupedExperts`' three grouped products over the chunks that
+    hold a live row, each chunk with its own part of the group sizes;
+    ``xs`` and the result are ``(chunk 0, the other chunks)`` pairs. The
+    backward is chunked alike, from chunk 0's kept ``x·w_gate`` and
+    ``x·w_up`` (a further chunk computes its two again); a chunk's rows
+    past the last group get no gradient."""
+    return _gated_ffn_live_fwd(xs, wg, wu, wd, sizes)[0]
+
+
+@_traced_once
+def _gated_ffn_live_fwd(xs, wg, wu, wd, sizes):
+    chunk_rows = xs[0].shape[0]
+
+    def ffn(c, x):
+        sz = _chunk_sizes(sizes, c, chunk_rows)
+        a = jax.lax.ragged_dot(x, wg, sz)
+        b = jax.lax.ragged_dot(x, wu, sz)
+        return jax.lax.ragged_dot(nn.silu(a) * b, wd, sz), a, b
+
+    out, a, b = ffn(0, xs[0])
+    (out,), _ = _over_live_chunks(
+        lambda c, x: ((ffn(c, x)[0],), ()), jnp.sum(sizes), xs,
+        first=((out,), ()),
+    )
+    return out, (xs, a, b, wg, wu, wd, sizes)
+
+
+@_traced_once
+def _gated_ffn_live_bwd(res, d_out):
+    xs, a0, b0, wg, wu, wd, sizes = res
+    chunk_rows = xs[0].shape[0]
+    n_live = jnp.sum(sizes)
+
+    def grads(c, x, d_out, a=None, b=None):
+        sz = _chunk_sizes(sizes, c, chunk_rows)
+        product = lambda rows, w: jax.lax.ragged_dot(rows, w, sz)
+        if a is None:
+            a, b = product(x, wg), product(x, wu)
+        h, gate_vjp = jax.vjp(lambda a, b: nn.silu(a) * b, a, b)
+        d_h, d_wd = jax.vjp(product, h, wd)[1](d_out)
+        d_a, d_b = gate_vjp(d_h)
+        d_xa, d_wg = jax.vjp(product, x, wg)[1](d_a)
+        d_xb, d_wu = jax.vjp(product, x, wu)[1](d_b)
+        live = _live_mask(c, chunk_rows, n_live)[:, None]
+        return (jnp.where(live, d_xa + d_xb, 0),), (d_wg, d_wu, d_wd)
+
+    (d_xs,), d_ws = _over_live_chunks(
+        grads, n_live, xs, d_out, first=grads(0, xs[0], d_out[0], a0, b0)
+    )
+    return (d_xs, *d_ws, None)
+
+
+_gated_ffn_live.defvjp(_gated_ffn_live_fwd, _gated_ffn_live_bwd)

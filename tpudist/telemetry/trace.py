@@ -127,6 +127,9 @@ MOE_COUNTERS = {
     "tokens": "expert_gemm_roofline",  # rows routed to each held expert
     "held_share": "expert_gemm_roofline",  # share of rows whose expert is held (printed beside the expected)
     "load_max_over_mean": "expert_load_max_over_mean",  # over the held experts
+    # rows of the chunks that ran / T·k (``ep.row_chunks``): 1 / n_chunks on
+    # an even step, more where the router paid for a further chunk
+    "row_share_computed": "moe_ms",
 }
 
 
