@@ -1,7 +1,7 @@
 """The program and the benchmark that judges it agree.
 
 ``benchmarks/`` copies facts from the program — operation counts, the
-chip's peaks, ``fit``'s parameters, span and scope names — and tier-1 runs
+chip's peaks, ``fit``'s parameters, span, phase and scope names — and tier-1 runs
 none of ``benchmarks/tests``. Each case here holds one copy to its source,
 and names what broke: a scope renamed in the program would otherwise show
 as a ``null`` per-layer metric on the driver's machine, and a ``fit``
@@ -9,7 +9,7 @@ parameter renamed as a cell that cannot run. Reads ``benchmarks/``, edits
 nothing there; CPU only.
 
 The names themselves are declared in ``tpudist/telemetry/trace.py``
-(``FIT_SPANS``, ``STEP_SCOPES``); the modules that emit them spell them as
+(``FIT_SPANS``, ``BRINGUP_SPANS``, ``STEP_SCOPES``); the modules that emit them spell them as
 literals (most sit below ``telemetry``), so (d) and (f) look for the
 literal where it is emitted.
 """
@@ -37,7 +37,7 @@ if str(REPO) not in sys.path:
 from benchmarks import spans  # noqa: E402
 from tpudist.telemetry import flops  # noqa: E402
 from tpudist.telemetry.trace import (  # noqa: E402
-    BLOCK_SCOPES, FIT_SPANS, STEP_SCOPES, TRAIN_STEP,
+    BLOCK_SCOPES, BRINGUP_SPANS, FIT_SPANS, STEP_SCOPES, TRAIN_STEP,
 )
 
 BENCH = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -234,12 +234,15 @@ def test_attention_kernel_patterns_follow_the_declared_scope(family, scope):
 
 @functools.cache
 def _span_literals():
-    """First arguments of the program's ``span(...)`` calls, by file."""
+    """First arguments of the program's ``span(...)`` calls and of its
+    bring-up's ``bringup.enter(...)`` calls, by file."""
     found = {}
     for path in sorted((REPO / "tpudist").rglob("*.py")):
         for node in ast.walk(ast.parse(path.read_text())):
-            if not (isinstance(node, ast.Call) and node.args
-                    and getattr(node.func, "id", None) == "span"):
+            if not (isinstance(node, ast.Call) and node.args and (
+                    getattr(node.func, "id", None) == "span"
+                    or (getattr(node.func, "attr", None) == "enter" and
+                        getattr(node.func.value, "id", None) == "bringup"))):
                 continue
             first = node.args[0]
             if isinstance(first, ast.Constant) and isinstance(first.value, str):
@@ -249,17 +252,48 @@ def _span_literals():
     return found
 
 
-@pytest.mark.parametrize("name", sorted(FIT_SPANS))
+@pytest.mark.parametrize("name", sorted(FIT_SPANS) + sorted(BRINGUP_SPANS))
 def test_every_declared_span_is_emitted_under_its_name(name):
     """The call sites keep their literals: each declared span is the first
-    argument of a ``span(...)`` call somewhere under ``tpudist/`` (that it
-    shows once a step on the profiler's timeline is
-    tests/test_telemetry_fit.py's)."""
+    argument of a ``span(...)`` call (a bring-up phase: of a
+    ``bringup.enter(...)`` call) somewhere under ``tpudist/`` (that it
+    shows once a step on the profiler's timeline, or once a run in the
+    ``bringup`` row, is tests/test_telemetry_fit.py's)."""
     assert name in _span_literals()
 
 
 def test_no_span_is_emitted_undeclared():
-    assert not set(_span_literals()) - set(FIT_SPANS)
+    assert not set(_span_literals()) - set(FIT_SPANS) - set(BRINGUP_SPANS)
+
+
+@pytest.mark.parametrize("metric", ["fit_bringup_s", "init_state_s",
+                                    "first_step_s"])
+def test_bringup_readers_sum_declared_phases(metric):
+    """Each reader of the ``bringup`` row's phases quotes the phases the
+    program declares for its metric: none undeclared, none left out."""
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
+    assert sorted(reader.SPANS) == sorted(
+        n for n, metrics in BRINGUP_SPANS.items() if metric in metrics)
+
+
+def test_the_two_sums_of_phases_cover_the_bringup_once():
+    """``fit_bringup_s`` and ``first_step_s`` split the declared phases
+    between them, so that with ``pre_fit_s`` and the printed remainder
+    they add up to ``setup_s``."""
+    for name, metrics in BRINGUP_SPANS.items():
+        assert len({"fit_bringup_s", "first_step_s"} & set(metrics)) == 1, name
+    declared = {m["name"] for m in BENCH["per_layer"]}
+    assert {m for metrics in BRINGUP_SPANS.values() for m in metrics} \
+        <= declared
+
+
+@pytest.mark.parametrize("name", sorted(BRINGUP_SPANS))
+def test_no_step_reader_picks_up_a_bringup_phase(name):
+    """``spans.load`` keeps a host event by ``PROGRAM_SPANS``' prefixes
+    and counts what it keeps once a step: a phase of the bring-up, should
+    a profiler session ever cover it, is none of them."""
+    assert name.startswith("bringup/")
+    assert not name.startswith(spans.PROGRAM_SPANS)
 
 
 # -- (f) the declared device scopes are in a lowered step's name stacks -------

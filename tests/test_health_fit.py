@@ -187,7 +187,8 @@ def test_fit_crash_path_writes_report(tmp_path):
 
 def test_fit_health_off_keeps_stream_kinds_and_extends_heartbeat(tmp_path):
     """Default TelemetryConfig (health detectors off): no fleet /
-    straggler / divergence / watchdog rows — the pre-PR kind set exactly —
+    straggler / divergence / watchdog rows — the pre-PR kind set (and the one
+    ``bringup`` row every telemetry run writes) —
     while heartbeat rows carry the new identity fields APPENDED after the
     byte-identical existing ones, and the run report exists as a separate
     file (never a stream row)."""
@@ -197,7 +198,8 @@ def test_fit_health_off_keeps_stream_kinds_and_extends_heartbeat(tmp_path):
     kinds = {r["kind"] for r in rows}
     assert kinds <= {"run_meta", "health", "mfu", "step_breakdown",
                      "throughput", "memory", "anomaly", "heartbeat",
-                     "train_time", "run_summary", "comm", "warning"}
+                     "train_time", "run_summary", "comm", "warning",
+                     "bringup"}
     beats = [r for r in rows if r["kind"] == "heartbeat"]
     assert [r["step"] for r in beats] == [4, 8, 12]
     for r in beats:

@@ -11,11 +11,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from tpudist.data.loader import DataLoader
 from tpudist.models.gpt2 import GPT2
 from tpudist.telemetry import TelemetryConfig
-from tpudist.telemetry.trace import FIT_SPANS
+from tpudist.telemetry.trace import BRINGUP_SPANS, FIT_SPANS
 from tpudist.train import fit, lm_loss
 
 VOCAB = 256
@@ -283,7 +284,8 @@ def test_fit_moe_rows_and_real_moe_mfu(tmp_path):
 MAIN_SPANS = tuple(n for n in FIT_SPANS if not n.startswith("input/"))
 
 
-def _traced_fit(tmp_path, **kw):
+def _traced_fit(tmp_path, prefixes=("fit/", "input/", "tpudist_train"),
+                **kw):
     """A 4-step ``fit(profile=False)`` under a profiler session the TEST
     started — any session will do, as the benchmark's does."""
     import glob
@@ -310,7 +312,7 @@ def _traced_fit(tmp_path, **kw):
             continue
         for i, line in enumerate(plane.lines):
             for e in line.events:
-                if e.name.startswith(("fit/", "input/", "tpudist_train")):
+                if e.name.startswith(prefixes):
                     by_name.setdefault(e.name, []).append(
                         (i, e.start_ns, e.start_ns + e.duration_ns,
                          dict(e.stats)))
@@ -381,10 +383,10 @@ def test_fit_stream_without_trace_has_no_span_rows_and_no_device_s(tmp_path):
     )
     rows = _rows(tmp_path / "NS_telemetry_0.jsonl")
     assert [r["kind"] for r in rows] == [
-        "throughput", "health", "step_breakdown", "run_summary",
+        "bringup", "throughput", "health", "step_breakdown", "run_summary",
         "train_time",
     ]
-    assert [k for k in rows[2] if k != "run_id"] == [
+    assert [k for k in rows[3] if k != "run_id"] == [
         "v", "t", "kind", "rank", "step", "interval_s", "data_wait_s",
         "dispatch_s"]
 
@@ -493,3 +495,257 @@ def test_lowered_explicit_reducer_and_policy_casts_are_named():
     cast = jax.jit(amp.BF16_COMPUTE.cast_to_compute).lower(
         {"w": jnp.ones((2, 2))}).as_text(debug_info=True)
     assert "/cast/" in cast
+
+
+# -- the bring-up account: contiguous phases, a compile table, one row ------
+
+
+def _listeners():
+    """JAX's registered monitoring listeners: (durations, events)."""
+    from jax._src import monitoring
+
+    return (len(monitoring.get_event_duration_listeners()),
+            len(monitoring.get_event_listeners()))
+
+
+def _fit(tmp_path, job, loader=None, **kw):
+    return fit(
+        _tiny_lm(), kw.pop("tx", optax.adam(1e-3)), loader or _loader(),
+        epochs=kw.pop("epochs", 1), job_id=job, batch_size=16,
+        loss_fn=kw.pop("loss_fn", lm_loss), input_key="tokens",
+        label_key="tokens", log_dir=str(tmp_path), profile=False, **kw)
+
+
+def test_fit_writes_one_bringup_row_after_step_one(tmp_path):
+    """Telemetry on, trace off: exactly one ``bringup`` row, written when
+    the first dispatch has returned — before any row of step 1 — whose
+    phases are the declared ones in the declared order, each starting
+    where the one before ended, from ``fit``'s entry, summing to
+    ``total_s``; the compile table names the step function; and the
+    stream holds no ``span`` row and, the run being steady, no
+    ``recompile`` warning."""
+    import time
+
+    before = time.monotonic(), time.perf_counter()
+    _fit(tmp_path, "BU", epochs=2, telemetry=TelemetryConfig(mfu=False))
+    rows = _rows(tmp_path / "BU_telemetry_0.jsonl")
+    assert rows[0]["kind"] == "bringup" and rows[0]["step"] == 1
+    (row,) = [r for r in rows if r["kind"] == "bringup"]
+    assert not [r for r in rows if r["kind"] in ("span", "warning")]
+
+    assert [name for name, _, _ in row["phases"]] == list(BRINGUP_SPANS)
+    assert row["phases"][0][1] == 0.0
+    for (_, t0, dur_s), (name, t1, _) in zip(row["phases"], row["phases"][1:]):
+        assert t1 == pytest.approx(t0 + dur_s, abs=1e-9), name
+        assert dur_s >= 0.0
+    assert sum(d for _, _, d in row["phases"]) == pytest.approx(
+        row["total_s"], abs=1e-9)
+    # the entry on both clocks, read inside this call of fit
+    assert before[0] <= row["t_entry"] <= time.monotonic() - row["total_s"]
+    assert before[1] <= row["t_entry_perf"] \
+        <= time.perf_counter() - row["total_s"]
+
+    table = {entry["fun"]: entry for entry in row["compile"]}
+    assert len(row["compile"]) <= 13 and "other" in table
+    for fun in ("step_fn", "_init"):  # the step and the state's init
+        assert table[fun]["n"] >= 1
+        assert table[fun]["trace_s"] > 0 and table[fun]["lower_s"] > 0
+        assert table[fun]["backend_s"] > 0
+    assert 0 < row["trace_lower_s"] <= sum(
+        entry["trace_s"] + entry["lower_s"] for entry in row["compile"])
+    assert row["backend_s"] > 0
+    # the union of all three lies inside fit's bring-up
+    assert row["trace_lower_s"] + row["backend_s"] <= row["total_s"]
+    assert row["cache_hits"] >= 0 and row["cache_misses"] >= 0
+    # the first dispatch compiles the step: most of its phase, and more
+    # than every phase before the loop but the state's init
+    phase = {name: dur_s for name, _, dur_s in row["phases"]}
+    assert phase["bringup/first_dispatch"] >= table["step_fn"]["backend_s"]
+    assert phase["bringup/init_state"] >= table["_init"]["backend_s"]
+
+
+def test_fit_bringup_phases_on_the_profilers_timeline_without_telemetry(
+        tmp_path):
+    """Telemetry off: the phases are profiler annotations all the same —
+    a session around ``fit`` shows each once, in the declared order, on
+    the main thread's line, none overlapping the next, step 1's dispatch
+    inside the last."""
+    events = _traced_fit(tmp_path, prefixes=("bringup/", "tpudist_train"))
+    phases = sorted((a, b, name, line) for name, found in events.items()
+                    if name.startswith("bringup/")
+                    for line, a, b, _ in found)
+    assert [name for _, _, name, _ in phases] == list(BRINGUP_SPANS)
+    for (_, end, first, _), (start, _, second, _) in zip(phases, phases[1:]):
+        assert end <= start, (first, second)
+    (line,) = {line for *_, line in phases}
+    (first_step,) = [(a, b) for at, a, b, stats in events["tpudist_train"]
+                     if stats["step_num"] == 1 and at == line]
+    start, end, name, _ = phases[-1]
+    assert name == "bringup/first_dispatch"
+    assert start <= first_step[0] and first_step[1] <= end
+    assert not list(tmp_path.glob("*telemetry*"))
+
+
+def test_fit_bringup_phases_replay_as_span_rows_with_trace_on(tmp_path):
+    """trace=True: the phases are ``span`` rows too, on the tracer's clock
+    (``t_entry`` + the row's offsets), the ones closed before the sink was
+    up replayed in order; the loop's spans of step 1 nest in the last two."""
+    _fit(tmp_path, "BT", telemetry=TelemetryConfig(trace=True))
+    rows = _rows(tmp_path / "BT_telemetry_0.jsonl")
+    (row,) = [r for r in rows if r["kind"] == "bringup"]
+    spans = [r for r in rows if r["kind"] == "span"]
+    phases = [r for r in spans if r["name"].startswith("bringup/")]
+    assert [r["name"] for r in phases] == list(BRINGUP_SPANS)
+    for r, (name, offset, dur_s) in zip(phases, row["phases"]):
+        assert r["ph"] == "X" and r["cat"] == "train"
+        assert r["t0"] == pytest.approx(row["t_entry"] + offset, abs=1e-9)
+        assert r["dur_s"] == dur_s
+    by_name = {r["name"]: r for r in phases}
+
+    def inside(child, parent):
+        return (parent["t0"] <= child["t0"] and child["t0"] + child["dur_s"]
+                <= parent["t0"] + parent["dur_s"])
+
+    (first_next,) = [r for r in spans if r["name"] == "fit/next_batch"
+                     and r["step"] == 1]
+    (first_step,) = [r for r in spans if r["name"] == "tpudist_train"
+                     and r["step"] == 1]
+    assert inside(first_next, by_name["bringup/first_batch"])
+    assert inside(first_step, by_name["bringup/first_dispatch"])
+
+
+def _raising_init(params):
+    raise RuntimeError("no optimizer state today")
+
+
+def _raising_loss(logits, tokens):
+    raise RuntimeError("no loss today")
+
+
+@pytest.mark.parametrize("telemetry", [False, True])
+@pytest.mark.parametrize("raises", [None, "in_init_state", "in_first_dispatch"])
+def test_fit_leaves_no_listener_behind(tmp_path, monkeypatch, telemetry,
+                                       raises):
+    """With telemetry off ``fit`` registers no ``jax.monitoring`` listener,
+    keeps no buffer and writes no row; with it on, its listeners are there
+    while it runs and gone when it returns or raises, wherever."""
+    from tpudist.telemetry import trace
+
+    made, during = [], []
+
+    class Watched(trace.Bringup):
+        def __init__(self, **kw):
+            super().__init__(**kw)
+            made.append(self)
+            during.append(_listeners())
+
+    monkeypatch.setattr(trace, "Bringup", Watched)
+    kw = {"in_init_state": {"tx": optax.GradientTransformation(
+              _raising_init, None)},
+          "in_first_dispatch": {"loss_fn": _raising_loss}}.get(raises, {})
+    before = _listeners()
+    if raises:
+        with pytest.raises(RuntimeError, match="today"):
+            _fit(tmp_path, "LS", telemetry=telemetry, **kw)
+    else:
+        _fit(tmp_path, "LS", telemetry=telemetry)
+    assert _listeners() == before
+    (bringup,) = made
+    own = 1 if telemetry else 0
+    assert during == [(before[0] + own, before[1] + own)]
+    if not telemetry:
+        assert bringup.phases is None
+        assert not list(tmp_path.glob("*telemetry*"))
+    else:
+        rows = _rows(tmp_path / "LS_telemetry_0.jsonl") if (
+            tmp_path / "LS_telemetry_0.jsonl").exists() else []
+        assert len([r for r in rows if r["kind"] == "bringup"]) == (
+            0 if raises else 1)
+        # what it kept is whole: every phase entered was closed
+        names = [name for name, _, _ in bringup.phases]
+        assert names == list(BRINGUP_SPANS)[:len(names)]
+        assert len(names) == {None: 9, "in_init_state": 2,
+                              "in_first_dispatch": 9}[raises]
+
+
+class _Ragged:
+    """A sized loader whose batches have the row counts it is given."""
+
+    batch_size = 16
+
+    def __init__(self, rows):
+        self.rows = rows
+
+    def __len__(self):
+        return len(self.rows)
+
+    def __iter__(self):
+        rng = np.random.Generator(np.random.PCG64(0))
+        for n in self.rows:
+            yield {"tokens": rng.integers(0, 254, (n, 16)).astype(np.int32)}
+
+
+@pytest.mark.parametrize("rows, want", [
+    ([16, 16, 8, 16, 16], [3]),   # the third batch has another shape
+    ([16, 16, 16, 16, 16], []),   # a steady run
+])
+def test_fit_names_the_step_that_recompiled(tmp_path, rows, want):
+    """A compile that ends after the first dispatch has returned is a
+    ``recompile`` warning with the step being dispatched, the function's
+    name and its trace / lower / backend seconds, and with trace=True an
+    instant on the timeline; a steady run has none."""
+    _fit(tmp_path, "RC", _Ragged(rows), telemetry=TelemetryConfig(trace=True))
+    stream = _rows(tmp_path / "RC_telemetry_0.jsonl")
+    warned = [r for r in stream if r["kind"] == "warning"
+              and r["tag"] == "recompile"]
+    assert [r["step"] for r in warned] == want
+    for r in warned:
+        assert r["fun"] == "step_fn"
+        assert r["trace_s"] > 0 and r["lower_s"] > 0 and r["backend_s"] > 0
+    instants = [r for r in stream if r["kind"] == "span"
+                and r["name"] == "recompile"]
+    assert [(r["step"], r["ph"], r["fun"]) for r in instants] == [
+        (s, "i", "step_fn") for s in want]
+    # the bring-up's own compile of the step is in its row, not a warning
+    (row,) = [r for r in stream if r["kind"] == "bringup"]
+    assert "step_fn" in {entry["fun"] for entry in row["compile"]}
+
+
+@pytest.mark.parametrize("case", ["fresh", "resumed", "aot_cache"])
+def test_goodput_bringup_counts_from_fits_entry(tmp_path, case):
+    """The run report's ``goodput.bringup_s`` is what its docstring says:
+    fit entry → first loop iteration. With the restore, the cache load and
+    the AOT path's compile it equals the sum of the seven phases before
+    the loop, and the partition still sums to ``total_s``."""
+    from tpudist.resilience.goodput import COMPONENTS
+
+    kw = {}
+    if case == "resumed":
+        kw = {"checkpoint_dir": str(tmp_path / "ck"), "checkpoint_every": 2}
+        _fit(tmp_path, "G0", telemetry=True, **kw)
+        kw["epochs"] = 2
+    elif case == "aot_cache":
+        kw = {"compile_cache": str(tmp_path / "cc")}
+    _fit(tmp_path, "GP", telemetry=True, **kw)
+    goodput = json.loads((tmp_path / "GP_report.json").read_text())["goodput"]
+    (row,) = [r for r in _rows(tmp_path / "GP_telemetry_0.jsonl")
+              if r["kind"] == "bringup"]
+    before_loop = sum(dur_s for name, _, dur_s in row["phases"]
+                      if "fit_bringup_s" in BRINGUP_SPANS[name])
+    booked = goodput["bringup_s"] + goodput["restore_s"] \
+        + goodput["cache_load_s"]
+    if case == "aot_cache":
+        # compiled at bring-up (a miss): booked there, and iteration 1 is
+        # an ordinary step
+        assert goodput["compile_s"] > 0
+        booked += goodput["compile_s"]
+    else:
+        # iteration 1 whole: the two phases of the first step and its rest
+        assert goodput["compile_s"] >= row["total_s"] - before_loop - 5e-3
+    if case == "resumed":
+        assert goodput["restore_s"] > 0 and row["step"] == 5
+    assert booked == pytest.approx(before_loop, abs=5e-3)
+    assert goodput["total_s"] >= row["total_s"]
+    assert goodput["productive_step_s"] + sum(
+        goodput[c] for c in COMPONENTS) == pytest.approx(
+            goodput["total_s"], abs=1e-5)

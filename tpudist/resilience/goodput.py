@@ -10,7 +10,11 @@ into disjoint components and aggregates them ACROSS restart generations
 through the ``{job}_report.json`` each generation leaves behind:
 
 - ``bringup_s`` — fit entry → first loop iteration (state init, replica
-  verification, telemetry bring-up), minus the restore below;
+  verification, telemetry bring-up), minus the restore below; ``fit``
+  builds the tracker on its first lines, so with ``restore_s``,
+  ``cache_load_s`` and the AOT path's ``compile_s`` this is the sum of
+  the seven phases before the loop in the ``bringup`` telemetry row
+  (``tpudist.telemetry.trace.BRINGUP_SPANS``);
 - ``restore_s`` — checkpoint restore (the resume read);
 - ``compile_s`` — the first loop iteration wall time (jit traces and
   compiles synchronously on first call, so iteration 1 *is* the compile,

@@ -678,6 +678,24 @@ class Telemetry:
         if self.rank == 0:
             self.sink.write("compile_cache", **dict(info))
 
+    def set_bringup(self, step: int, info: Mapping[str, Any]) -> None:
+        """The one ``bringup`` row (rank 0), written when the first
+        dispatch has returned: ``fit``'s entry on both clocks, the
+        contiguous phases from there (``trace.BRINGUP_SPANS``) and the
+        compile table by function name (``trace.Bringup.finish``).
+        Written whenever telemetry is on, whatever ``trace`` says."""
+        if self.rank == 0:
+            self.sink.write("bringup", step, **dict(info))
+
+    def recompiled(self, step: int, fun: str, **seconds: float) -> None:
+        """A compile that ended after bring-up did: a ``recompile``
+        warning naming the function, the step being dispatched and the
+        trace / lower / backend seconds, and with ``trace`` an instant on
+        the timeline."""
+        self.warn("recompile", step, fun=fun, **seconds)
+        if self.tracer is not None:
+            self.tracer.instant("recompile", step=step, fun=fun)
+
     def set_anatomy(self, info: Mapping[str, Any] | None) -> None:
         """One ``anatomy`` row per introspected program (rank 0): XLA's
         own FLOPs/bytes count and static HBM breakdown for a compiled

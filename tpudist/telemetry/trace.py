@@ -38,6 +38,9 @@ stream stays byte-identical (the standing telemetry contract).
 interval of host work: a ``jax.profiler.TraceAnnotation`` always (on the
 profiler's clock, next to the device lines, whenever any profiler session
 records) and a ``span`` row as well when the run has a :class:`Tracer`.
+:class:`Bringup` strings ``fit``'s bring-up into contiguous phases through
+it and, with telemetry, keeps JAX's compile events by function name: the
+one ``bringup`` row (docs/OBSERVABILITY.md §8).
 """
 
 from __future__ import annotations
@@ -48,8 +51,9 @@ from typing import Callable, Mapping
 
 import jax
 
-__all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "TRAIN_STEP",
-           "FIT_SPANS", "STEP_SCOPES", "BLOCK_SCOPES", "MOE_COUNTERS"]
+__all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "Bringup",
+           "TRAIN_STEP", "FIT_SPANS", "BRINGUP_SPANS", "STEP_SCOPES",
+           "BLOCK_SCOPES", "MOE_COUNTERS"]
 
 # the step marker XProf's step-time view groups device work by; the name
 # predates the ``fit/...`` spans and the benchmark's gap labels quote it
@@ -73,6 +77,48 @@ FIT_SPANS = {
     "input/stage": "input_stage_ms",      # sharding one batch and its device_put
     TRAIN_STEP: "loop_host_ms",           # the step's dispatch
 }
+# Phases of ``fit``'s bring-up (:class:`Bringup`), main thread, in order,
+# disjoint and contiguous from ``fit``'s entry to the return of the first
+# dispatch; each with the benchmark metrics that read it off the
+# ``bringup`` row (all of them move ``setup_s``; docs/OBSERVABILITY.md §8):
+BRINGUP_SPANS = {
+    # plan / mesh resolution, the loader's probe(), init_input
+    "bringup/probe": ("fit_bringup_s",),
+    # create_train_state: eval_shape for the shardings, jit(_init) traced,
+    # lowered, compiled or loaded, dispatched
+    "bringup/init_state": ("init_state_s", "fit_bringup_s"),
+    # the init_params device_put tree, refresh_fused_compute
+    "bringup/place_params": ("fit_bringup_s",),
+    # verify_replicas: the first point that blocks on the device
+    "bringup/verify_replicas": ("fit_bringup_s",),
+    # build_step, attach_residual, run_meta
+    "bringup/build_step": ("fit_bringup_s",),
+    # AOT cache load, Checkpointer, restore or reshard, cc.finish
+    "bringup/restore": ("fit_bringup_s",),
+    # logger, profiler, build_telemetry, the H2D and comm probes, anatomy,
+    # the first log_memory: the observer's own cost
+    "bringup/telemetry": ("fit_bringup_s",),
+    # prefetch_to_mesh start-up and the first next(); fit/next_batch nests
+    "bringup/first_batch": ("first_step_s",),
+    # the first step(state, batch): trace + lower + compile or load;
+    # tpudist_train step 1 nests
+    "bringup/first_dispatch": ("first_step_s",),
+}
+# JAX's monitoring events the bring-up's compile table is built from (the
+# ones ``benchmarks/meter.py`` listens to from outside), each with the
+# column it fills:
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "misses",
+}
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_TABLE_ROWS = 12  # functions the row names; the rest is ``other``
+
 # Device scopes of the train step (``jax.named_scope``: metadata only) by
 # which a trace reader splits a step into passes. The modules that enter
 # them (``train.py``, ``optim.py``, ``amp.py``, ``comm.py``,
@@ -231,6 +277,195 @@ class span:
                 step=self.step, **self.tags,
             )
         return False
+
+
+def _union_s(intervals, since: float, until: float) -> float:
+    """Length of the union of ``(start, end)`` intervals clipped to
+    ``[since, until]`` (trace, lowering and compile-or-load nest)."""
+    total, covered_to = 0.0, since
+    for start, end in sorted(intervals):
+        start, end = max(start, covered_to), min(end, until)
+        if end > start:
+            total += end - start
+            covered_to = end
+    return total
+
+
+class Bringup:
+    """``fit``'s bring-up account: contiguous phases and, with telemetry,
+    a compile table (``BRINGUP_SPANS``; docs/OBSERVABILITY.md §8).
+
+    :meth:`enter` closes the open phase and opens the next, each a
+    :class:`span` — a ``jax.profiler.TraceAnnotation`` always. With
+    ``observe`` (the run has telemetry) the spans report to this object as
+    they would to a :class:`Tracer`: it reads ``time.monotonic`` (the
+    tracer's clock) once a boundary, so a phase starts on the reading its
+    predecessor ended on, and the first on the entry reading; it keeps
+    ``(name, t0, dur_s)`` and, from :meth:`attach` on, hands each to the
+    run's tracer as a ``span`` row. With ``observe`` only, listeners on
+    JAX's compile events (``COMPILE_EVENTS``, ``CACHE_EVENTS``: the ones
+    the benchmark's meter trusts, with the ``fun_name`` kept) fill a table
+    by function name. :meth:`finish` — the first dispatch has returned —
+    closes the last phase and returns the ``bringup`` row's fields; a
+    backend compile or cache load that ends later is a recompile and goes
+    to ``on_recompile(fun, trace_s=, lower_s=, backend_s=)``, from the
+    thread that compiled. :meth:`close` (``fit``'s ``finally``)
+    unregisters the listeners. Without ``observe``: no buffer, no
+    listener, no row.
+    """
+
+    def __init__(self, *, observe: bool, on_recompile=None):
+        self.phases: list[tuple[str, float, float]] | None = None
+        self._open: span | None = None
+        self._tracer: Tracer | None = None
+        self._listening = observe
+        if not observe:
+            return
+        self.phases = []
+        self.t_entry = self._now = time.monotonic()
+        self.t_entry_perf = time.perf_counter()
+        self._on_recompile = on_recompile
+        self._lock = threading.Lock()
+        self._finished = False
+        self._funs: dict[str, dict] = {}
+        self._intervals: dict[str, list] = {c: [] for c in
+                                            COMPILE_EVENTS.values()}
+        self._cache = {"hits": 0, "misses": 0, "retrieval_s": 0.0}
+        # hits and misses come without a name, inside the backend interval
+        # of the function they belong to: held by thread until it ends
+        self._unclaimed: dict[int, dict] = {}
+        self._late: dict[str, dict] = {}
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    # -- phases: what :class:`span` asks of a tracer ------------------------
+
+    def _clock(self) -> float:
+        return self._now
+
+    def span(self, name: str, dur_s: float, *, t0: float, **_) -> None:
+        self.phases.append((name, t0, dur_s))
+        if self._tracer is not None:
+            self._tracer.span(name, dur_s, t0=t0)
+
+    def enter(self, name: str) -> None:
+        self.close_phase()
+        self._open = span(
+            name, tracer=self if self.phases is not None else None
+        ).__enter__()
+
+    def close_phase(self) -> None:
+        if self._open is not None:
+            if self.phases is not None:
+                self._now = time.monotonic()
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def attach(self, tracer: Tracer | None) -> None:
+        """The sink is up: replay the phases closed before it was."""
+        if tracer is not None and self.phases is not None:
+            for name, t0, dur_s in self.phases:
+                tracer.span(name, dur_s, t0=t0)
+            self._tracer = tracer
+
+    # -- the compile table --------------------------------------------------
+
+    def _duration(self, event: str, seconds: float, fun_name: str = "?",
+                  **_) -> None:
+        column = COMPILE_EVENTS.get(event)
+        if column is None:
+            if event == CACHE_RETRIEVAL_EVENT:
+                with self._lock:
+                    self._cache["retrieval_s"] += seconds
+            return
+        end = time.monotonic()  # the listener fires as the event ends
+        # the trace event says ``step_fn``, lowering and the backend
+        # ``jit(step_fn)``: one row a function
+        fun_name = fun_name.removeprefix("jit(").removesuffix(")")
+        recompiled = None
+        with self._lock:
+            table = self._late if self._finished else self._funs
+            row = table.get(fun_name)
+            if row is None:
+                row = table[fun_name] = {
+                    "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+                    "hits": 0, "misses": 0, "calls": dict.fromkeys(
+                        COMPILE_EVENTS.values(), 0)}
+            row[column] += seconds
+            row["calls"][column] += 1
+            if column == "backend_s":
+                for key, count in self._unclaimed.pop(
+                        threading.get_ident(), {}).items():
+                    row[key] += count
+            if not self._finished:
+                self._intervals[column].append((end - seconds, end))
+            elif column == "backend_s":
+                # the traces that nest in this compile end with it
+                recompiled = row
+                self._late.clear()
+        if recompiled is not None and self._on_recompile is not None:
+            self._on_recompile(
+                fun_name, trace_s=recompiled["trace_s"],
+                lower_s=recompiled["lower_s"],
+                backend_s=recompiled["backend_s"])
+
+    def _event(self, event: str, **_) -> None:
+        key = CACHE_EVENTS.get(event)
+        if key is not None:
+            with self._lock:
+                self._cache[key] += 1
+                held = self._unclaimed.setdefault(threading.get_ident(), {})
+                held[key] = held.get(key, 0) + 1
+
+    def finish(self) -> dict | None:
+        """The first dispatch has returned: the ``bringup`` row's fields
+        (``None`` without ``observe``)."""
+        self.close_phase()
+        if self.phases is None:
+            return None
+        with self._lock:
+            self._finished = True
+            funs, intervals = self._funs, self._intervals
+            self._funs = self._intervals = None
+        since, until = self.t_entry, self._now
+        traced = intervals["trace_s"] + intervals["lower_s"]
+        trace_lower = _union_s(traced, since, until)
+        # the backend seconds outside every trace and lowering (a constant
+        # folded while tracing compiles inside the trace's interval), so
+        # that the two add up to the union of all three
+        backend = _union_s(
+            traced + intervals["backend_s"], since, until) - trace_lower
+        columns = ("n", "trace_s", "lower_s", "backend_s", "hits", "misses")
+        for row in funs.values():
+            # times the function went through its most repeated stage
+            row["n"] = max(row.pop("calls").values())
+        table = sorted(
+            ({"fun": fun, **{c: row[c] for c in columns}}
+             for fun, row in funs.items()),
+            key=lambda r: -(r["trace_s"] + r["lower_s"] + r["backend_s"]))
+        rest = table[COMPILE_TABLE_ROWS:]
+        table = table[:COMPILE_TABLE_ROWS]
+        if rest:
+            table.append({"fun": "other", **{
+                c: sum(r[c] for r in rest) for c in columns}})
+        return {
+            "t_entry": self.t_entry, "t_entry_perf": self.t_entry_perf,
+            "phases": [[name, t0 - since, dur_s]
+                       for name, t0, dur_s in self.phases],
+            "total_s": until - since,
+            "trace_lower_s": trace_lower, "backend_s": backend,
+            "cache_hits": self._cache["hits"],
+            "cache_misses": self._cache["misses"],
+            "cache_retrieval_s": self._cache["retrieval_s"],
+            "compile": table,
+        }
+
+    def close(self) -> None:
+        self.close_phase()
+        if self._listening:
+            self._listening = False
+            jax.monitoring.unregister_event_duration_listener(self._duration)
+            jax.monitoring.unregister_event_listener(self._event)
 
 
 class _Req:

@@ -1065,90 +1065,6 @@ def fit(
     import itertools
 
     from tpudist.data.loader import prefetch_to_mesh
-
-    if plan is not None:
-        if mesh is not None and mesh != plan.mesh:
-            raise ValueError(
-                f"fit got both a mesh and a plan ({plan.describe()}) over "
-                "a different mesh — build the plan over the run's mesh "
-                "(ParallelPlan(mesh)) or drop the mesh argument"
-            )
-        mesh = plan.mesh
-    mesh = mesh or mesh_lib.create_mesh()
-    world_size = world_size if world_size is not None else jax.device_count()
-    global_rank = (
-        global_rank if global_rank is not None else jax.process_index()
-    )
-    if batch_size is None:
-        # loader batch is per-process; the logged batch_size is per-replica
-        # (the reference's per-GPU --batch_size, main.py:25)
-        batch_size = train_loader.batch_size // jax.local_device_count()
-
-    # init sample batch = the mesh's replica count, not 1: models with manual
-    # (shard_map) axes — ring/Ulysses attention — refuse traces whose batch
-    # doesn't divide the mesh; zeros keep init cheap and content-independent.
-    # ``init_input`` overrides the probe-derived shape for models whose
-    # init takes more than batch[input_key] (e.g. T5's (enc, dec) tuple) —
-    # and skips the probe entirely (its only consumer).
-    if init_input is None:
-        # shape/dtype probe: one gathered sample where the loader supports
-        # it (a full first batch would e.g. JPEG-decode the whole thing
-        # twice)
-        sample = (
-            train_loader.probe()
-            if hasattr(train_loader, "probe")
-            else next(iter(train_loader))
-        )
-        sample_in = np.asarray(sample[input_key])
-        init_input = jnp.zeros(
-            (mesh_lib.data_parallel_size(mesh), *sample_in.shape[1:]),
-            sample_in.dtype,
-        )
-    if shard_opt_state:
-        if plan is not None:
-            # ZeRO-1 composed with the plan: skip the leaves the plan
-            # scatters over fsdp (no double-sharding — parallel/plan.py).
-            # On an expert plan the skip rule also needs the expert-sharded
-            # leaf SHAPES (the rule is shape-only), identified from an
-            # abstract trace of the init's partitioning metadata.
-            boxed = None
-            if plan.expert > 1:
-                boxed = jax.eval_shape(
-                    lambda: model.init(
-                        jax.random.PRNGKey(0), init_input, train=False
-                    )
-                )["params"]
-            tx = plan.wrap_zero1(tx, params=boxed)
-        else:
-            from tpudist.optim import shard_state as _zero1
-
-            tx = _zero1(tx, mesh)
-    state = create_train_state(model, seed, init_input, tx, mesh, plan=plan)
-    if init_params is not None:
-        # warm-start (e.g. an HF checkpoint through tpudist.interop):
-        # replace the random init leaf-for-leaf, keeping each leaf's mesh
-        # placement and dtype; optimizer state stays fresh
-        placed = jax.tree_util.tree_map(
-            lambda ref, new: jax.device_put(
-                jnp.asarray(new, ref.dtype), ref.sharding
-            ),
-            state.params, init_params,
-        )
-        from tpudist.optim import refresh_fused_compute
-
-        # a fused_adamw compute copy was cast from the DISCARDED random
-        # init — re-cast it from the warm-start weights (no-op for states
-        # without a usable copy, which the forward also never reads)
-        state = state.replace(
-            params=placed,
-            opt_state=refresh_fused_compute(state.opt_state, placed),
-        )
-    # DDP verifies rank param consistency at wrap time (main.py:83); same
-    # check here — same seed must have produced identical params (no-op
-    # single-process)
-    from tpudist.distributed import verify_replicas
-
-    verify_replicas(state.params)
     from tpudist.resilience import (
         GoodputTracker,
         Preempted,
@@ -1157,37 +1073,14 @@ def fit(
         restart_generation,
     )
     from tpudist.resilience import repair as repair_mod
+    from tpudist.telemetry.trace import TRAIN_STEP, Bringup, span
 
     generation = restart_generation()
     repair_policy = repair_mod.resolve_policy(repair)
-    repair_ctl = None
-    if repair_policy is not None:
-        if checkpoint_dir is None:
-            raise ValueError(
-                "fit(repair=...) needs checkpoint_dir: the escalation "
-                "ladder's first rung is a rollback to the last-known-good "
-                "checkpoint (docs/MULTIHOST.md)"
-            )
-        if not checkpoint_every and not checkpoint_every_s:
-            raise ValueError(
-                "fit(repair=...) needs a save cadence (checkpoint_every "
-                "and/or checkpoint_every_s): without periodic saves the "
-                "rollback target never advances past bring-up"
-            )
-        if keep_last is None:
-            # anchor-protecting retention: orbax's newest-N policy would
-            # prune the rollback target out from under the repair loop
-            keep_last = 3
-        # built BEFORE the step so the directive's RNG salt (and the
-        # repair-generation salt of a resumed post-repair trajectory)
-        # reaches the compiled program's dropout/SR streams
-        repair_ctl = repair_mod.RepairController(
-            repair_policy, checkpoint_dir, generation=generation
-        )
-        if not telemetry:
-            # the triggers ARE telemetry verdicts; a repair request with
-            # telemetry off would watch nothing
-            telemetry = True
+    if repair_policy is not None and not telemetry:
+        # the triggers ARE telemetry verdicts; a repair request with
+        # telemetry off would watch nothing
+        telemetry = True
     tel_cfg = None
     if telemetry:
         from tpudist.telemetry import TelemetryConfig
@@ -1196,168 +1089,300 @@ def fit(
             telemetry if isinstance(telemetry, TelemetryConfig)
             else TelemetryConfig()
         )
-
-    def build_step(step_seed):
-        return make_train_step(
-            model, tx, mesh,
-            loss_fn=loss_fn, input_key=input_key, label_key=label_key,
-            grad_accum=grad_accum, remat=remat, batch_spec=batch_spec,
-            forward_loss=forward_loss, dropout_seed=step_seed,
-            input_transform=input_transform, reduce=reduce, fused=fused,
-            **(tel_cfg.step_kwargs() if tel_cfg else {}),
-            # keep whatever sharding create_train_state produced
-            # (replicated for plain DP, sharded for TP-annotated models
-            # and plan-composed runs) — forcing replicated here would
-            # all-gather a TP model's params on the first step
-            state_sharding=state_shardings_of(state),
-            plan=plan,
-        )
-
-    eff_seed = (
-        repair_policy.salted_seed(seed, repair_ctl.salt)
-        if repair_ctl is not None else seed
-    )
-    step = build_step(eff_seed)
-    if step.grad_reducer is not None:
-        # error-feedback residual born sharded over the data replicas
-        # (no-op for methods that carry none)
-        state = step.grad_reducer.attach_residual(state)
-
-    # sized loaders only matter for resume math; a re-iterable loader without
-    # __len__ still trains as long as checkpointing is off
-    steps_per_epoch = len(train_loader) if hasattr(train_loader, "__len__") else None
-    if checkpoint_dir is not None and steps_per_epoch is None:
-        raise ValueError(
-            "checkpointing needs a sized train_loader (len() maps state.step "
-            "to an epoch/batch position for exact resume)"
-        )
-    run_meta = {
-        "steps_per_epoch": steps_per_epoch,
-        "batch_size": batch_size,
-        "world_size": world_size,
-        "grad_accum": grad_accum,
-        # the model-axis worlds the state's placements are bound to
-        # (composable-parallelism geometry): appended keys — metas
-        # written before this layer carried none and default to 1, and
-        # a NON-data-axis resize is default-denied with a precise hint
-        # (tpudist.resilience.elastic.refusal_reason)
-        "fsdp_world": int(mesh.shape[mesh_lib.FSDP_AXIS]),
-        "tensor_world": int(mesh.shape[mesh_lib.TENSOR_AXIS]),
-        "pipe_world": int(mesh.shape[mesh_lib.PIPELINE_AXIS]),
-    }
-    if shard_opt_state:
-        # ZeRO-1 changes the opt-state LAYOUT on disk (padded [world, cols]
-        # leaves): resuming it replicated (or at another world size) would
-        # die in orbax with a shape mismatch — make the geometry guard say
-        # so instead. Only recorded when on, so replicated runs' meta (and
-        # their resumability) is unchanged.
-        run_meta["shard_opt_state"] = True
-    if step.grad_reducer is not None:
-        # same geometry rule for the explicit-reduction path: the
-        # error-feedback residual's [world, ...] layout (and the stochastic
-        # rounding stream) is world-size-bound — resuming a quantized run
-        # replicated (or vice versa) must refuse, not silently diverge
-        run_meta["reduce"] = step.grad_reducer.method
-    if shard_opt_state or step.grad_reducer is not None:
-        # the world the stored layouts are actually bound to is the MESH's
-        # data-axis size, not the (process-count-shaped) world_size above:
-        # a device-count resize with an unchanged process count would
-        # otherwise slip past the geometry guard and die in orbax with a
-        # bare shape mismatch instead of a validated reshard/refusal
-        run_meta["data_world"] = int(mesh.shape[mesh_lib.DATA_AXIS])
-    chaos_inj = make_injector(chaos)
     # goodput spans only surface through the run report, so the tracker
-    # rides the telemetry switch; its per-boundary cost is two clock reads
+    # rides the telemetry switch; its per-boundary cost is two clock reads.
+    # Built here so that its clock starts at fit's entry, as the bring-up
+    # account's does
     gp = GoodputTracker(generation=generation) if tel_cfg is not None else None
-    # SIGTERM/SIGINT → a signal-safe flag checked at step boundaries — the
-    # graceful-preemption path (docs/MULTIHOST.md "Surviving preemption").
-    # Installed here (post state-init, before checkpoint bring-up and the
-    # whole loop — the step compile included): a preemption anywhere past
-    # this line exits 75 after persisting whatever had become restorable.
-    guard = PreemptionGuard(enabled=bool(preempt)).__enter__()
-    preempt_signum = None
-    repair_exit = None  # the ladder's rung-3 action, raised as exit 77
-    ckpt = None
-    start_step = 0
-    losses: list[float] = []
-    logger = None
-    tel = None
-    # bring-up diagnoses that happen BEFORE the telemetry sink exists
-    # (reshard record, checkpoint-fallback warnings, compile-cache
-    # outcome) — replayed into the sink once it is up
-    bringup_events: list[dict] = []
-    # AOT executable cache (tpudist.compile_cache): start deserializing
-    # the cached step executable NOW, on a side thread, so the load
-    # overlaps the checkpoint restore below instead of serializing with it
-    cc = cc_key = cc_handle = cc_staged = None
-    cc_info: dict | None = None
-    tel_box: list = []  # late-bound telemetry ref for the AOT fallback
-    if compile_cache is not None:
-        try:
-            from tpudist import compile_cache as cc_mod
+    guard = ckpt = tel = None  # what the finally below tears down
 
-            cc = cc_mod.CompileCache(compile_cache)
-            cc_staged = cc_mod.staged_example(step, train_loader)
-            if cc_staged is None:
+    def on_recompile(fun, **seconds):
+        # from the thread that compiled; the step being dispatched
+        if tel is not None:
+            tel.recompiled(global_step, fun, **seconds)
+
+    # the bring-up's phases (docs/OBSERVABILITY.md §8): profiler
+    # annotations always; with telemetry also the `bringup` row's phases
+    # and compile table, and a `recompile` warning for a compile after it
+    bringup = Bringup(observe=tel_cfg is not None, on_recompile=on_recompile)
+    try:
+        bringup.enter("bringup/probe")
+        if plan is not None:
+            if mesh is not None and mesh != plan.mesh:
+                raise ValueError(
+                    f"fit got both a mesh and a plan ({plan.describe()}) over "
+                    "a different mesh — build the plan over the run's mesh "
+                    "(ParallelPlan(mesh)) or drop the mesh argument"
+                )
+            mesh = plan.mesh
+        mesh = mesh or mesh_lib.create_mesh()
+        if world_size is None:
+            world_size = jax.device_count()
+        global_rank = (
+            global_rank if global_rank is not None else jax.process_index()
+        )
+        if batch_size is None:
+            # loader batch is per-process; the logged batch_size is per-replica
+            # (the reference's per-GPU --batch_size, main.py:25)
+            batch_size = train_loader.batch_size // jax.local_device_count()
+
+        # init sample batch = the mesh's replica count, not 1: models with
+        # manual (shard_map) axes — ring/Ulysses attention — refuse traces
+        # whose batch doesn't divide the mesh; zeros keep init cheap and
+        # content-independent. ``init_input`` overrides the probe-derived
+        # shape for models whose init takes more than batch[input_key] (e.g.
+        # T5's (enc, dec) tuple) — and skips the probe entirely (its only
+        # consumer).
+        if init_input is None:
+            # shape/dtype probe: one gathered sample where the loader supports
+            # it (a full first batch would e.g. JPEG-decode the whole thing
+            # twice)
+            sample = (
+                train_loader.probe()
+                if hasattr(train_loader, "probe")
+                else next(iter(train_loader))
+            )
+            sample_in = np.asarray(sample[input_key])
+            init_input = jnp.zeros(
+                (mesh_lib.data_parallel_size(mesh), *sample_in.shape[1:]),
+                sample_in.dtype,
+            )
+        bringup.enter("bringup/init_state")
+        if shard_opt_state:
+            if plan is not None:
+                # ZeRO-1 composed with the plan: skip the leaves the plan
+                # scatters over fsdp (no double-sharding — parallel/plan.py).
+                # On an expert plan the skip rule also needs the expert-sharded
+                # leaf SHAPES (the rule is shape-only), identified from an
+                # abstract trace of the init's partitioning metadata.
+                boxed = None
+                if plan.expert > 1:
+                    boxed = jax.eval_shape(
+                        lambda: model.init(
+                            jax.random.PRNGKey(0), init_input, train=False
+                        )
+                    )["params"]
+                tx = plan.wrap_zero1(tx, params=boxed)
+            else:
+                from tpudist.optim import shard_state as _zero1
+
+                tx = _zero1(tx, mesh)
+        state = create_train_state(
+            model, seed, init_input, tx, mesh, plan=plan
+        )
+        bringup.enter("bringup/place_params")
+        if init_params is not None:
+            # warm-start (e.g. an HF checkpoint through tpudist.interop):
+            # replace the random init leaf-for-leaf, keeping each leaf's mesh
+            # placement and dtype; optimizer state stays fresh
+            placed = jax.tree_util.tree_map(
+                lambda ref, new: jax.device_put(
+                    jnp.asarray(new, ref.dtype), ref.sharding
+                ),
+                state.params, init_params,
+            )
+            from tpudist.optim import refresh_fused_compute
+
+            # a fused_adamw compute copy was cast from the DISCARDED random
+            # init — re-cast it from the warm-start weights (no-op for states
+            # without a usable copy, which the forward also never reads)
+            state = state.replace(
+                params=placed,
+                opt_state=refresh_fused_compute(state.opt_state, placed),
+            )
+        # DDP verifies rank param consistency at wrap time (main.py:83); same
+        # check here — same seed must have produced identical params (no-op
+        # single-process)
+        from tpudist.distributed import verify_replicas
+
+        bringup.enter("bringup/verify_replicas")
+        verify_replicas(state.params)
+
+        bringup.enter("bringup/build_step")
+        repair_ctl = None
+        if repair_policy is not None:
+            if checkpoint_dir is None:
+                raise ValueError(
+                    "fit(repair=...) needs checkpoint_dir: the escalation "
+                    "ladder's first rung is a rollback to the last-known-good "
+                    "checkpoint (docs/MULTIHOST.md)"
+                )
+            if not checkpoint_every and not checkpoint_every_s:
+                raise ValueError(
+                    "fit(repair=...) needs a save cadence (checkpoint_every "
+                    "and/or checkpoint_every_s): without periodic saves the "
+                    "rollback target never advances past bring-up"
+                )
+            if keep_last is None:
+                # anchor-protecting retention: orbax's newest-N policy would
+                # prune the rollback target out from under the repair loop
+                keep_last = 3
+            # built BEFORE the step so the directive's RNG salt (and the
+            # repair-generation salt of a resumed post-repair trajectory)
+            # reaches the compiled program's dropout/SR streams
+            repair_ctl = repair_mod.RepairController(
+                repair_policy, checkpoint_dir, generation=generation
+            )
+
+        def build_step(step_seed):
+            return make_train_step(
+                model, tx, mesh,
+                loss_fn=loss_fn, input_key=input_key, label_key=label_key,
+                grad_accum=grad_accum, remat=remat, batch_spec=batch_spec,
+                forward_loss=forward_loss, dropout_seed=step_seed,
+                input_transform=input_transform, reduce=reduce, fused=fused,
+                **(tel_cfg.step_kwargs() if tel_cfg else {}),
+                # keep whatever sharding create_train_state produced
+                # (replicated for plain DP, sharded for TP-annotated models
+                # and plan-composed runs) — forcing replicated here would
+                # all-gather a TP model's params on the first step
+                state_sharding=state_shardings_of(state),
+                plan=plan,
+            )
+
+        eff_seed = (
+            repair_policy.salted_seed(seed, repair_ctl.salt)
+            if repair_ctl is not None else seed
+        )
+        step = build_step(eff_seed)
+        if step.grad_reducer is not None:
+            # error-feedback residual born sharded over the data replicas
+            # (no-op for methods that carry none)
+            state = step.grad_reducer.attach_residual(state)
+
+        # sized loaders only matter for resume math; a re-iterable loader
+        # without __len__ still trains as long as checkpointing is off
+        steps_per_epoch = (
+            len(train_loader) if hasattr(train_loader, "__len__") else None
+        )
+        if checkpoint_dir is not None and steps_per_epoch is None:
+            raise ValueError(
+                "checkpointing needs a sized train_loader (len() maps "
+                "state.step to an epoch/batch position for exact resume)"
+            )
+        run_meta = {
+            "steps_per_epoch": steps_per_epoch,
+            "batch_size": batch_size,
+            "world_size": world_size,
+            "grad_accum": grad_accum,
+            # the model-axis worlds the state's placements are bound to
+            # (composable-parallelism geometry): appended keys — metas
+            # written before this layer carried none and default to 1, and
+            # a NON-data-axis resize is default-denied with a precise hint
+            # (tpudist.resilience.elastic.refusal_reason)
+            "fsdp_world": int(mesh.shape[mesh_lib.FSDP_AXIS]),
+            "tensor_world": int(mesh.shape[mesh_lib.TENSOR_AXIS]),
+            "pipe_world": int(mesh.shape[mesh_lib.PIPELINE_AXIS]),
+        }
+        if shard_opt_state:
+            # ZeRO-1 changes the opt-state LAYOUT on disk (padded [world, cols]
+            # leaves): resuming it replicated (or at another world size) would
+            # die in orbax with a shape mismatch — make the geometry guard say
+            # so instead. Only recorded when on, so replicated runs' meta (and
+            # their resumability) is unchanged.
+            run_meta["shard_opt_state"] = True
+        if step.grad_reducer is not None:
+            # same geometry rule for the explicit-reduction path: the
+            # error-feedback residual's [world, ...] layout (and the stochastic
+            # rounding stream) is world-size-bound — resuming a quantized run
+            # replicated (or vice versa) must refuse, not silently diverge
+            run_meta["reduce"] = step.grad_reducer.method
+        if shard_opt_state or step.grad_reducer is not None:
+            # the world the stored layouts are actually bound to is the MESH's
+            # data-axis size, not the (process-count-shaped) world_size above:
+            # a device-count resize with an unchanged process count would
+            # otherwise slip past the geometry guard and die in orbax with a
+            # bare shape mismatch instead of a validated reshard/refusal
+            run_meta["data_world"] = int(mesh.shape[mesh_lib.DATA_AXIS])
+        chaos_inj = make_injector(chaos)
+        # SIGTERM/SIGINT → a signal-safe flag checked at step boundaries —
+        # the graceful-preemption path (docs/MULTIHOST.md "Surviving
+        # preemption"). Installed here (post state-init, before checkpoint
+        # bring-up and the whole loop — the step compile included): a
+        # preemption anywhere past this line exits 75 after persisting
+        # whatever had become restorable.
+        guard = PreemptionGuard(enabled=bool(preempt)).__enter__()
+        preempt_signum = None
+        repair_exit = None  # the ladder's rung-3 action, raised as exit 77
+        start_step = 0
+        losses: list[float] = []
+        logger = None
+        # bring-up diagnoses that happen BEFORE the telemetry sink exists
+        # (reshard record, checkpoint-fallback warnings, compile-cache
+        # outcome) — replayed into the sink once it is up
+        bringup_events: list[dict] = []
+        # AOT executable cache (tpudist.compile_cache): start deserializing
+        # the cached step executable NOW, on a side thread, so the load
+        # overlaps the checkpoint restore below instead of serializing with it
+        bringup.enter("bringup/restore")
+        cc = cc_key = cc_handle = cc_staged = None
+        cc_info: dict | None = None
+        tel_box: list = []  # late-bound telemetry ref for the AOT fallback
+        if compile_cache is not None:
+            try:
+                from tpudist import compile_cache as cc_mod
+
+                cc = cc_mod.CompileCache(compile_cache)
+                cc_staged = cc_mod.staged_example(step, train_loader)
+                if cc_staged is None:
+                    bringup_events.append({
+                        "tag": "compile_cache_unsupported",
+                        "reason": "loader cannot be probed into a shaped "
+                        "batch (device-resident operands or unsized stream) "
+                        "— falling through to ordinary tracing",
+                    })
+                    cc = None
+                else:
+                    tel_knobs = tel_cfg.step_kwargs() if tel_cfg else {}
+                    model_id = cc_mod.model_identity(model)
+                    if ":" not in model_id:
+                        # type-only identity (address-bearing default repr):
+                        # the key cannot see model-code edits — say so once
+                        bringup_events.append({
+                            "tag": "compile_cache_weak_key",
+                            "reason": "model repr is the default "
+                            "address-bearing one, so the cache key sees only "
+                            "the model TYPE — code edits with identical "
+                            "geometry would reuse a stale executable; bump "
+                            "the compile_cache dir after changing model code",
+                        })
+                    cc_key = cc_mod.step_key(
+                        mesh=mesh, state=state, batch=cc_staged,
+                        config={
+                            "reduce": getattr(
+                                step.grad_reducer, "method", "none"
+                            ),
+                            "fused": sorted(step.fused),
+                            "grad_accum": grad_accum,
+                            "remat": str(remat),
+                            "telemetry": bool(tel_knobs.get("telemetry")),
+                            "guard_nonfinite": bool(
+                                tel_knobs.get("guard_nonfinite")
+                            ),
+                            "shard_opt_state": bool(shard_opt_state),
+                            "loss_fn": getattr(
+                                loss_fn, "__qualname__", str(loss_fn)
+                            ),
+                            "forward_loss": (
+                                getattr(forward_loss, "__qualname__",
+                                        str(forward_loss))
+                                if forward_loss is not None else None
+                            ),
+                            "input_key": input_key,
+                            "label_key": label_key,
+                            # the SALTED seed: a post-repair trajectory's
+                            # program differs exactly when its RNG streams do
+                            "dropout_seed": eff_seed,
+                            "model": model_id,
+                        },
+                    )
+                    cc_handle = cc.begin_load(cc_key)
+            except Exception as exc:
                 bringup_events.append({
                     "tag": "compile_cache_unsupported",
-                    "reason": "loader cannot be probed into a shaped "
-                    "batch (device-resident operands or unsized stream) "
-                    "— falling through to ordinary tracing",
+                    "reason": f"{type(exc).__name__}: {exc}",
                 })
                 cc = None
-            else:
-                tel_knobs = tel_cfg.step_kwargs() if tel_cfg else {}
-                model_id = cc_mod.model_identity(model)
-                if ":" not in model_id:
-                    # type-only identity (address-bearing default repr):
-                    # the key cannot see model-code edits — say so once
-                    bringup_events.append({
-                        "tag": "compile_cache_weak_key",
-                        "reason": "model repr is the default "
-                        "address-bearing one, so the cache key sees only "
-                        "the model TYPE — code edits with identical "
-                        "geometry would reuse a stale executable; bump "
-                        "the compile_cache dir after changing model code",
-                    })
-                cc_key = cc_mod.step_key(
-                    mesh=mesh, state=state, batch=cc_staged,
-                    config={
-                        "reduce": getattr(
-                            step.grad_reducer, "method", "none"
-                        ),
-                        "fused": sorted(step.fused),
-                        "grad_accum": grad_accum,
-                        "remat": str(remat),
-                        "telemetry": bool(tel_knobs.get("telemetry")),
-                        "guard_nonfinite": bool(
-                            tel_knobs.get("guard_nonfinite")
-                        ),
-                        "shard_opt_state": bool(shard_opt_state),
-                        "loss_fn": getattr(
-                            loss_fn, "__qualname__", str(loss_fn)
-                        ),
-                        "forward_loss": (
-                            getattr(forward_loss, "__qualname__",
-                                    str(forward_loss))
-                            if forward_loss is not None else None
-                        ),
-                        "input_key": input_key,
-                        "label_key": label_key,
-                        # the SALTED seed: a post-repair trajectory's
-                        # program differs exactly when its RNG streams do
-                        "dropout_seed": eff_seed,
-                        "model": model_id,
-                    },
-                )
-                cc_handle = cc.begin_load(cc_key)
-        except Exception as exc:
-            bringup_events.append({
-                "tag": "compile_cache_unsupported",
-                "reason": f"{type(exc).__name__}: {exc}",
-            })
-            cc = None
-    try:
         if checkpoint_dir is not None:
             from tpudist.checkpoint import Checkpointer
 
@@ -1564,6 +1589,7 @@ def fit(
         # the logger truncates ("w") its TSV on construction, so it must not
         # exist until checkpoint bring-up has succeeded — a refused resume
         # above would otherwise clobber the previous run's metrics
+        bringup.enter("bringup/telemetry")
         logger = metrics_logger or MetricsLogger(
             job_id, batch_size, global_rank, world_size, log_dir=log_dir
         )
@@ -1574,7 +1600,6 @@ def fit(
         ) as p:
             print("Start")
             from tpudist.telemetry import TimedIterator, build_telemetry
-            from tpudist.telemetry.trace import TRAIN_STEP, span
             from tpudist.telemetry.flops import mesh_chips as flops_chips
 
             # sink attached BEFORE the first log_memory: the dual-sink
@@ -1623,9 +1648,11 @@ def fit(
                     gp.load_previous(tel.health.report_path)
                 logger.attach_sink(tel.sink)
                 tel_box.append(tel)
-                # replay bring-up diagnoses that predate the sink: the
-                # elastic reshard record, checkpoint-fallback warnings,
+                # replay what predates the sink: the bring-up phases closed
+                # so far (`span` rows, with trace=True), then the diagnoses —
+                # the elastic reshard record, checkpoint-fallback warnings,
                 # and the AOT-cache outcome
+                bringup.attach(tel.tracer)
                 for ev in bringup_events:
                     ev = dict(ev)
                     tag = ev.pop("tag")
@@ -1730,6 +1757,8 @@ def fit(
             logger.start_timer()
             if gp is not None:
                 gp.loop_started()
+            bringup.enter("bringup/first_batch")
+            bringing_up = True
             last_save_t = time.monotonic()
 
             # one-step-delayed metric resolution: step k's scalars (loss +
@@ -1857,11 +1886,21 @@ def fit(
                             break
                         start = time.time()
                         global_step += 1
+                        if bringing_up:
+                            bringup.enter("bringup/first_dispatch")
                         dispatch_t0 = time.perf_counter()
                         with span(TRAIN_STEP, step=global_step,
                                   tracer=tracer, marks_step=True):
                             state, metrics = step(state, batch)
                         dispatch_s = time.perf_counter() - dispatch_t0
+                        if bringing_up:
+                            # the first dispatch has returned: the bring-up
+                            # account closes (one `bringup` row); a compile
+                            # from here on is a `recompile` warning
+                            bringing_up = False
+                            row = bringup.finish()
+                            if tel is not None:
+                                tel.set_bringup(global_step, row)
                         for v in metrics.values():
                             v.copy_to_host_async()
                         if tel is not None:
@@ -2082,7 +2121,9 @@ def fit(
         # mirrors its TrainTime footer into the sink (dual-sink mode), so
         # the sink must outlive it (shutdown also stops the hang-watchdog
         # thread before the sink goes away)
-        guard.__exit__(None, None, None)
+        bringup.close()  # before the sink goes: its listeners write rows
+        if guard is not None:
+            guard.__exit__(None, None, None)
         if tel is not None:
             tel.shutdown()
         if ckpt:
