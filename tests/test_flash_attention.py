@@ -501,3 +501,233 @@ def test_block_causal_mask_on_a_ragged_sequence():
     for a, b in zip(_masked_grads(flash, q, k, v),
                     _masked_grads(dense, q, k, v)):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+
+# -- the fetch table: dead grid steps name the next live block ----------------
+
+
+def _committed_sweeps(monkeypatch, mask, rows, block_q, block_k, kv_len=None):
+    """What each of the three ``pallas_call``s is given, traced at these
+    shapes (one batch row, one head; ``jax.eval_shape``: nothing runs):
+    ``{kernel: (table, names)}`` with ``names[i, j]`` the block index the
+    call's committed index maps name for each of its in_specs at grid step
+    ``(0, 0, i, j)``."""
+    from tpudist.ops import flash_attention as fa
+
+    seen = []
+    real = fa._pallas
+
+    def spy(kernel, table, **kw):
+        seen.append((table, kw))
+        return real(kernel, table, **kw)
+
+    monkeypatch.setattr(fa, "_pallas", spy)
+    q = jax.ShapeDtypeStruct((1, 1, rows, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((1, 1, rows), jnp.float32)
+    jax.eval_shape(lambda q: fa._flash_fwd(
+        q, q, q, mask=mask, sm_scale=1.0, block_q=block_q, block_k=block_k,
+        kv_len=kv_len), q)
+    jax.eval_shape(lambda q, lse: fa._bwd_pallas(
+        (q, q, q, q, lse), q, mask=mask, sm_scale=1.0, block_q=block_q,
+        block_k=block_k, kv_len=kv_len, interpret=True), q, lse)
+    sweeps = {}
+    for name, (table, kw) in zip(("fwd", "dkv", "dq"), seen):
+        _, _, n_outer, n_inner = kw["grid"]
+        tbl = () if table is None else (table.ravel(),)
+        sweeps[name] = table, np.array(
+            [[[int(spec.index_map(0, 0, i, j, *tbl)[2])
+               for spec in kw["in_specs"]] for j in range(n_inner)]
+             for i in range(n_outer)])
+    return sweeps
+
+
+def _live(mask, kv_len, rows, block_q, block_k):
+    """[q tile, k tile]: has it an allowed pair (the mask's tile test; a
+    ``kv_len`` retires whole K blocks beside it)?"""
+    qi = np.arange(rows // block_q)[:, None]
+    ki = np.arange(rows // block_k)[None, :]
+    live = np.ones((qi.size, ki.size), bool)
+    if mask is not None:
+        live &= np.asarray(mask.tile_live(qi, ki, block_q, block_k))
+    if kv_len is not None:
+        live &= ki * block_k < kv_len
+    return live
+
+
+# (mask, rows, block_q, block_k, kv_len): the three cells' shapes, then
+# small ones — block diffusion, causal, block-causal, a ragged kv_len that
+# retires whole K blocks, and no mask
+_SWEEP_CASES = {
+    "sdar_cell": (("bd", 4, 4096), 8192, 512, 1024, None),
+    "kanana_cell": (("causal",), 8192, 512, 1024, None),
+    "zaya_cell": (("causal",), 4096, 512, 1024, None),
+    "bd_small": (("bd", 4, 512), 1024, 128, 256, None),
+    "bd_tile_blocks": (("bd", 128, 256), 512, 128, 128, None),
+    "causal_small": (("causal",), 512, 128, 256, None),
+    "block_causal": (("bd", 64, 0), 512, 128, 128, None),
+    "ragged_kv": (None, 512, 128, 128, 200),
+    "block_causal_ragged": (("bd", 8, 0), 512, 128, 128, 300),
+    "unmasked": (None, 1024, 128, 256, None),
+}
+
+
+def _mask_of(spec):
+    from tpudist.ops.attention import BlockMask
+
+    if spec is None:
+        return None
+    return CAUSAL if spec[0] == "causal" else BlockMask(*spec[1:])
+
+
+@pytest.mark.parametrize("case", list(_SWEEP_CASES))
+def test_committed_index_maps_fetch_once_a_live_tile(case, monkeypatch):
+    """Every committed index map over the whole grid of the forward, dkv
+    and dq kernels: the resident operands name the outer block; the
+    streamed ones (K / V; Q / dO / lse / delta in dkv) name their own block
+    at every live step, the next live block at a dead step before one (so
+    a dead run before a sweep's first live tile names that tile) and the
+    last live block after it; a sweep changes its streamed index once a
+    live tile (once where it has none); an unmasked call carries no
+    table."""
+    spec, rows, block_q, block_k, kv_len = _SWEEP_CASES[case]
+    mask = _mask_of(spec)
+    live = _live(mask, kv_len, rows, block_q, block_k)
+    sweeps = _committed_sweeps(monkeypatch, mask, rows, block_q, block_k,
+                               kv_len)
+    streamed = {"fwd": (1, 2), "dkv": (0, 1, 2, 3), "dq": (0, 1)}
+    for kernel, (table, names) in sweeps.items():
+        tiles = live.T if kernel == "dkv" else live  # [outer, inner]
+        if tiles.all():
+            assert table is None, kernel
+        n_outer, n_inner = tiles.shape
+        resident = [s for s in range(names.shape[2])
+                    if s not in streamed[kernel]]
+        assert (names[:, :, resident]
+                == np.arange(n_outer)[:, None, None]).all(), kernel
+        inner = names[:, :, streamed[kernel][0]]
+        for s in streamed[kernel]:
+            np.testing.assert_array_equal(names[:, :, s], inner)
+        for i in range(n_outer):
+            held = np.flatnonzero(tiles[i])
+            for j in range(n_inner):
+                later = held[held >= j]
+                want = (j if tiles[i, j] else later[0] if later.size
+                        else held[-1] if held.size else 0)
+                assert inner[i, j] == want, (kernel, i, j)
+            changes = 1 + int(np.sum(inner[i, 1:] != inner[i, :-1]))
+            assert changes == max(held.size, 1), (kernel, i)
+    if mask is None and kv_len is None:
+        assert all(table is None for table, _ in sweeps.values())
+    # numpy alone, while a step is traced: no JAX op is staged or run (on
+    # the chip each would compile, op by op, inside the step's trace)
+    from tpudist.ops import flash_attention as fa
+
+    for dkv in (False, True):
+        n_outer, n_inner = live.T.shape if dkv else live.shape
+        build = lambda: fa._fetch_table.__wrapped__(
+            mask, kv_len, n_outer, n_inner, block_q, block_k, dkv)
+        assert not jax.make_jaxpr(lambda: (build(), jnp.int32(0))[1])(
+            ).jaxpr.eqns
+
+
+@pytest.mark.parametrize("mask_spec, rows, blocks, share", [
+    (("bd", 4, 4096), 8192, None, 0.375),    # sdar_30b_bd_train_s4096
+    (("causal",), 8192, None, 0.5625),       # kanana2_30b_train_s8192
+    (("causal",), 4096, None, 0.625),        # zaya1_8b_train_s4096
+    (None, 8192, None, 1.0),
+    (None, 4096, None, 1.0),
+    (("bd", 4, 512), 1024, (128, 256), 16 / 32),
+    (("bd", 64, 0), 512, (128, 128), 10 / 16),
+    (("causal",), 512, (128, 256), 6 / 8),
+    (None, 512, (128, 128), 1.0),
+])
+def test_fetched_tile_share_is_what_the_index_maps_fetch(
+        mask_spec, rows, blocks, share, monkeypatch):
+    """The counter at the blocks each cell's shape takes (``None``: 512 x
+    1024) and at small ones, and each kernel's own share of steps whose
+    streamed block changes index, read off its committed index maps:
+    forward, dq and dkv alike, one fetch a live tile. Before the table
+    every step of a masked call fetched (1.0); the tiles computed are
+    unchanged."""
+    from tpudist.ops.flash_attention import (
+        computed_tile_share, default_blocks, fetched_tile_share,
+    )
+
+    mask = _mask_of(mask_spec)
+    if blocks is None:
+        copy = mask.noised_len if mask and mask.noised_len else rows
+        blocks = default_blocks(copy, copy, 128)[:2]
+        assert blocks == (512, 1024)
+    assert fetched_tile_share(mask, rows, *blocks) == share
+    assert computed_tile_share(mask, rows, *blocks) == share
+    for kernel, (_, names) in _committed_sweeps(
+            monkeypatch, mask, rows, *blocks).items():
+        inner = names[:, :, 0 if kernel != "fwd" else 1]
+        fetches = inner.shape[0] + np.sum(inner[:, 1:] != inner[:, :-1])
+        assert fetches / inner.size == share, kernel
+
+
+@pytest.mark.parametrize("block, block_q, block_k", [
+    (4, 128, 128), (16, 128, 256),
+])
+def test_dead_runs_fetch_nothing_and_change_no_bit(block, block_q, block_k,
+                                                   monkeypatch):
+    """Two copies of 512 rows in blocks of ``block``: a dkv sweep with dead
+    Q blocks before its first live one (a clean K block that only later
+    rows see), a forward sweep with a dead K block between two live ones
+    (a noised Q block's own block, then the clean past). The forward and
+    the Pallas backward (interpret mode) through the fetch table against
+    the dense reference and the scan backward, and against the same
+    kernels with every step naming its own block, as before the table:
+    equal to the last bit."""
+    from tpudist.ops import flash_attention as fa
+    from tpudist.ops.attention import BlockMask
+
+    length = 512
+    mask = BlockMask(block, length)
+    rows = 2 * length
+    fwd_table = fa._fetch_table(mask, None, rows // block_q, rows // block_k,
+                                block_q, block_k, False)
+    dkv_table = fa._fetch_table(mask, None, rows // block_k, rows // block_q,
+                                block_q, block_k, True)
+    live = _live(mask, None, rows, block_q, block_k)
+    first = [np.flatnonzero(r)[0] for r in live.T]
+    assert max(first) > 0 and all(  # dkv: a dead run before the first live
+        (dkv_table[i, :f] == f).all() for i, f in enumerate(first))
+    gaps = [i for i, r in enumerate(live) if np.any(np.diff(
+        np.flatnonzero(r)) > 1)]
+    assert gaps  # forward: a dead run between two live tiles
+
+    rng = np.random.Generator(np.random.PCG64(block))
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
+               for _ in range(3))
+    g = jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
+    sm = 1.0 / np.sqrt(128)
+
+    def kernels():
+        o, lse = fa._flash_fwd(q, k, v, mask=mask, sm_scale=sm,
+                               block_q=block_q, block_k=block_k)
+        grads = fa._bwd_pallas((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                               block_q=block_q, block_k=block_k,
+                               interpret=True)
+        return [np.asarray(x) for x in (o, lse, *grads)]
+
+    got = kernels()
+    assert fwd_table is not None and dkv_table is not None
+    dense = mask.dense(rows)[None, None]
+    ref = lambda q, k, v: dot_product_attention(
+        *(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+        mask=dense).transpose(0, 2, 1, 3)
+    o_ref, vjp = jax.vjp(ref, q, k, v)
+    np.testing.assert_allclose(got[0], o_ref, atol=2e-5, rtol=2e-5)
+    for a, b in zip(got[2:], vjp(g)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+    scan = fa._bwd_blockwise((q, k, v, jnp.asarray(got[0]),
+                              jnp.asarray(got[1])), g, mask=mask,
+                             sm_scale=sm, block_k=block_k)
+    for a, b in zip(got[2:], scan):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+    monkeypatch.setattr(fa, "_fetch_table", lambda *a: None)
+    for a, b in zip(got, kernels()):
+        np.testing.assert_array_equal(a, b)

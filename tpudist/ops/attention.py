@@ -101,8 +101,12 @@ class BlockMask:
         if not self.noised_len:
             return q0, k0, None, False, False
         qn, kn = q0 < self.noised_len, k0 < self.noised_len
-        q0 = q0 - jnp.where(qn, 0, self.noised_len)
-        k0 = k0 - jnp.where(kn, 0, self.noised_len)
+        # numpy grids (the flash kernels' static fetch tables, built while
+        # a step is traced) stay numpy: a jnp op there would run, and on
+        # the chip compile, op by op
+        where = np.where if isinstance(qn, np.ndarray) else jnp.where
+        q0 = q0 - where(qn, 0, self.noised_len)
+        k0 = k0 - where(kn, 0, self.noised_len)
         return q0, k0, (qn & ~kn).astype(jnp.int32), qn & kn, ~qn & kn
 
     def _tile_rule(self, bq_lo, bq_hi, bk_lo, bk_hi, strict, same, never):
