@@ -29,6 +29,7 @@ Multi-host works exactly like main.py: ``python -m tpudist.launch ...``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 
@@ -89,7 +90,8 @@ def parse_args(argv=None):
                    "seq_len per chip (dense models only)")
     # model family + size
     p.add_argument("--arch", default="gpt2",
-                   choices=["gpt2", "llama", "zaya", "kanana", "sdar"],
+                   choices=["gpt2", "llama", "zaya", "kanana", "sdar",
+                            "laguna"],
                    help="decoder family: GPT-2 (learned positions, GELU MLP, "
                    "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA), ZAYA1 "
                    "(compressed convolutional attention, top-1 experts "
@@ -104,7 +106,15 @@ def parse_args(argv=None):
                    "blocks: the noised and the clean copy of a sequence "
                    "through one stack; --block_length, --t_min, --experts, "
                    "--moe_top_k, --held, --num_kv_heads, --head_dim, "
-                   "--ffn_dim; the mask token is one id past --vocab_size)")
+                   "--ffn_dim; the mask token is one id past --vocab_size) "
+                   "or Laguna (Laguna-XS.2's geometry: a full-attention "
+                   "layer, then three sliding-window ones, in periods — YaRN "
+                   "on half of each full head, a gated output in all —, a "
+                   "leading dense layer, sigmoid top-8 of 256 experts beside "
+                   "a shared one, untied head; --hidden_dim, --depth, "
+                   "--vocab_size, --seq_len and --held cut it, and "
+                   "--experts, --head_dim, --ffn_dim, --dense_ffn_dim where "
+                   "non-zero)")
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
@@ -114,8 +124,9 @@ def parse_args(argv=None):
                    help="llama SwiGLU width (0 = 8/3*hidden rounded to 256)")
     p.add_argument("--head_dim", default=0, type=int,
                    help="zaya: size of a latent attention head; kanana: of "
-                   "a value head and of a key's part without position "
-                   "(0 = hidden_dim / num_heads)")
+                   "a value head and of a key's part without position; "
+                   "sdar: of a head (0 = hidden_dim / num_heads); laguna: of "
+                   "a head (0 = 128)")
     p.add_argument("--rope_dim", default=0, type=int,
                    help="kanana: rotary channels of a key beside its "
                    "--head_dim others (0 = head_dim / 2)")
@@ -123,8 +134,8 @@ def parse_args(argv=None):
                    help="kanana: width of the key/value latent (0 = "
                    "hidden_dim / 4)")
     p.add_argument("--dense_ffn_dim", default=0, type=int,
-                   help="kanana: SwiGLU width of the leading dense layer "
-                   "(0 = 3 * hidden_dim)")
+                   help="kanana, laguna: SwiGLU width of the leading dense "
+                   "layer (0 = kanana: 3 * hidden_dim; laguna: 8192)")
     p.add_argument("--shared_experts", default=2, type=int,
                    help="kanana: shared experts of width --ffn_dim beside "
                    "the routed ones")
@@ -137,7 +148,7 @@ def parse_args(argv=None):
     p.add_argument("--router_width", default=256, type=int,
                    help="zaya: width of the MLP router and of its carry")
     p.add_argument("--held", default="", type=str,
-                   help="zaya, kanana, sdar: 'first,count' — the contiguous experts this "
+                   help="zaya, kanana, sdar, laguna: 'first,count' — the contiguous experts this "
                    "run holds (one shard's share of an expert-parallel "
                    "layer: the router scores all --experts, tokens of the "
                    "others contribute nothing here); empty = all")
@@ -328,7 +339,8 @@ def main(argv=None):
     n_dev = jax.device_count()
     if args.expert_axis:
         expert_axis = args.expert_axis
-    elif args.experts and args.arch not in ("zaya", "kanana", "sdar"):
+    elif args.experts and args.arch not in ("zaya", "kanana", "sdar",
+                                            "laguna"):
         # (the dropless layer runs one shard's experts, no exchange)
         # largest axis that divides both the expert count (weights shard
         # evenly) and the devices left over from the other model axes
@@ -469,6 +481,36 @@ def main(argv=None):
                                 held=held),
                 block_length=args.block_length,
                 rope_theta=args.rope_theta, remat_policy=args.remat_policy,
+                dtype=dtype, attn_impl=args.attn, mesh=mesh,
+            )
+        if args.arch == "laguna":
+            from tpudist.models.laguna import PERIOD, laguna_xs_2
+
+            if args.dropout or args.scan_layers or args.generate or args.init_hf:
+                raise SystemExit(
+                    "laguna trains unrolled, without dropout; --generate "
+                    "and --init_hf have no path for it yet"
+                )
+            # the published geometry, cut by the generic size flags
+            base = laguna_xs_2()
+            routing = dataclasses.replace(
+                base.routing,
+                num_experts=args.experts or base.routing.num_experts,
+                held=(tuple(int(n) for n in args.held.split(","))
+                      if args.held else None),
+            )
+            head_dim = args.head_dim or base.head_dim
+            ffn_dim = args.ffn_dim or base.ffn_dim
+            periods = -(-args.depth // len(PERIOD))
+            return base.clone(
+                vocab_size=args.vocab_size, max_seq_len=args.seq_len,
+                hidden_dim=args.hidden_dim, depth=args.depth,
+                layer_types=PERIOD * periods,
+                heads_per_layer=base.heads_per_layer[:len(PERIOD)] * periods,
+                head_dim=head_dim, full_rotary_dim=head_dim // 2,
+                ffn_dim=ffn_dim, shared_dim=ffn_dim,
+                dense_ffn_dim=args.dense_ffn_dim or base.dense_ffn_dim,
+                routing=routing, remat_policy=args.remat_policy,
                 dtype=dtype, attn_impl=args.attn, mesh=mesh,
             )
         if args.arch == "llama":

@@ -65,7 +65,8 @@ def _one_chip_mesh():
 
 
 @pytest.mark.parametrize("name", ["gpt2-medium", "bert-large",
-                                  "kanana-2-30b-a3b", "sdar-30b-a3b"])
+                                  "kanana-2-30b-a3b", "sdar-30b-a3b",
+                                  "laguna-xs-2"])
 def test_family_flops_per_token_is_the_programs_counter(name, monkeypatch):
     """``step_mfu_pct``'s numerator (``benchmarks/families/*.py``
     ``train_flops_per_token``, "copied from telemetry/flops.py") equals
@@ -119,7 +120,8 @@ def _calls(path, func):
     ]
 
 
-@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana", "sdar"])
+@pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana", "sdar",
+                                    "laguna"])
 def test_every_argument_the_cell_passes_is_a_parameter_of_fit(family):
     """``benchmarks/cell.py`` calls ``fit(model, tx, loader, <its own
     keywords>, **built["fit"])``: every one of them is a parameter."""
@@ -199,13 +201,12 @@ def test_exchange_and_loss_head_scopes_are_declared():
         spans.EXCHANGE_SCOPE, "loss_head"}
 
 
-# the Kanana-2 cell's readers (PR 32) and the SDAR cell's (PR 34) quote
-# block scopes: each is declared for that metric, and none declared for it
-# is left out
+# the Kanana-2, SDAR and Laguna cells' readers quote block scopes: each is
+# declared for that metric, and none declared for it is left out
 @pytest.mark.parametrize("metric, attribute", [
     ("mla_proj_ms", "STAGES"), ("moe_shared_ms", "STAGE"),
     ("moe_topk_ms", "STAGES"), ("bd_proj_ms", "STAGES"),
-    ("bd_moe_ms", "STAGES"),
+    ("bd_moe_ms", "STAGES"), ("lg_proj_ms", "STAGES"),
 ])
 def test_block_stage_readers_sum_declared_scopes(metric, attribute):
     reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
@@ -218,14 +219,20 @@ def test_block_stage_readers_sum_declared_scopes(metric, attribute):
         s for s, m in BLOCK_SCOPES.items() if m == declared)
 
 
-@pytest.mark.parametrize("family, scope", [("zaya", "cca_attn"),
-                                           ("kanana", "mla_attn"),
-                                           ("sdar", "bd_attn")])
+@pytest.mark.parametrize("family, scope", [
+    ("zaya", "cca_attn"), ("kanana", "mla_attn"), ("sdar", "bd_attn"),
+    ("laguna:sliding_attention", "swa_attn"),
+    ("laguna:full_attention", "full_attn"),
+])
 def test_attention_kernel_patterns_follow_the_declared_scope(family, scope):
     """XLA names a Pallas call after its innermost scope: the family's
-    ``ATTENTION_OPS`` finds ``<scope>.<k>`` for the scope the program
-    declares for that family's roofline metric, and no other block scope."""
-    pattern = _family(family).ATTENTION_OPS
+    ``ATTENTION_OPS`` (a family of two kinds of layer: each kind's, under
+    ``KINDS``) finds ``<scope>.<k>`` for the scope the program declares for
+    that roofline metric, and no other block scope."""
+    name, _, kind = family.partition(":")
+    module = _family(name)
+    pattern = module.KINDS[kind].ATTENTION_OPS if kind \
+        else module.ATTENTION_OPS
     assert BLOCK_SCOPES[scope].endswith("_attn_roofline")
     assert re.match(pattern, scope) and re.match(pattern, f"{scope}.17")
     assert not [s for s in BLOCK_SCOPES if s != scope
@@ -377,7 +384,8 @@ def kanana_block_paths():
 
 @pytest.mark.parametrize(
     "scope", sorted(s for s in BLOCK_SCOPES
-                    if not s.startswith(("cca_", "attn_", "bd_"))))
+                    if not s.startswith(("cca_", "attn_", "bd_", "lg_",
+                                         "swa_", "full_"))))
 def test_lowered_kanana_step_holds_the_block_scope_in_both_passes(
         scope, kanana_block_paths):
     """Each scope of the Kanana-2 block is in the lowered step's name
@@ -437,6 +445,48 @@ def test_lowered_sdar_step_holds_the_block_scope_in_both_passes(
     assert bd_head_ms.SCOPE in STEP_SCOPES
     assert any(spans.scope_of(p).startswith(bd_head_ms.SCOPE)
                for p in sdar_block_paths)
+
+
+@pytest.fixture(scope="module")
+def laguna_block_paths():
+    """Name stacks of the tiny Laguna configuration's lowered step under
+    its cell's recipe (per-block recomputation, fused norms, chunked CE):
+    layer 0 full and dense, 1-3 sliding, 4 full."""
+    from tpudist.train import create_train_state, make_train_step
+
+    config, traffic = _tiny("laguna")
+    mesh = _one_chip_mesh()
+    built = _family("laguna").build(config, traffic, mesh)
+    seq, rows = traffic["seq_len"], traffic["per_chip_batch"]
+    state = create_train_state(
+        built["model"], 0, jnp.zeros((1, seq), jnp.int32), built["tx"],
+        mesh=mesh)
+    step_args = inspect.signature(make_train_step).parameters
+    step = make_train_step(
+        built["model"], built["tx"], mesh,
+        **{k: v for k, v in built["fit"].items() if k in step_args})
+    return _paths(step.jitted.lower(
+        state, step.stage({"tokens": np.zeros((rows, seq), np.int32)})))
+
+
+@pytest.mark.parametrize(
+    "scope", sorted(s for s, m in BLOCK_SCOPES.items()
+                    if s.startswith("lg_") or m in (
+                        "swa_attn_roofline", "full_attn_roofline", "moe_ms")))
+def test_lowered_laguna_step_holds_the_block_scope_in_both_passes(
+        scope, laguna_block_paths):
+    """Each scope of the Laguna block is in the lowered step's name stacks
+    as a direct child of a block, in the forward and in the backward pass,
+    where ``layer_metrics/moe_ms.py`` ``stage_of`` finds it whatever
+    recomputation puts before the block: in the full expert layer ``h_4``
+    for ``full_attn``, in the sliding ``h_1`` for every other."""
+    from benchmarks.layer_metrics import moe_ms
+
+    block = "/h_4/" if scope == "full_attn" else "/h_1/"
+    held = {p for p in laguna_block_paths
+            if moe_ms.stage_of(p, "%fusion = f32[]") == scope and block in p}
+    assert held, f"no op of the lowered step is under {block}{scope}"
+    assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
 
 
 # -- (e) documents name files that exist --------------------------------------

@@ -372,24 +372,28 @@ def _computed_tiles(mask, rows, block_q, block_k):
     return computed
 
 
-@pytest.mark.parametrize("length, block, noised, block_q, block_k, share", [
-    (512, 4, True, 128, 256, 16 / 32),  # clean 6, noised to clean 6, diagonal 4
-    (1024, 4, True, 256, 512, 16 / 32),  # the cell's blocks at an eighth
-    (256, 4, True, 128, 128, 8 / 16),   # 3 + 3 + 2
-    (256, 128, True, 128, 128, 6 / 16),  # tile-sized blocks: 3 + 1 + 2
-    (512, 64, False, 128, 128, 10 / 16),  # block-causal
-    (512, 1, False, 128, 128, 10 / 16),   # the causal instance
-    (512, 1, False, 128, 256, 6 / 8),
+@pytest.mark.parametrize("length, block, noised, block_q, block_k, share, window", [
+    (512, 4, True, 128, 256, 16 / 32, 0),  # clean 6, noised to clean 6, diagonal 4
+    (1024, 4, True, 256, 512, 16 / 32, 0),  # the cell's blocks at an eighth
+    (256, 4, True, 128, 128, 8 / 16, 0),   # 3 + 3 + 2
+    (256, 128, True, 128, 128, 6 / 16, 0),  # tile-sized blocks: 3 + 1 + 2
+    (512, 64, False, 128, 128, 10 / 16, 0),  # block-causal
+    (512, 1, False, 128, 128, 10 / 16, 0),   # the causal instance
+    (512, 1, False, 128, 256, 6 / 8, 0),
+    # sliding windows on the band's grid: the diagonal and the tile before
+    (512, 1, False, 128, 128, 7 / 16, 128),
+    (512, 1, False, 128, 256, 6 / 8, 200),
+    (1024, 1, False, 256, 128, 16 / 32, 300),
 ])
 def test_computed_tile_share_is_what_the_kernel_runs(
-        length, block, noised, block_q, block_k, share):
+        length, block, noised, block_q, block_k, share, window):
     """The static counter of the tile skipping equals the kernel's own
     products, tile for tile."""
     from tpudist.ops.attention import BlockMask
     from tpudist.ops.flash_attention import computed_tile_share
 
     rows = 2 * length if noised else length
-    mask = BlockMask(block, length if noised else 0)
+    mask = BlockMask(block, length if noised else 0, window)
     assert computed_tile_share(mask, rows, block_q, block_k) == share
     tiles = (rows // block_q) * (rows // block_k)
     assert _computed_tiles(mask, rows, block_q, block_k) == share * tiles
@@ -453,21 +457,38 @@ def test_mask_misuse_is_refused():
         flash_attention(q, k, v, mask=BlockMask(4, 128), block_q=256)
     with pytest.raises(ValueError, match="divide noised_len"):
         BlockMask(8, 12)
+    # a window goes with one copy at block 1
+    with pytest.raises(ValueError, match="causal mask only"):
+        BlockMask(4, window=8)
+    with pytest.raises(ValueError, match="causal mask only"):
+        BlockMask(1, 128, window=8)
+    with pytest.raises(ValueError, match=">= 0"):
+        BlockMask(window=-1)
+    assert not BlockMask(window=8).causal and BlockMask(window=0).causal
 
 
-@pytest.mark.parametrize("block, noised_len, rows", [
-    (4, 256, 512), (128, 256, 512), (256, 256, 512), (1, 128, 256),
-    (32, 256, 512), (64, 0, 512), (3, 0, 384),
+@pytest.mark.parametrize("block, noised_len, rows, window", [
+    (4, 256, 512, 0), (128, 256, 512, 0), (256, 256, 512, 0),
+    (1, 128, 256, 0), (32, 256, 512, 0), (64, 0, 512, 0), (3, 0, 384, 0),
+    # sliding windows: under a tile, across tiles, one key, wider than S
+    (1, 0, 512, 128), (1, 0, 512, 200), (1, 0, 384, 1), (1, 0, 768, 300),
+    (1, 0, 512, 5000),
 ])
-def test_tile_tests_are_the_dense_mask_tile_by_tile(block, noised_len, rows):
+def test_tile_tests_are_the_dense_mask_tile_by_tile(block, noised_len, rows,
+                                                    window):
     """``tile_live`` = some pair of the tile allowed, ``tile_full`` = every
     pair, ``tile_allowed`` = the tile's pairs themselves, each against the
     boolean array of ``allowed`` cut into tiles, for every tile that lies
-    whole in one copy."""
+    whole in one copy; a window's ``allowed`` against ``0 <= i - j < W``
+    written out."""
     from tpudist.ops.attention import BlockMask
 
-    mask = BlockMask(block, noised_len)
+    mask = BlockMask(block, noised_len, window)
     dense = np.asarray(mask.dense(rows))
+    if window:
+        pos = np.arange(rows)
+        np.testing.assert_array_equal(dense, (pos[:, None] >= pos[None, :])
+                                      & (pos[:, None] - pos[None, :] < window))
     for block_q, block_k in ((128, 128), (128, 256), (256, 128)):
         if noised_len % block_q or noised_len % block_k or rows % block_k \
                 or rows % block_q:
@@ -597,6 +618,8 @@ def test_committed_index_maps_fetch_once_a_live_tile(case, monkeypatch):
     streamed = {"fwd": (1, 2), "dkv": (0, 1, 2, 3), "dq": (0, 1)}
     for kernel, (table, names) in sweeps.items():
         tiles = live.T if kernel == "dkv" else live  # [outer, inner]
+        # no window: the square grid, as before the band's
+        assert names.shape[:2] == tiles.shape, kernel
         if tiles.all():
             assert table is None, kernel
         n_outer, n_inner = tiles.shape
@@ -731,3 +754,137 @@ def test_dead_runs_fetch_nothing_and_change_no_bit(block, block_q, block_k,
     monkeypatch.setattr(fa, "_fetch_table", lambda *a: None)
     for a, b in zip(got, kernels()):
         np.testing.assert_array_equal(a, b)
+
+
+# -- the sliding window: BlockMask(window=W) and the band's grid --------------
+
+# (rows, window, block_q, block_k): a window under one tile, one that
+# straddles tiles, one wider than a tile, blocks taller than wide
+_WINDOW_CASES = [(512, 128, 128, 128), (512, 200, 128, 256),
+                 (1024, 300, 256, 128)]
+
+
+@pytest.mark.parametrize("rows, window, block_q, block_k", _WINDOW_CASES)
+def test_sliding_window_matches_dense_masked_attention(rows, window, block_q,
+                                                       block_k):
+    """The windowed kernel (interpret mode) against dense attention under
+    the same mask as a boolean array: output, dq, dk, dv — the scan
+    backward."""
+    from tpudist.ops.attention import BlockMask
+
+    mask = BlockMask(window=window)
+    q, k, v = _qkv(b=1, s=rows, h=2, d=32, seed=rows + window)
+    dense = lambda q, k, v: dot_product_attention(
+        q, k, v, mask=mask.dense(rows)[None, None])
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, mask=mask, block_q=block_q, block_k=block_k)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), _masked_grads(flash, q, k, v),
+                          _masked_grads(dense, q, k, v)):
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("rows, window, block_q, block_k", _WINDOW_CASES)
+def test_sliding_window_pallas_backward_matches_the_scan_backward(
+        rows, window, block_q, block_k):
+    """The Pallas dq / dkv kernels on the band's grid (interpret mode) give
+    the blockwise scan's dq, dk, dv, and the forward on its band the dense
+    output."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import (
+        _bwd_blockwise, _bwd_pallas, _flash_fwd,
+    )
+
+    mask = BlockMask(window=window)
+    rng = np.random.Generator(np.random.PCG64(window))
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
+               for _ in range(3))
+    sm = 1.0 / np.sqrt(128)
+    o, lse = _flash_fwd(q, k, v, mask=mask, sm_scale=sm, block_q=block_q,
+                        block_k=block_k)
+    want_o = dot_product_attention(
+        *(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
+        mask=mask.dense(rows)[None, None]).transpose(0, 2, 1, 3)
+    np.testing.assert_allclose(o, want_o, atol=2e-5, rtol=2e-5)
+    g = jnp.asarray(rng.normal(size=o.shape), jnp.float32)
+    got = _bwd_pallas((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                      block_q=block_q, block_k=block_k, interpret=True)
+    want = _bwd_blockwise((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                          block_k=block_k)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows, window, block_q, block_k", [
+    (8192, 512, 512, 512), (8192, 512, 512, 1024), (8192, 512, 256, 512),
+    *_WINDOW_CASES, (512, 1, 128, 128), (512, 512, 128, 128),
+])
+def test_band_grid_names_each_live_tile_once(rows, window, block_q, block_k,
+                                             monkeypatch):
+    """A windowed call's three grids span the band and no more: the inner
+    axis is the widest sweep's live tiles; every sweep names its live
+    blocks in order, one index change a live tile, then stays on its last
+    (the steps past a narrower band fetch nothing); no fetch table rides
+    along. ``grid_step_share`` is the grids' steps over the square's tiles
+    and ``fetched_tile_share`` the live tiles over the same."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import (
+        computed_tile_share, fetched_tile_share, grid_step_share,
+    )
+
+    mask = BlockMask(window=window)
+    live = _live(mask, None, rows, block_q, block_k)
+    sweeps = _committed_sweeps(monkeypatch, mask, rows, block_q, block_k)
+    streamed = {"fwd": (1, 2), "dkv": (0, 1, 2, 3), "dq": (0, 1)}
+    steps = 0
+    for kernel, (table, names) in sweeps.items():
+        assert table is None, kernel
+        tiles = live.T if kernel == "dkv" else live  # [outer, inner]
+        widest = int(tiles.sum(axis=1).max())
+        assert names.shape[:2] == (tiles.shape[0], widest), kernel
+        steps += names.shape[0] * names.shape[1]
+        resident = [s for s in range(names.shape[2])
+                    if s not in streamed[kernel]]
+        assert (names[:, :, resident]
+                == np.arange(tiles.shape[0])[:, None, None]).all(), kernel
+        for i, row in enumerate(tiles):
+            held = np.flatnonzero(row)
+            assert held.size and (np.diff(held) == 1).all()  # contiguous
+            for s in streamed[kernel]:
+                want = np.concatenate([held, np.full(widest - held.size,
+                                                     held[-1])])
+                np.testing.assert_array_equal(names[i, :, s], want)
+    square = 3 * live.size
+    assert grid_step_share(mask, rows, block_q, block_k) == steps / square
+    assert fetched_tile_share(mask, rows, block_q, block_k) \
+        == computed_tile_share(mask, rows, block_q, block_k) \
+        == live.sum() / live.size
+
+
+def test_band_grid_at_the_cells_shape():
+    """8,192 rows and a window of 512 at the blocks ``default_blocks``
+    takes for it, 512 x 512: 31 of 256 tiles computed and fetched (the
+    window needs 0.0606 of the square), two grid steps a sweep in each
+    kernel — 0.125 of the square's, where the square grid ran 1.0 of them
+    and 225 of a sweep's 256 steps were empty. At 512 x 1024: 23 of 128
+    tiles, 88 of 384 steps."""
+    from tpudist.ops.attention import BlockMask
+    from tpudist.ops.flash_attention import (
+        computed_tile_share, default_blocks, fetched_tile_share,
+        grid_step_share,
+    )
+
+    mask = BlockMask(window=512)
+    blocks = default_blocks(8192, 8192, 128, 512)
+    assert blocks == (512, 512, True)
+    assert default_blocks(8192, 8192, 128) == (512, 1024, True)  # as before
+    assert computed_tile_share(mask, 8192, 512, 512) == 31 / 256
+    assert fetched_tile_share(mask, 8192, 512, 512) == 31 / 256
+    assert grid_step_share(mask, 8192, 512, 512) == 0.125
+    assert computed_tile_share(mask, 8192, 512, 1024) == 23 / 128
+    assert grid_step_share(mask, 8192, 512, 1024) == 88 / 384 <= 0.25
+    need = (512 * 8192 - 512 * 511 / 2) / 8192 ** 2
+    assert 0.0605 < need < 31 / 256
+    for other in (None, CAUSAL, BlockMask(4), BlockMask(4, 4096)):
+        assert grid_step_share(other, 8192, 512, 1024) == 1.0

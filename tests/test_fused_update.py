@@ -110,22 +110,26 @@ def test_leaf_shapes_bit_equal_optax_chain(shape):
     """The cells' regime, leaf by leaf: bf16 gradients (taken with respect
     to the bf16 copy) on f32 masters and moments, clip, masked decay, the
     bf16 copy. Bit-equal to the optax chain given the same gradients —
-    upcast to f32 after the clip, as ``adamw_math`` takes them (optax alone
-    would form ``(1−b1)·g`` and ``g²`` in bf16)."""
+    clipped in f32 and rounded back to bf16, then upcast to f32, as
+    ``adamw_math`` takes them (optax alone would take the norm and form
+    ``(1−b1)·g`` and ``g²`` in bf16)."""
     r = np.random.Generator(np.random.PCG64(3))
     params = {"kernel": jnp.asarray(r.standard_normal(shape), jnp.float32)}
     ftx = fused_adamw(1e-2, weight_decay=0.1, mask=decay_mask, clip_norm=1.0,
                       compute_dtype=jnp.bfloat16)
+    cast = lambda dtype: optax.stateless(lambda u, _: jax.tree_util.tree_map(
+        lambda t: t.astype(dtype), u))
     rtx = optax.chain(
+        cast(jnp.float32),
         optax.clip_by_global_norm(1.0),
-        optax.stateless(lambda u, _: jax.tree_util.tree_map(
-            lambda t: t.astype(jnp.float32), u)),
+        cast(jnp.bfloat16),
+        cast(jnp.float32),
         optax.adamw(1e-2, weight_decay=0.1, mask=decay_mask),
     )
 
     fp, fs = _run(ftx, params, n_steps=3, grad_dtype=jnp.bfloat16)
     rp, rs = _run(rtx, params, n_steps=3, grad_dtype=jnp.bfloat16)
-    adam = rs[2][0]
+    adam = rs[-1][0]
     for got, want in [(fp, rp), (fs.mu, adam.mu), (fs.nu, adam.nu)]:
         assert got["kernel"].dtype == jnp.float32
         np.testing.assert_array_equal(
@@ -136,6 +140,26 @@ def test_leaf_shapes_bit_equal_optax_chain(shape):
         np.asarray(fs.compute["kernel"], np.float32),
         np.asarray(rp["kernel"].astype(jnp.bfloat16), np.float32),
     )
+
+
+def test_clip_of_bfloat16_gradients_takes_the_norm_in_float32():
+    """A norm rounded to bf16 scales every leaf by one error (0.3% here,
+    where 2.006 lies between bf16's 2.0 and 2.016): the clipped gradient's
+    global norm is the clip's to rounding of its elements."""
+    r = np.random.Generator(np.random.PCG64(5))
+    grads = {k: r.standard_normal(shape) for k, shape in
+             [("w", (64, 130)), ("b", (130,))]}
+    scale = 2.006 / np.sqrt(sum(np.sum(g * g) for g in grads.values()))
+    grads = {k: jnp.asarray(g * scale, jnp.bfloat16) for k, g in grads.items()}
+    norm = np.sqrt(sum(np.sum(np.asarray(g, np.float64) ** 2)
+                       for g in grads.values()))
+    assert abs(float(jnp.bfloat16(norm)) - norm) / norm > 2e-3
+    params = {k: jnp.zeros(g.shape, jnp.float32) for k, g in grads.items()}
+    tx = fused_adamw(1e-3, b1=0.9, clip_norm=1.0)
+    _, state = tx.update(grads, tx.init(params), params)
+    clipped = np.sqrt(sum(np.sum(np.asarray(m, np.float64) ** 2)
+                          for m in state.mu.values())) / (1 - 0.9)
+    assert clipped == pytest.approx(1.0, abs=2e-4)
 
 
 def _eqns(jaxpr):

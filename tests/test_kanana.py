@@ -300,9 +300,11 @@ def test_block_scopes_keep_the_trace_contract(setup):
     text = jax.jit(jax.grad(program_loss(model))).lower(
         params, tokens).as_text(debug_info=True)
     # (``cca_*`` are the ZAYA1 block's, ``attn_*`` / ``bd_attn`` the SDAR
-    # block's: test_zaya.py, test_sdar.py)
+    # block's, ``lg_*`` / ``swa_attn`` / ``full_attn`` the Laguna block's:
+    # test_zaya.py, test_sdar.py, test_laguna.py)
     mine = [s for s in BLOCK_SCOPES
-            if not s.startswith(("cca_", "attn_", "bd_"))]
+            if not s.startswith(("cca_", "attn_", "bd_", "lg_", "swa_",
+                                 "full_"))]
     assert len(mine) == 11
     for scope in mine:
         assert f"h_1/{scope}/" in text, scope

@@ -169,6 +169,25 @@ def test_flash_attention_block_diffusion_mask_fwd_bwd_2x4096(compile_for_chip):
     assert hlo.count("tpu_custom_call") >= 3  # forward, dkv, dq
 
 
+@pytest.mark.parametrize("blocks", [(512, 512), (512, 1024)])
+def test_flash_attention_sliding_window_fwd_bwd_s8192(compile_for_chip,
+                                                      blocks):
+    """The Laguna cell's sliding-window call: 8,192 rows under
+    ``BlockMask(window=512)``, forward and both Pallas backward kernels on
+    the band's grid — the inner block offset from the outer one and
+    clamped by ``min`` in the index maps and the kernels — at the blocks
+    ``default_blocks`` takes (512 x 512) and at 512 x 1024. One head: the
+    kernels' code does not depend on the count."""
+    from tpudist.ops.attention import BlockMask
+
+    q = ((1, 8192, 1, 128), BF16)
+    hlo = compile_for_chip(
+        _fwd_bwd(functools.partial(
+            flash_attention, mask=BlockMask(window=512), block_q=blocks[0],
+            block_k=blocks[1])), q, q, q)
+    assert hlo.count("tpu_custom_call") >= 3  # forward, dkv, dq
+
+
 def test_flash_attention_keys_192_values_128_fwd_bwd_s8192(compile_for_chip):
     """The Kanana-2 cell's MLA call: 32 heads, keys of 192, values of 128,
     8192 tokens, the shape's own blocks and the Pallas backward. Mosaic
@@ -648,3 +667,88 @@ def test_sdar_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
         >= 6 * config["num_hidden_layers"]
     assert not any(f"[{pairs}," in kernels[k] for k in grouped)
+
+
+def test_laguna_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
+    """The ``laguna_xs2_train_s8192`` cell's whole train step — its own
+    configuration, traffic and family file, 565.2M parameters, 8,192
+    tokens — compiled for one described chip: it fits beside the harness's
+    copy; each kind's attention kernel is there under the name the trace
+    reader looks for, three a layer — ``full_attn.<k>`` at 48 heads over
+    the square grid, ``swa_attn.<k>`` at 64 heads over the band's grid —;
+    and the grouped products are the compiler's ragged-dot kernels over
+    one chunk of the 65,536 (token, choice) rows of a layer."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import common, laguna as family
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import TrainState, make_train_step
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    monkeypatch.setattr(common, "resolve_attn", lambda requested, seq: "flash")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks/configs/laguna-xs-2.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks/traffic/train_s8192_b1.json")) as f:
+        traffic = json.load(f)
+    mesh = mesh_lib.create_mesh(devices=topo.devices[:1])
+    built = family.build(config, traffic, mesh)
+    everywhere = NamedSharding(mesh, P())
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        tree)
+    params = placed(built["param_shapes"])
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == 565_204_992
+    state = TrainState(
+        step=jax.ShapeDtypeStruct((), I32, sharding=everywhere),
+        params=params, batch_stats={},
+        opt_state=placed(jax.eval_shape(built["tx"].init, params)))
+    kw = built["fit"]
+    step = make_train_step(
+        built["model"], built["tx"], mesh, loss_fn=kw["loss_fn"],
+        input_key="tokens", label_key="tokens",
+        forward_loss=kw["forward_loss"], fused=kw["fused"])
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (traffic["per_chip_batch"], traffic["seq_len"]), I32,
+        sharding=everywhere)}
+    traced = step.jitted.trace(state, batch)
+    assert remat.forward_attention_kernels(traced.jaxpr) \
+        == config["num_hidden_layers"]
+    rows = traffic["seq_len"] * config["num_experts_per_tok"]
+    chunk_rows, n_chunks = ep.row_chunks(
+        rows, config["num_experts_held"], config["num_experts"])
+    lowered = traced.lower()
+    assert set(_expert_stages(lowered)) == {
+        "moe_norm", "moe_router", "moe_dispatch", "moe_experts",
+        "moe_combine", "moe_shared"}
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 7.37 + 5.23 GiB under dots_saveable (3.03 GiB of temporaries under
+    # full), + the harness's 2.26 GB copy <= 15.9 GB of the chip's 16.9
+    assert 8e9 < held < 13.7e9, held
+    hlo = compiled.as_text()
+    kernels = {
+        name: line for line in hlo.splitlines()
+        for name in re.findall(
+            r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            line)}
+    for kind, heads in (("full_attention", 48), ("sliding_attention", 64)):
+        pattern = family.KINDS[kind].ATTENTION_OPS
+        attention = [k for k in kernels if re.match(pattern, k)]
+        layers = config["layer_types"][:config["num_hidden_layers"]].count(kind)
+        # per layer: forward, dkv, dq
+        assert len(attention) == 3 * layers, (kind, sorted(kernels))
+        for k in attention:
+            assert set(re.findall(r"bf16\[1,(\d+),8192,128\]", kernels[k])) \
+                == {str(heads)}, kernels[k][:300]
+    expert_layers = config["num_hidden_layers"] - 1
+    grouped = [k for k in kernels if k.startswith("ragged-dot")]
+    assert len(grouped) >= 9 * expert_layers, len(grouped)
+    assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
+        >= 6 * expert_layers
+    assert not any(f"[{rows}," in kernels[k] for k in grouped)

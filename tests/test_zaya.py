@@ -239,9 +239,12 @@ def test_block_scopes_keep_the_trace_contract(setup):
     text = jax.jit(jax.grad(program_loss(model))).lower(
         params, tokens).as_text(debug_info=True)
     # (``mla_*`` and ``moe_shared`` are the Kanana-2 block's, ``attn_*``
-    # and ``bd_attn`` the SDAR block's: test_kanana.py, test_sdar.py)
+    # and ``bd_attn`` the SDAR block's, ``lg_*``, ``swa_attn`` and
+    # ``full_attn`` the Laguna block's: test_kanana.py, test_sdar.py,
+    # test_laguna.py)
     mine = [s for s in BLOCK_SCOPES
-            if not s.startswith(("mla_", "attn_", "bd_"))
+            if not s.startswith(("mla_", "attn_", "bd_", "lg_", "swa_",
+                                 "full_"))
             and s != "moe_shared"]
     assert len(mine) == 8
     for scope in mine:

@@ -24,9 +24,12 @@ TPU-first choices mirror :mod:`tpudist.models.gpt2`:
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from typing import Any
 
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
 from tpudist.mesh import TENSOR_AXIS
@@ -34,8 +37,45 @@ from tpudist.ops.attention import multi_head_attention
 from tpudist.parallel.tp import partitioned as _partitioned
 
 
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (arXiv:2309.00071) as ``rope_type: yarn`` states
+    it: ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    ``beta_slow`` and the ``attention_factor`` that multiplies cos and sin
+    (HF's ``attention_scaling``). The frequencies are HF's
+    ``_compute_yarn_parameters`` at the rotary width (truncated correction
+    range)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def correction_range(self, dim: int, theta: float) -> tuple[int, int]:
+        """``(low, high)``: the pairs below ``low`` turn more than
+        ``beta_fast`` times over the original context (kept), those past
+        ``high`` fewer than ``beta_slow`` times (interpolated)."""
+        at = lambda turns: dim * math.log(
+            self.original_max_position_embeddings / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+        return (max(math.floor(at(self.beta_fast)), 0),
+                min(math.ceil(at(self.beta_slow)), dim - 1))
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """``float32 [dim / 2]``: ``f_i = theta^(-2i/dim)``, ramp ``r_i =
+        clip((i - low) / (high - low), 0, 1)``, ``f_i (1 - r_i) + (f_i /
+        factor) r_i``. Numpy: a constant of the step, nothing traced."""
+        low, high = self.correction_range(dim, theta)
+        f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                       0.0, 1.0)
+        return (f * (1 - ramp) + f / self.factor * ramp).astype(np.float32)
+
+
 def apply_rope(x, *, theta: float = 10000.0, positions=None,
-               rotary_dim: int | None = None, interleaved: bool = False):
+               rotary_dim: int | None = None, interleaved: bool = False,
+               yarn: Yarn | None = None):
     """Rotary position embedding over ``x: [B, S, H, D]``: rotate-half
     convention (channel ``i`` pairs with ``i + D/2``), or with
     ``interleaved`` adjacent pairs ``(2i, 2i+1)`` turned in place (a
@@ -45,7 +85,10 @@ def apply_rope(x, *, theta: float = 10000.0, positions=None,
     positions — slot-pooled decode, where every cache slot sits at its own
     sequence length). ``rotary_dim`` rotates the first ``rotary_dim``
     channels of every head and passes the rest through untouched (a
-    ``partial_rotary_factor`` < 1); the default is the whole head."""
+    ``partial_rotary_factor`` < 1); the default is the whole head.
+    ``yarn`` takes the frequencies of :class:`Yarn` at the rotary width
+    and multiplies cos and sin by its ``attention_factor`` (the channels
+    passed through are not scaled)."""
     b, s, h, d = x.shape
     if rotary_dim is not None and rotary_dim != d:
         if not 0 < rotary_dim < d or rotary_dim % 2:
@@ -54,10 +97,14 @@ def apply_rope(x, *, theta: float = 10000.0, positions=None,
                 f"size {d}"
             )
         rotated = apply_rope(x[..., :rotary_dim], theta=theta,
-                             positions=positions, interleaved=interleaved)
+                             positions=positions, interleaved=interleaved,
+                             yarn=yarn)
         return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(yarn.inv_freq(d, theta))
     if positions is None:
         positions = jnp.arange(s, dtype=jnp.float32)
     angles = positions[..., :, None] * freqs  # [S, half] or [B, S, half]
@@ -67,6 +114,8 @@ def apply_rope(x, *, theta: float = 10000.0, positions=None,
     else:
         cos = jnp.cos(angles)[None, :, None, :]           # [1, S, 1, half]
         sin = jnp.sin(angles)[None, :, None, :]
+    if yarn is not None and yarn.attention_factor != 1.0:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     if interleaved:
         pairs = x.astype(jnp.float32).reshape(b, s, h, half, 2)
         x1, x2 = pairs[..., 0], pairs[..., 1]

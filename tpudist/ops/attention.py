@@ -47,15 +47,18 @@ class BlockMask:
 
     ``BlockMask()`` is the causal mask (``block`` 1, one copy),
     ``BlockMask(b)`` block-causal, ``BlockMask(b, L)`` over ``2 L`` rows
-    the block-diffusion training mask (BD3-LM, arXiv:2503.09573). It
-    answers two questions, for traced scalars, index vectors and numpy
-    arrays alike: which pairs are allowed (:meth:`allowed`; inside a
-    kernel :meth:`tile_allowed`), and whether a (query tile, key tile)
-    holds any allowed pair (:meth:`tile_live`) or nothing else
+    the block-diffusion training mask (BD3-LM, arXiv:2503.09573), and
+    ``BlockMask(window=W)`` the sliding window: query ``i`` sees key ``j``
+    iff ``0 <= i - j < W`` (a window goes with one copy at ``block`` 1
+    only). It answers two questions, for traced scalars, index vectors
+    and numpy arrays alike: which pairs are allowed (:meth:`allowed`;
+    inside a kernel :meth:`tile_allowed`), and whether a (query tile, key
+    tile) holds any allowed pair (:meth:`tile_live`) or nothing else
     (:meth:`tile_full`) — for tiles that do not straddle ``noised_len``."""
 
     block: int = 1
     noised_len: int = 0
+    window: int = 0
 
     def __post_init__(self):
         if self.block < 1 or self.noised_len < 0 \
@@ -63,10 +66,15 @@ class BlockMask:
             raise ValueError(
                 f"block {self.block} must be >= 1 and divide noised_len "
                 f"{self.noised_len}")
+        if self.window < 0 or (self.window and (
+                self.block != 1 or self.noised_len)):
+            raise ValueError(
+                f"window {self.window} must be >= 0 and goes with the "
+                "causal mask only (block 1, one copy)")
 
     @property
     def causal(self) -> bool:
-        return self.block == 1 and self.noised_len == 0
+        return self.block == 1 and self.noised_len == 0 and not self.window
 
     def _blk(self, pos):
         """Block index of the in-copy positions ``pos``."""
@@ -80,6 +88,8 @@ class BlockMask:
         """Elementwise: may query position ``q_pos`` see key ``k_pos``?"""
         if self.causal:
             return q_pos >= k_pos
+        if self.window:
+            return (q_pos >= k_pos) & (q_pos - k_pos < self.window)
         if not self.noised_len:
             return self._blk(k_pos) <= self._blk(q_pos)
         qn, kn = q_pos < self.noised_len, k_pos < self.noised_len
@@ -128,6 +138,10 @@ class BlockMask:
         ``noised_len``)."""
         if self.causal:
             return ki * block_k <= qi * block_q + (block_q - 1)
+        if self.window:  # some i - j of the tile within [0, W)
+            q0, k0 = qi * block_q, ki * block_k
+            return (k0 <= q0 + (block_q - 1)) \
+                & (q0 - (k0 + block_k - 1) < self.window)
         q0, k0, *rule = self._tile(qi, ki, block_q, block_k)
         return self._tile_rule(
             self._blk(q0), self._blk(q0 + (block_q - 1)),
@@ -136,6 +150,10 @@ class BlockMask:
     def tile_full(self, qi, ki, block_q: int, block_k: int):
         """Is EVERY pair of that tile allowed (so that it needs no
         elementwise mask)?"""
+        if self.window:  # every i - j of the tile within [0, W)
+            q0, k0 = qi * block_q, ki * block_k
+            return (k0 + (block_k - 1) <= q0) \
+                & (q0 + (block_q - 1) - k0 < self.window)
         q0, k0, *rule = self._tile(qi, ki, block_q, block_k)
         return self._tile_rule(
             self._blk(q0 + (block_q - 1)), self._blk(q0),
@@ -146,8 +164,13 @@ class BlockMask:
         tile, from its scalars: two iotas, two shifts and two compares an
         element, where the general form pays for finding each element's
         copy."""
-        q0, k0, strict, same, _ = self._tile(qi, ki, block_q, block_k)
         shape = (block_q, block_k)
+        if self.window:
+            back = qi * block_q - ki * block_k \
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            return (back >= 0) & (back < self.window)
+        q0, k0, strict, same, _ = self._tile(qi, ki, block_q, block_k)
         bq = self._blk(q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
         bk = self._blk(k0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
         return self._tile_rule(bq, bq, bk, bk, strict, same, False)

@@ -30,6 +30,13 @@ Design (FlashAttention-2 style, TPU-first):
   two-level way (ragged caller shapes are padded to the 128-tile multiple
   by the wrapper). :func:`computed_tile_share` is the static counter of
   what the first level leaves;
+- a sliding window (``BlockMask(window=W)``) leaves a BAND of live tiles,
+  a constant number a sweep: its grid's inner axis spans only the band
+  (:func:`_band`: ``first(outer) + j``, clamped at the band's last block
+  by the index map, so that a step past it fetches nothing), and the
+  empty grid steps are bounded by the band, not by ``S²``;
+  :func:`grid_step_share` is its counter. Every other mask keeps the
+  square grid;
 - two backward paths, both O(S·block) memory, recomputing p from the saved
   log-sum-exp: a blockwise ``lax.scan`` in plain JAX and the Pallas FA-2
   dq/dkv kernels. :func:`default_blocks` decides by shape (since PR 28):
@@ -62,7 +69,7 @@ DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 
 
-def default_blocks(seq_q: int, seq_k: int, head_dim: int):
+def default_blocks(seq_q: int, seq_k: int, head_dim: int, window: int = 0):
     """``(block_q, block_k, pallas_bwd)`` for a call that names none, from
     the (128-padded) sequence lengths and the head size (the VALUE width
     where keys are wider: latent attention's keys of 192 on values of 128
@@ -71,14 +78,18 @@ def default_blocks(seq_q: int, seq_k: int, head_dim: int):
     causal, bf16: forward + backward 6.9 ms with blocks 512 x 1024 and the
     Pallas backward against 26.7 ms with 128 x 128 and the scan backward —
     so heads of 128 from 2048 tokens on take the largest of those blocks
-    that divide the sequence. Every other shape keeps 128 x 128 and the
-    scan backward (at head size 64 and up to 4096 tokens the scan was the
-    faster one)."""
+    that divide the sequence. Under a sliding ``window`` of at most 512
+    the K blocks are as tall as the Q blocks (512 x 512: 31 of 256 tiles
+    computed at 8,192 rows and a window of 512, where 512 x 1024 computes
+    23 of 128, half again the pairs; measured on a v5e chip, PERF.md §6).
+    Every other shape keeps 128 x 128 and the scan backward (at head size
+    64 and up to 4096 tokens the scan was the faster one)."""
     if head_dim == 128 and min(seq_q, seq_k) >= 2048:
         fit = lambda most, seq: next(
             b for b in (most, most // 2, most // 4, 128) if seq % b == 0
         )
-        return fit(512, seq_q), fit(1024, seq_k), True
+        widest_k = 512 if 0 < window <= 512 else 1024
+        return fit(512, seq_q), fit(widest_k, seq_k), True
     return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K, False
 
 
@@ -109,33 +120,101 @@ def computed_tile_share(mask: BlockMask | None, seq_len: int, block_q: int,
         mask.tile_live(qi, ki, block_q, block_k))))
 
 
+def _kernel_sweeps(seq_len: int, block_q: int, block_k: int):
+    """``(n_outer, n_inner, dkv)`` of the three kernels' grids: forward
+    and dq sweep K blocks a Q block, dkv Q blocks a K block."""
+    n_q, n_k = seq_len // block_q, seq_len // block_k
+    return ((n_q, n_k, False), (n_q, n_k, False), (n_k, n_q, True))
+
+
 def fetched_tile_share(mask: BlockMask | None, seq_len: int, block_q: int,
                        block_k: int) -> float:
-    """Share of the kernels' grid steps that fetch their streamed block
-    under ``mask`` at these blocks: K / V in the forward and dq kernels,
-    Q / dO / lse / delta in the dkv kernel. The committed index maps
-    (:func:`_sweep_maps` over :func:`_fetch_table`) are evaluated over one
-    (batch, head) of each kernel's grid; a step fetches where its block
-    differs from the previous step's of the same sweep, and a sweep's first
-    step always does (the pipeline may also keep a block across two sweeps:
-    not counted). Dead steps name the next live block, so each sweep fetches
-    once a live tile: at 8,192 rows and 512 x 1024, 0.375 under
-    ``BlockMask(4, 4096)`` and 0.5625 causal, where every step fetched
-    before; 1.0 without a mask."""
-    n_q, n_k = seq_len // block_q, seq_len // block_k
-    fetched = steps = 0
-    # forward and dq sweep K blocks a Q block, dkv Q blocks a K block
-    for n_outer, n_inner, dkv in ((n_q, n_k, False), (n_q, n_k, False),
-                                  (n_k, n_q, True)):
+    """Fetches of the streamed block by the three kernels' grid steps under
+    ``mask`` at these blocks, over the ``(S / block_q) (S / block_k)``
+    tiles each kernel has: K / V in the forward and dq kernels, Q / dO /
+    lse / delta in the dkv kernel. The committed index maps
+    (:func:`_sweep_maps` over :func:`_fetch_table` or :func:`_band`) are
+    evaluated over one (batch, head) of each kernel's grid; a step fetches
+    where its block differs from the previous step's of the same sweep,
+    and a sweep's first step always does (the pipeline may also keep a
+    block across two sweeps: not counted). Dead steps name the next live
+    block, so each sweep fetches once a live tile: at 8,192 rows and 512 x
+    1024, 0.375 under ``BlockMask(4, 4096)`` and 0.5625 causal, where every
+    step fetched before; 1.0 without a mask."""
+    fetched = tiles = 0
+    for n_outer, n_inner, dkv in _kernel_sweeps(seq_len, block_q, block_k):
+        band = _band(mask, n_outer, n_inner, block_q, block_k, dkv)
         table = _fetch_table(mask, None, n_outer, n_inner, block_q, block_k,
                              dkv)
-        _, at_inner = _sweep_maps(table, n_inner)
+        _, at_inner = _sweep_maps(table, n_inner, band)
         tbl = () if table is None else (table.ravel(),)
         for i in range(n_outer):
-            names = [at_inner(0, 0, i, j, *tbl) for j in range(n_inner)]
+            names = [at_inner(0, 0, i, j, *tbl)[2]
+                     for j in range(band[0] if band else n_inner)]
             fetched += 1 + sum(a != b for a, b in zip(names, names[1:]))
-        steps += n_outer * n_inner
-    return fetched / steps
+        tiles += n_outer * n_inner
+    return fetched / tiles
+
+
+def grid_step_share(mask: BlockMask | None, seq_len: int, block_q: int,
+                    block_k: int) -> float:
+    """The three kernels' grid steps over their ``(S / block_q) (S /
+    block_k)`` tiles each: 1.0 but under a sliding window, whose grid
+    spans the band (:func:`_band`) — at 8,192 rows and a window of 512,
+    88 / 384 = 0.229 at blocks 512 x 1024, 0.125 at 512 x 512."""
+    steps = tiles = 0
+    for n_outer, n_inner, dkv in _kernel_sweeps(seq_len, block_q, block_k):
+        band = _band(mask, n_outer, n_inner, block_q, block_k, dkv)
+        steps += n_outer * (band[0] if band else n_inner)
+        tiles += n_outer * n_inner
+    return steps / tiles
+
+
+def _imin(a, b):
+    """``min`` of a traced scalar (an index map's, a kernel's) or of a
+    Python int (a counter's, evaluated at trace time)."""
+    return min(a, b) if isinstance(a, (int, np.integer)) else jnp.minimum(a, b)
+
+
+def _imax(a, b):
+    return max(a, b) if isinstance(a, (int, np.integer)) else jnp.maximum(a, b)
+
+
+@functools.lru_cache(maxsize=None)
+def _band(mask, n_outer, n_inner, block_q, block_k, dkv):
+    """The inner axis of a windowed call's grid, ``(steps, first, last)``,
+    or ``None`` for any other mask (the square grid). Under ``0 <= i - j <
+    W`` the live inner blocks of a sweep are contiguous, from
+    ``first(outer)`` to ``last(outer)``: a Q block sees the keys from ``W
+    - 1`` before its first row to its last row (forward, dq), a K block is
+    seen by the rows from its first key to ``W - 1`` after its last (dkv).
+    Grid step ``j`` works on inner block ``first(outer) + j``; ``steps``
+    is the widest band, so a narrower sweep (the sequence's first blocks,
+    the last ones in dkv) ends on dead steps, which the index map clamps
+    to ``last(outer)``: the block already resident, nothing fetched.
+    ``first`` and ``last`` take a traced scalar or a Python int."""
+    if mask is None or not mask.window:
+        return None
+    w = mask.window
+    if dkv:
+        first = lambda o: o * block_k // block_q
+        last = lambda o: _imin((o * block_k + block_k + w - 2) // block_q,
+                               n_inner - 1)
+    else:  # a masked call has as many keys as rows: last < n_inner
+        first = lambda o: _imax(o * block_q - (w - 1), 0) // block_k
+        last = lambda o: (o * block_q + block_q - 1) // block_k
+    steps = max(last(o) - first(o) + 1 for o in range(n_outer))
+    return steps, first, last
+
+
+def _inner(band, outer, j):
+    """The inner block grid step ``j`` of a sweep works on, and whether it
+    lies in its sweep's band (``True``: the square grid)."""
+    if band is None:
+        return j, True
+    _, first, last = band
+    block = first(outer) + j
+    return block, block <= last(outer)
 
 
 def _tile_live(mask, kv_len, qi, ki, block_q, block_k):
@@ -163,7 +242,10 @@ def _fetch_table(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
     live tile (a ``kv_len`` that retires a whole K block: init zeroes its
     dk / dv) names block 0. :func:`_tile_live` decides, the test the
     bodies run, here on numpy grids at trace time (numpy throughout: no
-    JAX op runs)."""
+    JAX op runs). A windowed call needs none: its grid spans the band
+    (:func:`_band`), whose index map clamps the steps past it."""
+    if mask is not None and mask.window:
+        return None
     outer = np.arange(n_outer)[:, None]
     inner = np.arange(n_inner)[None, :]
     qi, ki = (inner, outer) if dkv else (outer, inner)
@@ -181,12 +263,17 @@ def _fetch_table(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
     return table
 
 
-def _sweep_maps(table, n_inner):
+def _sweep_maps(table, n_inner, band=None):
     """Index maps of a ``(b, h, outer, inner)`` grid: the resident block's
     (the outer index) and the streamed block's — the inner index, or where
     there is a fetch table, what it names (read from scalar memory,
-    flattened)."""
+    flattened), or on a band's grid (:func:`_band`) the step's block,
+    clamped at the band's last."""
     at_outer = lambda b, h, i, j, *_: (b, h, i, 0)
+    if band is not None:
+        _, first, last = band
+        return at_outer, lambda b, h, i, j: (
+            b, h, _imin(first(i) + j, last(i)), 0)
     if table is None:
         return at_outer, lambda b, h, i, j: (b, h, j, 0)
     return at_outer, lambda b, h, i, j, tbl: (b, h, tbl[i * n_inner + j], 0)
@@ -248,15 +335,21 @@ def _tile_keep(mask, kv_len, qi, ki, block_q, block_k):
     return keep
 
 
-def _when_live(mask, kv_len, qi, ki, block_q, block_k, compute):
+def _when_live(mask, kv_len, qi, ki, block_q, block_k, compute,
+               in_band=True):
     """Run ``compute(mask)`` for tile ``(qi, ki)`` if it has anything to
-    compute. Under a mask other than the causal one the body is traced
-    twice: a tile whose EVERY pair is allowed (``BlockMask.tile_full``:
-    half of the live tiles under the block-diffusion mask) takes the
-    branch without the elementwise mask — a tile's score work is VPU-bound
-    and the mask is a good part of it."""
+    compute (and, on a band's grid, lies in its sweep's band: ``in_band``).
+    Under a mask other than the causal one the body is traced twice: a
+    tile whose EVERY pair is allowed (``BlockMask.tile_full``: half of the
+    live tiles under the block-diffusion mask) takes the branch without
+    the elementwise mask — a tile's score work is VPU-bound and the mask
+    is a good part of it. A window narrower than a tile's diagonal span
+    (``W < block_q + block_k - 1``) leaves no tile full: one trace."""
     live = _tile_live(mask, kv_len, qi, ki, block_q, block_k)
-    if mask is None or mask.causal or kv_len is not None:
+    if in_band is not True:
+        live &= in_band
+    if mask is None or mask.causal or kv_len is not None or (
+            mask.window and mask.window < block_q + block_k - 1):
         pl.when(live)(lambda: compute(mask))
         return
     full = mask.tile_full(qi, ki, block_q, block_k)
@@ -269,13 +362,14 @@ def _fwd_kernel(
     o_ref, lse_ref,       # [1,1,bq,dv], [1,1,bq,128] (lane-padded, see _flash_fwd)
     m_scr, l_scr, acc_scr,  # VMEM f32: [bq,128], [bq,128], [bq,dv]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None,
+    kv_len: int | None = None, band=None,
 ):
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     nk = pl.num_programs(3)
+    ki, in_band = _inner(band, qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -315,9 +409,9 @@ def _fwd_kernel(
         )
         acc_scr[...] = acc_scr[...] * alpha + pv
 
-    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = l_scr[:, :1]
         # guard fully-masked rows (no mask here has one, but it keeps the
@@ -345,11 +439,12 @@ def _flash_fwd(q, k, v, *, mask, sm_scale, block_q, block_k, kv_len=None):
             f"seq_k={s_k}, block_q={block_q}, block_k={block_k}"
         )
     n_q, n_k = s_q // block_q, s_k // block_k
-    grid = (b, h, n_q, n_k)
+    band = _band(mask, n_q, n_k, block_q, block_k, False)
+    grid = (b, h, n_q, band[0] if band else n_k)
 
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, mask=mask,
-        block_q=block_q, block_k=block_k, kv_len=kv_len,
+        block_q=block_q, block_k=block_k, kv_len=kv_len, band=band,
     )
     # lse rides a lane-padded [b,h,s_q,128] buffer: a [*, *, bq] block would
     # put a size-1 dim in the sublane slot, which Mosaic's (8,128) tiling
@@ -359,7 +454,7 @@ def _flash_fwd(q, k, v, *, mask, sm_scale, block_q, block_k, kv_len=None):
         jax.ShapeDtypeStruct((b, h, s_q, 128), jnp.float32),
     ]
     table = _fetch_table(mask, kv_len, n_q, n_k, block_q, block_k, False)
-    at_q, at_k = _sweep_maps(table, n_k)
+    at_q, at_k = _sweep_maps(table, n_k, band)
     o, lse = _pallas(
         kernel, table,
         grid=grid,
@@ -414,14 +509,15 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref,                      # [1,1,bk,d], [1,1,bk,dv]
     dk_scr, dv_scr,                      # VMEM f32 [bk,d], [bk,dv]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None,
+    kv_len: int | None = None, band=None,
 ):
     """dk/dv: K/V block resident, sweep over Q blocks (grid dim 3)."""
     ki = pl.program_id(2)
-    qi = pl.program_id(3)
+    step = pl.program_id(3)
     nq = pl.num_programs(3)
+    qi, in_band = _inner(band, ki, step)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -447,9 +543,9 @@ def _bwd_dkv_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -461,14 +557,15 @@ def _bwd_dq_kernel(
     dq_ref,                              # [1,1,bq,d]
     dq_scr,                              # VMEM f32 [bq,d]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None,
+    kv_len: int | None = None, band=None,
 ):
     """dq: Q block resident, sweep over K blocks (grid dim 3)."""
     qi = pl.program_id(2)
-    ki = pl.program_id(3)
+    step = pl.program_id(3)
     nk = pl.num_programs(3)
+    ki, in_band = _inner(band, qi, step)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -486,9 +583,9 @@ def _bwd_dq_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute)
+    _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -518,9 +615,11 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
 
     # q, k, dq, dk are d wide; v, o, do, dv are d_v wide. Each grid is
     # (b, h, i, j): i the resident block, j the streamed one, which a fetch
-    # table steers past the dead steps (dkv: i = k block, j = q block)
+    # table steers past the dead steps, or a window's band offsets from i
+    # (dkv: i = k block, j = q block)
+    dkv_band = _band(mask, nk, nq, block_q, block_k, True)
     dkv_table = _fetch_table(mask, kv_len, nk, nq, block_q, block_k, True)
-    at_i, at_j = _sweep_maps(dkv_table, nq)
+    at_i, at_j = _sweep_maps(dkv_table, nq, dkv_band)
     kspec = pl.BlockSpec((1, 1, block_k, d), at_i)
     vspec = pl.BlockSpec((1, 1, block_k, d_v), at_i)
     qspec_j = pl.BlockSpec((1, 1, block_q, d), at_j)
@@ -530,10 +629,10 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
     dk, dv = _pallas(
         functools.partial(
             _bwd_dkv_kernel, sm_scale=sm_scale, mask=mask,
-            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            block_q=block_q, block_k=block_k, kv_len=kv_len, band=dkv_band,
         ),
         dkv_table,
-        grid=(b, h, nk, nq),
+        grid=(b, h, nk, dkv_band[0] if dkv_band else nq),
         in_specs=[qspec_j, dospec_j, rspec_j, rspec_j, kspec, vspec],
         out_specs=[kspec, vspec],
         out_shape=[
@@ -547,9 +646,10 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
         interpret=interpret,
     )(q, do, lse_c, delta, k, v)
 
-    # dq grid: i = q block, j = k block, the forward's table
+    # dq grid: i = q block, j = k block, the forward's table or band
+    dq_band = _band(mask, nq, nk, block_q, block_k, False)
     dq_table = _fetch_table(mask, kv_len, nq, nk, block_q, block_k, False)
-    at_i, at_j = _sweep_maps(dq_table, nk)
+    at_i, at_j = _sweep_maps(dq_table, nk, dq_band)
     qspec = pl.BlockSpec((1, 1, block_q, d), at_i)
     dospec = pl.BlockSpec((1, 1, block_q, d_v), at_i)
     rspec_i = pl.BlockSpec((1, 1, block_q, 1), at_i)
@@ -558,10 +658,10 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
     dq = _pallas(
         functools.partial(
             _bwd_dq_kernel, sm_scale=sm_scale, mask=mask,
-            block_q=block_q, block_k=block_k, kv_len=kv_len,
+            block_q=block_q, block_k=block_k, kv_len=kv_len, band=dq_band,
         ),
         dq_table,
-        grid=(b, h, nq, nk),
+        grid=(b, h, nq, dq_band[0] if dq_band else nk),
         in_specs=[kspec_j, vspec_j, qspec, dospec, rspec_i, rspec_i],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -681,7 +781,9 @@ def flash_attention(
     BlockMask`); ``causal=True`` is its instance ``BlockMask()``, and the
     two lower to the same kernels. A mask over a noised and a clean copy
     fixes where the second copy starts, so its ``noised_len`` must be a
-    multiple of 128 (nothing is padded) and the blocks divide it.
+    multiple of 128 (nothing is padded) and the blocks divide it. A
+    sliding window (``BlockMask(window=W)``) runs on the band's grid
+    (:func:`_band`).
 
     Unaligned S is padded to the 128-tile multiple: padded KEYS are masked
     inside the kernels (``kv_len`` — also passable explicitly for
@@ -736,7 +838,8 @@ def flash_attention(
     # blocks follow the shape; under a mask over two copies they divide
     # one copy, so that no tile straddles the two (BlockMask.tile_live)
     copy = mask.noised_len if mask is not None and mask.noised_len else None
-    auto = default_blocks(copy or q.shape[1], copy or k.shape[1], d_v)
+    auto = default_blocks(copy or q.shape[1], copy or k.shape[1], d_v,
+                          mask.window if mask is not None else 0)
     block_q, block_k, pallas_bwd = (
         given if given is not None else chosen
         for given, chosen in zip((block_q, block_k, pallas_bwd), auto)

@@ -521,13 +521,18 @@ def fused_adamw(
         if clip_norm is not None:
             # optax.clip_by_global_norm's exact arithmetic (divide by the
             # norm, then scale by the max) so the fused chain stays
-            # bit-compatible with the unfused one
+            # bit-compatible with the unfused one. Gradients in a compute
+            # dtype (bfloat16) are summed and scaled in float32: a norm
+            # rounded to bfloat16 scales every leaf by the same error, up
+            # to 2^-9, and a sum of squares kept in it loses more
             with jax.named_scope("grad_clip"):
-                g_norm = optax.global_norm(grads)
+                wide = lambda t: t.astype(jnp.promote_types(t.dtype,
+                                                             jnp.float32))
+                g_norm = optax.global_norm(jax.tree_util.tree_map(wide, grads))
                 grads = jax.tree_util.tree_map(
                     lambda t: jnp.where(
                         g_norm < clip_norm, t,
-                        (t / g_norm.astype(t.dtype)) * clip_norm,
+                        ((wide(t) / g_norm) * clip_norm).astype(t.dtype),
                     ),
                     grads,
                 )

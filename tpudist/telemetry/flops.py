@@ -204,6 +204,47 @@ def sdar_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
             * num_heads * head_dim)
 
 
+def window_pairs(seq: int, window: int | None) -> int:
+    """(query, key) pairs a head of one sequence attends to: the causal
+    triangle ``S (S + 1) / 2``, or under a sliding window ``sum_i min(i +
+    1, W)``."""
+    if not window or window >= seq:
+        return seq * (seq + 1) // 2
+    return window * seq - window * (window - 1) // 2
+
+
+def laguna_train_flops(tokens: float, *, hidden: int, depth: int,
+                       dense_layers: int, vocab: int, seq: int,
+                       heads_per_layer, windows, num_kv_heads: int,
+                       head_dim: int, dense_ffn_dim: int, ffn_dim: int,
+                       shared_dim: int, num_experts: int, top_k: int,
+                       held_share: float) -> float:
+    """Laguna geometry (tpudist.models.laguna): layer ``l`` has
+    ``heads_per_layer[l]`` query heads and attends causally (``windows[l]``
+    ``None``) or in a sliding window. Weights a token, 6x: the fused q/k/v,
+    the output gate and the output projection of every layer, a dense
+    SwiGLU in the leading layers, in the others the fp32 router GEMM H·E,
+    the shared expert (3·H·shared) and ``top_k`` routed SwiGLU experts at
+    the share of the experts this shard HOLDS; un-tied head V·H. Attention
+    at the pairs each layer NEEDS (:func:`window_pairs`: the causal
+    triangle, or the window's band): QK^T and PV, three passes, ``12 ·
+    head_dim`` a pair a head."""
+    weights = vocab * hidden
+    attention = 0.0
+    for layer in range(depth):
+        h = heads_per_layer[layer]
+        weights += (hidden * (h + 2 * num_kv_heads) * head_dim
+                    + 2 * h * head_dim * hidden)
+        if layer < dense_layers:
+            weights += 3 * hidden * dense_ffn_dim
+        else:
+            weights += (hidden * num_experts + 3 * hidden * shared_dim
+                        + top_k * held_share * 3 * hidden * ffn_dim)
+        attention += h * window_pairs(seq, windows[layer])
+    return (6.0 * tokens * weights
+            + 12.0 * head_dim * attention * tokens / seq)
+
+
 def bert_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
                      seq: int) -> float:
     """BERT MLM: 12·H² encoder blocks + the MLM head's H² transform and
@@ -360,6 +401,24 @@ def train_step_flops(model: Any, batch: Mapping[str, Any], *,
             held_share=routing.held_range[1] / routing.num_experts,
             block_length=model.block_length,
         )
+    if family == "laguna":
+        seq = shape[-1]
+        routing = model.routing
+        from tpudist.models.laguna import SLIDING
+
+        return laguna_train_flops(
+            _rows(shape, 1) * seq, hidden=model.hidden_dim,
+            depth=model.depth, dense_layers=model.dense_layers,
+            vocab=model.vocab_size, seq=seq,
+            heads_per_layer=model.heads_per_layer,
+            windows=[model.window if kind == SLIDING else None
+                     for kind in model.layer_types],
+            num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
+            dense_ffn_dim=model.dense_ffn_dim, ffn_dim=model.ffn_dim,
+            shared_dim=model.shared_dim, num_experts=routing.num_experts,
+            top_k=routing.top_k,
+            held_share=routing.held_range[1] / routing.num_experts,
+        )
     if family == "bert":
         seq = shape[-1]
         return bert_train_flops(
@@ -399,7 +458,7 @@ def tokens_per_step(model: Any, batch: Mapping[str, Any], *,
     except (KeyError, AttributeError):
         return None
     if family in ("gpt2", "llama", "bert", "gpt2_moe", "llama_moe",
-                  "kanana", "sdar"):
+                  "kanana", "sdar", "laguna"):
         return _rows(shape, 1) * shape[-1]
     if family in ("vit", "resnet"):
         return _rows(shape, 3)

@@ -135,16 +135,17 @@ STEP_SCOPES = {
 
 # Device scopes inside a block of ``tpudist.models.zaya`` (``cca_*``), of
 # ``tpudist.models.kanana`` (``mla_*``, ``moe_shared``) and of
-# ``tpudist.models.sdar`` (``attn_*``, ``bd_attn``), the ``moe_*`` of
-# ``parallel/ep.py`` ``dropless_moe`` in all three — flax module
-# names and ``jax.named_scope``s (metadata only), direct children of the
-# block ``h_<n>`` so that a trace reader that folds an op's name stack to
-# its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
+# ``tpudist.models.sdar`` (``attn_*``, ``bd_attn``) and of
+# ``tpudist.models.laguna`` (``lg_*``, ``swa_attn``, ``full_attn``), the
+# ``moe_*`` of ``parallel/ep.py`` ``dropless_moe`` in all four — flax
+# module names and ``jax.named_scope``s (metadata only), direct children of
+# the block ``h_<n>`` so that a trace reader that folds an op's name stack
+# to its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
 # them apart — each with the benchmark metric that reads it
-# (docs/OBSERVABILITY.md §8; tests/test_zaya.py, tests/test_kanana.py and
-# tests/test_sdar.py hold the models to them; ``moe_topk_ms`` reads the
-# four ``moe_ms`` stages in the Kanana-2 cell, ``bd_moe_ms`` in the SDAR
-# cell):
+# (docs/OBSERVABILITY.md §8; tests/test_zaya.py, tests/test_kanana.py,
+# tests/test_sdar.py and tests/test_laguna.py hold the models to them;
+# ``moe_topk_ms`` reads the four ``moe_ms`` stages in the Kanana-2 cell,
+# ``bd_moe_ms`` in the SDAR cell):
 BLOCK_SCOPES = {
     "cca_proj": "cca_mix_ms",    # CCA's down-projection (q, k, v_a, v_b)
     "cca_mix": "cca_mix_ms",     # q-k mean, convolutions, norms, rotary, value shift
@@ -166,6 +167,12 @@ BLOCK_SCOPES = {
     "attn_rope": "bd_proj_ms",   # rotary on q and k at the rows' positions
     "bd_attn": "bd_attn_roofline",  # the masked attention call; its kernel is ``bd_attn.<k>``
     "attn_out": "bd_proj_ms",    # SDAR's output projection
+    "lg_qkv": "lg_proj_ms",      # Laguna's fused q/k/v projection (either kind)
+    "lg_gate": "lg_proj_ms",     # the output gate's projection and sigmoid
+    "lg_rope": "lg_proj_ms",     # rotary: YaRN on half a head (full), plain (sliding)
+    "swa_attn": "swa_attn_roofline",  # the sliding-window call; its kernel is ``swa_attn.<k>``
+    "full_attn": "full_attn_roofline",  # the full causal call; its kernel is ``full_attn.<k>``
+    "lg_out": "lg_proj_ms",      # the gate's product and the output projection
 }
 # Counters the dropless expert layer sows into ``moe_stats`` (a ``moe`` row
 # field ``h_<n>/<counter>`` a logged step), each with its metric:
