@@ -524,23 +524,24 @@ def test_block_causal_mask_on_a_ragged_sequence():
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
 
-# -- the fetch table: dead grid steps name the next live block ----------------
+# -- the live-step grid: a static list of the live tiles ----------------------
 
 
 def _committed_sweeps(monkeypatch, mask, rows, block_q, block_k, kv_len=None):
     """What each of the three ``pallas_call``s is given, traced at these
     shapes (one batch row, one head; ``jax.eval_shape``: nothing runs):
-    ``{kernel: (table, names)}`` with ``names[i, j]`` the block index the
-    call's committed index maps name for each of its in_specs at grid step
-    ``(0, 0, i, j)``."""
+    ``{kernel: (steps, names)}`` with ``steps`` its step list (or ``None``)
+    and ``names[pos]`` the block index the call's committed index maps name
+    for each of its in_specs at grid step ``(0, 0, *pos)`` — ``pos`` one
+    index on the live-step grid, two on the square and the band's."""
     from tpudist.ops import flash_attention as fa
 
     seen = []
     real = fa._pallas
 
-    def spy(kernel, table, **kw):
-        seen.append((table, kw))
-        return real(kernel, table, **kw)
+    def spy(kernel, steps, **kw):
+        seen.append((steps, kw))
+        return real(kernel, steps, **kw)
 
     monkeypatch.setattr(fa, "_pallas", spy)
     q = jax.ShapeDtypeStruct((1, 1, rows, 128), jnp.bfloat16)
@@ -552,13 +553,13 @@ def _committed_sweeps(monkeypatch, mask, rows, block_q, block_k, kv_len=None):
         (q, q, q, q, lse), q, mask=mask, sm_scale=1.0, block_q=block_q,
         block_k=block_k, kv_len=kv_len, interpret=True), q, lse)
     sweeps = {}
-    for name, (table, kw) in zip(("fwd", "dkv", "dq"), seen):
-        _, _, n_outer, n_inner = kw["grid"]
-        tbl = () if table is None else (table.ravel(),)
-        sweeps[name] = table, np.array(
-            [[[int(spec.index_map(0, 0, i, j, *tbl)[2])
-               for spec in kw["in_specs"]] for j in range(n_inner)]
-             for i in range(n_outer)])
+    for name, (steps, kw) in zip(("fwd", "dkv", "dq"), seen):
+        dims = kw["grid"][2:]
+        tbl = () if steps is None else (steps.ravel(),)
+        sweeps[name] = steps, np.array(
+            [[int(spec.index_map(0, 0, *pos, *tbl)[2])
+              for spec in kw["in_specs"]] for pos in np.ndindex(*dims)]
+        ).reshape(*dims, len(kw["in_specs"]))
     return sweeps
 
 
@@ -573,6 +574,22 @@ def _live(mask, kv_len, rows, block_q, block_k):
     if kv_len is not None:
         live &= ki * block_k < kv_len
     return live
+
+
+def _want_steps(tiles):
+    """The live-step list a kernel should carry for its ``[outer, inner]``
+    live tiles, written out sweep by sweep: each live tile once, in the
+    square grid's order, flagged at its sweep's ends; a sweep with none
+    one step, opening and closing it, on the block the step before
+    named."""
+    want = []
+    for i, row in enumerate(tiles):
+        held = np.flatnonzero(row)
+        if not held.size:
+            want.append((i, want[-1][1] if want else 0, 1, 1))
+        for j in held:
+            want.append((i, j, j == held[0], j == held[-1]))
+    return np.array(want, np.int32)
 
 
 # (mask, rows, block_q, block_k, kv_len): the three cells' shapes, then
@@ -603,51 +620,62 @@ def _mask_of(spec):
 @pytest.mark.parametrize("case", list(_SWEEP_CASES))
 def test_committed_index_maps_fetch_once_a_live_tile(case, monkeypatch):
     """Every committed index map over the whole grid of the forward, dkv
-    and dq kernels: the resident operands name the outer block; the
-    streamed ones (K / V; Q / dO / lse / delta in dkv) name their own block
-    at every live step, the next live block at a dead step before one (so
-    a dead run before a sweep's first live tile names that tile) and the
-    last live block after it; a sweep changes its streamed index once a
-    live tile (once where it has none); an unmasked call carries no
-    table."""
+    and dq kernels. A masked call runs on the live-step grid: every step
+    names a live tile, each live tile exactly once, in the square grid's
+    sweep order, the first / last flags at the sweep's ends, a sweep with
+    no live tile one flagged step; the resident operands name the list's
+    outer block, the streamed ones (K / V; Q / dO / lse / delta in dkv) its
+    inner block, so a sweep changes its streamed index once a live tile.
+    An unmasked call carries no list and runs on the square grid. Building
+    the list stages no JAX op."""
     spec, rows, block_q, block_k, kv_len = _SWEEP_CASES[case]
     mask = _mask_of(spec)
     live = _live(mask, kv_len, rows, block_q, block_k)
     sweeps = _committed_sweeps(monkeypatch, mask, rows, block_q, block_k,
                                kv_len)
     streamed = {"fwd": (1, 2), "dkv": (0, 1, 2, 3), "dq": (0, 1)}
-    for kernel, (table, names) in sweeps.items():
+    for kernel, (steps, names) in sweeps.items():
         tiles = live.T if kernel == "dkv" else live  # [outer, inner]
-        # no window: the square grid, as before the band's
-        assert names.shape[:2] == tiles.shape, kernel
-        if tiles.all():
-            assert table is None, kernel
-        n_outer, n_inner = tiles.shape
-        resident = [s for s in range(names.shape[2])
+        resident = [s for s in range(names.shape[-1])
                     if s not in streamed[kernel]]
-        assert (names[:, :, resident]
-                == np.arange(n_outer)[:, None, None]).all(), kernel
-        inner = names[:, :, streamed[kernel][0]]
+        if tiles.all():  # the square grid, as before the list
+            assert steps is None and names.shape[:2] == tiles.shape, kernel
+            assert (names[:, :, resident]
+                    == np.arange(tiles.shape[0])[:, None, None]).all()
+            for s in streamed[kernel]:
+                assert (names[:, :, s] == np.arange(tiles.shape[1])).all()
+            continue
+        np.testing.assert_array_equal(steps, _want_steps(tiles),
+                                      err_msg=kernel)
+        outer, inner, first, last = steps.T
+        assert names.shape == (len(steps), len(streamed[kernel])
+                               + len(resident)), kernel
+        for s in resident:
+            np.testing.assert_array_equal(names[:, s], outer)
         for s in streamed[kernel]:
-            np.testing.assert_array_equal(names[:, :, s], inner)
-        for i in range(n_outer):
-            held = np.flatnonzero(tiles[i])
-            for j in range(n_inner):
-                later = held[held >= j]
-                want = (j if tiles[i, j] else later[0] if later.size
-                        else held[-1] if held.size else 0)
-                assert inner[i, j] == want, (kernel, i, j)
-            changes = 1 + int(np.sum(inner[i, 1:] != inner[i, :-1]))
-            assert changes == max(held.size, 1), (kernel, i)
+            np.testing.assert_array_equal(names[:, s], inner)
+        # each live tile named once; the other steps open and close a
+        # sweep with no live tile, one a sweep
+        at_live = tiles[outer, inner]
+        assert at_live.sum() == tiles.sum() == len(
+            {(o, i) for o, i in zip(outer[at_live], inner[at_live])})
+        hollow = ~tiles.any(axis=1)
+        assert (~at_live).sum() == hollow.sum()
+        assert (first[~at_live] == 1).all() and (last[~at_live] == 1).all()
+        assert set(outer[~at_live]) == set(np.flatnonzero(hollow))
+        # a sweep's steps name distinct inner blocks: one fetch a tile
+        same_sweep = outer[1:] == outer[:-1]
+        assert (inner[1:][same_sweep] > inner[:-1][same_sweep]).all()
+        assert first.sum() == last.sum() == tiles.shape[0]
     if mask is None and kv_len is None:
-        assert all(table is None for table, _ in sweeps.values())
+        assert all(steps is None for steps, _ in sweeps.values())
     # numpy alone, while a step is traced: no JAX op is staged or run (on
     # the chip each would compile, op by op, inside the step's trace)
     from tpudist.ops import flash_attention as fa
 
     for dkv in (False, True):
         n_outer, n_inner = live.T.shape if dkv else live.shape
-        build = lambda: fa._fetch_table.__wrapped__(
+        build = lambda: fa._step_list.__wrapped__(
             mask, kv_len, n_outer, n_inner, block_q, block_k, dkv)
         assert not jax.make_jaxpr(lambda: (build(), jnp.int32(0))[1])(
             ).jaxpr.eqns
@@ -666,14 +694,18 @@ def test_committed_index_maps_fetch_once_a_live_tile(case, monkeypatch):
 ])
 def test_fetched_tile_share_is_what_the_index_maps_fetch(
         mask_spec, rows, blocks, share, monkeypatch):
-    """The counter at the blocks each cell's shape takes (``None``: 512 x
+    """The counters at the blocks each cell's shape takes (``None``: 512 x
     1024) and at small ones, and each kernel's own share of steps whose
-    streamed block changes index, read off its committed index maps:
-    forward, dq and dkv alike, one fetch a live tile. Before the table
-    every step of a masked call fetched (1.0); the tiles computed are
+    streamed block changes index, read off its committed index maps in the
+    order its grid runs them: forward, dq and dkv alike, one fetch a live
+    tile (where the dead steps fetched, every step of a masked call did:
+    1.0).
+    The live-step grid runs a step a live tile, so ``grid_step_share`` is
+    the same share (1.0 on the square grid); the tiles computed are
     unchanged."""
     from tpudist.ops.flash_attention import (
         computed_tile_share, default_blocks, fetched_tile_share,
+        grid_step_share,
     )
 
     mask = _mask_of(mask_spec)
@@ -683,11 +715,16 @@ def test_fetched_tile_share_is_what_the_index_maps_fetch(
         assert blocks == (512, 1024)
     assert fetched_tile_share(mask, rows, *blocks) == share
     assert computed_tile_share(mask, rows, *blocks) == share
+    assert grid_step_share(mask, rows, *blocks) == share
+    tiles = (rows // blocks[0]) * (rows // blocks[1])
     for kernel, (_, names) in _committed_sweeps(
             monkeypatch, mask, rows, *blocks).items():
-        inner = names[:, :, 0 if kernel != "fwd" else 1]
-        fetches = inner.shape[0] + np.sum(inner[:, 1:] != inner[:, :-1])
-        assert fetches / inner.size == share, kernel
+        names = names.reshape(-1, names.shape[-1])  # in the grid's order
+        inner = names[:, {"fwd": 1, "dkv": 0, "dq": 0}[kernel]]
+        outer = names[:, {"fwd": 0, "dkv": 4, "dq": 2}[kernel]]
+        fetches = 1 + np.sum((inner[1:] != inner[:-1])
+                             | (outer[1:] != outer[:-1]))
+        assert fetches / tiles == len(names) / tiles == share, kernel
 
 
 @pytest.mark.parametrize("block, block_q, block_k", [
@@ -698,28 +735,33 @@ def test_dead_runs_fetch_nothing_and_change_no_bit(block, block_q, block_k,
     """Two copies of 512 rows in blocks of ``block``: a dkv sweep with dead
     Q blocks before its first live one (a clean K block that only later
     rows see), a forward sweep with a dead K block between two live ones
-    (a noised Q block's own block, then the clean past). The forward and
-    the Pallas backward (interpret mode) through the fetch table against
-    the dense reference and the scan backward, and against the same
-    kernels with every step naming its own block, as before the table:
-    equal to the last bit."""
+    (a noised Q block's own block, then the clean past) — the live-step
+    lists step over neither. The forward and the Pallas backward
+    (interpret mode) on the live-step grid against the dense reference and
+    the scan backward, and against the same kernels fed a list of EVERY
+    tile of the square, whose dead steps the liveness test in the bodies
+    skips: equal to the last bit."""
     from tpudist.ops import flash_attention as fa
     from tpudist.ops.attention import BlockMask
 
     length = 512
     mask = BlockMask(block, length)
     rows = 2 * length
-    fwd_table = fa._fetch_table(mask, None, rows // block_q, rows // block_k,
-                                block_q, block_k, False)
-    dkv_table = fa._fetch_table(mask, None, rows // block_k, rows // block_q,
-                                block_q, block_k, True)
+    fwd_steps = fa._step_list(mask, None, rows // block_q, rows // block_k,
+                              block_q, block_k, False)
+    dkv_steps = fa._step_list(mask, None, rows // block_k, rows // block_q,
+                              block_q, block_k, True)
     live = _live(mask, None, rows, block_q, block_k)
     first = [np.flatnonzero(r)[0] for r in live.T]
-    assert max(first) > 0 and all(  # dkv: a dead run before the first live
-        (dkv_table[i, :f] == f).all() for i, f in enumerate(first))
+    assert max(first) > 0  # dkv: a dead run before the first live tile
+    opens = dkv_steps[dkv_steps[:, 2] == 1]
+    np.testing.assert_array_equal(opens[:, 1], first)  # ... stepped over
     gaps = [i for i, r in enumerate(live) if np.any(np.diff(
         np.flatnonzero(r)) > 1)]
-    assert gaps  # forward: a dead run between two live tiles
+    assert gaps  # forward: a dead run between two live tiles ...
+    for i in gaps:  # ... stepped over
+        np.testing.assert_array_equal(fwd_steps[fwd_steps[:, 0] == i, 1],
+                                      np.flatnonzero(live[i]))
 
     rng = np.random.Generator(np.random.PCG64(block))
     q, k, v = (jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
@@ -736,7 +778,6 @@ def test_dead_runs_fetch_nothing_and_change_no_bit(block, block_q, block_k,
         return [np.asarray(x) for x in (o, lse, *grads)]
 
     got = kernels()
-    assert fwd_table is not None and dkv_table is not None
     dense = mask.dense(rows)[None, None]
     ref = lambda q, k, v: dot_product_attention(
         *(x.transpose(0, 2, 1, 3) for x in (q, k, v)),
@@ -751,9 +792,47 @@ def test_dead_runs_fetch_nothing_and_change_no_bit(block, block_q, block_k,
     for a, b in zip(got[2:], scan):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
-    monkeypatch.setattr(fa, "_fetch_table", lambda *a: None)
+    square = lambda mask, kv_len, n_outer, n_inner, *_: fa._steps(
+        np.ones((n_outer, n_inner), bool))
+    monkeypatch.setattr(fa, "_step_list", square)
     for a, b in zip(got, kernels()):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("case", ["ragged_kv", "block_causal_ragged"])
+def test_key_blocks_a_kv_len_retires_get_zero_gradients(case):
+    """A ``kv_len`` that retires whole K blocks leaves their dkv sweeps
+    without a live tile: each is one step that opens and closes its sweep,
+    and its dk / dv come out zero — written, not left as the interpreter's
+    NaN fill — while the other rows match the scan backward."""
+    from tpudist.ops import flash_attention as fa
+
+    spec, rows, block_q, block_k, kv_len = _SWEEP_CASES[case]
+    mask = _mask_of(spec)
+    live = _live(mask, kv_len, rows, block_q, block_k)
+    retired = np.flatnonzero(~live.any(axis=0))  # K blocks
+    assert retired.size and retired[0] * block_k >= kv_len
+    dkv_steps = fa._step_list(mask, kv_len, rows // block_k, rows // block_q,
+                              block_q, block_k, True)
+    hollow = dkv_steps[np.isin(dkv_steps[:, 0], retired)]
+    assert len(hollow) == retired.size and (hollow[:, 2:] == 1).all()
+
+    rng = np.random.Generator(np.random.PCG64(kv_len))
+    q, k, v, g = (jnp.asarray(rng.normal(size=(1, 2, rows, 128)), jnp.float32)
+                  for _ in range(4))
+    sm = 1.0 / np.sqrt(128)
+    o, lse = fa._flash_fwd(q, k, v, mask=mask, sm_scale=sm, block_q=block_q,
+                           block_k=block_k, kv_len=kv_len)
+    got = fa._bwd_pallas((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                         block_q=block_q, block_k=block_k, kv_len=kv_len,
+                         interpret=True)
+    want = fa._bwd_blockwise((q, k, v, o, lse), g, mask=mask, sm_scale=sm,
+                             block_k=block_k, kv_len=kv_len)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5, err_msg=name)
+    for grad in got[1:]:
+        tail = np.asarray(grad)[:, :, retired[0] * block_k:]
+        assert tail.size and (tail == 0).all()
 
 
 # -- the sliding window: BlockMask(window=W) and the band's grid --------------
@@ -825,7 +904,7 @@ def test_band_grid_names_each_live_tile_once(rows, window, block_q, block_k,
     """A windowed call's three grids span the band and no more: the inner
     axis is the widest sweep's live tiles; every sweep names its live
     blocks in order, one index change a live tile, then stays on its last
-    (the steps past a narrower band fetch nothing); no fetch table rides
+    (the steps past a narrower band fetch nothing); no step list rides
     along. ``grid_step_share`` is the grids' steps over the square's tiles
     and ``fetched_tile_share`` the live tiles over the same."""
     from tpudist.ops.attention import BlockMask
@@ -838,8 +917,8 @@ def test_band_grid_names_each_live_tile_once(rows, window, block_q, block_k,
     sweeps = _committed_sweeps(monkeypatch, mask, rows, block_q, block_k)
     streamed = {"fwd": (1, 2), "dkv": (0, 1, 2, 3), "dq": (0, 1)}
     steps = 0
-    for kernel, (table, names) in sweeps.items():
-        assert table is None, kernel
+    for kernel, (listed, names) in sweeps.items():
+        assert listed is None, kernel
         tiles = live.T if kernel == "dkv" else live  # [outer, inner]
         widest = int(tiles.sum(axis=1).max())
         assert names.shape[:2] == (tiles.shape[0], widest), kernel
@@ -886,5 +965,8 @@ def test_band_grid_at_the_cells_shape():
     assert grid_step_share(mask, 8192, 512, 1024) == 88 / 384 <= 0.25
     need = (512 * 8192 - 512 * 511 / 2) / 8192 ** 2
     assert 0.0605 < need < 31 / 256
-    for other in (None, CAUSAL, BlockMask(4), BlockMask(4, 4096)):
-        assert grid_step_share(other, 8192, 512, 1024) == 1.0
+    # every other call: the square grid unmasked, else the live steps
+    assert grid_step_share(None, 8192, 512, 1024) == 1.0
+    for other in (CAUSAL, BlockMask(4), BlockMask(4, 4096)):
+        assert grid_step_share(other, 8192, 512, 1024) \
+            == computed_tile_share(other, 8192, 512, 1024) < 1.0

@@ -111,7 +111,7 @@ class BlockMask:
         if not self.noised_len:
             return q0, k0, None, False, False
         qn, kn = q0 < self.noised_len, k0 < self.noised_len
-        # numpy grids (the flash kernels' static fetch tables, built while
+        # numpy grids (the flash kernels' static step lists, built while
         # a step is traced) stay numpy: a jnp op there would run, and on
         # the chip compile, op by op
         where = np.where if isinstance(qn, np.ndarray) else jnp.where

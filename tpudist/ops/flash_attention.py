@@ -7,36 +7,44 @@ context-parallel path reuses blockwise.
 
 Design (FlashAttention-2 style, TPU-first):
 
-- grid ``(batch, heads, q_blocks, k_blocks)`` with the K dimension innermost,
-  so the f32 VMEM scratch accumulators (running max ``m``, normalizer ``l``,
-  output ``acc``) persist across the K sweep of one Q block;
+- each kernel works sweep by sweep: one resident block (a Q block in the
+  forward and dq kernels, a K block in dkv) and a walk over the streamed
+  blocks it meets, so the f32 VMEM scratch accumulators (running max
+  ``m``, normalizer ``l``, output ``acc``; dq; dk / dv) persist across the
+  sweep;
 - per tile: one MXU matmul ``q·kᵀ`` (f32 accumulation), online-softmax
   rescale on the VPU, one MXU matmul ``p·v`` into the accumulator — the
   S×S score matrix never exists in HBM;
 - masking is two-level, under ONE description of the mask
-  (:class:`tpudist.ops.attention.BlockMask`: causal, block-causal, or the
-  block-diffusion mask over a noised and a clean copy) that the wrapper,
-  the three kernels and the scan backward share: a (Q tile, K tile) with no
-  allowed pair is predicated off with ``pl.when`` (``BlockMask.tile_live``
-  on the ``program_id``s — no MXU work issued — and no block fetched for
-  it: a static table in scalar memory, :func:`_fetch_table`, steers the
-  streamed operand's index map to the next live tile of the sweep, and
-  the pipeline copies a block only when its index changes;
-  :func:`fetched_tile_share` is its counter), a tile whose every pair is
-  allowed runs unmasked
-  (``BlockMask.tile_full``), every other tile is masked elementwise from
-  ``broadcasted_iota`` (the causal instance compares positions, the others
-  ``BlockMask.tile_allowed``); ``kv_len`` masks right-padded keys the same
-  two-level way (ragged caller shapes are padded to the 128-tile multiple
-  by the wrapper). :func:`computed_tile_share` is the static counter of
-  what the first level leaves;
-- a sliding window (``BlockMask(window=W)``) leaves a BAND of live tiles,
-  a constant number a sweep: its grid's inner axis spans only the band
+  (:class:`tpudist.ops.attention.BlockMask`: causal, block-causal, the
+  block-diffusion mask over a noised and a clean copy, or a sliding
+  window) that the wrapper, the three kernels and the scan backward share:
+  a (Q tile, K tile) with no allowed pair is never stepped over where the
+  grid can leave it out and predicated off with ``pl.when`` where it
+  cannot (``BlockMask.tile_live``: no MXU work runs), a tile whose every
+  pair is allowed runs unmasked (``BlockMask.tile_full``), every other
+  tile is masked elementwise from ``broadcasted_iota`` (the causal
+  instance compares positions, the others ``BlockMask.tile_allowed``);
+  ``kv_len`` masks right-padded keys the same two-level way (ragged caller
+  shapes are padded to the 128-tile multiple by the wrapper).
+  :func:`computed_tile_share` is the static counter of what the first
+  level leaves;
+- three grids, picked by what the call's mask, ``kv_len`` and blocks
+  leave live (:func:`_grid`): the SQUARE ``(b, h, outer, inner)`` where
+  every tile is live (no mask, no ``kv_len`` that retires a K block); the
+  LIVE STEPS ``(b, h, n_steps)`` where some tile is dead and there is no
+  window (causal, block-causal, block diffusion, a ragged ``kv_len``):
+  the last axis walks a static list in scalar memory (:func:`_step_list`)
+  of the live tiles alone, sweep by sweep in the square's order, each
+  entry the outer block, the inner block and whether it opens or closes
+  its sweep, so that no grid step is empty and each live tile is fetched
+  once (the pipeline copies a block only when its index changes); the
+  BAND under a sliding window (``BlockMask(window=W)``), whose live tiles
+  are a constant number a sweep: the inner axis spans only the band
   (:func:`_band`: ``first(outer) + j``, clamped at the band's last block
-  by the index map, so that a step past it fetches nothing), and the
-  empty grid steps are bounded by the band, not by ``S²``;
-  :func:`grid_step_share` is its counter. Every other mask keeps the
-  square grid;
+  by the index map, so that a step past it fetches nothing).
+  :func:`grid_step_share` counts the steps each grid runs and
+  :func:`fetched_tile_share` the fetches its index maps make;
 - two backward paths, both O(S·block) memory, recomputing p from the saved
   log-sum-exp: a blockwise ``lax.scan`` in plain JAX and the Pallas FA-2
   dq/dkv kernels. :func:`default_blocks` decides by shape (since PR 28):
@@ -127,46 +135,55 @@ def _kernel_sweeps(seq_len: int, block_q: int, block_k: int):
     return ((n_q, n_k, False), (n_q, n_k, False), (n_k, n_q, True))
 
 
+def _committed_names(mask, seq_len, block_q, block_k):
+    """For each of the three kernels ``(names, tiles)``: the ``(outer,
+    inner)`` blocks its committed index maps name at each step of one
+    (batch, head) of its grid (:func:`_grid`), in the order the grid runs
+    them, and its ``(S / block_q) (S / block_k)`` tiles."""
+    for n_outer, n_inner, dkv in _kernel_sweeps(seq_len, block_q, block_k):
+        dims, steps, _, at_outer, at_inner = _grid(
+            mask, None, n_outer, n_inner, block_q, block_k, dkv)
+        tbl = () if steps is None else (steps.ravel(),)
+        names = [(at_outer(0, 0, *pos, *tbl)[2], at_inner(0, 0, *pos, *tbl)[2])
+                 for pos in np.ndindex(*dims)]
+        yield names, n_outer * n_inner
+
+
 def fetched_tile_share(mask: BlockMask | None, seq_len: int, block_q: int,
                        block_k: int) -> float:
     """Fetches of the streamed block by the three kernels' grid steps under
     ``mask`` at these blocks, over the ``(S / block_q) (S / block_k)``
     tiles each kernel has: K / V in the forward and dq kernels, Q / dO /
-    lse / delta in the dkv kernel. The committed index maps
-    (:func:`_sweep_maps` over :func:`_fetch_table` or :func:`_band`) are
-    evaluated over one (batch, head) of each kernel's grid; a step fetches
-    where its block differs from the previous step's of the same sweep,
-    and a sweep's first step always does (the pipeline may also keep a
-    block across two sweeps: not counted). Dead steps name the next live
-    block, so each sweep fetches once a live tile: at 8,192 rows and 512 x
-    1024, 0.375 under ``BlockMask(4, 4096)`` and 0.5625 causal, where every
-    step fetched before; 1.0 without a mask."""
+    lse / delta in the dkv kernel. The committed index maps (:func:`_grid`)
+    are evaluated over one (batch, head) of each kernel's grid; a step
+    fetches where its block differs from the previous step's of the same
+    sweep, and a sweep's first step always does (the pipeline may also keep
+    a block across two sweeps: not counted). Each live tile is fetched
+    once: at 8,192 rows and 512 x 1024, 0.375 under ``BlockMask(4, 4096)``
+    and 0.5625 causal (1.0 while the grid ran dead steps that fetched);
+    1.0 without a mask."""
     fetched = tiles = 0
-    for n_outer, n_inner, dkv in _kernel_sweeps(seq_len, block_q, block_k):
-        band = _band(mask, n_outer, n_inner, block_q, block_k, dkv)
-        table = _fetch_table(mask, None, n_outer, n_inner, block_q, block_k,
-                             dkv)
-        _, at_inner = _sweep_maps(table, n_inner, band)
-        tbl = () if table is None else (table.ravel(),)
-        for i in range(n_outer):
-            names = [at_inner(0, 0, i, j, *tbl)[2]
-                     for j in range(band[0] if band else n_inner)]
-            fetched += 1 + sum(a != b for a, b in zip(names, names[1:]))
-        tiles += n_outer * n_inner
+    for names, square in _committed_names(mask, seq_len, block_q, block_k):
+        fetched += sum(
+            prev is None or outer != prev[0] or inner != prev[1]
+            for prev, (outer, inner) in zip([None] + names, names))
+        tiles += square
     return fetched / tiles
 
 
 def grid_step_share(mask: BlockMask | None, seq_len: int, block_q: int,
                     block_k: int) -> float:
     """The three kernels' grid steps over their ``(S / block_q) (S /
-    block_k)`` tiles each: 1.0 but under a sliding window, whose grid
-    spans the band (:func:`_band`) — at 8,192 rows and a window of 512,
-    88 / 384 = 0.229 at blocks 512 x 1024, 0.125 at 512 x 512."""
+    block_k)`` tiles each: 1.0 on the square grid (every tile live); on the
+    live-step grid the live share, one step more for a sweep with no live
+    tile (:func:`_step_list`) — at 8,192 rows and 512 x 1024, 0.375 under
+    ``BlockMask(4, 4096)`` and 0.5625 causal, ``computed_tile_share``'s
+    values; on a window's band (:func:`_band`) at 8,192 rows and a window
+    of 512, 88 / 384 = 0.229 at blocks 512 x 1024, 0.125 at 512 x 512."""
     steps = tiles = 0
-    for n_outer, n_inner, dkv in _kernel_sweeps(seq_len, block_q, block_k):
-        band = _band(mask, n_outer, n_inner, block_q, block_k, dkv)
-        steps += n_outer * (band[0] if band else n_inner)
-        tiles += n_outer * n_inner
+    for names, square in _committed_names(mask, seq_len, block_q, block_k):
+        steps += len(names)
+        tiles += square
     return steps / tiles
 
 
@@ -183,7 +200,7 @@ def _imax(a, b):
 @functools.lru_cache(maxsize=None)
 def _band(mask, n_outer, n_inner, block_q, block_k, dkv):
     """The inner axis of a windowed call's grid, ``(steps, first, last)``,
-    or ``None`` for any other mask (the square grid). Under ``0 <= i - j <
+    or ``None`` for any other mask (the square or the live-step grid). Under ``0 <= i - j <
     W`` the live inner blocks of a sweep are contiguous, from
     ``first(outer)`` to ``last(outer)``: a Q block sees the keys from ``W
     - 1`` before its first row to its last row (forward, dq), a K block is
@@ -219,8 +236,9 @@ def _inner(band, outer, j):
 
 def _tile_live(mask, kv_len, qi, ki, block_q, block_k):
     """Has tile ``(qi, ki)`` anything to compute? A traced scalar from the
-    ``program_id``s (or ``True``): the mask's own test, and a ``kv_len``
-    shorter than the padded K retires whole K blocks too."""
+    grid step's blocks (or ``True``; numpy on numpy grids): the mask's own
+    test, and a ``kv_len`` shorter than the padded K retires whole K blocks
+    too."""
     live = True
     if mask is not None:
         live = mask.tile_live(qi, ki, block_q, block_k)
@@ -229,21 +247,39 @@ def _tile_live(mask, kv_len, qi, ki, block_q, block_k):
     return live
 
 
+def _steps(take):
+    """The steps of a live-step grid over the tiles ``take`` marks, a
+    boolean ``[n_outer, n_inner]``: ``int32 [n_steps, 4]``, each row
+    ``(outer, inner, first, last)`` — sweep by sweep and, in a sweep, the
+    inner blocks in order (the square grid's order, so that each resident
+    block accumulates its tiles as it did there), ``first`` / ``last`` 1
+    on the sweep's first / last step. A sweep with no tile marked gets one
+    step that opens and closes it (init and finalize: a K block that a
+    ``kv_len`` retires gets its zero dk / dv), naming the inner block of
+    the step before, so that nothing is fetched for it."""
+    take = take.copy()
+    hollow = ~take.any(axis=1)
+    take[hollow, 0] = True
+    outer, inner = np.nonzero(take)
+    for s in np.flatnonzero(hollow[outer]):
+        inner[s] = inner[s - 1] if s else 0
+    ends = outer[1:] != outer[:-1]
+    steps = np.stack([outer, inner, np.r_[True, ends], np.r_[ends, True]],
+                     axis=1).astype(np.int32)
+    steps.flags.writeable = False
+    return steps
+
+
 @functools.lru_cache(maxsize=None)
-def _fetch_table(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
-    """The inner block each step of a kernel's grid names, ``int32
-    [n_outer, n_inner]``, or ``None`` where every tile is live. A sweep is
-    one outer block's walk over the inner ones: K blocks a Q block in the
-    forward and dq kernels, Q blocks a K block (``dkv``) in the dkv kernel.
-    A live step names its own block; a dead one the NEXT live block of its
-    sweep, so that the pipeline fetches it while the dead run passes and
-    the live step finds it resident, or after the sweep's last live tile
-    that tile's block, so that nothing more is fetched; a sweep with no
-    live tile (a ``kv_len`` that retires a whole K block: init zeroes its
-    dk / dv) names block 0. :func:`_tile_live` decides, the test the
-    bodies run, here on numpy grids at trace time (numpy throughout: no
-    JAX op runs). A windowed call needs none: its grid spans the band
-    (:func:`_band`), whose index map clamps the steps past it."""
+def _step_list(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
+    """The live-step grid of a kernel (:func:`_steps` over its live tiles),
+    or ``None`` where every tile is live (the square grid) or under a
+    window (the band's grid, :func:`_band`). A sweep is one outer block's
+    walk over the inner ones: K blocks a Q block in the forward and dq
+    kernels (one list for both), Q blocks a K block (``dkv``) in the dkv
+    kernel. :func:`_tile_live` decides, the test the bodies run, here on
+    numpy grids at trace time (numpy throughout: a ``jnp`` op while a step
+    is traced would compile on the chip by itself)."""
     if mask is not None and mask.window:
         return None
     outer = np.arange(n_outer)[:, None]
@@ -251,49 +287,66 @@ def _fetch_table(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
     qi, ki = (inner, outer) if dkv else (outer, inner)
     live = np.broadcast_to(_tile_live(mask, kv_len, qi, ki, block_q, block_k),
                            (n_outer, n_inner))
-    if live.all():
-        return None
-    table = np.zeros((n_outer, n_inner), np.int32)
-    for row, row_live in zip(table, live):
-        held = np.flatnonzero(row_live)
-        if held.size:
-            row[:] = held[np.minimum(np.searchsorted(held, np.arange(n_inner)),
-                                     held.size - 1)]
-    table.flags.writeable = False
-    return table
+    return None if live.all() else _steps(live)
 
 
-def _sweep_maps(table, n_inner, band=None):
-    """Index maps of a ``(b, h, outer, inner)`` grid: the resident block's
-    (the outer index) and the streamed block's — the inner index, or where
-    there is a fetch table, what it names (read from scalar memory,
-    flattened), or on a band's grid (:func:`_band`) the step's block,
-    clamped at the band's last."""
-    at_outer = lambda b, h, i, j, *_: (b, h, i, 0)
+def _grid(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv):
+    """``(dims, steps, band, at_outer, at_inner)`` of a kernel: its grid
+    after ``(b, h)``, its step list (scalar prefetch) or ``None``, its band
+    or ``None``, and the index maps of the resident block (the outer one)
+    and of the streamed block (the inner one). The live-step grid
+    ``(n_steps,)`` reads both blocks from its list; the band's ``(n_outer,
+    band)`` names ``first(outer) + j``, clamped at the band's last block;
+    the square ``(n_outer, n_inner)`` names ``(i, j)``."""
+    steps = _step_list(mask, kv_len, n_outer, n_inner, block_q, block_k, dkv)
+    if steps is not None:
+        return ((len(steps),), steps, None,
+                lambda b, h, s, tbl: (b, h, tbl[4 * s], 0),
+                lambda b, h, s, tbl: (b, h, tbl[4 * s + 1], 0))
+    band = _band(mask, n_outer, n_inner, block_q, block_k, dkv)
+    at_outer = lambda b, h, i, j: (b, h, i, 0)
     if band is not None:
         _, first, last = band
-        return at_outer, lambda b, h, i, j: (
-            b, h, _imin(first(i) + j, last(i)), 0)
-    if table is None:
-        return at_outer, lambda b, h, i, j: (b, h, j, 0)
-    return at_outer, lambda b, h, i, j, tbl: (b, h, tbl[i * n_inner + j], 0)
+        return ((n_outer, band[0]), None, band, at_outer,
+                lambda b, h, i, j: (b, h, _imin(first(i) + j, last(i)), 0))
+    return ((n_outer, n_inner), None, None, at_outer,
+            lambda b, h, i, j: (b, h, j, 0))
 
 
-def _pallas(kernel, table, *, grid, in_specs, out_specs, scratch_shapes,
+def _position(band, steps):
+    """``(outer, inner, first, last, in_band)`` of the grid step a kernel
+    body runs: its resident and its streamed block, whether the step opens
+    / closes its sweep (each a function, called where the body tests it,
+    which keeps the square and the band's grids' traces free of the list),
+    and whether it lies in its sweep's band (``True`` off the band's
+    grid). On the live-step grid all of it is read from the list in scalar
+    memory (``steps``, flattened)."""
+    if steps is not None:
+        s = pl.program_id(2)
+        return (steps[4 * s], steps[4 * s + 1],
+                lambda: steps[4 * s + 2] != 0, lambda: steps[4 * s + 3] != 0,
+                True)
+    outer, j = pl.program_id(2), pl.program_id(3)
+    inner, in_band = _inner(band, outer, j)
+    return (outer, inner, lambda: j == 0,
+            lambda: j == pl.num_programs(3) - 1, in_band)
+
+
+def _pallas(kernel, steps, *, grid, in_specs, out_specs, scratch_shapes,
             out_shape, interpret):
-    """``pallas_call`` of ``kernel`` over ``grid``; with a fetch table the
-    table rides as the one scalar-prefetch operand, which only the index
-    maps read (the body is traced under the kernel's own name and
-    source)."""
-    if table is None:
+    """``pallas_call`` of ``kernel`` over ``grid``; with a step list the
+    list rides, flattened, as the one scalar-prefetch operand, which the
+    index maps read and the body gets as ``steps=`` (traced under the
+    kernel's own name and source)."""
+    if steps is None:
         return pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
             out_shape=out_shape, scratch_shapes=scratch_shapes,
             interpret=interpret)
 
     @functools.wraps(kernel.func)
-    def body(tbl_ref, *refs):
-        return kernel(*refs)
+    def body(steps_ref, *refs):
+        return kernel(*refs, steps=steps_ref)
 
     call = pl.pallas_call(
         body,
@@ -301,7 +354,7 @@ def _pallas(kernel, table, *, grid, in_specs, out_specs, scratch_shapes,
             num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
             out_specs=out_specs, scratch_shapes=scratch_shapes),
         out_shape=out_shape, interpret=interpret)
-    return functools.partial(call, table.ravel())
+    return functools.partial(call, steps.ravel())
 
 
 def _keep(mask, kv_len, q_pos, k_pos):
@@ -362,14 +415,11 @@ def _fwd_kernel(
     o_ref, lse_ref,       # [1,1,bq,dv], [1,1,bq,128] (lane-padded, see _flash_fwd)
     m_scr, l_scr, acc_scr,  # VMEM f32: [bq,128], [bq,128], [bq,dv]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None, band=None,
+    kv_len: int | None = None, band=None, steps=None,
 ):
-    qi = pl.program_id(2)
-    step = pl.program_id(3)
-    nk = pl.num_programs(3)
-    ki, in_band = _inner(band, qi, step)
+    qi, ki, first, last, in_band = _position(band, steps)
 
-    @pl.when(step == 0)
+    @pl.when(first())
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -411,7 +461,7 @@ def _fwd_kernel(
 
     _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(step == nk - 1)
+    @pl.when(last())
     def _finalize():
         l = l_scr[:, :1]
         # guard fully-masked rows (no mask here has one, but it keeps the
@@ -438,9 +488,8 @@ def _flash_fwd(q, k, v, *, mask, sm_scale, block_q, block_k, kv_len=None):
             f"flash attention needs 128-aligned blocks: seq_q={s_q}, "
             f"seq_k={s_k}, block_q={block_q}, block_k={block_k}"
         )
-    n_q, n_k = s_q // block_q, s_k // block_k
-    band = _band(mask, n_q, n_k, block_q, block_k, False)
-    grid = (b, h, n_q, band[0] if band else n_k)
+    dims, steps, band, at_q, at_k = _grid(
+        mask, kv_len, s_q // block_q, s_k // block_k, block_q, block_k, False)
 
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, mask=mask,
@@ -453,11 +502,9 @@ def _flash_fwd(q, k, v, *, mask, sm_scale, block_q, block_k, kv_len=None):
         jax.ShapeDtypeStruct((b, h, s_q, d_v), q.dtype),
         jax.ShapeDtypeStruct((b, h, s_q, 128), jnp.float32),
     ]
-    table = _fetch_table(mask, kv_len, n_q, n_k, block_q, block_k, False)
-    at_q, at_k = _sweep_maps(table, n_k, band)
     o, lse = _pallas(
-        kernel, table,
-        grid=grid,
+        kernel, steps,
+        grid=(b, h, *dims),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), at_q),
             pl.BlockSpec((1, 1, block_k, d), at_k),
@@ -509,15 +556,12 @@ def _bwd_dkv_kernel(
     dk_ref, dv_ref,                      # [1,1,bk,d], [1,1,bk,dv]
     dk_scr, dv_scr,                      # VMEM f32 [bk,d], [bk,dv]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None, band=None,
+    kv_len: int | None = None, band=None, steps=None,
 ):
-    """dk/dv: K/V block resident, sweep over Q blocks (grid dim 3)."""
-    ki = pl.program_id(2)
-    step = pl.program_id(3)
-    nq = pl.num_programs(3)
-    qi, in_band = _inner(band, ki, step)
+    """dk/dv: K/V block resident, sweep over Q blocks."""
+    ki, qi, first, last, in_band = _position(band, steps)
 
-    @pl.when(step == 0)
+    @pl.when(first())
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
@@ -545,7 +589,7 @@ def _bwd_dkv_kernel(
 
     _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(step == nq - 1)
+    @pl.when(last())
     def _finalize():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -557,15 +601,12 @@ def _bwd_dq_kernel(
     dq_ref,                              # [1,1,bq,d]
     dq_scr,                              # VMEM f32 [bq,d]
     *, sm_scale: float, mask: BlockMask | None, block_q: int, block_k: int,
-    kv_len: int | None = None, band=None,
+    kv_len: int | None = None, band=None, steps=None,
 ):
-    """dq: Q block resident, sweep over K blocks (grid dim 3)."""
-    qi = pl.program_id(2)
-    step = pl.program_id(3)
-    nk = pl.num_programs(3)
-    ki, in_band = _inner(band, qi, step)
+    """dq: Q block resident, sweep over K blocks."""
+    qi, ki, first, last, in_band = _position(band, steps)
 
-    @pl.when(step == 0)
+    @pl.when(first())
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
@@ -585,7 +626,7 @@ def _bwd_dq_kernel(
 
     _when_live(mask, kv_len, qi, ki, block_q, block_k, _compute, in_band)
 
-    @pl.when(step == nk - 1)
+    @pl.when(last())
     def _finalize():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
@@ -613,13 +654,12 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
     # validated compiled on a real v5e chip (grads match the scan backward)
     lse_c = lse[..., None]  # [b,h,sq,1]
 
-    # q, k, dq, dk are d wide; v, o, do, dv are d_v wide. Each grid is
-    # (b, h, i, j): i the resident block, j the streamed one, which a fetch
-    # table steers past the dead steps, or a window's band offsets from i
-    # (dkv: i = k block, j = q block)
-    dkv_band = _band(mask, nk, nq, block_q, block_k, True)
-    dkv_table = _fetch_table(mask, kv_len, nk, nq, block_q, block_k, True)
-    at_i, at_j = _sweep_maps(dkv_table, nq, dkv_band)
+    # q, k, dq, dk are d wide; v, o, do, dv are d_v wide. Each grid names
+    # i, the resident block, and j, the streamed one (_grid: the square's
+    # indices, a window's band, or the live-step list's entries; dkv: i = k
+    # block, j = q block)
+    dims, dkv_steps, dkv_band, at_i, at_j = _grid(
+        mask, kv_len, nk, nq, block_q, block_k, True)
     kspec = pl.BlockSpec((1, 1, block_k, d), at_i)
     vspec = pl.BlockSpec((1, 1, block_k, d_v), at_i)
     qspec_j = pl.BlockSpec((1, 1, block_q, d), at_j)
@@ -631,8 +671,8 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
             _bwd_dkv_kernel, sm_scale=sm_scale, mask=mask,
             block_q=block_q, block_k=block_k, kv_len=kv_len, band=dkv_band,
         ),
-        dkv_table,
-        grid=(b, h, nk, dkv_band[0] if dkv_band else nq),
+        dkv_steps,
+        grid=(b, h, *dims),
         in_specs=[qspec_j, dospec_j, rspec_j, rspec_j, kspec, vspec],
         out_specs=[kspec, vspec],
         out_shape=[
@@ -646,10 +686,9 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
         interpret=interpret,
     )(q, do, lse_c, delta, k, v)
 
-    # dq grid: i = q block, j = k block, the forward's table or band
-    dq_band = _band(mask, nq, nk, block_q, block_k, False)
-    dq_table = _fetch_table(mask, kv_len, nq, nk, block_q, block_k, False)
-    at_i, at_j = _sweep_maps(dq_table, nk, dq_band)
+    # dq grid: i = q block, j = k block, the forward's list or band
+    dims, dq_steps, dq_band, at_i, at_j = _grid(
+        mask, kv_len, nq, nk, block_q, block_k, False)
     qspec = pl.BlockSpec((1, 1, block_q, d), at_i)
     dospec = pl.BlockSpec((1, 1, block_q, d_v), at_i)
     rspec_i = pl.BlockSpec((1, 1, block_q, 1), at_i)
@@ -660,8 +699,8 @@ def _bwd_pallas(res, g, *, mask, sm_scale, block_q, block_k, kv_len=None,
             _bwd_dq_kernel, sm_scale=sm_scale, mask=mask,
             block_q=block_q, block_k=block_k, kv_len=kv_len, band=dq_band,
         ),
-        dq_table,
-        grid=(b, h, nq, dq_band[0] if dq_band else nk),
+        dq_steps,
+        grid=(b, h, *dims),
         in_specs=[kspec_j, vspec_j, qspec, dospec, rspec_i, rspec_i],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
