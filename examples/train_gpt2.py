@@ -91,7 +91,7 @@ def parse_args(argv=None):
     # model family + size
     p.add_argument("--arch", default="gpt2",
                    choices=["gpt2", "llama", "zaya", "kanana", "sdar",
-                            "laguna"],
+                            "laguna", "nemotron_h"],
                    help="decoder family: GPT-2 (learned positions, GELU MLP, "
                    "tied head), Llama (RoPE, RMSNorm, SwiGLU, GQA), ZAYA1 "
                    "(compressed convolutional attention, top-1 experts "
@@ -114,7 +114,14 @@ def parse_args(argv=None):
                    "a shared one, untied head; --hidden_dim, --depth, "
                    "--vocab_size, --seq_len and --held cut it, and "
                    "--experts, --head_dim, --ffn_dim, --dense_ffn_dim where "
-                   "non-zero)")
+                   "non-zero) or Nemotron-H (Nemotron-3-Nano's geometry: "
+                   "Mamba-2 mixers through the chunked state-space kernel, "
+                   "grouped-query attention and squared-ReLU expert layers "
+                   "in its published pattern, sigmoid top-6 of 128 experts "
+                   "beside a shared one, untied head; --hidden_dim, --depth, "
+                   "--vocab_size, --seq_len and --held cut it, and --experts "
+                   "and --ffn_dim where non-zero; a scan chunk past "
+                   "--seq_len is cut to it)")
     p.add_argument("--hidden_dim", default=768, type=int)
     p.add_argument("--depth", default=12, type=int)
     p.add_argument("--num_heads", default=12, type=int)
@@ -148,7 +155,7 @@ def parse_args(argv=None):
     p.add_argument("--router_width", default=256, type=int,
                    help="zaya: width of the MLP router and of its carry")
     p.add_argument("--held", default="", type=str,
-                   help="zaya, kanana, sdar, laguna: 'first,count' — the contiguous experts this "
+                   help="zaya, kanana, sdar, laguna, nemotron_h: 'first,count' — the contiguous experts this "
                    "run holds (one shard's share of an expert-parallel "
                    "layer: the router scores all --experts, tokens of the "
                    "others contribute nothing here); empty = all")
@@ -340,7 +347,7 @@ def main(argv=None):
     if args.expert_axis:
         expert_axis = args.expert_axis
     elif args.experts and args.arch not in ("zaya", "kanana", "sdar",
-                                            "laguna"):
+                                            "laguna", "nemotron_h"):
         # (the dropless layer runs one shard's experts, no exchange)
         # largest axis that divides both the expert count (weights shard
         # evenly) and the devices left over from the other model axes
@@ -512,6 +519,31 @@ def main(argv=None):
                 dense_ffn_dim=args.dense_ffn_dim or base.dense_ffn_dim,
                 routing=routing, remat_policy=args.remat_policy,
                 dtype=dtype, attn_impl=args.attn, mesh=mesh,
+            )
+        if args.arch == "nemotron_h":
+            from tpudist.models.nemotron_h import nemotron_3_nano
+
+            if args.dropout or args.scan_layers or args.generate or args.init_hf:
+                raise SystemExit(
+                    "nemotron_h trains unrolled, without dropout; --generate "
+                    "and --init_hf have no path for it yet"
+                )
+            # the published geometry, cut by the generic size flags
+            base = nemotron_3_nano()
+            routing = dataclasses.replace(
+                base.routing,
+                num_experts=args.experts or base.routing.num_experts,
+                held=(tuple(int(n) for n in args.held.split(","))
+                      if args.held else None),
+            )
+            ffn_dim = args.ffn_dim or base.ffn_dim
+            return base.clone(
+                vocab_size=args.vocab_size, max_seq_len=args.seq_len,
+                hidden_dim=args.hidden_dim, depth=args.depth,
+                chunk=min(base.chunk, args.seq_len), ffn_dim=ffn_dim,
+                shared_dim=2 * ffn_dim, routing=routing,
+                remat_policy=args.remat_policy, dtype=dtype,
+                attn_impl=args.attn, mesh=mesh,
             )
         if args.arch == "llama":
             from tpudist.models.llama import Llama

@@ -66,7 +66,7 @@ def _one_chip_mesh():
 
 @pytest.mark.parametrize("name", ["gpt2-medium", "bert-large",
                                   "kanana-2-30b-a3b", "sdar-30b-a3b",
-                                  "laguna-xs-2"])
+                                  "laguna-xs-2", "nemotron-3-nano-30b-a3b"])
 def test_family_flops_per_token_is_the_programs_counter(name, monkeypatch):
     """``step_mfu_pct``'s numerator (``benchmarks/families/*.py``
     ``train_flops_per_token``, "copied from telemetry/flops.py") equals
@@ -121,7 +121,7 @@ def _calls(path, func):
 
 
 @pytest.mark.parametrize("family", ["gpt2", "bert", "zaya", "kanana", "sdar",
-                                    "laguna"])
+                                    "laguna", "nemotron_h"])
 def test_every_argument_the_cell_passes_is_a_parameter_of_fit(family):
     """``benchmarks/cell.py`` calls ``fit(model, tx, loader, <its own
     keywords>, **built["fit"])``: every one of them is a parameter."""
@@ -201,12 +201,14 @@ def test_exchange_and_loss_head_scopes_are_declared():
         spans.EXCHANGE_SCOPE, "loss_head"}
 
 
-# the Kanana-2, SDAR and Laguna cells' readers quote block scopes: each is
-# declared for that metric, and none declared for it is left out
+# the Kanana-2, SDAR, Laguna and Nemotron-H cells' readers quote block
+# scopes: each is declared for that metric, and none declared for it is
+# left out
 @pytest.mark.parametrize("metric, attribute", [
     ("mla_proj_ms", "STAGES"), ("moe_shared_ms", "STAGE"),
     ("moe_topk_ms", "STAGES"), ("bd_proj_ms", "STAGES"),
     ("bd_moe_ms", "STAGES"), ("lg_proj_ms", "STAGES"),
+    ("mamba_mix_ms", "STAGES"), ("ssd_roofline", "STAGE"),
 ])
 def test_block_stage_readers_sum_declared_scopes(metric, attribute):
     reader = importlib.import_module(f"benchmarks.layer_metrics.{metric}")
@@ -385,7 +387,8 @@ def kanana_block_paths():
 @pytest.mark.parametrize(
     "scope", sorted(s for s in BLOCK_SCOPES
                     if not s.startswith(("cca_", "attn_", "bd_", "lg_",
-                                         "swa_", "full_"))))
+                                         "swa_", "full_", "mamba_", "ssd_",
+                                         "gqa_"))))
 def test_lowered_kanana_step_holds_the_block_scope_in_both_passes(
         scope, kanana_block_paths):
     """Each scope of the Kanana-2 block is in the lowered step's name
@@ -487,6 +490,66 @@ def test_lowered_laguna_step_holds_the_block_scope_in_both_passes(
             if moe_ms.stage_of(p, "%fusion = f32[]") == scope and block in p}
     assert held, f"no op of the lowered step is under {block}{scope}"
     assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
+
+
+@pytest.fixture(scope="module")
+def nemotron_h_block_paths():
+    """Name stacks of the tiny Nemotron-H configuration's lowered step under
+    its cell's recipe (per-block recomputation that keeps the scan kernel's
+    residuals, fused norms, chunked CE): layers ``MEMEM*E``."""
+    from tpudist.train import create_train_state, make_train_step
+
+    config, traffic = _tiny("nemotron_h")
+    mesh = _one_chip_mesh()
+    built = _family("nemotron_h").build(config, traffic, mesh)
+    seq, rows = traffic["seq_len"], traffic["per_chip_batch"]
+    state = create_train_state(
+        built["model"], 0, jnp.zeros((1, seq), jnp.int32), built["tx"],
+        mesh=mesh)
+    step_args = inspect.signature(make_train_step).parameters
+    step = make_train_step(
+        built["model"], built["tx"], mesh,
+        **{k: v for k, v in built["fit"].items() if k in step_args})
+    return _paths(step.jitted.lower(
+        state, step.stage({"tokens": np.zeros((rows, seq), np.int32)})))
+
+
+@pytest.mark.parametrize(
+    "scope", sorted(s for s, m in BLOCK_SCOPES.items()
+                    if s.startswith(("mamba_", "ssd_", "gqa_"))
+                    or m == "moe_ms"))
+def test_lowered_nemotron_h_step_holds_the_block_scope_in_both_passes(
+        scope, nemotron_h_block_paths):
+    """Each scope of the Nemotron-H block is in the lowered step's name
+    stacks as a direct child of a block of its kind, in the forward and in
+    the backward pass, where ``layer_metrics/moe_ms.py`` ``stage_of`` finds
+    it whatever recomputation puts before the block: the Mamba-2 mixer's
+    (the scan's backward included) in ``h_2``, the attention's in ``h_5``,
+    the expert layer's in ``h_6``."""
+    from benchmarks.layer_metrics import moe_ms
+
+    block = "/h_6/" if scope.startswith("moe_") \
+        else "/h_5/" if scope.startswith("gqa_") else "/h_2/"
+    held = {p for p in nemotron_h_block_paths
+            if moe_ms.stage_of(p, "%fusion = f32[]") == scope and block in p}
+    assert held, f"no op of the lowered step is under {block}{scope}"
+    assert {spans.pass_of(p) for p in held} >= {"fwd", "bwd"}
+
+
+def test_the_scan_counter_feeds_a_declared_metric():
+    """``ssd_log_carry`` (``SSD_COUNTERS``) names the metric whose reader
+    prints it, and the family reads it under that field name."""
+    from benchmarks.families import nemotron_h
+    from tpudist.telemetry.trace import SSD_COUNTERS
+
+    declared = {m["name"] for m in BENCH["per_layer"]}
+    assert set(SSD_COUNTERS.values()) <= declared
+    row = {"kind": "moe", "step": 9, **{f"h_{i}/{name}": -88.0
+                                        for name in SSD_COUNTERS
+                                        for i in (0, 2)}}
+    ctx = {"window": type("W", (), {"warmup_steps": 6}), "telemetry_rows": [row]}
+    assert nemotron_h.ssd_counters(ctx) == {"ssd_log_carry": -88.0,
+                                            "layers_steps": 2}
 
 
 # -- (e) documents name files that exist --------------------------------------

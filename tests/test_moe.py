@@ -797,3 +797,109 @@ def test_routing_says_what_it_cannot_hold():
     assert Routing(16, scoring="sigmoid").routed_scale == 1.0
     assert Routing(16).held_range == (0, 16)
     assert Routing(16, held=(8, 8)).held_range == (8, 8)
+
+
+class _Relu2(nn.Module):
+    """``dropless_moe`` of squared-ReLU experts beside a shared one."""
+
+    routing: Routing
+    ffn_dim: int
+
+    @nn.compact
+    def __call__(self, u):
+        return dropless_moe(self, u, routing=self.routing,
+                            ffn_dim=self.ffn_dim, shared_dim=12,
+                            expert_act="relu2")[0]
+
+
+@pytest.mark.parametrize("held,E,how", [((0, 4), 32, "collapsed"),
+                                        ((8, 8), 16, "even")])
+def test_relu2_experts_are_the_dense_oracle(held, E, how):
+    """``expert_act="relu2"``: ``relu(x·w_up)²·w_down``, two matrices a
+    held expert and the shared one, against the same layer written densely
+    from the program's own selection — output and the gradients of the
+    tokens, the router and every weight — through the chunked path (4 of
+    32 held: 4 chunks, every one of them run when the router sends all
+    choices to held experts) and the one-chunk path (8 of 16). Float32,
+    1e-5: summation order only."""
+    from tpudist.parallel import ep
+    from tpudist.parallel.ep import relu2, select_experts
+
+    routing = Routing(E, top_k=6, held=held, scoring="sigmoid",
+                      routed_scale=2.5)
+    assert ep.row_chunks(64 * 6, held[1], E)[1] == (4 if E == 32 else 1)
+    layer = _Relu2(routing, 24)
+    x = _moe_inputs(T=64)
+    params = layer.init(jax.random.key(1), x)["params"]
+    assert set(params["moe_experts"]) == {"w_up", "w_down"}
+    assert set(params["moe_shared"]) == {"w_up", "w_down"}
+    x, kernel, _ = _routed(x, params["moe_router"]["kernel"], routing, how)
+    params["moe_router"]["kernel"] = kernel
+    first, count = routing.held_range
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+
+    def dense(params, x):
+        with jax.default_matmul_precision("highest"):
+            idx, gates = select_experts(x @ params["moe_router"]["kernel"],
+                                        routing)
+            w, s = params["moe_experts"], params["moe_shared"]
+            y = relu2(x @ s["w_up"]["kernel"]) @ s["w_down"]["kernel"]
+            for e in range(count):
+                gate = jnp.sum(jnp.where(idx == first + e, gates, 0.0), -1)
+                y = y + gate[..., None] * (relu2(x @ w["w_up"][e])
+                                           @ w["w_down"][e])
+            return y
+
+    def ours(params, x):
+        with jax.default_matmul_precision("highest"):
+            return layer.apply({"params": params}, x)
+
+    run = lambda f: jax.jit(jax.value_and_grad(
+        lambda p, x: jnp.sum(f(p, x) * probe), argnums=(0, 1)))(params, x)
+    (got, got_grads), (want, want_grads) = run(ours), run(dense)
+    np.testing.assert_allclose(ours(params, x), dense(params, x),
+                               rtol=1e-5, atol=1e-5)
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5),
+        got_grads, want_grads)
+    assert got == pytest.approx(float(want), rel=1e-5)
+
+
+def test_swiglu_experts_are_untouched_by_the_relu2_form():
+    """The default stays the SiLU-gated layer: its parameters and its
+    traced program are those of ``expert_act="swiglu"`` named outright,
+    with three grouped products a chunk where ``relu2`` has two and no
+    gate."""
+    from tpudist.parallel import ep
+
+    routing = Routing(128, top_k=6, held=(0, 16), scoring="sigmoid")
+    x = _moe_inputs(T=64)
+
+    def traced(layer):
+        params = layer.init(jax.random.key(1), x)["params"]
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(layer.apply({"params": p}, x)[0])))(params))
+        return params, text
+
+    class Named(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            return dropless_moe(self, u, routing=routing, ffn_dim=24,
+                                expert_act="swiglu")
+
+    default, named = traced(_Dropless(routing, 24)), traced(Named())
+    assert jax.tree.map(jnp.shape, default[0]) \
+        == jax.tree.map(jnp.shape, named[0])
+    assert default[1] == named[1]
+    assert "logistic" in default[1]
+    forward = lambda act: ep._gated_ffn_live_fwd if act == "swiglu" \
+        else ep._relu2_ffn_live_fwd
+    chunk = lambda n: (jnp.zeros((96, 16)), jnp.zeros((288, 16)))
+    ws = {"swiglu": [jnp.zeros((16, 16, 24))] * 2 + [jnp.zeros((16, 24, 16))],
+          "relu2": [jnp.zeros((16, 16, 24)), jnp.zeros((16, 24, 16))]}
+    for act, products in (("swiglu", 3), ("relu2", 2)):
+        jaxpr = jax.make_jaxpr(forward(act))(
+            chunk(4), *ws[act], jnp.zeros(16, jnp.int32))
+        # chunk 0 straight, and once more in the loop over the others
+        assert sum(eqn.primitive.name.startswith("ragged_dot")
+                   for eqn in _eqns(jaxpr.jaxpr)) == 2 * products, act

@@ -752,3 +752,108 @@ def test_laguna_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
     assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
         >= 6 * expert_layers
     assert not any(f"[{rows}," in kernels[k] for k in grouped)
+
+
+def test_ssd_scan_fwd_bwd_compiles_at_a_mamba2_layers_shape(compile_for_chip):
+    """The chunked state-space scan at Nemotron-3-Nano's Mamba-2 layer and
+    the cell's sequence (64 heads of 64, 8 groups of state 128, 8,192
+    positions in 64 chunks of 128), forward and its chunk-parallel
+    backward as one program: the forward kernel is there, once, writing
+    ``y`` and every chunk's float32 starting state in the kernel's tiles
+    (two heads of 64 a 128-lane tile)."""
+    from tpudist.ops.ssd import ssd_scan
+
+    def loss(*args):
+        return jnp.sum(ssd_scan(*args).astype(jnp.float32))
+
+    hlo = compile_for_chip(
+        jax.grad(loss, argnums=range(6)),
+        ((1, 8192, 64, 64), jnp.bfloat16), ((1, 8192, 64), jnp.float32),
+        ((64,), jnp.float32), ((1, 8192, 8, 128), jnp.bfloat16),
+        ((1, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32))
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    head = calls[0].split(" custom-call(")[0]
+    assert "bf16[1,8192,4096]" in head and "f32[1,64,8,4,128,128]" in head, \
+        head
+
+
+def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
+                                                               monkeypatch):
+    """The ``nemotron3_nano_train_s8192`` cell's whole train step — its own
+    configuration, traffic and family file, 528.1M parameters, 8,192
+    tokens — compiled for one described chip: it fits beside the harness's
+    copy under per-block ``dots_saveable``; the scan's forward kernel runs
+    once a Mamba-2 layer (its output and states kept, not made again) and
+    the trace reader's pattern finds it; the attention kernel is there
+    under ``gqa_attn``, three calls; the expert layers' grouped products
+    are the compiler's ragged-dot kernels over one chunk of the 49,152
+    (token, choice) rows."""
+    import json
+    import os
+    import re
+
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from benchmarks.families import common, nemotron_h as family
+    from benchmarks.layer_metrics import ssd_roofline
+    from tpudist import mesh as mesh_lib
+    from tpudist.train import TrainState, make_train_step
+
+    monkeypatch.setattr(backend, "interpret", lambda: False)
+    monkeypatch.setattr(common, "resolve_attn", lambda requested, seq: "flash")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/nemotron-3-nano-30b-a3b.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "benchmarks/traffic/train_s8192_b1.json")) as f:
+        traffic = json.load(f)
+    mesh = mesh_lib.create_mesh(devices=topo.devices[:1])
+    built = family.build(config, traffic, mesh)
+    everywhere = NamedSharding(mesh, P())
+    placed = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        tree)
+    params = placed(built["param_shapes"])
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) == 528_092_736
+    state = TrainState(
+        step=jax.ShapeDtypeStruct((), I32, sharding=everywhere),
+        params=params, batch_stats={},
+        opt_state=placed(jax.eval_shape(built["tx"].init, params)))
+    kw = built["fit"]
+    step = make_train_step(
+        built["model"], built["tx"], mesh, loss_fn=kw["loss_fn"],
+        input_key="tokens", label_key="tokens",
+        forward_loss=kw["forward_loss"], fused=kw["fused"])
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (traffic["per_chip_batch"], traffic["seq_len"]), I32,
+        sharding=everywhere)}
+    traced = step.jitted.trace(state, batch)
+    kinds = family.kinds(config)
+    assert remat.forward_kernels(traced.jaxpr, ("ssd.py",)) == kinds.count("M")
+    assert remat.forward_attention_kernels(traced.jaxpr) == kinds.count("*")
+    compiled = traced.lower().compile()
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # 7.39 + 4.00 GB under dots_saveable (2.96 GB of temporaries under
+    # full), + the harness's 2.11 GB copy <= 13.5 GB of the chip's 17.2
+    assert 8e9 < held < 12.5e9, held
+    kernels = {
+        name: line for line in compiled.as_text().splitlines()
+        for name in re.findall(
+            r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+            line)}
+    scans = [k for k in kernels if ssd_roofline.KERNEL.match(k)]
+    assert len(scans) == kinds.count("M"), sorted(kernels)
+    attention = [k for k in kernels if re.match(r"^gqa_attn(\.\d+)?$", k)]
+    assert len(attention) == 3 * kinds.count("*"), sorted(kernels)
+    rows = traffic["seq_len"] * config["num_experts_per_tok"]
+    chunk_rows, n_chunks = ep.row_chunks(
+        rows, config["num_experts_held"], config["n_routed_experts"])
+    assert (chunk_rows, n_chunks) == (6144, 8)
+    grouped = [k for k in kernels if k.startswith("ragged-dot")]
+    assert len(grouped) >= 6 * kinds.count("E"), len(grouped)
+    assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
+        >= 4 * kinds.count("E")
+    assert not any(f"[{rows}," in kernels[k] for k in grouped)

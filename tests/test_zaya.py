@@ -240,11 +240,12 @@ def test_block_scopes_keep_the_trace_contract(setup):
         params, tokens).as_text(debug_info=True)
     # (``mla_*`` and ``moe_shared`` are the Kanana-2 block's, ``attn_*``
     # and ``bd_attn`` the SDAR block's, ``lg_*``, ``swa_attn`` and
-    # ``full_attn`` the Laguna block's: test_kanana.py, test_sdar.py,
-    # test_laguna.py)
+    # ``full_attn`` the Laguna block's, ``mamba_*``, ``ssd_scan`` and
+    # ``gqa_*`` the Nemotron-H block's: test_kanana.py, test_sdar.py,
+    # test_laguna.py, test_nemotron_h.py)
     mine = [s for s in BLOCK_SCOPES
             if not s.startswith(("mla_", "attn_", "bd_", "lg_", "swa_",
-                                 "full_"))
+                                 "full_", "mamba_", "ssd_", "gqa_"))
             and s != "moe_shared"]
     assert len(mine) == 8
     for scope in mine:

@@ -865,8 +865,11 @@ def select_experts(logits, routing: Routing):
 
 def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
                  ffn_dim: int, shared_dim: int = 0, dtype=jnp.float32,
-                 mesh=None, norm_eps: float = 1e-5):
-    """Dropless expert FFN (SiLU-gated) over the experts ``routing.held``.
+                 mesh=None, norm_eps: float = 1e-5,
+                 expert_act: str = "swiglu"):
+    """Dropless expert FFN over the experts ``routing.held``: SiLU-gated
+    (``expert_act="swiglu"``) or two matrices around a squared ReLU
+    (``"relu2"``: ``relu(x·w_up)²·w_down``), the shared expert alike.
 
     Call it inside ``owner``'s compact method: the router
     (``moe_router``) and the stacked expert weights (``moe_experts``,
@@ -880,8 +883,9 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     compute in ``dtype``). ``r_prev``: the MLP router's carried state
     ``[B, S, width]`` or ``None``. Returns ``(y [B, S, d], r)``.
 
-    ``shared_dim`` > 0 adds a shared expert (``moe_shared``, one SiLU-gated
-    FFN of that width over EVERY token, ungated) to the result: every
+    ``shared_dim`` > 0 adds a shared expert (``moe_shared``, one FFN of
+    that width and of the same ``expert_act`` over EVERY token, ungated)
+    to the result: every
     shard of an expert-parallel layer computes it alike, so the shards'
     results add up to the whole layer's with it counted once.
 
@@ -967,9 +971,8 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
             xs = _live_rows_of_choices(tokens, order, inverse, n_live, k,
                                        chunk_rows)
 
-    out = GroupedExperts(count, ffn_dim, dtype, name="moe_experts")(
-        xs, sizes
-    )
+    out = GroupedExperts(count, ffn_dim, dtype, act=expert_act,
+                         name="moe_experts")(xs, sizes)
 
     with jax.named_scope("moe_combine"):
         w = (gates.reshape(T * k) * held).astype(dtype)
@@ -981,7 +984,8 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
         else:
             y = _mix_live_rows(out, w, order, inverse, n_live, k)
     if shared_dim:
-        y = y + SharedExpert(shared_dim, dtype, name="moe_shared")(tokens)
+        y = y + SharedExpert(shared_dim, dtype, act=expert_act,
+                             name="moe_shared")(tokens)
 
     load = sizes.astype(jnp.float32)
     owner.sow("moe_stats", "tokens", load)
@@ -1000,28 +1004,41 @@ def dropless_moe(owner: nn.Module, u, r_prev=None, *, routing: Routing,
     return y.reshape(b, s, d), r
 
 
+def relu2(a):
+    """The squared ReLU of a non-gated expert (``mlp_hidden_act: relu2``)."""
+    return jnp.square(jax.nn.relu(a))
+
+
 class SharedExpert(nn.Module):
-    """The expert every token passes: ``(silu(x·w_gate) ⊙ x·w_up)·w_down``,
-    no bias (``n_shared_experts`` of a DeepSeek-V3-family layer are one FFN
-    of their summed width)."""
+    """The expert every token passes: ``(silu(x·w_gate) ⊙ x·w_up)·w_down``
+    (``act="swiglu"``) or ``relu(x·w_up)²·w_down`` (``"relu2"``), no bias
+    (``n_shared_experts`` of a DeepSeek-V3-family layer are one FFN of
+    their summed width)."""
 
     ffn_dim: int
     dtype: Any = jnp.float32
+    act: str = "swiglu"
 
     @nn.compact
     def __call__(self, x):
         dense = lambda name, width: nn.Dense(
             width, use_bias=False, dtype=self.dtype, name=name
         )
+        if self.act == "relu2":
+            return dense("w_down", x.shape[-1])(
+                relu2(dense("w_up", self.ffn_dim)(x)))
+        if self.act != "swiglu":
+            raise ValueError(f"unknown expert_act {self.act!r}")
         h = nn.silu(dense("w_gate", self.ffn_dim)(x)) \
             * dense("w_up", self.ffn_dim)(x)
         return dense("w_down", x.shape[-1])(h)
 
 
 class GroupedExperts(nn.Module):
-    """The held experts' SiLU-gated FFNs over rows sorted by expert:
+    """The held experts' FFNs over rows sorted by expert:
     ``(silu(x·w_gate[e]) ⊙ x·w_up[e])·w_down[e]`` for the rows of group
-    ``e``, as three grouped products (``jax.lax.ragged_dot``). Rows past
+    ``e``, as three grouped products (``jax.lax.ragged_dot``), or with
+    ``act="relu2"`` ``relu(x·w_up[e])²·w_down[e]``, two. Rows past
     ``sum(sizes)`` belong to no group; what the product leaves there is
     not defined (the TPU lowering leaves values) and the caller masks it.
     ``xs`` is the sorted rows, or the ``(chunk 0, the other chunks)`` pair
@@ -1031,6 +1048,7 @@ class GroupedExperts(nn.Module):
     count: int
     ffn_dim: int
     dtype: Any = jnp.float32
+    act: str = "swiglu"
 
     @nn.compact
     def __call__(self, xs, sizes):
@@ -1040,6 +1058,15 @@ class GroupedExperts(nn.Module):
             name, nn.initializers.lecun_normal(batch_axis=(0,)), shape,
             jnp.float32,
         ).astype(self.dtype)
+        if self.act == "relu2":
+            wu = w("w_up", (self.count, d, self.ffn_dim))
+            wd = w("w_down", (self.count, self.ffn_dim, d))
+            if chunked:
+                return _relu2_ffn_live(xs, wu, wd, sizes)
+            return jax.lax.ragged_dot(
+                relu2(jax.lax.ragged_dot(xs, wu, sizes)), wd, sizes)
+        if self.act != "swiglu":
+            raise ValueError(f"unknown expert_act {self.act!r}")
         wg = w("w_gate", (self.count, d, self.ffn_dim))
         wu = w("w_up", (self.count, d, self.ffn_dim))
         wd = w("w_down", (self.count, self.ffn_dim, d))
@@ -1113,3 +1140,55 @@ def _gated_ffn_live_bwd(res, d_out):
 
 
 _gated_ffn_live.defvjp(_gated_ffn_live_fwd, _gated_ffn_live_bwd)
+
+
+@jax.custom_vjp
+def _relu2_ffn_live(xs, wu, wd, sizes):
+    """:func:`_gated_ffn_live` for ``act="relu2"``: the two grouped
+    products over the chunks that hold a live row, chunk 0's ``x·w_up``
+    kept for the backward (a further chunk computes it again)."""
+    return _relu2_ffn_live_fwd(xs, wu, wd, sizes)[0]
+
+
+@_traced_once
+def _relu2_ffn_live_fwd(xs, wu, wd, sizes):
+    chunk_rows = xs[0].shape[0]
+
+    def ffn(c, x):
+        sz = _chunk_sizes(sizes, c, chunk_rows)
+        a = jax.lax.ragged_dot(x, wu, sz)
+        return jax.lax.ragged_dot(relu2(a), wd, sz), a
+
+    out, a = ffn(0, xs[0])
+    (out,), _ = _over_live_chunks(
+        lambda c, x: ((ffn(c, x)[0],), ()), jnp.sum(sizes), xs,
+        first=((out,), ()),
+    )
+    return out, (xs, a, wu, wd, sizes)
+
+
+@_traced_once
+def _relu2_ffn_live_bwd(res, d_out):
+    xs, a0, wu, wd, sizes = res
+    chunk_rows = xs[0].shape[0]
+    n_live = jnp.sum(sizes)
+
+    def grads(c, x, d_out, a=None):
+        sz = _chunk_sizes(sizes, c, chunk_rows)
+        product = lambda rows, w: jax.lax.ragged_dot(rows, w, sz)
+        if a is None:
+            a = product(x, wu)
+        h, act_vjp = jax.vjp(relu2, a)
+        d_h, d_wd = jax.vjp(product, h, wd)[1](d_out)
+        (d_a,) = act_vjp(d_h)
+        d_x, d_wu = jax.vjp(product, x, wu)[1](d_a)
+        live = _live_mask(c, chunk_rows, n_live)[:, None]
+        return (jnp.where(live, d_x, 0),), (d_wu, d_wd)
+
+    (d_xs,), d_ws = _over_live_chunks(
+        grads, n_live, xs, d_out, first=grads(0, xs[0], d_out[0], a0)
+    )
+    return (d_xs, *d_ws, None)
+
+
+_relu2_ffn_live.defvjp(_relu2_ffn_live_fwd, _relu2_ffn_live_bwd)

@@ -51,6 +51,10 @@ outside a ``jax.checkpoint`` a name lowers to nothing. The static counter
 that says it engaged is :func:`forward_attention_kernels`: ``depth`` in a
 training step under ``dots_saveable`` or without recomputation, ``2·depth``
 under ``full`` / ``save_nothing`` (``tests/test_remat_attention.py``).
+The chunked state-space scan (``ops/ssd.py``) names its output and every
+chunk's starting state alike (``ssd_out``, ``ssd_states``: ``B·H·S·P`` in
+the compute dtype + ``B·chunks·H·N·P`` float32 a layer); its counter is
+:func:`forward_kernels` of ``ssd.py``.
 """
 
 from __future__ import annotations
@@ -60,8 +64,10 @@ from typing import Any, Callable
 import jax
 
 # The attention kernels' forward rules name their output and its row
-# log-sum-exp so (``checkpoint_name``); ``dots_saveable`` keeps both.
-KERNEL_RESIDUALS = ("attn_out", "attn_lse")
+# log-sum-exp so (``checkpoint_name``), the state-space scan's
+# (``ops/ssd.py``) its output and every chunk's starting state;
+# ``dots_saveable`` keeps them all.
+KERNEL_RESIDUALS = ("attn_out", "attn_lse", "ssd_out", "ssd_states")
 
 # name -> jax.checkpoint policy callable (None = jax.checkpoint's default,
 # which saves nothing). "none" is absent on purpose: it means "do not wrap".
@@ -79,24 +85,30 @@ POLICY_NAMES = ("none", "full", "dots_saveable", "save_nothing")
 _ATTENTION_KERNEL_FILES = ("flash_attention.py", "vmem_attention.py")
 
 
-def forward_attention_kernels(jaxpr) -> int:
+def forward_kernels(jaxpr, files: tuple[str, ...]) -> int:
     """Static counter: the ``pallas_call``s of a traced program (a
     ``ClosedJaxpr`` or ``Jaxpr``, e.g. ``jitted.trace(*args).jaxpr``) whose
-    kernel is an attention FORWARD kernel — ``_fwd_kernel*`` of
-    ``ops/flash_attention.py`` or ``ops/vmem_attention.py``, by the kernel
-    function's own name and file. A training step holds ``depth`` of them
-    where the backward gets ``o`` and ``lse`` from what the policy kept,
-    ``2·depth`` where each block's backward launches the forward again."""
+    kernel is a FORWARD kernel — ``_fwd_kernel*`` of one of ``files``
+    (under ``tpudist/ops/``), by the kernel function's own name and
+    file."""
     n = 0
     for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
         if eqn.primitive.name == "pallas_call":
             kernel = eqn.params["jaxpr"].debug_info
             n += (kernel.func_name.startswith("_fwd_kernel")
-                  and kernel.func_filename.endswith(_ATTENTION_KERNEL_FILES))
+                  and kernel.func_filename.endswith(files))
             continue  # a kernel's body holds no kernel
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += forward_attention_kernels(sub)
+            n += forward_kernels(sub, files)
     return n
+
+
+def forward_attention_kernels(jaxpr) -> int:
+    """:func:`forward_kernels` of ``ops/flash_attention.py`` and
+    ``ops/vmem_attention.py``. A training step holds ``depth`` of them
+    where the backward gets ``o`` and ``lse`` from what the policy kept,
+    ``2·depth`` where each block's backward launches the forward again."""
+    return forward_kernels(jaxpr, _ATTENTION_KERNEL_FILES)
 
 
 def resolve(policy: str | bool | None | Callable):

@@ -245,6 +245,45 @@ def laguna_train_flops(tokens: float, *, hidden: int, depth: int,
             + 12.0 * head_dim * attention * tokens / seq)
 
 
+def nemotron_h_train_flops(tokens: float, *, hidden: int, vocab: int,
+                           seq: int, pattern: str, mamba_heads: int,
+                           mamba_head_dim: int, n_groups: int,
+                           state_dim: int, conv_kernel: int, chunk: int,
+                           num_heads: int, num_kv_heads: int, head_dim: int,
+                           ffn_dim: int, shared_dim: int, num_experts: int,
+                           top_k: int, held_share: float) -> float:
+    """Nemotron-H geometry (tpudist.models.nemotron_h): layer ``l`` is
+    ``pattern[l]``. Weights a token, 6x: a Mamba-2 layer's in projection
+    (``z``, ``xBC``, ``dt``) and out projection; an attention layer's
+    q/k/v and output; an expert layer's fp32 router GEMM H·E, the shared
+    expert and ``top_k`` routed experts at the share of the experts this
+    shard HOLDS, two matrices each (squared ReLU, no gate); un-tied head
+    V·H. A Mamba-2 layer's chunked scan (:func:`tpudist.ops.ssd.ssd_cost`'s
+    forward a token) and its ``conv_kernel``-tap convolution, three passes;
+    attention at the causal triangle's pairs, ``12 · head_dim`` a pair a
+    head."""
+    from tpudist.ops.ssd import ssd_cost
+
+    inner = mamba_heads * mamba_head_dim
+    conv_dim = inner + 2 * n_groups * state_dim
+    per_layer = {
+        "M": hidden * (inner + conv_dim + mamba_heads) + inner * hidden,
+        "*": (hidden * (num_heads + 2 * num_kv_heads) * head_dim
+              + num_heads * head_dim * hidden),
+        "E": (hidden * num_experts + 2 * hidden * shared_dim
+              + top_k * held_share * 2 * hidden * ffn_dim),
+    }
+    weights = vocab * hidden + sum(per_layer[kind] for kind in pattern)
+    scan = ssd_cost(batch=1, seq=seq, heads=mamba_heads,
+                    head_dim=mamba_head_dim, groups=n_groups,
+                    state=state_dim, chunk=chunk,
+                    itemsize=2)["fwd"]["flops"] / seq \
+        + 2 * conv_kernel * conv_dim
+    attention = 12.0 * head_dim * num_heads * window_pairs(seq, None) / seq
+    return tokens * (6.0 * weights + pattern.count("M") * 3.0 * scan
+                     + pattern.count("*") * attention)
+
+
 def bert_train_flops(tokens: float, *, hidden: int, depth: int, vocab: int,
                      seq: int) -> float:
     """BERT MLM: 12·H² encoder blocks + the MLM head's H² transform and
@@ -419,6 +458,22 @@ def train_step_flops(model: Any, batch: Mapping[str, Any], *,
             top_k=routing.top_k,
             held_share=routing.held_range[1] / routing.num_experts,
         )
+    if family == "nemotron_h":
+        seq = shape[-1]
+        routing = model.routing
+        return nemotron_h_train_flops(
+            _rows(shape, 1) * seq, hidden=model.hidden_dim,
+            vocab=model.vocab_size, seq=seq,
+            pattern=model.pattern[:model.depth],
+            mamba_heads=model.mamba_heads,
+            mamba_head_dim=model.mamba_head_dim, n_groups=model.n_groups,
+            state_dim=model.state_dim, conv_kernel=model.conv_kernel,
+            chunk=model.chunk, num_heads=model.num_heads,
+            num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
+            ffn_dim=model.ffn_dim, shared_dim=model.shared_dim,
+            num_experts=routing.num_experts, top_k=routing.top_k,
+            held_share=routing.held_range[1] / routing.num_experts,
+        )
     if family == "bert":
         seq = shape[-1]
         return bert_train_flops(
@@ -458,7 +513,7 @@ def tokens_per_step(model: Any, batch: Mapping[str, Any], *,
     except (KeyError, AttributeError):
         return None
     if family in ("gpt2", "llama", "bert", "gpt2_moe", "llama_moe",
-                  "kanana", "sdar", "laguna"):
+                  "kanana", "sdar", "laguna", "nemotron_h"):
         return _rows(shape, 1) * shape[-1]
     if family in ("vit", "resnet"):
         return _rows(shape, 3)

@@ -53,7 +53,7 @@ import jax
 
 __all__ = ["Tracer", "ServeTracer", "MetricsExporter", "span", "Bringup",
            "TRAIN_STEP", "FIT_SPANS", "BRINGUP_SPANS", "STEP_SCOPES",
-           "BLOCK_SCOPES", "MOE_COUNTERS"]
+           "BLOCK_SCOPES", "MOE_COUNTERS", "SSD_COUNTERS"]
 
 # the step marker XProf's step-time view groups device work by; the name
 # predates the ``fit/...`` spans and the benchmark's gap labels quote it
@@ -136,16 +136,18 @@ STEP_SCOPES = {
 # Device scopes inside a block of ``tpudist.models.zaya`` (``cca_*``), of
 # ``tpudist.models.kanana`` (``mla_*``, ``moe_shared``) and of
 # ``tpudist.models.sdar`` (``attn_*``, ``bd_attn``) and of
-# ``tpudist.models.laguna`` (``lg_*``, ``swa_attn``, ``full_attn``), the
-# ``moe_*`` of ``parallel/ep.py`` ``dropless_moe`` in all four — flax
+# ``tpudist.models.laguna`` (``lg_*``, ``swa_attn``, ``full_attn``) and of
+# ``tpudist.models.nemotron_h`` (``mamba_*``, ``ssd_scan``, ``gqa_*``), the
+# ``moe_*`` of ``parallel/ep.py`` ``dropless_moe`` in all five — flax
 # module names and ``jax.named_scope``s (metadata only), direct children of
 # the block ``h_<n>`` so that a trace reader that folds an op's name stack
 # to its first two components (``benchmarks/spans.py`` ``scope_of``) keeps
 # them apart — each with the benchmark metric that reads it
 # (docs/OBSERVABILITY.md §8; tests/test_zaya.py, tests/test_kanana.py,
-# tests/test_sdar.py and tests/test_laguna.py hold the models to them;
-# ``moe_topk_ms`` reads the four ``moe_ms`` stages in the Kanana-2 cell,
-# ``bd_moe_ms`` in the SDAR cell):
+# tests/test_sdar.py, tests/test_laguna.py and tests/test_nemotron_h.py
+# hold the models to them; ``moe_topk_ms`` reads the four ``moe_ms`` stages
+# in the Kanana-2 cell, ``bd_moe_ms`` in the SDAR cell; ``block_scope_ms``
+# is no metric but the traced run's printed table of every stage):
 BLOCK_SCOPES = {
     "cca_proj": "cca_mix_ms",    # CCA's down-projection (q, k, v_a, v_b)
     "cca_mix": "cca_mix_ms",     # q-k mean, convolutions, norms, rotary, value shift
@@ -173,6 +175,14 @@ BLOCK_SCOPES = {
     "swa_attn": "swa_attn_roofline",  # the sliding-window call; its kernel is ``swa_attn.<k>``
     "full_attn": "full_attn_roofline",  # the full causal call; its kernel is ``full_attn.<k>``
     "lg_out": "lg_proj_ms",      # the gate's product and the output projection
+    "mamba_in_proj": "mamba_mix_ms",  # Mamba-2's in projection (z, xBC, dt)
+    "mamba_conv": "mamba_mix_ms",  # the causal depthwise convolution, its bias, SiLU
+    "ssd_scan": "ssd_roofline",  # softplus, A, the chunked scan kernel and its backward
+    "mamba_gate_norm": "mamba_mix_ms",  # y x silu(z), the RMSNorm over groups
+    "mamba_out_proj": "mamba_mix_ms",  # Mamba-2's out projection
+    "gqa_qkv": "block_scope_ms",  # Nemotron-H's fused q/k/v projection
+    "gqa_attn": "block_scope_ms",  # its causal attention call; the kernel is ``gqa_attn.<k>``
+    "gqa_out": "block_scope_ms",  # its output projection
 }
 # Counters the dropless expert layer sows into ``moe_stats`` (a ``moe`` row
 # field ``h_<n>/<counter>`` a logged step), each with its metric:
@@ -183,6 +193,13 @@ MOE_COUNTERS = {
     # rows of the chunks that ran / T·k (``ep.row_chunks``): 1 / n_chunks on
     # an even step, more where the router paid for a further chunk
     "row_share_computed": "moe_ms",
+}
+# Counters a Mamba-2 block sows into ``moe_stats`` beside them (the same
+# ``moe`` rows), each with its metric:
+SSD_COUNTERS = {
+    # mean over heads and chunks of sum_chunk dt·A: the log of what a state
+    # keeps across one chunk (printed beside the scan's roofline)
+    "ssd_log_carry": "ssd_roofline",
 }
 
 
