@@ -6,6 +6,8 @@ test_dp_equivalence pins down DP: sharded ≡ unsharded, dispatch ≡ a
 per-token reference computation.
 """
 
+from typing import Any
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -903,3 +905,161 @@ def test_swiglu_experts_are_untouched_by_the_relu2_form():
         # chunk 0 straight, and once more in the loop over the others
         assert sum(eqn.primitive.name.startswith("ragged_dot")
                    for eqn in _eqns(jaxpr.jaxpr)) == 2 * products, act
+
+
+# the expert cells' configurations -> the (model, expert) widths their
+# grouped products run at
+_CELL_WIDTHS = {
+    "nemotron-3-nano-30b-a3b": (2816, 2048),
+    "kanana-2-30b-a3b": (2048, 768),
+    "sdar-30b-a3b": (2048, 768),
+    "laguna-xs-2": (2048, 512),
+    "zaya1-8b": (2048, 2048),
+}
+
+
+def _cell_config(name):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, f"benchmarks/configs/{name}.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name,widths,want", [
+    *((name, None, want) for name, want in _CELL_WIDTHS.items()),
+    ("tiny", (16, 24), (16, 24)),
+    ("tiny_padded", (240, 464), (256, 512)),
+])
+def test_grouped_widths_at_the_cells_widths(name, widths, want):
+    """The static counter: ``grouped_widths`` at each expert cell's
+    published widths (its configuration file) and at two test widths.
+    Nemotron-H's 2,688 and 1,856 — tiles 128 wide in the compiler's
+    grouped-product kernel — run at 2,816 x 2,048, 1.156x the products'
+    work; no other cell's width moves."""
+    from tpudist.parallel import ep
+
+    if widths is None:
+        config = _cell_config(name)
+        widths = config["hidden_size"], config["moe_intermediate_size"]
+    got = ep.grouped_widths(*widths)
+    assert got == want
+    ratio = got[0] * got[1] / (widths[0] * widths[1])
+    if name.startswith("nemotron"):
+        assert ratio == pytest.approx(1.156, abs=1e-3)
+    assert 1.0 <= ratio <= (9 / 8) ** 2
+
+
+class _Experts(nn.Module):
+    """``dropless_moe`` of one ``expert_act`` in one compute ``dtype``."""
+
+    routing: Routing
+    ffn_dim: int
+    act: str
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, u):
+        return dropless_moe(self, u, routing=self.routing,
+                            ffn_dim=self.ffn_dim, dtype=self.dtype,
+                            expert_act=self.act)[0]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("held,E,how", [((0, 4), 32, "collapsed"),
+                                        ((8, 8), 16, "even")],
+                         ids=["chunked", "one_chunk"])
+@pytest.mark.parametrize("act", ["relu2", "swiglu"])
+def test_padded_widths_are_the_unpadded_layer(act, held, E, how, dtype,
+                                              monkeypatch):
+    """A model width of 240 and an expert width of 464 run their grouped
+    products at 256 x 512 (``grouped_widths``): the parameters keep their
+    shapes, and the output and the gradients of the tokens and of every
+    expert weight are those of the same layer run at its own widths —
+    through the chunked path (every chunk run) and the one-chunk path,
+    in both activations. The added columns and rows are exact noughts:
+    float32 to 1e-5 (summation order), bfloat16 to one rounding of its
+    8-bit mantissa, 2^-7, each of the largest value as well."""
+    from tpudist.parallel import ep
+
+    d, ff = 240, 464
+    assert ep.grouped_widths(d, ff) == (256, 512)
+    routing = Routing(E, top_k=6, held=held, scoring="sigmoid")
+    layer = _Experts(routing, ff, act, dtype)
+    x = _moe_inputs(T=64, d=d)
+    params = layer.init(jax.random.key(1), x)["params"]
+    count = held[1]
+    shapes = {k: v.shape for k, v in params["moe_experts"].items()}
+    assert shapes == {"w_up": (count, d, ff), "w_down": (count, ff, d),
+                      **({"w_gate": (count, d, ff)} if act == "swiglu"
+                         else {})}
+    x, kernel, _ = _routed(x, params["moe_router"]["kernel"], routing, how)
+    params["moe_router"]["kernel"] = kernel
+    probe = jax.random.normal(jax.random.key(2), x.shape)
+
+    def loss(p, x):
+        y = layer.apply({"params": p}, x)
+        return jnp.sum(y.astype(jnp.float32) * probe), y
+
+    def run():
+        traced = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).trace(
+            params, x)
+        # the stacked weights ``[count, in, out]`` the products take
+        widths = {eqn.invars[1].aval.shape[1:] for eqn in _eqns(traced.jaxpr)
+                  if eqn.primitive.name.startswith("ragged_dot")
+                  and eqn.invars[1].aval.ndim == 3}
+        grads, y = traced.lower().compile()(params, x)
+        return y, grads, widths
+
+    y, grads, widths = run()
+    assert widths == {(256, 512), (512, 256)}
+    monkeypatch.setattr(ep, "grouped_widths", lambda d, ff: (d, ff))
+    y1, grads1, widths1 = run()
+    assert widths1 == {(d, ff), (ff, d)}
+    assert float(jnp.max(jnp.abs(y1))) > 0
+
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        tol = 1e-5 if dtype == jnp.float32 else 2 ** -7
+        np.testing.assert_allclose(a, b, rtol=tol,
+                                   atol=tol * float(np.max(np.abs(b))))
+
+    close(y, y1)
+    jax.tree.map(close, grads, grads1)
+
+
+@pytest.mark.parametrize("name", [*_CELL_WIDTHS, "tiny"])
+def test_widths_that_do_not_qualify_trace_to_the_unpadded_program(name,
+                                                                  monkeypatch):
+    """At a width ``grouped_widths`` leaves alone, the expert layer's
+    forward and backward trace to the program the layer had before widths
+    were padded: the same jaxpr text as with the rule switched off (the
+    products at their own widths, no pad, no slice) — the four other
+    expert cells' widths and routings, at 64 tokens. At Nemotron-H's
+    widths the texts differ: there the rule engages."""
+    from tpudist.parallel import ep
+
+    if name == "tiny":
+        d, ff, act, held, E, k = 16, 24, "swiglu", (0, 4), 32, 2
+    else:
+        config = _cell_config(name)
+        d, ff = config["hidden_size"], config["moe_intermediate_size"]
+        E = config.get("n_routed_experts") or config["num_experts"]
+        k = config["num_experts_per_tok"]
+        held = (0, config["num_experts_held"])
+        act = "relu2" if name.startswith("nemotron") else "swiglu"
+    layer = _Experts(Routing(E, top_k=k, held=held), ff, act, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, 64, d), jnp.float32)
+    params = jax.eval_shape(layer.init, jax.random.key(0), x)["params"]
+
+    def text():
+        return str(jax.make_jaxpr(jax.grad(
+            lambda p, x: jnp.sum(layer.apply({"params": p}, x)
+                                 .astype(jnp.float32)),
+            argnums=(0, 1)))(params, x))
+
+    padded = text()
+    monkeypatch.setattr(ep, "grouped_widths", lambda d, ff: (d, ff))
+    assert (padded != text()) == name.startswith("nemotron")

@@ -789,7 +789,7 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
     the trace reader's pattern finds it; the attention kernel is there
     under ``gqa_attn``, three calls; the expert layers' grouped products
     are the compiler's ragged-dot kernels over one chunk of the 49,152
-    (token, choice) rows."""
+    (token, choice) rows, on tiles at least 256 wide on both widths."""
     import json
     import os
     import re
@@ -857,3 +857,14 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
     assert sum(f"[{chunk_rows}," in kernels[k] for k in grouped) \
         >= 4 * kinds.count("E")
     assert not any(f"[{rows}," in kernels[k] for k in grouped)
+    # the products run at ``grouped_widths`` (2,816 x 2,048 for 2,688 x
+    # 1,856, 1.156x the work): the compiler's kernel tiles both widths 256
+    # or 512 wide, where it cut them 128 wide at the published widths
+    d, ff = config["hidden_size"], config["moe_intermediate_size"]
+    d_pad, ff_pad = ep.grouped_widths(d, ff)
+    assert d_pad * ff_pad / (d * ff) == pytest.approx(1.156, abs=1e-3)
+    tilings = [t for k in grouped if (t := re.search(
+        r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', kernels[k]))]
+    assert len(tilings) >= 6 * kinds.count("E"), grouped
+    assert all(min(int(t[2]), int(t[3])) >= 256 for t in tilings), \
+        sorted({t[0] for t in tilings})

@@ -678,6 +678,39 @@ def row_chunks(rows: int, count: int, num_experts: int) -> tuple[int, int]:
     return rows, 1
 
 
+# ``jax.lax.ragged_dot`` lowers on the TPU to the compiler's grouped-product
+# kernel, whose tile on each width is the largest power of two up to 512
+# that divides it: 1,856 and 2,688 (Nemotron-H) are cut 128 wide, and a
+# grid step of 512 x 128 x 128 costs as much in its fixed overhead as in
+# MXU work (the products ran at 5.6% of their roofline). A width that
+# rounds up to a multiple of ``_WIDTH_TILE`` for at most an eighth more is
+# run at that width, zero-padded; the parameters keep their shapes.
+_WIDTH_TILE = 256
+
+
+def grouped_widths(d: int, ff: int) -> tuple[int, int]:
+    """``(d_pad, ff_pad)``: the widths :class:`GroupedExperts` runs its
+    grouped products at for a model width ``d`` and an expert width
+    ``ff`` — each rounded up to the next multiple of 256 where that adds
+    at most an eighth of it, else as it is. Noughts in the added columns
+    of ``w_up`` / ``w_gate`` give ``relu²(0) = silu(0)·0 = 0``, the added
+    rows of ``w_down`` add nothing: the layer's function is unchanged."""
+    def wide(width):
+        up = -(-width // _WIDTH_TILE) * _WIDTH_TILE
+        return up if 8 * (up - width) <= width else width
+
+    return wide(d), wide(ff)
+
+
+def _cols(x, n: int):
+    """Rows ``x`` with ``n`` columns: noughts added after the last, or the
+    first ``n`` kept (no operation where it has ``n``)."""
+    d = x.shape[-1]
+    if d < n:
+        return jnp.pad(x, ((0, 0), (0, n - d)))
+    return x[:, :n] if d > n else x
+
+
 def _live_chunks(n_live, chunk_rows: int):
     """How many chunks run: those whose first row is live, and chunk 0."""
     return jnp.maximum(1, (n_live + chunk_rows - 1) // chunk_rows)
@@ -1043,7 +1076,9 @@ class GroupedExperts(nn.Module):
     not defined (the TPU lowering leaves values) and the caller masks it.
     ``xs`` is the sorted rows, or the ``(chunk 0, the other chunks)`` pair
     :func:`dropless_moe` cuts them into: then the products run over the
-    chunks that hold a live row and the result is such a pair too."""
+    chunks that hold a live row and the result is such a pair too. The
+    products run at :func:`grouped_widths`: the weights are zero-padded
+    as they are cast, and a chunk's rows as it is worked on."""
 
     count: int
     ffn_dim: int
@@ -1054,27 +1089,37 @@ class GroupedExperts(nn.Module):
     def __call__(self, xs, sizes):
         chunked = isinstance(xs, tuple)
         d = (xs[0] if chunked else xs).shape[-1]
-        w = lambda name, shape: self.param(
-            name, nn.initializers.lecun_normal(batch_axis=(0,)), shape,
-            jnp.float32,
-        ).astype(self.dtype)
+        ff = self.ffn_dim
+        d_pad, ff_pad = grouped_widths(d, ff)
+
+        def w(name, shape, wide):
+            p = self.param(
+                name, nn.initializers.lecun_normal(batch_axis=(0,)),
+                (self.count, *shape), jnp.float32,
+            ).astype(self.dtype)
+            if shape == wide:
+                return p
+            return jnp.pad(p, [(0, 0)] + [(0, n - m)
+                                          for m, n in zip(shape, wide)])
+
+        into, out_of = ((d, ff), (d_pad, ff_pad)), ((ff, d), (ff_pad, d_pad))
         if self.act == "relu2":
-            wu = w("w_up", (self.count, d, self.ffn_dim))
-            wd = w("w_down", (self.count, self.ffn_dim, d))
+            wu, wd = w("w_up", *into), w("w_down", *out_of)
             if chunked:
                 return _relu2_ffn_live(xs, wu, wd, sizes)
-            return jax.lax.ragged_dot(
-                relu2(jax.lax.ragged_dot(xs, wu, sizes)), wd, sizes)
+            return _cols(jax.lax.ragged_dot(
+                relu2(jax.lax.ragged_dot(_cols(xs, d_pad), wu, sizes)), wd,
+                sizes), d)
         if self.act != "swiglu":
             raise ValueError(f"unknown expert_act {self.act!r}")
-        wg = w("w_gate", (self.count, d, self.ffn_dim))
-        wu = w("w_up", (self.count, d, self.ffn_dim))
-        wd = w("w_down", (self.count, self.ffn_dim, d))
+        wg, wu = w("w_gate", *into), w("w_up", *into)
+        wd = w("w_down", *out_of)
         if chunked:
             return _gated_ffn_live(xs, wg, wu, wd, sizes)
-        h = nn.silu(jax.lax.ragged_dot(xs, wg, sizes)) \
-            * jax.lax.ragged_dot(xs, wu, sizes)
-        return jax.lax.ragged_dot(h, wd, sizes)
+        x = _cols(xs, d_pad)
+        h = nn.silu(jax.lax.ragged_dot(x, wg, sizes)) \
+            * jax.lax.ragged_dot(x, wu, sizes)
+        return _cols(jax.lax.ragged_dot(h, wd, sizes), d)
 
 
 def _chunk_sizes(sizes, c, chunk_rows: int):
@@ -1092,7 +1137,9 @@ def _gated_ffn_live(xs, wg, wu, wd, sizes):
     ``xs`` and the result are ``(chunk 0, the other chunks)`` pairs. The
     backward is chunked alike, from chunk 0's kept ``x·w_gate`` and
     ``x·w_up`` (a further chunk computes its two again); a chunk's rows
-    past the last group get no gradient."""
+    past the last group get no gradient. Weights wider than the rows
+    (:func:`grouped_widths`) take each chunk's rows padded with noughts,
+    and its results and row gradients cut back to the rows' width."""
     return _gated_ffn_live_fwd(xs, wg, wu, wd, sizes)[0]
 
 
@@ -1102,9 +1149,11 @@ def _gated_ffn_live_fwd(xs, wg, wu, wd, sizes):
 
     def ffn(c, x):
         sz = _chunk_sizes(sizes, c, chunk_rows)
-        a = jax.lax.ragged_dot(x, wg, sz)
-        b = jax.lax.ragged_dot(x, wu, sz)
-        return jax.lax.ragged_dot(nn.silu(a) * b, wd, sz), a, b
+        wide = _cols(x, wu.shape[1])
+        a = jax.lax.ragged_dot(wide, wg, sz)
+        b = jax.lax.ragged_dot(wide, wu, sz)
+        return _cols(jax.lax.ragged_dot(nn.silu(a) * b, wd, sz),
+                     x.shape[-1]), a, b
 
     out, a, b = ffn(0, xs[0])
     (out,), _ = _over_live_chunks(
@@ -1123,15 +1172,17 @@ def _gated_ffn_live_bwd(res, d_out):
     def grads(c, x, d_out, a=None, b=None):
         sz = _chunk_sizes(sizes, c, chunk_rows)
         product = lambda rows, w: jax.lax.ragged_dot(rows, w, sz)
+        d, x = x.shape[-1], _cols(x, wu.shape[1])
         if a is None:
             a, b = product(x, wg), product(x, wu)
         h, gate_vjp = jax.vjp(lambda a, b: nn.silu(a) * b, a, b)
-        d_h, d_wd = jax.vjp(product, h, wd)[1](d_out)
+        d_h, d_wd = jax.vjp(product, h, wd)[1](_cols(d_out, wd.shape[-1]))
         d_a, d_b = gate_vjp(d_h)
         d_xa, d_wg = jax.vjp(product, x, wg)[1](d_a)
         d_xb, d_wu = jax.vjp(product, x, wu)[1](d_b)
         live = _live_mask(c, chunk_rows, n_live)[:, None]
-        return (jnp.where(live, d_xa + d_xb, 0),), (d_wg, d_wu, d_wd)
+        return (jnp.where(live, _cols(d_xa + d_xb, d), 0),), \
+            (d_wg, d_wu, d_wd)
 
     (d_xs,), d_ws = _over_live_chunks(
         grads, n_live, xs, d_out, first=grads(0, xs[0], d_out[0], a0, b0)
@@ -1156,8 +1207,8 @@ def _relu2_ffn_live_fwd(xs, wu, wd, sizes):
 
     def ffn(c, x):
         sz = _chunk_sizes(sizes, c, chunk_rows)
-        a = jax.lax.ragged_dot(x, wu, sz)
-        return jax.lax.ragged_dot(relu2(a), wd, sz), a
+        a = jax.lax.ragged_dot(_cols(x, wu.shape[1]), wu, sz)
+        return _cols(jax.lax.ragged_dot(relu2(a), wd, sz), x.shape[-1]), a
 
     out, a = ffn(0, xs[0])
     (out,), _ = _over_live_chunks(
@@ -1176,14 +1227,15 @@ def _relu2_ffn_live_bwd(res, d_out):
     def grads(c, x, d_out, a=None):
         sz = _chunk_sizes(sizes, c, chunk_rows)
         product = lambda rows, w: jax.lax.ragged_dot(rows, w, sz)
+        d, x = x.shape[-1], _cols(x, wu.shape[1])
         if a is None:
             a = product(x, wu)
         h, act_vjp = jax.vjp(relu2, a)
-        d_h, d_wd = jax.vjp(product, h, wd)[1](d_out)
+        d_h, d_wd = jax.vjp(product, h, wd)[1](_cols(d_out, wd.shape[-1]))
         (d_a,) = act_vjp(d_h)
         d_x, d_wu = jax.vjp(product, x, wu)[1](d_a)
         live = _live_mask(c, chunk_rows, n_live)[:, None]
-        return (jnp.where(live, d_x, 0),), (d_wu, d_wd)
+        return (jnp.where(live, _cols(d_x, d), 0),), (d_wu, d_wd)
 
     (d_xs,), d_ws = _over_live_chunks(
         grads, n_live, xs, d_out, first=grads(0, xs[0], d_out[0], a0)
