@@ -94,8 +94,8 @@ SCAN_TOL = 2e-5
                          ids=["published_init", "harness_init"])
 @pytest.mark.parametrize("chunks", [1, 2, 5])
 def test_ssd_scan_matches_the_sequential_recurrence(chunks, published):
-    """The kernel (interpret mode) and its chunk-parallel backward against
-    the recurrence a position at a time: ``y`` and the gradients of ``x``,
+    """The forward and backward kernels (interpret mode) against the
+    recurrence a position at a time: ``y`` and the gradients of ``x``,
     ``dt``, ``A``, ``B``, ``C`` and ``D``. Under the published
     initialisation the state a chunk hands on is a large part of the
     state-space output of the next (checked against the same chunks each
@@ -120,6 +120,63 @@ def test_ssd_scan_matches_the_sequential_recurrence(chunks, published):
             ssm = want - D[:, None] * x
             assert float(jnp.max(jnp.abs(carried[:, 8:]))) \
                 > 0.2 * float(jnp.max(jnp.abs(ssm[:, 8:])))
+
+
+def _vjp(fn, args, cot):
+    y, pullback = jax.vjp(fn, *args)
+    return pullback(cot.astype(y.dtype))
+
+
+@functools.cache
+def _backward_pair(chunk: int):
+    """The kernels' gradients and JAX's gradient of :func:`ssd.ssd_chunked`
+    (the XLA form of the same mathematics), and of the chunked form run on
+    each chunk alone (no state handed between chunks: the control)."""
+    def alone(x, dt, A, B, C, D):
+        cut = lambda v: v.reshape((-1, chunk) + v.shape[2:])
+        return ssd.ssd_chunked(cut(x), cut(dt), A, cut(B), cut(C), D,
+                               chunk=chunk).reshape(x.shape)
+
+    pair = (functools.partial(ssd.ssd_scan, chunk=chunk),
+            functools.partial(ssd.ssd_chunked, chunk=chunk), alone)
+    return [jax.jit(lambda cot, *a, fn=fn: _vjp(fn, a, cot)) for fn in pair]
+
+
+def _rel_rms(got, want):
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+
+
+# bfloat16 operands, float32 accumulation, on both sides: the two round
+# different partial sums (the kernel's dx once, the chunked form's two
+# terms apiece), so they agree to about one bfloat16 step (2^-8 = 3.9e-3)
+# of RMS: measured 1.2e-3 - 4.0e-3 on six seeds. 1e-2; the state dropped
+# between chunks misses it by 5x or more (measured >= 5.2e-2)
+BF16_TOL = 1e-2
+
+
+@pytest.mark.parametrize("published", [True, False],
+                         ids=["published_init", "harness_init"])
+def test_ssd_backward_kernel_matches_the_chunked_mathematics(published):
+    """The backward kernel against JAX's gradient of the chunk-parallel
+    XLA form, in the cell's layout: bfloat16 ``x``, ``B``, ``C``; 2 groups
+    of 8 heads of 64 (4 tiles of two heads a group), 3 chunks; all six
+    gradients, as the relative RMS of the gap. The same chunks computed
+    each from nought are outside the tolerance, so the state's cotangent
+    carried back across chunks is what is compared."""
+    chunk = 16
+    x, dt, A, B, C, D = scan_inputs(jax.random.key(7), 3, published, b=1,
+                                    chunk=chunk, h=16, p=64, g=2, n=32)
+    bf = jnp.bfloat16
+    args = (x.astype(bf), dt, A, B.astype(bf), C.astype(bf), D)
+    cot = jax.random.normal(jax.random.key(8), x.shape)
+    kernel, chunked, alone = _backward_pair(chunk)
+    got, want, control = (f(cot, *args) for f in (kernel, chunked, alone))
+    for name, g, w, c in zip("x dt A B C D".split(), got, want, control):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        assert _rel_rms(g, w) < BF16_TOL, name
+        if name != "D":  # the skip's gradient holds no state
+            assert _rel_rms(c, w) > 5 * BF16_TOL, name
 
 
 def test_chunk_count_is_the_kernel_grid():
@@ -327,7 +384,8 @@ def test_recomputation_keeps_the_scan_kernel_once_a_layer(setup, policy,
     """Under ``dots_saveable`` a Mamba-2 block's backward finds the
     kernel's output and states kept (``remat.KERNEL_RESIDUALS``) and the
     step holds one forward kernel a Mamba-2 layer; under ``full`` each
-    block's backward launches it again."""
+    block's backward launches it again. The backward kernel runs once a
+    layer under both."""
     tokens, params = setup
     model = tiny(remat_policy=policy)
     traced = jax.jit(jax.grad(program_loss(model))).trace(
@@ -335,6 +393,39 @@ def test_recomputation_keeps_the_scan_kernel_once_a_layer(setup, policy,
     mamba = CONFIG["hybrid_override_pattern"][:CONFIG["num_hidden_layers"]]
     assert remat.forward_kernels(traced.jaxpr, ("ssd.py",)) \
         == per_layer * mamba.count("M")
+    assert remat.pallas_kernels(traced.jaxpr, ("ssd.py",), "_bwd_kernel") \
+        == mamba.count("M")
+
+
+def _scans_from(jaxpr, file: str) -> int:
+    """``scan`` equations of a traced program (inside custom rules and
+    loops too) that a line of ``file`` made."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and any(
+                f.file_name.endswith(file)
+                for f in eqn.source_info.traceback.frames):
+            n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _scans_from(getattr(sub, "jaxpr", sub), file)
+    return n
+
+
+def test_a_steps_backward_runs_one_scan_kernel_a_layer(setup):
+    """Static counter of the backward: a step's gradient holds exactly one
+    backward kernel of ``ops/ssd.py`` (``_bwd_kernel``) a Mamba-2 layer,
+    beside one forward kernel, and no ``lax.scan`` of ``ops/ssd.py``: the
+    state's cotangent is carried across the chunks inside the kernel."""
+    tokens, params = setup
+    model = tiny(remat_policy="dots_saveable")
+    traced = jax.jit(jax.grad(program_loss(model))).trace(
+        params["harness_init"], tokens)
+    mamba = CONFIG["hybrid_override_pattern"][
+        :CONFIG["num_hidden_layers"]].count("M")
+    assert remat.pallas_kernels(traced.jaxpr, ("ssd.py",), "_bwd_kernel") \
+        == mamba
+    assert remat.forward_kernels(traced.jaxpr, ("ssd.py",)) == mamba
+    assert _scans_from(traced.jaxpr.jaxpr, "ops/ssd.py") == 0
 
 
 def test_block_scopes_keep_the_trace_contract(setup):

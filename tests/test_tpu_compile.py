@@ -757,10 +757,10 @@ def test_laguna_cell_step_compiles_at_the_published_widths(topo, monkeypatch):
 def test_ssd_scan_fwd_bwd_compiles_at_a_mamba2_layers_shape(compile_for_chip):
     """The chunked state-space scan at Nemotron-3-Nano's Mamba-2 layer and
     the cell's sequence (64 heads of 64, 8 groups of state 128, 8,192
-    positions in 64 chunks of 128), forward and its chunk-parallel
-    backward as one program: the forward kernel is there, once, writing
-    ``y`` and every chunk's float32 starting state in the kernel's tiles
-    (two heads of 64 a 128-lane tile)."""
+    positions in 64 chunks of 128), forward and backward as one program:
+    two kernels, each once — the forward writing ``y`` and every chunk's
+    float32 starting state in the kernel's tiles (two heads of 64 a
+    128-lane tile), the backward (under ``transpose``) writing ``dx``."""
     from tpudist.ops.ssd import ssd_scan
 
     def loss(*args):
@@ -773,10 +773,15 @@ def test_ssd_scan_fwd_bwd_compiles_at_a_mamba2_layers_shape(compile_for_chip):
         ((1, 8192, 8, 128), jnp.bfloat16), ((64,), jnp.float32))
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(calls) == 1
-    head = calls[0].split(" custom-call(")[0]
+    assert len(calls) == 2
+    backward = [c for c in calls if "/transpose(" in c]
+    assert len(backward) == 1
+    (forward,) = [c for c in calls if c not in backward]
+    head = forward.split(" custom-call(")[0]
     assert "bf16[1,8192,4096]" in head and "f32[1,64,8,4,128,128]" in head, \
         head
+    head = backward[0].split(" custom-call(")[0]
+    assert "(bf16[1,8192,4096]" in head, head
 
 
 def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
@@ -785,8 +790,9 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
     configuration, traffic and family file, 528.1M parameters, 8,192
     tokens — compiled for one described chip: it fits beside the harness's
     copy under per-block ``dots_saveable``; the scan's forward kernel runs
-    once a Mamba-2 layer (its output and states kept, not made again) and
-    the trace reader's pattern finds it; the attention kernel is there
+    once a Mamba-2 layer (its output and states kept, not made again), its
+    backward kernel once a layer too, and the trace reader finds all of
+    them (by the kernel's name or its scope); the attention kernel is there
     under ``gqa_attn``, three calls; the expert layers' grouped products
     are the compiler's ragged-dot kernels over one chunk of the 49,152
     (token, choice) rows, on tiles at least 256 wide on both widths."""
@@ -798,6 +804,7 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
 
     from benchmarks.families import common, nemotron_h as family
     from benchmarks.layer_metrics import ssd_roofline
+    from benchmarks.layer_metrics.moe_ms import stage_of
     from tpudist import mesh as mesh_lib
     from tpudist.train import TrainState, make_train_step
 
@@ -832,6 +839,8 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
     traced = step.jitted.trace(state, batch)
     kinds = family.kinds(config)
     assert remat.forward_kernels(traced.jaxpr, ("ssd.py",)) == kinds.count("M")
+    assert remat.pallas_kernels(traced.jaxpr, ("ssd.py",), "_bwd_kernel") \
+        == kinds.count("M")
     assert remat.forward_attention_kernels(traced.jaxpr) == kinds.count("*")
     compiled = traced.lower().compile()
     memory = compiled.memory_analysis()
@@ -844,8 +853,15 @@ def test_nemotron_h_cell_step_compiles_at_the_published_widths(topo,
         for name in re.findall(
             r"%?([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
             line)}
-    scans = [k for k in kernels if ssd_roofline.KERNEL.match(k)]
-    assert len(scans) == kinds.count("M"), sorted(kernels)
+    op_name = lambda line: (re.findall(r'op_name="([^"]*)"', line)
+                            or [""])[0]
+    # the kernels under the Mamba-2 block's ``ssd_scan`` scope
+    scans = [k for k in kernels if "/ssd_scan/" in op_name(kernels[k])]
+    backward = [k for k in scans if "/transpose(" in op_name(kernels[k])]
+    assert len(scans) == 2 * kinds.count("M"), sorted(kernels)
+    assert len(backward) == kinds.count("M"), scans
+    assert all(ssd_roofline.KERNEL.match(k) or stage_of(
+        op_name(kernels[k]), k) == ssd_roofline.STAGE for k in scans), scans
     attention = [k for k in kernels if re.match(r"^gqa_attn(\.\d+)?$", k)]
     assert len(attention) == 3 * kinds.count("*"), sorted(kernels)
     rows = traffic["seq_len"] * config["num_experts_per_tok"]

@@ -1,6 +1,6 @@
 """Chunked state-space scan (SSD, the state-space duality of Mamba-2,
-arXiv:2405.21060) as a Pallas TPU forward kernel beside a chunk-parallel
-backward.
+arXiv:2405.21060) as a Pallas TPU forward kernel and a Pallas TPU backward
+kernel.
 
 No counterpart in the system this repo was modelled on. The recurrence it
 computes, per head ``h`` of ``P`` channels in group ``g(h) = h // (H / G)``
@@ -26,18 +26,26 @@ decay matrix and the in-chunk product on the MXU, adds the carried state's
 every chunk's starting state: the two residuals (``tpudist/remat.py``
 ``KERNEL_RESIDUALS``: ``ssd_out``, ``ssd_states``).
 
-**Backward** (:func:`_ssd_bwd`): chunk-parallel XLA contractions from the
-saved states — the gradients of the chunk's own terms given ``H_c``, and
-of the chunk's contribution to ``H_{c+1}`` — with one recurrence over the
-chunks (not the positions) for the state's cotangent; the groups side by
-side (a ``jax.vmap``: 0.16 GB more of a step's temporaries at the cell's
-shape than a loop over them, and none of its serial steps).
+**Backward** (:func:`_bwd_kernel`): the forward's mirror, on the same grid
+with the chunks walked from the last. The cotangent of the state a chunk
+hands on, ``G``, lives in VMEM scratch (float32, in the forward's tiles)
+and starts from nought; each step reads the chunk's saved starting state
+``H_c`` (it rebuilds nothing), forms ``C B^T`` once for the group and per
+head the masked decay matrix, and gives ``dx = M^T dy + (B ⊙ W) G + D dy``
+(``M`` the in-chunk product's matrix, ``W_s = dt_s exp(cs_L - cs_s)``),
+``dB`` and ``dC`` (through ``C B^T``, ``W`` and ``exp(cs_t) C H_c``), each
+position's cotangent of ``dt`` (its direct terms) and of ``cs``, the
+chunk's share of ``dD``, and moves ``G`` back: ``G <- exp(cs_L) G + sum_t
+exp(cs_t) C_t^T dy_t``. XLA keeps the per-position work: the in-chunk
+reverse cumulative sum of ``cs``'s cotangent, and from it ``dt``'s and
+``A``'s.
 
-Decays, exponentials and the state are float32; matmul operands are in the
-compute dtype (``x``'s). Cumulative sums are taken by XLA in float32 and
-handed to the kernel in the two layouts it reads (a column per head, a row
-per head). :func:`ssd_chunked` is the same mathematics in XLA alone (the
-microbenchmark's comparison); :func:`ssd_cost` the work a call needs.
+Decays, exponentials, the state and its cotangent are float32; matmul
+operands are in the compute dtype (``x``'s). Cumulative sums are taken by
+XLA in float32 and handed to the kernels in the layouts they read (a
+column per head, a row per head, exact bfloat16 parts). :func:`ssd_chunked`
+is the same mathematics in XLA alone (the microbenchmark's comparison, and
+the tests'); :func:`ssd_cost` the work a call needs.
 """
 
 from __future__ import annotations
@@ -198,10 +206,167 @@ def _fwd(x, dt, A, B, C, D, chunk: int):
     return y.reshape(b, s, heads, p), states
 
 
-# -- the chunk-parallel mathematics, one group: the backward's and the XLA
-#    comparison's. ``x [b, nc, L, hpg, P]``, ``dt [b, nc, L, hpg]``,
-#    ``A``, ``D`` ``[hpg]``, ``B``, ``C`` ``[b, nc, L, N]``, ``st [b, nc,
-#    hpg, N, P]`` the state each chunk starts from ---------------------------
+def _split3(v):
+    """``v`` (float32) as three bfloat16 parts that sum to it exactly."""
+    hi = v.astype(jnp.bfloat16)
+    rest = v - hi.astype(F32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(F32)).astype(jnp.bfloat16)
+
+
+def _split2(v):
+    """``v`` (float32) as two bfloat16 parts that sum to it within 2^-16."""
+    hi = v.astype(jnp.bfloat16)
+    return hi, (v - hi.astype(F32)).astype(jnp.bfloat16)
+
+
+def _bwd_kernel(x_ref, dy_ref, b_ref, c_ref, csr_ref, dtr_ref, parts_ref,
+                dl_ref, st_ref, dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref,
+                dd_ref, grad, *, head_dim: int, per_tile: int):
+    """One (batch row, group, chunk), the chunks from the last. ``x_ref``
+    and ``dy_ref`` ``[L, hpg·P]``, ``b_ref`` and ``c_ref`` ``[L, N]``,
+    ``csr_ref`` / ``dtr_ref`` ``[hpg, L]`` float32, ``parts_ref`` ``[L,
+    6·hpg]`` the same two as columns in exact bfloat16 parts
+    (:func:`_split3`), ``dl_ref`` ``[1, hpg·P]`` ``D`` on each head's
+    lanes, ``st_ref`` the chunk's starting state as the forward wrote it.
+    Writes ``dx``, ``dB`` and ``dC`` ``[L, N]``, the cotangents of ``dt``
+    (its direct terms, ``ddt_ref``) and of ``cs`` (``dcs_ref``), a row per
+    head ``[hpg, L]``, float32, and ``dd_ref`` ``[1, hpg]``, the chunk's
+    share of ``dD``. ``grad`` ``[tiles, N, per_tile·P]`` holds the
+    cotangent of the state the chunk hands on, and leaves the cotangent of
+    the one it starts from.
+
+    The in-chunk matrices are held transposed, ``[s, t]`` (source,
+    target): ``M^T = (B C^T)^T ⊙ exp(cs_t - cs_s) ⊙ dt_s``, so that ``dx =
+    M^T dy`` and ``dM^T = x dy^T`` are plain products. A head's ``cs_s``
+    and ``dt_s`` across the lanes, and each head's sum over its lanes, are
+    products with 0/1 selections on the MXU (bfloat16 parts, float32 sums)
+    where the vector unit would broadcast and reduce across lanes. ``G``
+    enters its products as two parts of the compute dtype (:func:`_split2`),
+    which keeps ``dt``'s and ``A``'s gradients as close to the float32
+    recurrence as the chunk-parallel XLA form kept them."""
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        grad[...] = jnp.zeros_like(grad)
+
+    L = x_ref.shape[0]
+    hpg = csr_ref.shape[0]
+    width = per_tile * head_dim
+    cdt = x_ref.dtype
+    bf = jnp.bfloat16
+    nt = (((1,), (1,)), ((), ()))           # a b^T
+    tn = (((0,), (0,)), ((), ()))           # a^T b
+    b, c = b_ref[...], c_ref[...]
+    cbt = jax.lax.dot_general(b, c, nt, preferred_element_type=F32)  # [s, t]
+    causal = (jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+              >= jax.lax.broadcasted_iota(jnp.int32, (L, L), 0))
+    csr, dtr, parts = csr_ref[...], dtr_ref[...], parts_ref[...]
+    head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, hpg), 1)
+    head_row = jax.lax.broadcasted_iota(jnp.int32, (hpg, 1), 0)
+    last_row = jax.lax.broadcasted_iota(jnp.int32, (L, 1), 0) == L - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    # column r of parts_ref: part r // hpg (cs 0-2, dt 3-5) of head r % hpg
+    part = jax.lax.broadcasted_iota(jnp.int32, (6 * hpg, 1), 0)
+    part_of_cs, part_head = part < 3 * hpg, part % hpg
+
+    def spread(of_lane, n):
+        """``[L, 2n]``: lane ``k < n`` holds ``cs_s`` of head
+        ``of_lane[k]``, lane ``n + k`` its ``dt_s``, exactly."""
+        sel = ((part_head == jnp.concatenate([of_lane, of_lane], axis=1))
+               & (part_of_cs == (jax.lax.broadcasted_iota(
+                   jnp.int32, (1, 2 * n), 1) < n)))
+        return jnp.dot(parts, sel.astype(bf), preferred_element_type=F32)
+
+    dcbt = jnp.zeros((L, L), F32)
+    db = jnp.zeros(b.shape, F32)
+    dc = jnp.zeros(c.shape, F32)
+    lane_dt = jnp.zeros((hpg, L), F32)  # d dt_s through W_s
+    lane_cs = jnp.zeros((hpg, L), F32)  # d cs through C H_c and W_s
+    in_cs = jnp.zeros((hpg, L), F32)    # d cs_t through M
+    q_cols = jnp.zeros((L, hpg), F32)   # d dt_s through M
+    dd = jnp.zeros((1, hpg), F32)
+    for tile in range(hpg // per_tile):
+        lanes = slice(tile * width, (tile + 1) * width)
+        xt, dyt = x_ref[:, lanes], dy_ref[:, lanes]
+        xf, dyf = xt.astype(F32), dyt.astype(F32)
+        h = st_ref[tile]                                     # H_c [N, width]
+        g = grad[tile]                                       # of H_{c+1}
+        of_lane = jnp.full((1, width), tile * per_tile, jnp.int32)
+        for i in range(1, per_tile):
+            of_lane += (lane >= i * head_dim).astype(jnp.int32)
+        per_lane = spread(of_lane, width)
+        cs_l, dt_l = per_lane[:, :width], per_lane[:, width:]
+        into = jnp.exp(cs_l)                                 # exp(cs_t)
+        keep = jnp.exp(cs_l[L - 1:, :])                      # exp(cs_L)
+        decay = jnp.exp(cs_l[L - 1:, :] - cs_l)              # exp(cs_L - cs_s)
+        weight = dt_l * decay                                # W_s
+        hc = h.astype(cdt)
+        # G's products in two parts of the compute dtype (~16 bits)
+        gs = [g.astype(cdt)] if cdt == F32 else _split2(g)
+        ch = jnp.dot(c, hc, preferred_element_type=F32)      # C H_c
+        v = jnp.zeros((L, width), F32)                       # B G
+        ydy = (into * dyf).astype(cdt)
+        wx = (weight * xf).astype(cdt)
+        dc += jax.lax.dot_general(ydy, hc, nt, preferred_element_type=F32)
+        for u in gs:
+            v += jnp.dot(b, u, preferred_element_type=F32)
+            db += jax.lax.dot_general(wx, u, nt, preferred_element_type=F32)
+        dx = weight * v + dl_ref[:, lanes] * dyf
+        own = jnp.sum(dyf * xf, axis=0, keepdims=True)       # dD, by lane
+        for i in range(per_tile):
+            j = tile * per_tile + i
+            mine = (lane >= i * head_dim) & (lane < (i + 1) * head_dim)
+            cs_t = jnp.sum(jnp.where(head_row == j, csr, 0.0), axis=0,
+                           keepdims=True)                    # [1, L]
+            both = spread(jnp.full((1, L), j, jnp.int32), L)
+            cs_s, dt_s = both[:, :L], both[:, L:]            # [L, L]
+            et = jnp.exp(jnp.where(causal, cs_t - cs_s, -jnp.inf))
+            mt = cbt * et * dt_s                             # M^T
+            dx += jnp.dot(mt.astype(cdt), jnp.where(mine, dyt, 0).astype(cdt),
+                          preferred_element_type=F32)
+            r = jax.lax.dot_general(jnp.where(mine, xt, 0).astype(cdt), dyt,
+                                    nt, preferred_element_type=F32) * et
+            dcbt += r * dt_s                # dM^T ⊙ E^T ⊙ dt_s
+            q = r * cbt                     # dM^T ⊙ M^T / dt_s
+            # cs_t gains the column sums of dM^T ⊙ M^T and cs_s loses its
+            # row sums: both from one matrix, so that they cancel
+            in_cs = jnp.where(head_row == j, jnp.sum(
+                q * dt_s, axis=0, keepdims=True), in_cs)
+            q_cols = jnp.where(head_lane == j, jnp.sum(
+                q, axis=1, keepdims=True), q_cols)
+            dd = jnp.where(head_lane == j, jnp.sum(jnp.where(
+                mine, own, 0.0), axis=1, keepdims=True), dd)
+        dx_ref[:, lanes] = dx.astype(dx_ref.dtype)
+        onward = v * xf                     # dW_s, by lane
+        tail = (keep * jnp.sum(g * h, axis=0, keepdims=True)
+                + jnp.sum(onward * weight, axis=0, keepdims=True))  # d cs_L
+        # each head's sum over its lanes, [hpg, L], of the two parts
+        to_head = (head_row == of_lane).astype(bf)
+        to_head = jnp.concatenate([to_head, to_head], axis=1)
+        head_sums = lambda u: jax.lax.dot_general(
+            to_head, jnp.concatenate(_split2(u), axis=1), nt,
+            preferred_element_type=F32)
+        lane_dt += head_sums(onward * decay)
+        lane_cs += head_sums(into * dyf * ch - onward * weight
+                             + jnp.where(last_row, tail, 0.0))
+        grad[tile] = keep * g + jax.lax.dot_general(
+            c, ydy, tn, preferred_element_type=F32)
+    dcb16 = dcbt.astype(cdt)
+    dc += jax.lax.dot_general(dcb16, b, tn, preferred_element_type=F32)
+    db += jnp.dot(dcb16, c, preferred_element_type=F32)
+    db_ref[...] = db.astype(db_ref.dtype)
+    dc_ref[...] = dc.astype(dc_ref.dtype)
+    q_rows = q_cols.T
+    ddt_ref[...] = lane_dt + q_rows
+    dcs_ref[...] = lane_cs + in_cs - dtr * q_rows
+    dd_ref[...] = dd
+
+
+# -- the chunk-parallel mathematics, one group: the XLA comparison's.
+#    ``x [b, nc, L, hpg, P]``, ``dt [b, nc, L, hpg]``, ``A``, ``D``
+#    ``[hpg]``, ``B``, ``C`` ``[b, nc, L, N]``, ``st [b, nc, hpg, N, P]``
+#    the state each chunk starts from -----------------------------------------
 
 
 def _in_chunk(x, dt, A, B, C, D, st):
@@ -259,8 +424,8 @@ def _states(contrib, decay):
 
 def ssd_chunked(x, dt, A, B, C, D, *, chunk: int = 128):
     """:func:`ssd_scan`'s mathematics in XLA contractions alone (all
-    groups at once; differentiable by JAX): the comparison a
-    microbenchmark sets beside the kernel."""
+    groups at once; differentiable by JAX): the comparison the
+    microbenchmark and the tests set beside the kernels."""
     b, s, heads, p = x.shape
     groups = B.shape[2]
     hpg = heads // groups
@@ -289,55 +454,74 @@ def _ssd_vjp_fwd(x, dt, A, B, C, D, chunk):
 
 
 def _ssd_bwd(chunk, res, gy):
+    """Backward rule: the kernel's reverse sweep, then the per-position sums
+    it leaves — the in-chunk reverse cumulative sum of ``cs``'s cotangent,
+    and from it ``dt``'s and ``A``'s."""
     x, dt, A, B, C, D, states = res
     b, s, heads, p = x.shape
     groups, n = B.shape[2:]
-    hpg, nc = heads // groups, s // chunk
-    # the kernel's tiles back to heads: [G, b, nc, hpg, N, P]
-    tiles = states.shape[3]
-    st = states.reshape(b, nc, groups, tiles, n, hpg // tiles, p)
-    st = jnp.moveaxis(st.transpose(0, 1, 2, 3, 5, 4, 6).reshape(
-        b, nc, groups, hpg, n, p), 2, 0)
-    f32 = lambda v: v.astype(F32)
-    xg, dtg, bg, cg = _by_group(x, f32(dt), B, C, chunk, groups)
-    gyg = jnp.moveaxis(gy.reshape(b, nc, chunk, groups, hpg, p), 3, 0)
-    per = lambda v: f32(v).reshape(groups, hpg)
-
-    def own(args):
-        # the chunks' own terms, given the states they start from
-        *operands, g = args
-        return jax.vjp(_in_chunk, *operands)[1](f32(g))
-
-    gx, gdt, gA, gB, gC, gD, gst = jax.vmap(own)(
-        (xg, dtg, per(A), bg, cg, per(D), st, gyg))
-    decay = jnp.exp(jnp.sum((dtg * per(A)[:, None, None, None]).reshape(
-        groups, b, nc, chunk, hpg), axis=3))                 # [G, b, nc, hpg]
-
-    # the state's cotangent, chunk c's total from c + 1's: the one
-    # recurrence, over the chunks
-    def back(g_next, inp):
-        g_own, keep = inp
-        return g_own + keep[..., None, None] * g_next, g_next
-
-    _, g_after = jax.lax.scan(
-        back, jnp.zeros_like(gst[:, :, 0]),
-        (jnp.moveaxis(gst, 2, 0), jnp.moveaxis(decay, 2, 0)), reverse=True)
-    g_contrib = jnp.moveaxis(g_after, 0, 2)                  # of H_{c+1}
-    g_decay = jnp.sum(g_contrib * st, axis=(-2, -1))
-
-    def onward(args):
-        *operands, g_c, g_k = args
-        return jax.vjp(_carry, *operands)[1]((g_c, g_k))
-
-    gx2, gdt2, gA2, gB2 = jax.vmap(onward)(
-        (xg, dtg, per(A), bg, g_contrib, g_decay))
-    heads_last = lambda v: jnp.moveaxis(v, 0, 3)
-    return (heads_last(gx + gx2).reshape(x.shape).astype(x.dtype),
-            heads_last(gdt + gdt2).reshape(dt.shape).astype(dt.dtype),
-            (gA + gA2).reshape(heads).astype(A.dtype),
-            heads_last(gB + gB2).reshape(B.shape).astype(B.dtype),
-            heads_last(gC).reshape(C.shape).astype(C.dtype),
-            gD.reshape(heads).astype(D.dtype))
+    hpg = heads // groups
+    nc = chunk_count(s, chunk)
+    per_tile = _heads_per_tile(hpg, p)
+    tiles = hpg // per_tile
+    cs = _chunk_cumsum(dt, A, chunk).reshape(b, s, groups, hpg)
+    dt32 = dt.astype(F32)
+    dtg = dt32.reshape(b, s, groups, hpg)
+    rows = lambda v: v.transpose(0, 2, 3, 1)                 # [b, G, hpg, S]
+    to = lambda v: v.astype(x.dtype).reshape(b, s, groups * n)
+    kernel = functools.partial(_bwd_kernel, head_dim=p, per_tile=per_tile)
+    back = lambda c: nc - 1 - c                              # reverse sweep
+    lanes = pl.BlockSpec((None, chunk, hpg * p),
+                         lambda i, g, c: (i, back(c), g))
+    state = pl.BlockSpec((None, chunk, n), lambda i, g, c: (i, back(c), g))
+    row = pl.BlockSpec((None, None, hpg, chunk),
+                       lambda i, g, c: (i, g, 0, back(c)))
+    # cs and dt as columns of exact bfloat16 parts, [b, G, S, 6·hpg]
+    parts = jnp.concatenate(_split3(cs) + _split3(dtg),
+                            axis=-1).transpose(0, 2, 1, 3)
+    dx, dB, dC, ddt, dcs, dD = pl.pallas_call(
+        kernel,
+        grid=(b, groups, nc),
+        in_specs=[
+            lanes, lanes, state, state, row, row,
+            pl.BlockSpec((None, None, chunk, 6 * hpg),
+                         lambda i, g, c: (i, g, back(c), 0)),
+            pl.BlockSpec((None, 1, hpg * p), lambda i, g, c: (g, 0, 0)),
+            pl.BlockSpec((None, None, None, tiles, n, per_tile * p),
+                         lambda i, g, c: (i, back(c), g, 0, 0, 0)),
+        ],
+        out_specs=[
+            lanes, state, state, row, row,
+            pl.BlockSpec((None, None, None, 1, hpg),
+                         lambda i, g, c: (i, g, back(c), 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, heads * p), x.dtype),
+            jax.ShapeDtypeStruct((b, s, groups * n), B.dtype),
+            jax.ShapeDtypeStruct((b, s, groups * n), C.dtype),
+            jax.ShapeDtypeStruct((b, groups, hpg, s), F32),
+            jax.ShapeDtypeStruct((b, groups, hpg, s), F32),
+            jax.ShapeDtypeStruct((b, groups, nc, 1, hpg), F32),
+        ],
+        scratch_shapes=[pltpu.VMEM((tiles, n, per_tile * p), F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=backend.interpret(),
+    )(x.reshape(b, s, heads * p), gy.reshape(b, s, heads * p), to(B), to(C),
+      rows(cs), rows(dtg), parts,
+      jnp.repeat(D.astype(F32), p).reshape(groups, 1, hpg * p), states)
+    heads_of = lambda v: v.transpose(0, 3, 1, 2).reshape(b, s, heads)
+    dcs = heads_of(dcs)
+    # cs_t sums dt_u A over u <= t in the chunk
+    da = jax.lax.cumsum(dcs.reshape(b, nc, chunk, heads), axis=2,
+                        reverse=True).reshape(b, s, heads)
+    ddt = heads_of(ddt) + A.astype(F32) * da
+    return (dx.reshape(x.shape),
+            ddt.astype(dt.dtype),
+            jnp.sum(dt32 * da, axis=(0, 1)).astype(A.dtype),
+            dB.reshape(B.shape),
+            dC.reshape(C.shape),
+            jnp.sum(dD, axis=(0, 2, 3)).reshape(heads).astype(D.dtype))
 
 
 _ssd.defvjp(_ssd_vjp_fwd, _ssd_bwd)

@@ -54,7 +54,9 @@ under ``full`` / ``save_nothing`` (``tests/test_remat_attention.py``).
 The chunked state-space scan (``ops/ssd.py``) names its output and every
 chunk's starting state alike (``ssd_out``, ``ssd_states``: ``B·H·S·P`` in
 the compute dtype + ``B·chunks·H·N·P`` float32 a layer); its counter is
-:func:`forward_kernels` of ``ssd.py``.
+:func:`forward_kernels` of ``ssd.py``, and its backward kernel's is
+:func:`pallas_kernels` of ``ssd.py`` and ``_bwd_kernel``: one a layer in a
+training step, under every policy.
 """
 
 from __future__ import annotations
@@ -85,22 +87,26 @@ POLICY_NAMES = ("none", "full", "dots_saveable", "save_nothing")
 _ATTENTION_KERNEL_FILES = ("flash_attention.py", "vmem_attention.py")
 
 
-def forward_kernels(jaxpr, files: tuple[str, ...]) -> int:
+def pallas_kernels(jaxpr, files: tuple[str, ...], prefix: str) -> int:
     """Static counter: the ``pallas_call``s of a traced program (a
     ``ClosedJaxpr`` or ``Jaxpr``, e.g. ``jitted.trace(*args).jaxpr``) whose
-    kernel is a FORWARD kernel — ``_fwd_kernel*`` of one of ``files``
-    (under ``tpudist/ops/``), by the kernel function's own name and
-    file."""
+    kernel function's name starts with ``prefix`` and whose file is one of
+    ``files`` (under ``tpudist/ops/``)."""
     n = 0
     for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
         if eqn.primitive.name == "pallas_call":
             kernel = eqn.params["jaxpr"].debug_info
-            n += (kernel.func_name.startswith("_fwd_kernel")
+            n += (kernel.func_name.startswith(prefix)
                   and kernel.func_filename.endswith(files))
             continue  # a kernel's body holds no kernel
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += forward_kernels(sub, files)
+            n += pallas_kernels(sub, files, prefix)
     return n
+
+
+def forward_kernels(jaxpr, files: tuple[str, ...]) -> int:
+    """:func:`pallas_kernels` of the FORWARD kernels, ``_fwd_kernel*``."""
+    return pallas_kernels(jaxpr, files, "_fwd_kernel")
 
 
 def forward_attention_kernels(jaxpr) -> int:
